@@ -123,9 +123,20 @@ def test_batch_iterator_host_sharding(tmp_path):
                     shard_index=1, num_shards=2),
     )
     assert s0.num_examples + s1.num_examples == full.num_examples
-    rows0 = np.concatenate([b["fare"] for b in s0])
-    rows1 = np.concatenate([b["fare"] for b in s1])
-    assert len(np.intersect1d(rows0, rows1)) <= 1  # disjoint (fp collisions aside)
+
+    def rows(it):
+        # A row's identity is (fare, trip_miles, tips): unique in the
+        # sample, where fare alone repeats (five values occur twice).
+        return {
+            key
+            for b in it
+            for key in zip(b["fare"].tolist(), b["trip_miles"].tolist(),
+                           b["tips"].tolist())
+        }
+
+    rows0, rows1 = rows(s0), rows(s1)
+    assert rows0 and rows1
+    assert not rows0 & rows1  # the shards are disjoint
 
 
 def test_batch_iterator_prefetch_matches_lazy_stream(tmp_path):

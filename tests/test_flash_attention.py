@@ -22,14 +22,11 @@ def _qkv(b=2, l=64, h=2, d=16, seed=0):
 FLASH = functools.partial(flash_attention, block_q=16, block_k=16,
                           interpret=True)
 
-# CPU interpret mode computes exact f32, so parity with dense is tight.  On
-# the real chip (TPP_TEST_REAL_TPU=1) BOTH paths round every matmul through
-# the MXU's bf16 multiply under XLA default precision, and the two different
-# contraction orders legitimately diverge at O(1e-2) — same math, hardware
-# rounding.  Verified on TPU v5 lite: max abs diff 2.5e-2 across the suite.
-_ON_TPU = jax.default_backend() == "tpu"
-_FWD_TOL = dict(rtol=5e-2, atol=5e-2) if _ON_TPU else dict(rtol=2e-5, atol=2e-5)
-_GRAD_TOL = dict(rtol=5e-2, atol=5e-2) if _ON_TPU else dict(rtol=1e-4, atol=1e-4)
+# CPU interpret mode computes exact f32, so parity with dense is tight.
+# (On the chip both paths round every matmul through the MXU's bf16
+# multiply and diverge at O(1e-2); that comparison lives in chip_smoke.py.)
+_FWD_TOL = dict(rtol=2e-5, atol=2e-5)
+_GRAD_TOL = dict(rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("causal", [False, True])
@@ -85,31 +82,6 @@ def test_flash_grad_matches_dense_with_mask(causal):
     gd = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(gf, gd):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), **_GRAD_TOL)
-
-
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="memory analysis needs the real TPU compiler")
-def test_flash_training_memory_beats_dense_at_long_seq():
-    """At L=2048 the flash fwd+bwd path must need less live memory than
-    dense (which materializes [b,h,L,L] scores in both passes)."""
-    b, l, h, d = 2, 2048, 4, 64
-    rng = np.random.default_rng(0)
-    mk = lambda: jnp.asarray(
-        rng.normal(size=(b, l, h, d)).astype(np.float32))
-    q, k, v = mk(), mk(), mk()
-
-    def peak(fn):
-        lowered = jax.jit(
-            lambda q, k, v: jax.grad(
-                lambda q, k, v: jnp.sum(fn(q, k, v) ** 2),
-                argnums=(0, 1, 2),
-            )(q, k, v)
-        ).lower(q, k, v)
-        return lowered.compile().memory_analysis().temp_size_in_bytes
-
-    flash_peak = peak(lambda q, k, v: flash_attention(q, k, v, causal=True))
-    dense_peak = peak(lambda q, k, v: dense_attention(q, k, v, causal=True))
-    assert flash_peak < dense_peak / 2, (flash_peak, dense_peak)
 
 
 def test_flash_bf16_and_jit():

@@ -604,3 +604,53 @@ def test_aot_disabled_falls_back_to_legacy_warm(tmp_path, monkeypatch):
     # The warm still pre-traced every bucket (the legacy guarantee).
     out = loaded.predict({"ids": np.repeat(batch["ids"], 8, axis=0)})
     assert np.asarray(out).shape == (8,)
+
+
+def test_fleet_replicas_compute_on_their_own_devices(tmp_path, monkeypatch):
+    """One replica per device, for real: each replica's predict runs
+    against a params copy resident on ITS device (committed params used to
+    drag every replica's work back to device 0), and a second version's
+    swap-gate warm installs — and serves from — one AOT executable set per
+    replica device with zero post-warm fallbacks."""
+    import shutil
+
+    import jax
+
+    monkeypatch.setenv("TPP_AOT_CACHE", str(tmp_path / "aot-cache"))
+    from tpu_pipelines.serving.fleet import ServingFleet
+
+    uri, _ = _toy_payload(tmp_path)
+    base = tmp_path / "versions"
+    base.mkdir()
+    shutil.copytree(uri, base / "1")
+    fleet = ServingFleet("toy", str(base), replicas=4, max_versions=2,
+                         max_batch_size=4)
+    try:
+        fleet.load_version(str(base / "1"))
+        devices = [r.device for r in fleet.pool.replicas]
+        assert len(set(devices)) == 4 and None not in devices
+        batch = {"ids": np.arange(12, dtype=np.int32).reshape(2, 6)}
+        loaded = fleet.active_loaded()
+        want = np.asarray(loaded.predict(batch))
+        for replica in fleet.pool.replicas:
+            got = replica.submit(batch, 2, timeout_s=60.0)
+            np.testing.assert_allclose(got, want, rtol=1e-6)
+            leaves = jax.tree_util.tree_leaves(
+                loaded.params_on(replica.device)
+            )
+            assert all(x.devices() == {replica.device} for x in leaves)
+
+        # Second version: the canary batch exists now, so the swap gate
+        # AOT-warms every bucket for every replica device.
+        fleet.submit(batch, 2)
+        shutil.copytree(uri, base / "2")
+        fleet.load_version(str(base / "2"))
+        loaded2 = fleet.active_loaded()
+        homes = {key[2] for key in loaded2.aot.entries}
+        assert homes == {None, *devices[1:]}      # device 0 is home
+        for replica in fleet.pool.replicas:
+            got = replica.submit(batch, 2, timeout_s=60.0)
+            np.testing.assert_allclose(got, want, rtol=1e-6)
+        assert loaded2.aot.fallbacks == 0
+    finally:
+        fleet.close()

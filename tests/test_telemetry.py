@@ -322,12 +322,12 @@ def test_train_loop_publishes_gauges_and_nan_watchdog():
 
 
 def test_goodput_tracker_drives_real_library_end_to_end():
-    """Regression for the ``cloud_logger=`` kwarg drift: the recorder and
-    calculator are constructed against the REAL installed
-    ml_goodput_measurement (keyword is ``logger=``), events are recorded,
-    and ``summary()`` must come back non-empty.  Before the fix the
-    constructor TypeError was swallowed by the best-effort except, silently
-    downgrading every run to the host-input-wait proxy."""
+    """Regression for constructor drift: the recorder and calculator are
+    constructed against the REAL installed ml_goodput_measurement (keyword
+    ``cloud_logger=``; reads return ``(entries, cursor)``), events are
+    recorded, and ``summary()`` must come back non-empty.  Only a missing
+    library may downgrade a run to the host-input-wait proxy — a
+    mismatched constructor raises."""
     goodput_lib = pytest.importorskip("ml_goodput_measurement")
     del goodput_lib
 
@@ -403,8 +403,12 @@ def test_goodput_mirror_counts_failures_and_retries_once(tmp_path):
         logger.write_cloud_logging_entry(dict(entry))   # dead: no write
         assert len(path.read_text().splitlines()) == 2
         # Every entry stayed in memory regardless of mirror state.
-        entries = logger.read_cloud_logging_entries()
+        entries, last = logger.read_cloud_logging_entries()
         assert len(entries) == 7
+        # Incremental read from the returned cursor: nothing new.
+        assert logger.read_cloud_logging_entries(
+            last_entry_info=last
+        ) == ([], (None, None))
     finally:
         if hasattr(goodput_mod, "open"):
             del goodput_mod.open

@@ -1,0 +1,374 @@
+"""Compile the main path's kernels and the BERT-base train step for a TPU
+v5e that is DESCRIBED, not attached (on-chip-measurement guide, section 2).
+
+What interpret-mode tests cannot see — a tiling the chip's compiler
+refuses, a kernel that needs more fast memory than it may use, a program
+that does not fit 16 GiB, a collective the compiler moved — shows here at
+no chip time.  A compile is not a run: results and times come from
+``chip_smoke.py`` on the chip.
+
+The topology is described inside a module-scoped fixture, never while a
+module is imported: only one process may hold the TPU library, and under
+``pytest -n`` every worker imports this file while only the worker that is
+given it runs its tests.  All such tests stay in THIS file for the same
+reason.  On the CPU backend the autotune table is keyed ``cpu`` and misses,
+so the kernels are handed the blocks the committed table holds for the
+described chip's ``device_kind`` — what a run there would pick.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else libtpu logs to /tmp
+
+HBM_BYTES = 16 * 1024**3   # one v5e chip
+V5E_KIND = "TPU v5 lite"   # jax's device_kind for v5e: the table's key
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip (it warns and recompiles).
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    from tpu_pipelines.parallel.mesh import MeshConfig, make_mesh
+
+    return make_mesh(MeshConfig(), devices=list(topo.devices))
+
+
+def _table_blocks(op, b, h, l, d, causal):
+    """(block_q, block_k) the committed table holds for the v5e, or None
+    (the kernel then takes its defaults, as a run on the chip would)."""
+    from tpu_pipelines.ops import autotune
+
+    key = autotune.make_key(
+        op, b, h, l, d, "bfloat16", causal, device_kind=V5E_KIND
+    )
+    entry = autotune._lookup_entry(autotune.key_id(key), V5E_KIND)
+    return None if entry is None else (entry["block_q"], entry["block_k"])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _fits(compiled):
+    m = compiled.memory_analysis()
+    need = (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+    assert need < HBM_BYTES, m
+    return m
+
+
+# ------------------------------------------------------------------ kernels
+
+
+FLASH_CASES = [
+    # (id, batch, seq, heads, head_dim, causal, masked)
+    ("workhorse_2048", 8, 2048, 12, 64, False, False),
+    ("long_8192", 8, 8192, 12, 64, False, False),
+    ("causal_2048", 8, 2048, 12, 64, True, False),
+    ("bert_masked_128", 256, 128, 12, 64, False, True),
+]
+
+
+@pytest.mark.parametrize(
+    "b,l,h,d,causal,masked",
+    [c[1:] for c in FLASH_CASES], ids=[c[0] for c in FLASH_CASES],
+)
+def test_flash_fwd_bwd_compiles_for_v5e(one_chip, b, l, h, d, causal, masked):
+    from tpu_pipelines.ops.flash_attention import flash_attention
+
+    fwd = _table_blocks("flash_fwd", b, h, l, d, causal)
+    bwd = _table_blocks("flash_bwd", b, h, l, d, causal)
+    if l == 8192:
+        assert fwd == bwd == (256, 256)  # the table's pick is what compiles
+    kw = {}
+    if fwd:
+        kw.update(block_q=fwd[0], block_k=fwd[1])
+    if bwd:
+        kw.update(bwd_block_q=bwd[0], bwd_block_k=bwd[1])
+
+    def loss(q, k, v, mask):
+        out = flash_attention(
+            q, k, v, causal=causal, kv_mask=mask if masked else None,
+            interpret=False, **kw,
+        )
+        return out.astype(jnp.float32).sum()
+
+    qkv = _sds((b, l, h, d), jnp.bfloat16, one_chip)
+    mask = _sds((b, l), jnp.int32, one_chip)
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+        qkv, qkv, qkv, mask
+    )
+    assert "tpu_custom_call" in lowered.as_text()
+    _fits(lowered.compile())
+
+
+def test_flash_decode_compiles_for_v5e(one_chip):
+    from tpu_pipelines.ops.flash_attention import flash_decode_attention
+
+    b, l, h, d = 8, 2048, 8, 64
+    blocks = _table_blocks("flash_decode", b, h, l, d, False)
+    kw = {"block_k": blocks[1]} if blocks else {}
+    q = _sds((b, 1, h, d), jnp.bfloat16, one_chip)
+    kv = _sds((b, l, h, d), jnp.bfloat16, one_chip)
+    mask = _sds((b, l), jnp.int32, one_chip)
+    lowered = jax.jit(
+        lambda q, k, v, m: flash_decode_attention(
+            q, k, v, kv_mask=m, interpret=False, **kw
+        )
+    ).lower(q, kv, kv, mask)
+    assert "tpu_custom_call" in lowered.as_text()
+    _fits(lowered.compile())
+
+
+def test_flash_training_memory_beats_dense_at_long_seq(one_chip):
+    """At L=2048 the flash fwd+bwd path must need less live memory than
+    dense (which materializes [b,h,L,L] scores in both passes) — read from
+    the TPU compiler's own memory analysis."""
+    from tpu_pipelines.ops.flash_attention import flash_attention
+    from tpu_pipelines.parallel.ring_attention import dense_attention
+
+    x = _sds((2, 2048, 4, 64), jnp.float32, one_chip)
+
+    def temp(fn):
+        g = jax.grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v) ** 2), argnums=(0, 1, 2)
+        )
+        m = jax.jit(g).lower(x, x, x).compile().memory_analysis()
+        return m.temp_size_in_bytes
+
+    flash = temp(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=False)
+    )
+    dense = temp(lambda q, k, v: dense_attention(q, k, v, causal=True))
+    assert flash < dense / 2, (flash, dense)
+
+
+# ------------------------------------------------------- BERT-base train step
+
+
+BERT_BATCH, BERT_SEQ = 256, 128
+
+
+def _bert_step(mesh=None, dp_mode=""):
+    """The step the Trainer builds for examples/bert at bert-base width:
+    bert_trainer_module's loss, adamw, the ``rbg`` training key, state
+    donated — from shapes only.  ``dp_mode`` routes the gradient exchange
+    through ``_make_dp_forward_backward`` exactly as ``train_loop`` does."""
+    from tpu_pipelines.models.bert import DEFAULT_HPARAMS, build_bert_model
+    from tpu_pipelines.parallel.partition import fsdp_param_partition
+    from tpu_pipelines.trainer.train_loop import (
+        TrainState,
+        _make_dp_forward_backward,
+    )
+
+    hp = {**DEFAULT_HPARAMS, "max_len": BERT_SEQ}
+    assert hp["vocab_size"] == 30528 and hp["d_model"] == 768
+    model = build_bert_model(hp)
+    optimizer = optax.adamw(2e-5)
+    batch = {
+        "input_ids": np.zeros((BERT_BATCH, BERT_SEQ), np.int32),
+        "attention_mask": np.ones((BERT_BATCH, BERT_SEQ), np.int32),
+        "label": np.zeros((BERT_BATCH,), np.int32),
+    }
+
+    def features(b):
+        return {k: v for k, v in b.items() if k != "label"}
+
+    def loss_fn(params, b, rng):
+        logits = model.apply(
+            {"params": params}, features(b),
+            deterministic=False, rngs={"dropout": rng},
+        )
+        labels = jnp.asarray(b["label"], jnp.int32)
+        loss = optax.softmax_cross_entropy_with_integer_labels(
+            logits, labels
+        ).mean()
+        return loss, {"accuracy": jnp.mean(jnp.argmax(logits, -1) == labels)}
+
+    def make_state():
+        rng = jax.random.key(0, impl="rbg")
+        params = model.init(rng, features(batch))["params"]
+        return TrainState.create(params, optimizer, rng)
+
+    state = jax.eval_shape(make_state)
+    n_params = sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(state.params)
+    )
+    assert 105e6 < n_params < 115e6, n_params  # bert-base: the proof of width
+
+    fsdp_specs = None
+    dp_fb = None
+    if dp_mode:
+        if dp_mode == "fsdp":
+            fsdp_specs = fsdp_param_partition(state.params, mesh)
+        dp_fb = _make_dp_forward_backward(
+            loss_fn, mesh, dp_mode, buckets=2,
+            grad_blocks=mesh.shape["data"], fsdp_specs=fsdp_specs,
+        )
+
+    def step_fn(st, b):
+        step_rng = jax.random.fold_in(st.rng, st.step)
+        if dp_fb is not None:
+            loss, metrics, grads, _ = dp_fb(st.params, None, b, step_rng)
+        else:
+            (loss, metrics), grads = jax.value_and_grad(
+                loss_fn, has_aux=True
+            )(st.params, b, step_rng)
+        updates, opt_state = optimizer.update(grads, st.opt_state, st.params)
+        return st.replace(
+            step=st.step + 1,
+            params=optax.apply_updates(st.params, updates),
+            opt_state=opt_state,
+        ), {"loss": loss, **metrics}
+
+    return step_fn, state, batch, fsdp_specs
+
+
+def test_bert_base_train_step_fits_one_v5e(one_chip):
+    step_fn, state, batch, _ = _bert_step()
+    state_abs = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip), state
+    )
+    batch_abs = {
+        k: _sds(v.shape, v.dtype, one_chip) for k, v in batch.items()
+    }
+    compiled = jax.jit(step_fn, donate_argnums=(0,)).lower(
+        state_abs, batch_abs
+    ).compile()
+    m = _fits(compiled)
+    # params + two adamw moments, f32: the resident state the step updates
+    # in place (donated, so it is aliased and not counted twice).
+    assert m.argument_size_in_bytes > 3 * 4 * 105e6
+    assert m.alias_size_in_bytes > 3 * 4 * 105e6
+
+
+def _window_text(mesh4, dp_mode):
+    """Compiled text of a 2-step scan window of the BERT-base step on the
+    described 2x2, sharded the way ``train_loop`` shards it."""
+    step_fn, state, batch, fsdp_specs = _bert_step(mesh4, dp_mode)
+    rep = NamedSharding(mesh4, P())
+    if fsdp_specs is not None:
+        from tpu_pipelines.trainer.train_loop import _opt_state_sharding
+
+        p_shard = jax.tree_util.tree_map(
+            lambda spec: NamedSharding(mesh4, spec), fsdp_specs,
+            is_leaf=lambda x: isinstance(x, P),
+        )
+        state_shard = state.replace(
+            step=rep, rng=rep, params=p_shard,
+            opt_state=_opt_state_sharding(
+                state.opt_state, state.params, p_shard, mesh4
+            ),
+        )
+    else:
+        state_shard = jax.tree.map(lambda _: rep, state)
+    state_abs = jax.tree.map(
+        lambda x, s: _sds(x.shape, x.dtype, s), state, state_shard
+    )
+    win_abs = {
+        k: _sds(
+            (2,) + v.shape, v.dtype,
+            NamedSharding(mesh4, P(None, "data", *[None] * (v.ndim - 1))),
+        )
+        for k, v in batch.items()
+    }
+    compiled = jax.jit(
+        lambda st, bats: jax.lax.scan(step_fn, st, bats),
+        donate_argnums=(0,),
+    ).lower(state_abs, win_abs).compile()
+    return compiled, compiled.as_text()
+
+
+def _scan_body(text):
+    """The while-body computation that holds the backward's matmuls."""
+    bodies = [
+        body for header, body in _hlo_computations(text)
+        if "fusion(" in body and re.search(r"all-(reduce|gather)", body)
+    ]
+    assert bodies, "no computation with collectives in the window"
+    return max(bodies, key=len)
+
+
+def _hlo_computations(text):
+    blocks, cur, header = [], [], None
+    for line in text.splitlines():
+        if header is None:
+            if line.rstrip().endswith("{"):
+                header, cur = line, []
+        elif line.startswith("}"):
+            blocks.append((header, "\n".join(cur)))
+            header = None
+        else:
+            cur.append(line)
+    return blocks
+
+
+def test_bucketed_allreduce_stays_inside_backward_on_v5e_2x2(mesh4):
+    """What tests/test_multichip_window.py cannot decide on a toy (whose
+    few bytes any compiler merges into one all-reduce): at BERT-base size
+    the TPU compiler keeps SEVERAL gradient all-reduces inside the scan
+    body, placed between the backward's fusions — not one collective
+    hoisted to the window boundary.  Whether they hide behind compute is a
+    time, and only a trace on four chips can say."""
+    compiled, text = _window_text(mesh4, "psum_bucketed")
+    _fits(compiled)
+    assert "while(" in text or "while (" in text
+    body = _scan_body(text).splitlines()
+    reduces = [
+        i for i, l in enumerate(body)
+        if re.search(r"all-reduce(-start)?\(", l)
+    ]
+    # >= the 2 requested buckets (the compiler may split them further).
+    assert len(reduces) >= 2, reduces
+    between = body[reduces[0]:reduces[-1]]
+    assert sum(" fusion(" in l for l in between) >= 10
+    # Replicated DP: every device holds all of params + both moments.
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes > 3 * 4 * 105e6
+
+
+def test_fsdp_window_shards_state_on_v5e_2x2(mesh4):
+    """fsdp on the described 2x2: per-device resident state is about a
+    quarter of the replicated step's, and the scan body gathers params and
+    reduce-scatters grads."""
+    compiled, text = _window_text(mesh4, "fsdp")
+    m = _fits(compiled)
+    full = 3 * 4 * 110e6
+    assert m.argument_size_in_bytes < 0.3 * full, m
+    body = _scan_body(text)
+    assert "all-gather" in body
+    assert "reduce-scatter" in body or "all-reduce" in body

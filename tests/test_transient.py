@@ -1,10 +1,10 @@
-"""Two-tier transient-error classification (round-4 advisor finding).
+"""Two-tier transient-error classification.
 
 A lone broad word ('internal', 'connection', 'socket', 'deadline') also
 appears in deterministic failures — an XLA ``INTERNAL: ...`` compile bug
 must not trigger the Evaluator's retry + recursive batch-split, which
 recompiles at every new shape and burns chip time on an error that can
-never succeed.  Specific tunnel-flake signatures stay one-hit transient.
+never succeed.  Specific transport-failure signatures stay one-hit transient.
 """
 
 import pytest
@@ -13,10 +13,10 @@ from tpu_pipelines.utils.transient import is_transient_error
 
 
 @pytest.mark.parametrize("msg", [
-    # The canonical round-2 evidence-killer, in full and in parts.
-    "INTERNAL: remote_compile: read body: connection reset",
-    "remote_compile failed",
-    "failed to read body",
+    # Transport failures, each by its own specific signature.
+    "INTERNAL: stream closed: connection reset",
+    "socket closed while sending the request",
+    "socket hang up",
     "DEADLINE_EXCEEDED: deadline exceeded waiting for response",
     "UNAVAILABLE: service is temporarily unavailable",
     "ConnectionResetError: [Errno 104] connection reset by peer",
@@ -42,7 +42,7 @@ def test_transient_signatures(msg):
     "ValueError: shapes do not match",
     "ImportError: no module named missing_dep",
     # OOM is explicitly never transient, even with a flake signature.
-    "RESOURCE_EXHAUSTED: remote_compile: out of memory",
+    "RESOURCE_EXHAUSTED: connection reset: out of memory",
 ])
 def test_deterministic_not_transient(msg):
     assert not is_transient_error(msg)

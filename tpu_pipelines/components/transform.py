@@ -11,7 +11,6 @@ training and serving, by construction.
 
 from __future__ import annotations
 
-import logging
 import os
 import time
 import shutil
@@ -25,8 +24,6 @@ from tpu_pipelines.transform.graph import TransformGraph
 from tpu_pipelines.utils.module_loader import load_fn
 
 MODULE_COPY = "module_file.py"
-
-log = logging.getLogger(__name__)
 
 
 @component(
@@ -111,19 +108,14 @@ def Transform(ctx):
     def materialize_chunk(raw):
         nonlocal on_device
         if on_device:
-            try:
-                cols = graph.apply_device(raw)
-            except Exception as e:  # noqa: BLE001 — host numpy is authoritative
-                log.warning(
-                    "device materialization failed (%s); using host numpy", e
-                )
+            # A device error fails the node: the only host materialization
+            # a device run accepts is the documented cannot-jit case.
+            cols = graph.apply_device(raw)
+            if graph.device_apply_active is False:
+                # apply_device decided the graph can't jit (string
+                # interface) and used the host path — record the truth.
                 on_device = False
-            else:
-                if graph.device_apply_active is False:
-                    # apply_device decided the graph can't jit (string
-                    # interface) and used the host path — record the truth.
-                    on_device = False
-                return cols
+            return cols
         return graph.apply_host(raw)
 
     def materialize_shard(task):
@@ -223,7 +215,7 @@ def Transform(ctx):
         ),
         # Input shard layout per split == output layout (shard i -> shard i).
         "data_shards": shard_counts,
-        # True = every chunk went through the jitted device path (a mid-run
-        # fallback to host numpy flips this off).
+        # True = every chunk went through the jitted device path; False on
+        # a device backend only when the graph cannot jit (string interface).
         "materialize_on_device": bool(on_device),
     }

@@ -16,8 +16,11 @@ Trial execution modes (the Katib parallel-pod equivalent):
     JSON spec, up to ``parallel_trials`` concurrently.  A trial that OOMs or
     crashes fails *that trial* — the component keeps going and picks the best
     of the survivors (it only fails when every trial failed).  Concurrency is
-    host-level: on a single TPU chip keep 1 (or give trials
-    ``custom_config`` platform overrides); on CPU or across pods it overlaps.
+    host-level, and a chip belongs to ONE process: these modes need a
+    parent that holds no accelerator (each child then opens the chip
+    itself, one at a time), and they refuse with the reason — never fall
+    to CPU trials — once this process has opened the chip.  On CPU or
+    across pods they overlap freely.
   - cluster fan-out (``trial_shards=k``): the TPUJobRunner emits one pod per
     shard running ``tuner_trial shard --shard i/k`` (candidates[i::k]) into a
     shared ``--shard-dir``, then the Tuner node itself runs with
@@ -46,6 +49,7 @@ from tpu_pipelines.trainer.fn_args import (
     ctx_data_uris,
     make_fn_args,
 )
+from tpu_pipelines.utils.chip import held_accelerator
 from tpu_pipelines.utils.module_loader import load_fn, load_module
 
 logger = logging.getLogger(__name__)
@@ -231,9 +235,29 @@ def _run_trials_inprocess(
     return outcomes
 
 
+def _refuse_child_under_held_chip() -> None:
+    """Subprocess trial modes start one python per trial.  A chip belongs
+    to one process, so once THIS process holds it (the runner trained or
+    transformed on it earlier) a trial child cannot open it — and must not
+    quietly train on the CPU instead.  Fail with the reason."""
+    platform = held_accelerator()
+    if platform:
+        raise RuntimeError(
+            "Tuner: subprocess trial modes (parallel_trials>1 / "
+            f"isolate_trials) start one process per trial, but this process "
+            f"already holds the {platform} and a chip belongs to one process "
+            "at a time — a trial child could not open it.  Use in-process "
+            "trials (parallel_trials=1, isolate_trials=False), or run the "
+            "trials where the parent holds no device: the cluster fan-out "
+            "(trial_shards) or a runner process that has not touched the "
+            "chip before the Tuner node (docs/components/tuner.md)."
+        )
+
+
 def _run_trial_subprocess(
     trial: int, cand: Dict[str, Any], module_file: str, fn_args: FnArgs
 ) -> Dict[str, Any]:
+    _refuse_child_under_held_chip()
     trial_dir = os.path.dirname(fn_args.serving_model_dir)
     os.makedirs(trial_dir, exist_ok=True)
     spec_path = os.path.join(trial_dir, SPEC_FILE)

@@ -11,7 +11,9 @@ sharding component would otherwise re-make:
     (capped at ``MAX_DEFAULT_SHARDS`` — beyond that, per-file overhead beats
     the parallelism on any realistic host).
   * **How to run per-shard work?**  ``map_shards`` (process pool — the
-    CPU-bound stats/ingest reductions hold the GIL) and ``thread_map``
+    CPU-bound stats/ingest reductions hold the GIL; threads once this
+    process has loaded an accelerator runtime, which must not be forked)
+    and ``thread_map``
     (thread pool — Parquet encode/decode and large-array numpy release the
     GIL, and the task closures are not picklable).
 
@@ -49,6 +51,7 @@ from tpu_pipelines.robustness import (
     record_retry,
 )
 from tpu_pipelines.testing import faults as _faults
+from tpu_pipelines.utils.chip import held_accelerator
 
 log = logging.getLogger("tpu_pipelines.data.shard_plan")
 
@@ -316,6 +319,16 @@ def map_shards_resilient(
     policy = retry_policy or RetryPolicy.from_env() or NO_RETRY
     workers = _pool_workers(n_tasks, workers)
     mode = os.environ.get(ENV_POOL, "process").strip() or "process"
+    if mode == "process" and held_accelerator():
+        # One process per chip, and no fork of the one that holds it: the
+        # accelerator runtime runs threads of its own, and a forked child
+        # inherits their locks mid-flight and the chip's open handles.
+        # Host-only shard work rides threads from here on.
+        log.info(
+            "%s: accelerator runtime is loaded in this process; "
+            "%d shard(s) run on threads, not a fork pool", label, n_tasks,
+        )
+        mode = "thread"
     call = (
         fn if isinstance(fn, _TracedShardFn)
         else _TracedShardFn(fn, label, mode)
@@ -490,9 +503,9 @@ def _drain_process_pool(
     replaces it).  Completed/failed shards settle; shards whose futures
     report BrokenProcessPool take a death mark and requeue."""
     try:
-        # fork, explicitly: spawn would re-import the full framework (and
-        # this environment preloads jax into every interpreter) per
-        # worker — seconds of startup against millisecond tasks.
+        # fork, explicitly: spawn would re-import the full framework per
+        # worker — seconds of startup against millisecond tasks.  Only
+        # reached while this process holds no accelerator (utils/chip.py).
         ctx = multiprocessing.get_context("fork")
         pool = ProcessPoolExecutor(
             max_workers=min(workers, len(batch)), mp_context=ctx

@@ -34,21 +34,18 @@ Dtype = Any
 # (ops/autotune.py) stores a per-device flash-vs-dense crossover sequence
 # length recorded by the bench flash_probe sweep — dense below it, flash
 # at/above it.  With no recorded crossover the rule degrades to the
-# feasibility estimate alone, which every probe so far justified: on v5e
-# (BENCH_R4/R5 flash_probe, BERT-base geometry b=8 h=12 d=64) dense is
-# faster than the untuned Pallas kernel across the whole band where its
-# O(L^2) score temporaries fit in HBM — ~30% faster at L=128, ~25% at
-# L=2048 — because XLA fuses the fwd score/softmax chain well.  Flash's
-# unconditional win is FEASIBILITY: at L=8192 the dense fwd+bwd wants
-# 38.7 GB of temporaries (16x the 2.42 GB measured at 2048 — it scales
-# with L^2) and cannot compile on a 16 GB chip, while flash runs in
+# feasibility estimate alone: dense wherever its O(L^2) score temporaries
+# fit in HBM (XLA fuses the fwd score/softmax chain well; which of the two
+# is faster on the chip below the crossover is in PERF.md, or "not
+# measured").  Flash's unconditional win is FEASIBILITY: the dense
+# temporaries scale with L^2 — at L=8192 (b=8 h=12 d=64) the fwd+bwd wants
+# tens of GB and cannot compile on a 16 GB chip, while flash runs in
 # O(block^2) VMEM scratch.  The feasibility estimate (the OOM guard):
 #
 #   temp ~= DENSE_ATTN_TEMP_FACTOR * B * H * Lq * Lkv * itemsize
 #
-# FACTOR=3 calibrates the estimate to XLA's measured allocation (805 MB of
-# raw [B,H,L,L] bf16 scores at the probe geometry vs 2.42 GB measured:
-# score + softmax-prob + dscore buffers are live at the backward peak).
+# FACTOR=3: score + softmax-prob + dscore buffers, each [B,H,L,L], are
+# live at the backward peak.
 DENSE_ATTN_TEMP_FACTOR = 3.0
 # Dense is chosen while its temp estimate stays under this fraction of
 # device memory — headroom for params, optimizer state and activations.
@@ -64,19 +61,23 @@ RING_MIN_SEQ = 2048
 def _device_memory_bytes() -> int:
     """Per-device accelerator memory, for the auto attention choice.
 
-    TPP_HBM_BYTES overrides; otherwise the backend's own bytes_limit;
-    16 GiB (v5e) as the fallback when the backend reports nothing (CPU
-    tests) — the decision only needs the right order of magnitude."""
+    TPP_HBM_BYTES overrides; otherwise the backend's own bytes_limit.
+    Only the CPU backend (tests, dry runs), which reports none, is given
+    a stand-in — 16 GiB, one v5e chip, so CPU runs take the branches a
+    chip run would.  An accelerator that reports no limit raises: a
+    guessed size would pick dense or flash for memory it does not have."""
     env = os.environ.get("TPP_HBM_BYTES")
     if env:
         return int(env)
-    try:
-        stats = jax.devices()[0].memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return 16 * 1024**3
+    dev = jax.devices()[0]
+    stats = dev.memory_stats()
+    if stats and stats.get("bytes_limit"):
+        return int(stats["bytes_limit"])
+    if dev.platform == "cpu":
+        return 16 * 1024**3
+    raise RuntimeError(
+        f"{dev.device_kind!r} reports no memory limit; set TPP_HBM_BYTES"
+    )
 
 
 def dense_attn_expected_temp_bytes(
@@ -369,9 +370,8 @@ class MultiHeadAttention(nn.Module):
         table, written by the bench flash_probe sweep), flash at/above
         it, and always flash when dense's O(L²) score temporaries cannot
         fit (dense_attn_fits stays as the OOM guard).  With no recorded
-        crossover: dense wherever it fits — the measured default on v5e
-        (BENCH_R4/R5 flash_probe: dense ~25-30% faster at L=128-2048;
-        flash's win is running at L=8192+ where dense cannot compile).
+        crossover: dense wherever it fits (flash's unconditional win is
+        running at L=8192+, where dense cannot compile).
     Ring/ulysses/flash require self-attention without an additive bias;
     cross attention and biased attention (T5 relative positions) always
     take the dense path.

@@ -1,11 +1,10 @@
 """Kernel autotuning: measured block configs + the flash/dense crossover.
 
-BENCH_R5's ``flash_probe`` showed the Pallas flash kernel *losing* to dense
-attention at the workhorse shape (b=8 h=12 L=2048: 28.0 ms vs 24.6 ms)
-because ``flash_attention``'s hard-coded ``block_q=128``/``block_k=128``
-were never tuned per shape or device — and ``attn_impl="auto"`` picked
+``flash_attention``'s hard-coded ``block_q=128``/``block_k=128`` were
+never tuned per shape or device, and ``attn_impl="auto"`` picked
 flash-vs-dense on memory feasibility alone, never consulting a
-measurement.  This module closes both gaps:
+measurement.  This module closes both gaps (what either is worth on the
+chip is in PERF.md; until it is measured there, it is "not measured"):
 
   * per ``(op, shape-bucket, dtype, causal, device_kind)`` key, sweep a
     candidate grid of ``(block_q, block_k)`` configurations (constrained
@@ -25,11 +24,11 @@ Storage (multi-process safe — PR 7's ``atomic_write_json`` under a
 canonical ``fingerprint_json`` so two fresh processes derive the SAME key
 for the same shape):
 
-  * user cache:  ``~/.cache/tpu_pipelines/autotune/<device_kind>.json``
-    (``TPP_AUTOTUNE_CACHE`` overrides the directory), written by sweeps;
+  * user cache:  ``<cache root>/autotune/<device_kind>.json`` (the root
+    of ``utils/compile_cache.py``; ``TPP_AUTOTUNE_CACHE`` overrides the
+    directory), written by sweeps;
   * committed table: ``tpu_pipelines/ops/autotune_table.json`` — winners
-    promoted into the repo so fresh checkouts start tuned (commit
-    workflow in PERFORMANCE.md §"Attention crossover").  User-cache
+    promoted into the repo so fresh checkouts start tuned.  User-cache
     entries shadow committed ones.
 
 ``TPP_AUTOTUNE`` controls behavior:
@@ -59,6 +58,7 @@ from tpu_pipelines.robustness.atomic import (
     atomic_write_json,
     load_json_tolerant,
 )
+from tpu_pipelines.utils.compile_cache import cache_root
 from tpu_pipelines.utils.fingerprint import fingerprint_json
 
 ENV_MODE = "TPP_AUTOTUNE"
@@ -165,9 +165,7 @@ def cache_dir() -> str:
     env = os.environ.get(ENV_CACHE_DIR)
     if env:
         return env
-    return os.path.join(
-        os.path.expanduser("~"), ".cache", "tpu_pipelines", "autotune"
-    )
+    return os.path.join(cache_root(), "autotune")
 
 
 def cache_path(device_kind: Optional[str] = None) -> str:
@@ -484,6 +482,28 @@ def _sweep_iters() -> int:
         return 10
 
 
+def _best_of(
+    op: str, swept: List[Dict[str, Any]], interpret: bool
+) -> Optional[Dict[str, Any]]:
+    """Fastest timed candidate.  One refused tiling is what a sweep is
+    for; EVERY candidate refused by the device's compiler means the kernel
+    does not build there, and that raises rather than falling to the
+    untuned defaults (which would fail the same way, later and further
+    from the cause)."""
+    timed = [r for r in swept if "ms" in r]
+    if timed:
+        return min(timed, key=lambda r: r["ms"])
+    if swept and not interpret:
+        raise RuntimeError(
+            f"autotune: no {op} candidate compiled on this device: "
+            + "; ".join(
+                f"{r['block_q']}x{r['block_k']}: {r.get('error')}"
+                for r in swept
+            )
+        )
+    return None
+
+
 def sweep_flash(
     batch: int,
     heads: int,
@@ -554,9 +574,7 @@ def sweep_flash(
             except Exception as e:  # invalid tiling for this backend
                 row["error"] = str(e).splitlines()[0][:160]
             swept.append(row)
-        timed = [r for r in swept if "ms" in r]
-        best = min(timed, key=lambda r: r["ms"]) if timed else None
-        results[op] = {"best": best, "swept": swept}
+        results[op] = {"best": _best_of(op, swept, interpret), "swept": swept}
     return results
 
 
@@ -618,9 +636,9 @@ def sweep_decode(
         except Exception as e:  # invalid tiling for this backend
             row["error"] = str(e).splitlines()[0][:160]
         swept.append(row)
-    timed = [r for r in swept if "ms" in r]
-    best = min(timed, key=lambda r: r["ms"]) if timed else None
-    return {"flash_decode": {"best": best, "swept": swept}}
+    return {"flash_decode": {
+        "best": _best_of("flash_decode", swept, interpret), "swept": swept,
+    }}
 
 
 # ---------------------------------------------------------------- dispatch

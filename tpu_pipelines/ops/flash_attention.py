@@ -35,6 +35,16 @@ NEG_INF = -1e30
 LANES = 128
 
 
+def _resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``interpret=None`` means the Pallas interpreter on the CPU backend
+    ONLY (tests, dry runs).  On a TPU — and on any backend that is not the
+    CPU — the kernels compile for real, so a kernel the chip's compiler
+    refuses raises instead of quietly running interpreted."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
+
+
 def _block_mask(kmask, qi, kj, block_q, block_k, causal):
     """[bq, bk] bool: allowed (key-visible and causal-visible) positions."""
     allowed = jnp.broadcast_to(kmask[None, :] > 0, (block_q, block_k))
@@ -393,8 +403,7 @@ def flash_decode_attention(
 
     from tpu_pipelines.ops import autotune
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(interpret)
     b, l, h, d = k.shape
     itemsize = jnp.dtype(q.dtype).itemsize
     concrete = not isinstance(q, jax.core.Tracer)
@@ -502,8 +511,9 @@ def flash_attention(
 
     Numerically equals ``dense_attention`` (same masking semantics, modulo
     rows whose whole allowed key set is empty: dense leaves them uniform,
-    flash leaves them zero).  ``interpret=None`` auto-selects the Pallas
-    interpreter off-TPU (CPU tests/dry runs).
+    flash leaves them zero).  ``interpret=None`` selects the Pallas interpreter
+    on the CPU backend only (tests/dry runs); anywhere else the kernels
+    compile for the device and a refusal raises.
 
     Block selection (ops/autotune.py): explicit ``block_q=``/``block_k=``
     (and ``bwd_block_q=``/``bwd_block_k=`` for the backward kernels, which
@@ -521,8 +531,7 @@ def flash_attention(
     """
     from tpu_pipelines.ops import autotune
 
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _resolve_interpret(interpret)
     b, l, h, d = q.shape
     itemsize = jnp.dtype(q.dtype).itemsize
     # Timing inside a jit trace would hang the trace on real device work:

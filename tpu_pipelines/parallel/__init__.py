@@ -6,7 +6,6 @@ TPU-native replacement for the reference's tf.distribute + NCCL stack
 ICI/DCN.  No user-level collective library exists or is needed.
 """
 
-from tpu_pipelines.parallel.compat import shard_map  # noqa: F401
 from tpu_pipelines.parallel.mesh import (  # noqa: F401
     VALID_MASK_KEY,
     MeshConfig,

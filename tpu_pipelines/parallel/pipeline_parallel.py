@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpu_pipelines.parallel.compat import shard_map
 
 # stage_fn(stage_params, activation [mb, ...]) -> activation [mb, ...]
 StageFn = Callable[[Any, jax.Array], jax.Array]
@@ -123,7 +122,7 @@ def gpipe(
             f"{batch_axis}={dp}"
         )
     micro_spec = P(None, batch_axis)
-    stacked = shard_map(
+    stacked = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(stage_spec, micro_spec),

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tpu_pipelines.parallel.compat import shard_map
 
 NEG_INF = -1e30  # finite mask value: exp underflows to 0, no NaN plumbing
 
@@ -166,14 +165,14 @@ def ring_attention(
     qkv_spec = P(batch_axis, axis, head_axis, None)
     mask_spec = P(batch_axis, axis)
     if has_mask:
-        return shard_map(
+        return jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
             out_specs=qkv_spec,
             check_vma=False,
         )(q, k, v, kv_mask)
-    return shard_map(
+    return jax.shard_map(
         lambda q, k, v: local_fn(q, k, v, None),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec),
@@ -238,14 +237,14 @@ def ulysses_attention(
     qkv_spec = P(batch_axis, axis, head_axis, None)
     mask_spec = P(batch_axis, axis)
     if kv_mask is not None:
-        return shard_map(
+        return jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
             out_specs=qkv_spec,
             check_vma=False,
         )(q, k, v, kv_mask)
-    return shard_map(
+    return jax.shard_map(
         lambda q, k, v: local_fn(q, k, v, None),
         mesh=mesh,
         in_specs=(qkv_spec, qkv_spec, qkv_spec),

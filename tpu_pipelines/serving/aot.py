@@ -27,8 +27,8 @@ Knobs:
   TPP_AOT=0          disable the executable table AND the disk cache
                      (warmup degrades to the legacy once-per-bucket
                      trace — still no mid-traffic compiles)
-  TPP_AOT_CACHE=dir  cache location (default
-                     ~/.cache/tpu_pipelines/aot)
+  TPP_AOT_CACHE=dir  cache location (default ``<cache root>/aot``, the
+                     root of utils/compile_cache.py)
 
 Cache entries are written atomically (tmp + rename) and read
 tolerantly: a torn/corrupt/version-skewed entry is a cache miss that
@@ -46,6 +46,7 @@ from typing import Any, Dict, Optional
 
 import numpy as np
 
+from tpu_pipelines.utils.compile_cache import cache_root
 from tpu_pipelines.utils.fingerprint import fingerprint_dir, fingerprint_json
 
 log = logging.getLogger("tpu_pipelines.serving")
@@ -68,7 +69,7 @@ def aot_enabled() -> bool:
 
 def cache_dir() -> str:
     return os.environ.get(ENV_AOT_CACHE, "").strip() or os.path.join(
-        os.path.expanduser("~"), ".cache", "tpu_pipelines", "aot"
+        cache_root(), "aot"
     )
 
 
@@ -114,8 +115,27 @@ def _cache_path(key: str) -> str:
     return os.path.join(cache_dir(), f"{key}.aotexe")
 
 
-def _load_cached(path: str) -> Optional[Any]:
-    """Deserialize a cached executable; None on any failure (miss)."""
+def _execution_devices(params: Any) -> Optional[list]:
+    """The devices an executable lowered against ``params`` runs on, in
+    assignment order — what ``deserialize_and_load`` must be told, or it
+    loads for EVERY device of the backend and the first call on a
+    multi-device host dies on a shard-count mismatch."""
+    import jax
+
+    for leaf in jax.tree_util.tree_leaves(params):
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is None:
+            continue
+        mesh = getattr(sharding, "mesh", None)
+        if mesh is not None:
+            return list(mesh.devices.flat)
+        return sorted(sharding.device_set, key=lambda d: d.id)
+    return None
+
+
+def _load_cached(path: str, devices: Optional[list]) -> Optional[Any]:
+    """Deserialize a cached executable onto ``devices``; None on any
+    failure (miss)."""
     if not os.path.exists(path):
         return None
     try:
@@ -124,7 +144,7 @@ def _load_cached(path: str) -> Optional[Any]:
         with open(path, "rb") as f:
             payload, in_tree, out_tree = pickle.load(f)
         return serialize_executable.deserialize_and_load(
-            payload, in_tree, out_tree
+            payload, in_tree, out_tree, execution_devices=devices
         )
     except Exception as e:  # noqa: BLE001 — torn/skewed entry = miss
         log.warning("aot: unreadable cache entry %s (%s)", path, e)
@@ -214,6 +234,7 @@ def warm_loaded(
     *,
     raw: bool = True,
     use_cache: Optional[bool] = None,
+    devices: Optional[list] = None,
 ) -> Dict[str, Any]:
     """AOT-compile every padded bucket shape for a loaded payload.
 
@@ -225,12 +246,17 @@ def warm_loaded(
     padded batch signature the replica batchers will pose, and in the
     disk cache for the next process.
 
+    ``devices``: the devices the payload will be asked to compute on (a
+    fleet passes one per replica); every bucket is compiled — or loaded
+    from the cache — for each, against the params copy resident there
+    (``loaded.params_on``).  None = the payload's home device only.
+
     Stub payloads (tests) and disabled AOT degrade to the legacy
     once-per-bucket call through the predict path, so the no-mid-traffic-
     compile guarantee holds everywhere; only its cost model changes.
 
     Returns ``{"buckets", "compiled", "cache_hits", "seconds",
-    "fallback_warm", "cached_to_disk"}``.
+    "fallback_warm", "cached_to_disk", "load_failed"}``.
     """
     from tpu_pipelines.serving.batching import bucket_sizes
 
@@ -244,7 +270,8 @@ def warm_loaded(
     )
     stats = {
         "buckets": list(buckets), "compiled": 0, "cache_hits": 0,
-        "fallback_warm": False, "cached_to_disk": 0, "seconds": 0.0,
+        "fallback_warm": False, "cached_to_disk": 0, "load_failed": 0,
+        "seconds": 0.0,
     }
     if (
         not aot_enabled()
@@ -263,56 +290,87 @@ def warm_loaded(
 
     import jax
 
+    from tpu_pipelines.trainer.export import AotDispatch
+
     host = loaded.host_preprocess if raw else (lambda b: b)
     if host is None:
         host = lambda b: b  # noqa: E731
     uri = getattr(loaded, "uri", "") or ""
     cacheable = use_cache if use_cache is not None else bool(uri)
     payload_fp = payload_fingerprint(uri) if cacheable else ""
-    if cacheable:
-        payload_fp += ":" + _params_placement_token(loaded.params)
     device_kind = jax.devices()[0].device_kind
     dtype = getattr(loaded, "dtype", "float32")
+    has_transform = getattr(loaded, "transform", None) is not None
     # Without a transform, raw and transformed dispatch the SAME
     # computation — one canonical cache key serves both, so a payload
     # prewarmed through either endpoint (Rewriter at export time, fleet
     # at swap time) hits the other's cache.
-    key_endpoint = (
-        endpoint if getattr(loaded, "transform", None) is not None
-        else "step"
-    )
-    params_abs = _abstract_params(loaded.params)
-    from tpu_pipelines.trainer.export import AotDispatch
-
+    key_endpoint = endpoint if has_transform else "step"
+    params_on = getattr(loaded, "params_on", None)
+    # Host preprocessing and the batch signature do not depend on the
+    # device: once per bucket, not once per (device, bucket).
+    posed = []
     for bucket in buckets:
-        padded = {k: np.repeat(v, bucket, axis=0) for k, v in row.items()}
-        device_batch = host(padded)
-        sig = AotDispatch.signature(device_batch)
-        exe = None
-        path = ""
-        if cacheable:
-            key = cache_key(
-                payload_fp, bucket, dtype, device_kind, key_endpoint, sig
-            )
-            path = _cache_path(key)
-            exe = _load_cached(path)
-        if exe is not None:
-            stats["cache_hits"] += 1
-        else:
-            compiled = step.lower(
-                params_abs, _abstract_tree(device_batch)
-            ).compile()
-            stats["compiled"] += 1
-            if cacheable and _store_cached(path, compiled):
-                stats["cached_to_disk"] += 1
-            exe = compiled
-        dispatch.install(endpoint, sig, exe)
-        if getattr(loaded, "transform", None) is None:
-            # Without a transform both endpoints dispatch the same
-            # computation — one lowering serves predict AND
-            # predict_transformed.
-            dispatch.install(
-                "transformed" if raw else "raw", sig, exe
-            )
+        device_batch = host(
+            {k: np.repeat(v, bucket, axis=0) for k, v in row.items()}
+        )
+        posed.append(
+            (bucket, device_batch, AotDispatch.signature(device_batch))
+        )
+
+    for device in dict.fromkeys(devices or [None]):
+        params = (
+            loaded.params if device is None or params_on is None
+            else params_on(device)
+        )
+        params_abs = _abstract_params(params)
+        exec_devices = _execution_devices(params)
+        placement_fp = (
+            payload_fp + ":" + _params_placement_token(params)
+            if cacheable else ""
+        )
+        for bucket, device_batch, sig in posed:
+            exe = None
+            path = ""
+            if cacheable:
+                key = cache_key(
+                    placement_fp, bucket, dtype, device_kind, key_endpoint,
+                    sig,
+                )
+                path = _cache_path(key)
+                exe = _load_cached(path, exec_devices)
+            if exe is not None:
+                # A deserialized executable is proven by one real call
+                # HERE, at the swap gate: an entry that loads but cannot
+                # run (built for another device set) is a counted, logged
+                # load failure and a recompile — never the first
+                # request's surprise.
+                try:
+                    jax.block_until_ready(exe(params, device_batch))
+                except Exception as e:  # noqa: BLE001 — any refusal
+                    log.warning(
+                        "aot: cache entry %s loaded but failed to run "
+                        "(%s); recompiling", path, e,
+                    )
+                    stats["load_failed"] += 1
+                    exe = None
+            if exe is not None:
+                stats["cache_hits"] += 1
+            else:
+                compiled = step.lower(
+                    params_abs, _abstract_tree(device_batch)
+                ).compile()
+                stats["compiled"] += 1
+                if cacheable and _store_cached(path, compiled):
+                    stats["cached_to_disk"] += 1
+                exe = compiled
+            dispatch.install(endpoint, sig, exe, device)
+            if not has_transform:
+                # Without a transform both endpoints dispatch the same
+                # computation — one lowering serves predict AND
+                # predict_transformed.
+                dispatch.install(
+                    "transformed" if raw else "raw", sig, exe, device
+                )
     stats["seconds"] = round(time.monotonic() - t0, 6)
     return stats

@@ -520,7 +520,13 @@ class ServingFleet:
         t0 = time.monotonic()
         try:
             stats = aot.warm_loaded(
-                loaded, batch, self._max_batch_size, raw=self.raw
+                loaded, batch, self._max_batch_size, raw=self.raw,
+                # One executable set per replica device: each replica
+                # computes on its own chip, against its own params copy.
+                devices=[
+                    r.device for r in self.pool.replicas
+                    if r.device is not None
+                ] or None,
             )
         except Exception as e:  # noqa: BLE001 — same verdict as the canary
             return f"bucket warmup failed: {type(e).__name__}: {e}"
@@ -532,9 +538,11 @@ class ServingFleet:
         if dispatch is not None and self._m_aot_after_warm is not None:
             dispatch.on_compile_after_warm = self._m_aot_after_warm.inc
         log.info(
-            "fleet: %s bucket warmup %.3fs (%d compiled, %d cache hits%s)",
+            "fleet: %s bucket warmup %.3fs (%d compiled, %d cache hits, "
+            "%d cache entries failed to load%s)",
             self.model_name, stats.get("seconds", 0.0),
             stats.get("compiled", 0), stats.get("cache_hits", 0),
+            stats.get("load_failed", 0),
             ", legacy trace path" if stats.get("fallback_warm") else "",
         )
         return ""

@@ -376,9 +376,13 @@ class Replica:
             # rebuild bumps the generation.
             _faults.replica_predict(_self.name, _self.generation)
 
+        # This replica's engine decodes on this replica's device, so it
+        # is handed the params copy that lives there (stub payloads in
+        # tests carry no per-device copies).
+        params_on = getattr(loaded, "params_on", None)
         engine = GenerativeEngine(
             fns,
-            loaded.params,
+            loaded.params if params_on is None else params_on(self.device),
             device=self.device,
             telemetry=self._decode_telemetry,
             fault_hook=_engine_fault_hook,

@@ -125,8 +125,13 @@ class AotDispatch:
     predict twin of the decode engine's counter.
     """
 
-    def __init__(self):
-        self.entries: Dict[Tuple[str, tuple], Any] = {}
+    def __init__(self, home=None):
+        # (endpoint, signature, device): an executable runs on the device
+        # it was compiled for, so a fleet with one replica per chip holds
+        # one entry per chip.  The payload's home device — where an
+        # unpinned caller computes — is keyed None, here and nowhere else.
+        self.home = home
+        self.entries: Dict[Tuple[str, tuple, Any], Any] = {}
         self.fallbacks = 0
         self.compiles_after_warm = 0
         self.on_compile_after_warm: Optional[Callable[[], None]] = None
@@ -140,15 +145,24 @@ class AotDispatch:
             for k, v in batch.items()
         ))
 
-    def lookup(self, endpoint: str, batch: Dict[str, Any]):
-        return self.entries.get((endpoint, self.signature(batch)))
+    def _key(self, endpoint: str, sig: tuple, device) -> tuple:
+        return (endpoint, sig, None if device == self.home else device)
 
-    def install(self, endpoint: str, sig: tuple, executable: Any) -> None:
+    def lookup(self, endpoint: str, batch: Dict[str, Any], device=None):
+        return self.entries.get(
+            self._key(endpoint, self.signature(batch), device)
+        )
+
+    def install(
+        self, endpoint: str, sig: tuple, executable: Any, device=None
+    ) -> None:
         with self._lock:
-            self.entries[(endpoint, sig)] = executable
+            self.entries[self._key(endpoint, sig, device)] = executable
 
-    def record_fallback(self, endpoint: str, batch: Dict[str, Any]) -> None:
-        sig = (endpoint, self.signature(batch))
+    def record_fallback(
+        self, endpoint: str, batch: Dict[str, Any], device=None
+    ) -> None:
+        sig = self._key(endpoint, self.signature(batch), device)
         fresh = False
         with self._lock:
             self.fallbacks += 1
@@ -224,6 +238,13 @@ class LoadedModel:
     # Ahead-of-time executable table (serving/aot.py warms it at the
     # fleet's canary gate; empty = lazy jit, the pre-ISSUE-14 behavior).
     aot: Optional[AotDispatch] = None
+    # ``params_on(device)``: the params tree resident on ``device``, copied
+    # there once on first ask (None = the home device the payload was
+    # restored onto).  predict/predict_transformed compute on the device
+    # the calling thread pinned with ``jax.default_device`` — a fleet
+    # replica per chip — instead of dragging every replica's work back to
+    # the home device the committed params would otherwise pin it to.
+    params_on: Callable[[Any], Any] = None
 
 
 def model_input_columns(
@@ -425,15 +446,34 @@ def load_exported_model(uri: str) -> LoadedModel:
     # AOT executable table: serving/aot.py fills it per padded bucket at
     # the fleet's swap gate; until then every lookup short-circuits on
     # the empty-dict check and the jit path below is exactly pre-AOT.
-    aot = AotDispatch()
+    home = jax.local_devices()[0]   # where restore_exported_params put them
+    aot = AotDispatch(home=home)
+    copies: Dict[Any, Any] = {}
+    copies_lock = threading.Lock()
+
+    def params_on(device=None):
+        if device is None or device == home:
+            return params
+        with copies_lock:
+            tree = copies.get(device)
+            if tree is None:
+                tree = copies[device] = jax.device_put(params, device)
+        return tree
 
     def _dispatch(endpoint: str, jit_fn, batch):
+        # Committed params decide where a jitted call runs, so the params
+        # follow the device the calling thread pinned (a fleet replica's
+        # ``jax.default_device``); unpinned callers stay on the home device.
+        device = jax.config.jax_default_device
+        if not isinstance(device, jax.Device):
+            device = None
+        p = params_on(device)
         if aot.entries:
-            exe = aot.lookup(endpoint, batch)
+            exe = aot.lookup(endpoint, batch, device)
             if exe is not None:
-                return exe(params, batch)
-            aot.record_fallback(endpoint, batch)
-        return jit_fn(params, batch)
+                return exe(p, batch)
+            aot.record_fallback(endpoint, batch, device)
+        return jit_fn(p, batch)
 
     if transform is not None:
         host_fn, device_fn, _ = transform.split_host_device()
@@ -533,4 +573,5 @@ def load_exported_model(uri: str) -> LoadedModel:
         training_schema_uri=str(spec.get("training_schema_uri") or ""),
         uri=os.path.abspath(uri),
         aot=aot,
+        params_on=params_on,
     )

@@ -102,11 +102,22 @@ class LocalEntryLogger:
             # its own backoff + single retry.
             self._mirror_retry_at = None
 
-    def read_cloud_logging_entries(self):
-        # The calculator iterates this return value directly as the list of
-        # payload dicts (no pagination tuple) — returning anything else makes
-        # ``_get_total_job_time`` iterate the wrapper and blow up on None.
-        return list(self._entries)
+    def read_cloud_logging_entries(
+        self, start_time=None, end_time=None, last_entry_info=None
+    ):
+        """``(payloads, (last_timestamp, last_id))`` — the installed
+        calculator's contract.  Ids are list positions, so a calculator
+        that kept ``last_entry_info`` from an earlier read gets only what
+        was written since; the time window is not needed on top of that
+        (entries are never retained across jobs here)."""
+        del start_time, end_time
+        first = 0
+        if last_entry_info is not None and last_entry_info[1] is not None:
+            first = int(last_entry_info[1]) + 1
+        entries = self._entries[first:]
+        if not entries:
+            return [], (None, None)
+        return list(entries), (_now(), str(first + len(entries) - 1))
 
 
 class GoodputTracker:
@@ -120,18 +131,17 @@ class GoodputTracker:
             from ml_goodput_measurement.src import goodput as goodput_mod
 
             self._logger = LocalEntryLogger(job_name, jsonl_path)
-            # Keyword is ``logger=`` (ml_goodput_measurement >= 0.0.2);
-            # the old ``cloud_logger=`` raised TypeError here, which the
-            # best-effort except silently downgraded EVERY run to the
-            # proxy path — the regression test drives this constructor
-            # for real.
-            self._recorder = goodput_mod.GoodputRecorder(
-                job_name, "local", logging_enabled=True,
-                logger=self._logger,
-            )
-            self._goodput_mod = goodput_mod
-        except Exception as e:  # noqa: BLE001 — accounting is best-effort
+        except ImportError as e:
             log.info("ml_goodput_measurement unavailable (%s); using proxy", e)
+            return
+        # Outside the ImportError guard on purpose: a constructor that no
+        # longer matches the installed library must fail loudly, not
+        # downgrade every run to the proxy path in silence.
+        self._recorder = goodput_mod.GoodputRecorder(
+            job_name, "local", logging_enabled=True,
+            cloud_logger=self._logger,
+        )
+        self._goodput_mod = goodput_mod
 
     @property
     def enabled(self) -> bool:
@@ -184,7 +194,7 @@ class GoodputTracker:
             return {}
         try:
             calc = self._goodput_mod.GoodputCalculator(
-                self.job_name, "local", logger=self._logger
+                self.job_name, "local", cloud_logger=self._logger
             )
             goodput_pct, badput, last_step = calc.get_job_goodput(
                 include_badput_breakdown=True

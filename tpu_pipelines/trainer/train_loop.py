@@ -125,10 +125,7 @@ def _peak_flops_per_chip(config: "TrainLoopConfig") -> float:
             return float(env)
         except ValueError:
             log.warning("ignoring non-numeric %s=%r", ENV_PEAK_FLOPS, env)
-    try:
-        kind = jax.local_devices()[0].device_kind.lower()
-    except Exception:  # noqa: BLE001
-        return 0.0
+    kind = jax.local_devices()[0].device_kind.lower()
     for key, peak in _PEAK_BF16_FLOPS:
         if key in kind:
             return peak
@@ -215,8 +212,8 @@ class TrainLoopConfig:
     # as ONE compiled ``lax.scan`` over a device-staged batch stack (leading
     # axis = step-in-window), with a single device->host metric fetch per
     # window — the per-step host round-trip (device_put + dispatch + drain)
-    # is the ~100x gap between the real train_loop path and the
-    # device-resident fori_loop ceiling on µs-scale steps (BENCH_R5).
+    # is the gap between the real train_loop path and the device-resident
+    # fori_loop ceiling on µs-scale steps.
     # None = read env TPP_WINDOW_STEPS, else default to ``log_every``
     # (window cadence == metric cadence); <=1 = the per-step loop,
     # bit-for-bit in metric semantics.  Windows shrink to land exactly on
@@ -257,7 +254,10 @@ class TrainLoopConfig:
     # independently of the mesh), all-gathered, and summed in block order —
     # the param trajectory is bitwise-invariant to the data-axis size, so
     # an elastic resume onto a survivor mesh continues the exact same
-    # trajectory; costs all-gather bandwidth (block grads move whole).
+    # trajectory; costs all-gather bandwidth (block grads move whole), and
+    # blocks run one at a time on a device that holds several.  Bitwise on
+    # the CPU backend always; on TPU it held on 4/2/1 chips at MXU-sized
+    # matmuls and not for sub-tile toy shapes (PERF.md, PR 21).
     # "fsdp": ZeRO-3 — params (and Adam moments) live SHARDED over the
     # data axis per ``param_partition`` (or a derived default: first dim
     # divisible by the axis), each leaf is all-gathered just-in-time
@@ -283,8 +283,8 @@ class TrainLoopConfig:
     # steps, force a device-to-host read of that step's loss (the same
     # cannot-lie transfer used for t_start below) and time the span since the
     # previous anchor.  The median windowed examples/sec over these spans is
-    # the defensible throughput figure on platforms where async dispatch (or
-    # a tunneled backend) lets host clocks run ahead of device progress.
+    # the defensible throughput figure wherever async dispatch lets host
+    # clocks run ahead of device progress.
     # 0 = whole-run timing only.
     anchor_every: int = 0
     # PRNG implementation for the training rng (dropout masks etc.).
@@ -472,7 +472,6 @@ def _make_dp_forward_backward(
     """
     from jax.ad_checkpoint import checkpoint_name
 
-    from tpu_pipelines.parallel.compat import shard_map
     from tpu_pipelines.parallel.partition import gather_leaf
 
     data_axis = mesh.shape["data"]
@@ -528,7 +527,15 @@ def _make_dp_forward_backward(
             ),
             mb,
         )
-        l_b, m_b, g_b, s_b = jax.vmap(block_fb)(bmb)
+        # One block at a time (lax.map), not vmap: every mesh size then runs
+        # the SAME per-block program on the same shapes, however many blocks
+        # a device holds.  A batched (vmap) block dimension changes the
+        # matmul shapes with the mesh, and the TPU compiler tiles — and so
+        # rounds — differently shaped matmuls differently: with vmap the
+        # bitwise invariance held on the CPU and broke on four chips at
+        # every size tried; with lax.map it holds there at MXU-sized
+        # matmuls (PERF.md, PR 21).
+        l_b, m_b, g_b, s_b = jax.lax.map(block_fb, bmb)
         gather = lambda t: jax.lax.all_gather(t, "data", tiled=True)
         inv = 1.0 / grad_blocks
         ordered_sum = lambda v: jnp.sum(gather(v), axis=0) * inv
@@ -655,7 +662,7 @@ def _make_dp_forward_backward(
             return loss, metrics, grads, new_ms
 
         pspec = fsdp_specs if mode == "fsdp" else P()
-        return shard_map(
+        return jax.shard_map(
             local,
             mesh=mesh,
             in_specs=(pspec, P(), P("data"), P()),
@@ -1487,10 +1494,8 @@ def train_loop(
                     profiling = False
                 if t_start is None:
                     # Start timing after step 1 retires (excludes compile time).  A
-                    # device-to-host READ, not block_until_ready: on some platforms
-                    # (e.g. tunneled experimental backends) block_until_ready returns
-                    # before execution finishes, which would start the clock early —
-                    # a transfer of the step's output cannot lie.
+                    # device-to-host READ of the step's output: the transfer cannot
+                    # complete before the step has executed.
                     np.asarray(metrics["loss"])
                     t_start = time.perf_counter()
                     compile_stats["warm"] = True  # later compiles are stalls
