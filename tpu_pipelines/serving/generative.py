@@ -93,7 +93,18 @@ Metrics (``serving_decode_*``, labeled per replica; catalog in
 docs/SERVING.md): steps/s, tokens/s, batch occupancy, cache pages in
 use, active/queued sequences + outstanding tokens, per-token latency
 histogram, evictions, step-time EWMA (what the router reads), prefix
-cache hits/misses/resident pages, speculative proposals/acceptances.
+cache hits/misses/resident pages, speculative proposals/acceptances,
+and the worker thread's time by phase (``ENGINE_PHASES``: exact sums of
+self seconds and occurrences) with each request's queue wait and time
+to first token.
+
+Tracing: every phase of the worker thread is also a
+``jax.profiler.TraceAnnotation`` named ``engine.<phase>`` (plus
+``engine.step.wait``, the step's device-to-host read).  Annotations are
+recorded only while a profiler session runs, into the same trace and on
+the same clock as the device's operations; the device side of a phase
+is its program's event, under the names in ``PROGRAM_NAMES``
+(docs/OBSERVABILITY.md "Engine phases in a profile").
 """
 
 from __future__ import annotations
@@ -116,6 +127,38 @@ from tpu_pipelines.serving.batching import (
 )
 
 log = logging.getLogger("tpu_pipelines.serving")
+
+# What the worker thread does, as the ``phase`` label of
+# ``serving_decode_engine_{seconds,phase}_total`` and, prefixed with
+# ``engine.``, as span names in a profile.  Every moment of the worker's
+# life belongs to exactly one (a nested phase's time is taken out of its
+# parent's), so the seven sums add up to the thread's lifetime.
+ENGINE_PHASES = (
+    "idle", "admit", "prefill", "insert", "step", "emit", "retire",
+)
+
+# The engine's device programs as a profile's "XLA Modules" line names
+# them: ``jit_`` + the ``__name__`` of the function handed to jax.jit.
+# ``jit_run`` is both the bucketed step and the speculative verify.
+# Readers of traces find programs by these names (the benchmark's
+# decode_step_hbm_share.serve reads ``jit_run``): renaming one of the
+# inner functions is a change to this tuple and to those readers.
+PROGRAM_NAMES = (
+    "jit_prefill", "jit_insert", "jit_move", "jit_clear", "jit_run",
+    "jit_accept",
+)
+
+
+def _jit_program(fn):
+    """``jax.jit`` for one of the engine's device programs, held to the
+    names a trace reader looks for."""
+    import jax
+
+    if "jit_" + fn.__name__ not in PROGRAM_NAMES:
+        raise ValueError(
+            f"engine program {fn.__name__!r} is not in PROGRAM_NAMES"
+        )
+    return jax.jit(fn)
 
 
 class EngineOverloaded(RuntimeError):
@@ -156,7 +199,16 @@ class DecodeSessionLost(RuntimeError):
 class _Sequence:
     """Host-side bookkeeping for one generation (the engine's unit of
     scheduling).  ``tokens`` mirrors the device state: its length IS the
-    sequence's next decode position."""
+    sequence's next decode position.
+
+    The request's timeline rides on the handle: ``arrival_s`` (submit),
+    ``admitted_s`` (the ``_admit`` turn that took it off the queue),
+    ``first_token_s`` (its first token on the host) and ``done_s``
+    (finished, whichever way), each ``time.monotonic()`` and ``None``
+    until reached.  On Linux ``time.monotonic()`` and
+    ``time.perf_counter()`` read the same clock (``CLOCK_MONOTONIC``,
+    see ``time.get_clock_info``), so a caller that stamps its own
+    events with ``perf_counter`` may subtract them from these."""
 
     inputs: np.ndarray              # [max_input_len] padded token ids
     input_mask: np.ndarray          # [max_input_len] 1/0 validity
@@ -175,10 +227,17 @@ class _Sequence:
     # Prefix-cache entry this live sequence holds a reader reference on
     # (None = admitted without the cache, or reference already released).
     prefix_entry: Any = None
+    # Per-engine request number: the ``seq`` argument of this request's
+    # ``engine.*`` spans and of its ``decode.join`` trace event.
+    seq_id: int = 0
+    admitted_s: Optional[float] = None
+    first_token_s: Optional[float] = None
+    done_s: Optional[float] = None
 
     def finish(self, error: Optional[BaseException] = None) -> None:
         if self._done.is_set():
             return
+        self.done_s = time.monotonic()
         if error is not None:
             self.error = error
         else:
@@ -454,6 +513,10 @@ class GenerativeEngine:
             [None] * self.max_batch_size
         )
         self._n_live = 0
+        self._n_submitted = 0
+        # Seconds of the phases already closed inside the phase that is
+        # open now (worker thread only; see _phase).
+        self._child_s = 0.0
         self._closed = False
         # Worker died (device fault / injected kill): reject new submits
         # immediately instead of queueing work nothing will ever serve.
@@ -537,7 +600,8 @@ class GenerativeEngine:
             )
 
         return (
-            jax.jit(prefill), jax.jit(insert), jax.jit(move), jax.jit(clear)
+            _jit_program(prefill), _jit_program(insert),
+            _jit_program(move), _jit_program(clear),
         )
 
     def _build_jits(self) -> None:
@@ -577,7 +641,7 @@ class GenerativeEngine:
             cache = jax.tree_util.tree_map_with_path(scrub, cache)
             return (cache, new_tok, new_pos, live, enc, mask)
 
-        self._jit_accept = jax.jit(accept)
+        self._jit_accept = _jit_program(accept)
 
     def _build_step(self, b: int, kv: int, fns):
         import jax
@@ -604,7 +668,7 @@ class GenerativeEngine:
             pos = pos.at[:b].set(pos[:b] + live[:b].astype(jnp.int32))
             return (cache, tok, pos, live, encoded, enc_mask), nxt
 
-        return jax.jit(run)
+        return _jit_program(run)
 
     def _build_verify(self, b: int, kv: int):
         """One bucketed target-verify program: score ``k = spec_tokens``
@@ -649,7 +713,7 @@ class GenerativeEngine:
             )
             return (cache, tok, pos, live, encoded, enc_mask), g
 
-        return jax.jit(run)
+        return _jit_program(run)
 
     def _program_for(self, cache, build, kind, b: int, kv: int):
         fn = cache.get((b, kv))
@@ -870,6 +934,8 @@ class GenerativeEngine:
                 raise RuntimeError("engine is closed")
             if self._dead:
                 raise RuntimeError("engine worker died")
+            self._n_submitted += 1
+            seq.seq_id = self._n_submitted
             self._queue.append(seq)
             self.telemetry.on_queue(self.outstanding_tokens_locked())
             self._cond.notify_all()
@@ -924,6 +990,28 @@ class GenerativeEngine:
 
     # ------------------------------------------------------------- worker
 
+    @contextlib.contextmanager
+    def _phase(self, phase: str, **args):
+        """One phase of the worker thread (``ENGINE_PHASES``): a span
+        ``engine.<phase>`` in a profile, and its self seconds and one
+        occurrence on the telemetry's counters.  The annotation records
+        only while a profiler session runs and costs a flag test when
+        none does; the counters are always on.  Phases nest by time, so
+        a phase's self time is its own less that of the phases closed
+        inside it.  Yields the annotation: ``set_metadata(**kw)`` adds
+        arguments that are known only inside the span."""
+        from jax.profiler import TraceAnnotation
+
+        outer, self._child_s = self._child_s, 0.0
+        t0 = time.perf_counter()
+        try:
+            with TraceAnnotation("engine." + phase, **args) as span:
+                yield span
+        finally:
+            dt = time.perf_counter() - t0
+            self.telemetry.on_phase(phase, max(0.0, dt - self._child_s))
+            self._child_s = outer + dt
+
     def _run(self) -> None:
         try:
             while True:
@@ -933,7 +1021,8 @@ class GenerativeEngine:
                         and not self._queue
                         and self._n_live == 0
                     ):
-                        self._cond.wait()
+                        with self._phase("idle"):
+                            self._cond.wait()
                     if self._closed:
                         return
                 if self._fault_hook is not None:
@@ -993,74 +1082,102 @@ class GenerativeEngine:
         per admitted sequence — or an arena scatter alone when the
         prefix cache already holds this prompt — metered by chunked-
         prefill credits when live sequences could starve."""
-        while True:
-            with self._lock:
-                if not self._queue or self._n_live >= self.max_batch_size:
+        # The unlocked look at the queue only keeps a round with nothing
+        # to admit from counting as an ``admit`` turn; ``_admit_one``
+        # decides under the lock.
+        while self._queue and self._n_live < self.max_batch_size:
+            with self._phase("admit") as span:
+                if not self._admit_one(span):
                     return
-                seq = self._queue[0]
-                entry = key = None
-                if self._prefix is not None:
-                    key, pages = PrefixCache.key_of(
-                        seq.inputs, seq.input_mask, self._ppage
-                    )
-                    entry = self._prefix.peek(key)
-                else:
-                    pages = self._prompt_pages(seq)
-                cost = 1 if entry is not None else pages
-                if (
-                    self.prefill_chunk_pages > 0
-                    and self._n_live > 0
-                    and cost > self._admit_credits
+
+    def _admit_one(self, span) -> bool:
+        """One turn of admission, for the sequence at the head of the
+        queue.  False when the head stays queued: chunked prefill has no
+        credits for it (or ``close`` emptied the queue meanwhile)."""
+        with self._lock:
+            if not self._queue or self._n_live >= self.max_batch_size:
+                return False
+            seq = self._queue[0]
+            entry = key = None
+            if self._prefix is not None:
+                key, pages = PrefixCache.key_of(
+                    seq.inputs, seq.input_mask, self._ppage
+                )
+                entry = self._prefix.peek(key)
+            else:
+                pages = self._prompt_pages(seq)
+            span.set_metadata(
+                seq=seq.seq_id, prefix_hit=int(entry is not None)
+            )
+            cost = 1 if entry is not None else pages
+            if (
+                self.prefill_chunk_pages > 0
+                and self._n_live > 0
+                and cost > self._admit_credits
+            ):
+                # Not enough credits between steps: leave the head
+                # queued, decode earns more, admission resumes next
+                # round — a long prompt never skips a live
+                # sequence's token deadline.
+                return False
+            self._queue.popleft()
+            if self.prefill_chunk_pages > 0 and self._n_live > 0:
+                self._admit_credits -= cost
+        seq.admitted_s = time.monotonic()
+        self.telemetry.on_admitted(seq.admitted_s - seq.arrival_s)
+        with self._dev():
+            self._ensure_arena()
+            d_cache1 = d_enc1 = None
+            if entry is not None:
+                self._prefix.hits += 1
+                self._prefix.touch(entry)
+                self.telemetry.on_prefix_hit(entry.pages)
+                cache1, enc1 = entry.cache, entry.encoded
+                d_cache1, d_enc1 = entry.draft_cache, entry.draft_encoded
+                t0 = entry.tok0
+            else:
+                with self._phase(
+                    "prefill", seq=seq.seq_id,
+                    prompt_tokens=int((seq.input_mask > 0).sum()),
                 ):
-                    # Not enough credits between steps: leave the head
-                    # queued, decode earns more, admission resumes next
-                    # round — a long prompt never skips a live
-                    # sequence's token deadline.
-                    return
-                self._queue.popleft()
-                if self.prefill_chunk_pages > 0 and self._n_live > 0:
-                    self._admit_credits -= cost
-            with self._dev():
-                self._ensure_arena()
-                d_cache1 = d_enc1 = None
-                if entry is not None:
-                    self._prefix.hits += 1
-                    self._prefix.touch(entry)
-                    self.telemetry.on_prefix_hit(entry.pages)
-                    cache1, enc1 = entry.cache, entry.encoded
-                    d_cache1, d_enc1 = entry.draft_cache, entry.draft_encoded
-                    t0 = entry.tok0
-                else:
                     cache1, enc1, tok0 = self._jit_prefill(
                         self.params, seq.inputs[None], seq.input_mask[None]
                     )
+                    # The device-to-host read the admission blocks on:
+                    # everything queued ahead of this prefill on the
+                    # device (arena scatters, a step) is waited for here.
                     t0 = int(tok0)
                     if self._spec:
                         d_cache1, d_enc1, _ = self._d_jit_prefill(
                             self.draft_params,
                             seq.inputs[None], seq.input_mask[None],
                         )
-                    if self._prefix is not None:
-                        self._prefix.misses += 1
-                        self.telemetry.on_prefix_miss()
-                        entry = self._prefix.insert(
-                            key, pages, t0, cache1, enc1, d_cache1, d_enc1
-                        )
-                seq.tokens.append(t0)
-                if t0 == self.eos_id or seq.max_new_tokens <= 1:
-                    if self._prefix is not None:
-                        self.telemetry.on_prefix_pages(
-                            self._prefix.pages_in_use()
-                        )
-                    self._complete(seq)
-                    continue
-                if entry is not None:
-                    self._prefix.acquire(entry)
-                    seq.prefix_entry = entry
+                if self._prefix is not None:
+                    self._prefix.misses += 1
+                    self.telemetry.on_prefix_miss()
+                    entry = self._prefix.insert(
+                        key, pages, t0, cache1, enc1, d_cache1, d_enc1
+                    )
+            seq.first_token_s = time.monotonic()
+            self.telemetry.on_first_token(
+                seq.first_token_s - seq.arrival_s
+            )
+            seq.tokens.append(t0)
+            if t0 == self.eos_id or seq.max_new_tokens <= 1:
+                if self._prefix is not None:
                     self.telemetry.on_prefix_pages(
                         self._prefix.pages_in_use()
                     )
-                slot = self._n_live
+                self._complete(seq)
+                return True
+            if entry is not None:
+                self._prefix.acquire(entry)
+                seq.prefix_entry = entry
+                self.telemetry.on_prefix_pages(
+                    self._prefix.pages_in_use()
+                )
+            slot = self._n_live
+            with self._phase("insert", seq=seq.seq_id, slot=slot):
                 self._arena = self._jit_insert(
                     self._arena, cache1, enc1, seq.input_mask[None],
                     np.int32(t0), np.int32(slot),
@@ -1073,17 +1190,19 @@ class GenerativeEngine:
                         self._d_arena, d_cache1, d_enc1,
                         seq.input_mask[None], np.int32(t0), np.int32(slot),
                     )
-            if seq.ctx is not None:
-                # Slot event: the sequence joined the continuous batch —
-                # the wait it paid in the queue is arrival -> now.
-                seq.ctx.span_from_mono(
-                    "decode.join", seq.arrival_s,
-                    slot=slot, budget_tokens=seq.max_new_tokens,
-                    prefix_hit=seq.prefix_entry is not None,
-                )
-            with self._lock:
-                self._slots[slot] = seq
-                self._n_live += 1
+        if seq.ctx is not None:
+            # Slot event: the sequence joined the continuous batch —
+            # the wait it paid in the queue is arrival -> now.
+            seq.ctx.span_from_mono(
+                "decode.join", seq.arrival_s,
+                slot=slot, budget_tokens=seq.max_new_tokens,
+                prefix_hit=seq.prefix_entry is not None,
+                seq=seq.seq_id,
+            )
+        with self._lock:
+            self._slots[slot] = seq
+            self._n_live += 1
+        return True
 
     def _spec_round(self) -> None:
         """One speculative round: ``k`` chained draft steps propose,
@@ -1097,216 +1216,225 @@ class GenerativeEngine:
         at the last emitted position and acceptance collapses.
         Rejected-tail KV in both arenas is scrubbed to exact zero by
         the accept program (see ``_build_jits``)."""
+        from jax.profiler import TraceAnnotation
+
         n = self._n_live
         k = self.spec_tokens
-        b = next(bk for bk in self.batch_buckets if bk >= n)
-        deepest = max(
-            len(s.tokens) for s in self._slots[:n] if s is not None
-        )
-        kv = next(kb for kb in self.kv_buckets if kb >= deepest + k)
         B = self.max_batch_size
-        toks = np.full((B, k), self.pad_id, np.int32)
-        for i in range(n):
-            s = self._slots[i]
-            if s is not None:
-                toks[i, 0] = s.tokens[-1]
-        t_start = time.perf_counter()
-        with self._dev():
-            d_fn = self._d_step_for(b, kv)
-            for j in range(1, k + 1):
-                self._d_arena, nxt = d_fn(self.draft_params, self._d_arena)
-                if j < k:
-                    toks[:b, j] = np.asarray(nxt)
-            self._arena, g = self._verify_for(b, kv)(
-                self.params, self._arena, toks
+        with self._phase("step") as span:
+            b = next(bk for bk in self.batch_buckets if bk >= n)
+            deepest = max(
+                len(s.tokens) for s in self._slots[:n] if s is not None
             )
-            gh = np.asarray(g)  # [b, k] — the device->host sync
-        dt = time.perf_counter() - t_start
-        if self.step_ewma_s is None:
-            self.step_ewma_s = dt
-        else:
-            a_ = self.STEP_EWMA_ALPHA
-            self.step_ewma_s = (1 - a_) * self.step_ewma_s + a_ * dt
-        self.steps_run += 1
-        now = time.monotonic()
-        proposed = accepted = 0
-        new_tok = np.full((B,), self.pad_id, np.int32)
-        new_pos = np.zeros((B,), np.int32)
-        for i in range(n):
-            seq = self._slots[i]
-            a = 0
-            while a < k - 1 and toks[i, a + 1] == gh[i, a]:
-                a += 1
-            proposed += k - 1
-            accepted += a
-            emitted = 0
-            for j in range(a + 1):
-                t = int(gh[i, j])
-                seq.tokens.append(t)
-                emitted += 1
-                self.telemetry.on_token()
-                if (
-                    t == self.eos_id
-                    or len(seq.tokens) >= seq.max_new_tokens
-                ):
-                    break
-            new_tok[i] = seq.tokens[-1]
-            new_pos[i] = len(seq.tokens)
-            if seq.ctx is not None:
-                seq.ctx.instant(
-                    "decode.spec", slot=i, token=len(seq.tokens),
-                    accepted=a, emitted=emitted,
-                    batch_bucket=b, kv_bucket=kv, live=n,
-                    step_s=round(dt, 6),
-                )
-        self.spec_proposed += proposed
-        self.spec_accepted += accepted
-        self.telemetry.on_spec(proposed, accepted)
-        pages = sum(
-            -(-(len(s.tokens) + 1) // self._page)
-            for s in self._slots[:n] if s is not None
-        )
-        self.telemetry.on_step(dt, self.step_ewma_s, n, b, pages, int(n))
-        with self._dev():
-            # Wholesale tok/pos sync of BOTH lanes to the emitted stream
-            # (rows past n carry pad/0, clear's convention).
-            self._arena = self._jit_accept(self._arena, new_tok, new_pos)
-            self._d_arena = self._jit_accept(
-                self._d_arena, new_tok, new_pos
-            )
-        for slot in range(n - 1, -1, -1):
-            seq = self._slots[slot]
-            t = seq.tokens[-1]
-            done = (
-                t == self.eos_id or len(seq.tokens) >= seq.max_new_tokens
-            )
-            if done:
-                if seq.ctx is not None and t == self.eos_id:
-                    seq.ctx.instant(
-                        "decode.eos", slot=slot, tokens=len(seq.tokens)
-                    )
-                self._retire(slot)
-                self._complete(seq)
-            elif (
-                self.hard_deadline
-                and seq.deadline_s is not None
-                and now > seq.deadline_s
-            ):
-                self.telemetry.on_evicted()
-                self._retire(slot)
-                self._evict_seq(
-                    seq, slot,
-                    f"per-token SLO deadline exceeded after "
-                    f"{len(seq.tokens)}/{seq.max_new_tokens} tokens",
-                )
-
-    def _step_once(self) -> None:
-        n = self._n_live
-        b = next(bk for bk in self.batch_buckets if bk >= n)
-        deepest = max(
-            len(s.tokens) for s in self._slots[:n] if s is not None
-        )
-        kv = next(k for k in self.kv_buckets if k >= deepest + 1)
-        fn = self._step_for(b, kv)
-        t0 = time.perf_counter()
-        with self._dev():
-            self._arena, nxt = fn(self.params, self._arena)
-            if self._spec:
-                # Keep the draft lane's KV stream gap-free even on the
-                # single-step fallback path (headroom near the cache
-                # end): the draft consumes the same tok/pos mirror, its
-                # own next-token guess is then overwritten by the
-                # accept-sync below.
-                self._d_arena, _ = self._d_step_for(b, kv)(
-                    self.draft_params, self._d_arena
-                )
-            toks = np.asarray(nxt)  # the one device->host sync per step
-        if self._spec:
-            new_tok = np.full((self.max_batch_size,), self.pad_id, np.int32)
-            new_pos = np.zeros((self.max_batch_size,), np.int32)
+            kv = next(kb for kb in self.kv_buckets if kb >= deepest + k)
+            span.set_metadata(live=n, b=b, kv=kv)
+            toks = np.full((B, k), self.pad_id, np.int32)
             for i in range(n):
                 s = self._slots[i]
                 if s is not None:
-                    new_tok[i] = int(toks[i])
-                    new_pos[i] = len(s.tokens) + 1
+                    toks[i, 0] = s.tokens[-1]
+            t_start = time.perf_counter()
             with self._dev():
+                d_fn = self._d_step_for(b, kv)
+                for j in range(1, k + 1):
+                    self._d_arena, nxt = d_fn(
+                        self.draft_params, self._d_arena
+                    )
+                    if j < k:
+                        with TraceAnnotation("engine.step.wait"):
+                            toks[:b, j] = np.asarray(nxt)
+                self._arena, g = self._verify_for(b, kv)(
+                    self.params, self._arena, toks
+                )
+                with TraceAnnotation("engine.step.wait"):
+                    gh = np.asarray(g)  # [b, k] — the device->host sync
+            dt = time.perf_counter() - t_start
+            if self.step_ewma_s is None:
+                self.step_ewma_s = dt
+            else:
+                a_ = self.STEP_EWMA_ALPHA
+                self.step_ewma_s = (1 - a_) * self.step_ewma_s + a_ * dt
+            self.steps_run += 1
+        with self._phase("emit", live=n):
+            now = time.monotonic()
+            proposed = accepted = 0
+            new_tok = np.full((B,), self.pad_id, np.int32)
+            new_pos = np.zeros((B,), np.int32)
+            for i in range(n):
+                seq = self._slots[i]
+                a = 0
+                while a < k - 1 and toks[i, a + 1] == gh[i, a]:
+                    a += 1
+                proposed += k - 1
+                accepted += a
+                emitted = 0
+                for j in range(a + 1):
+                    t = int(gh[i, j])
+                    seq.tokens.append(t)
+                    emitted += 1
+                    self.telemetry.on_token()
+                    if (
+                        t == self.eos_id
+                        or len(seq.tokens) >= seq.max_new_tokens
+                    ):
+                        break
+                new_tok[i] = seq.tokens[-1]
+                new_pos[i] = len(seq.tokens)
+                if seq.ctx is not None:
+                    seq.ctx.instant(
+                        "decode.spec", slot=i, token=len(seq.tokens),
+                        accepted=a, emitted=emitted,
+                        batch_bucket=b, kv_bucket=kv, live=n,
+                        step_s=round(dt, 6),
+                    )
+            self.spec_proposed += proposed
+            self.spec_accepted += accepted
+            self.telemetry.on_spec(proposed, accepted)
+            pages = sum(
+                -(-(len(s.tokens) + 1) // self._page)
+                for s in self._slots[:n] if s is not None
+            )
+            self.telemetry.on_step(
+                dt, self.step_ewma_s, n, b, pages, int(n)
+            )
+            with self._dev():
+                # Wholesale tok/pos sync of BOTH lanes to the emitted
+                # stream (rows past n carry pad/0, clear's convention).
+                self._arena = self._jit_accept(
+                    self._arena, new_tok, new_pos
+                )
                 self._d_arena = self._jit_accept(
                     self._d_arena, new_tok, new_pos
                 )
-        dt = time.perf_counter() - t0
-        if self.step_ewma_s is None:
-            self.step_ewma_s = dt
-        else:
-            a = self.STEP_EWMA_ALPHA
-            self.step_ewma_s = (1 - a) * self.step_ewma_s + a * dt
-        self.steps_run += 1
-        pages = sum(
-            -(-(len(s.tokens) + 1) // self._page)
-            for s in self._slots[:n] if s is not None
-        )
-        self.telemetry.on_step(dt, self.step_ewma_s, n, b, pages, int(n))
-        now = time.monotonic()
-        for slot in range(n - 1, -1, -1):
-            seq = self._slots[slot]
-            t = int(toks[slot])
-            seq.tokens.append(t)
-            self.telemetry.on_token()
-            if seq.ctx is not None:
-                # Per decode-step slot event: which step, which program
-                # bucket pair — the trace shows exactly which steps this
-                # sequence rode and with how much co-batched company.
-                seq.ctx.instant(
-                    "decode.step", slot=slot, token=len(seq.tokens),
-                    batch_bucket=b, kv_bucket=kv, live=n,
-                    step_s=round(dt, 6),
-                )
-            done = (
-                t == self.eos_id or len(seq.tokens) >= seq.max_new_tokens
+            for slot in range(n - 1, -1, -1):
+                self._settle(slot, self._slots[slot], now)
+
+    def _step_once(self) -> None:
+        from jax.profiler import TraceAnnotation
+
+        n = self._n_live
+        with self._phase("step") as span:
+            b = next(bk for bk in self.batch_buckets if bk >= n)
+            deepest = max(
+                len(s.tokens) for s in self._slots[:n] if s is not None
             )
-            # Retire the slot BEFORE waking the waiter: the client thread
-            # resumes to consistent accounting (outstanding_tokens of a
-            # finished sequence is already 0, its slot already free).
-            if done:
-                if seq.ctx is not None and t == self.eos_id:
-                    seq.ctx.instant(
-                        "decode.eos", slot=slot, tokens=len(seq.tokens)
+            kv = next(k for k in self.kv_buckets if k >= deepest + 1)
+            span.set_metadata(live=n, b=b, kv=kv)
+            fn = self._step_for(b, kv)
+            t0 = time.perf_counter()
+            with self._dev():
+                self._arena, nxt = fn(self.params, self._arena)
+                if self._spec:
+                    # Keep the draft lane's KV stream gap-free even on
+                    # the single-step fallback path (headroom near the
+                    # cache end): the draft consumes the same tok/pos
+                    # mirror, its own next-token guess is then
+                    # overwritten by the accept-sync below.
+                    self._d_arena, _ = self._d_step_for(b, kv)(
+                        self.draft_params, self._d_arena
                     )
-                self._retire(slot)
-                self._complete(seq)
-            elif (
-                self.hard_deadline
-                and seq.deadline_s is not None
-                and now > seq.deadline_s
-            ):
-                self.telemetry.on_evicted()
-                self._retire(slot)
-                self._evict_seq(
-                    seq, slot,
-                    f"per-token SLO deadline exceeded after "
-                    f"{len(seq.tokens)}/{seq.max_new_tokens} tokens",
+                with TraceAnnotation("engine.step.wait"):
+                    # the one device->host sync per step
+                    toks = np.asarray(nxt)
+            if self._spec:
+                new_tok = np.full(
+                    (self.max_batch_size,), self.pad_id, np.int32
                 )
+                new_pos = np.zeros((self.max_batch_size,), np.int32)
+                for i in range(n):
+                    s = self._slots[i]
+                    if s is not None:
+                        new_tok[i] = int(toks[i])
+                        new_pos[i] = len(s.tokens) + 1
+                with self._dev():
+                    self._d_arena = self._jit_accept(
+                        self._d_arena, new_tok, new_pos
+                    )
+            dt = time.perf_counter() - t0
+            if self.step_ewma_s is None:
+                self.step_ewma_s = dt
+            else:
+                a = self.STEP_EWMA_ALPHA
+                self.step_ewma_s = (1 - a) * self.step_ewma_s + a * dt
+            self.steps_run += 1
+            pages = sum(
+                -(-(len(s.tokens) + 1) // self._page)
+                for s in self._slots[:n] if s is not None
+            )
+            self.telemetry.on_step(
+                dt, self.step_ewma_s, n, b, pages, int(n)
+            )
+        with self._phase("emit", live=n):
+            now = time.monotonic()
+            for slot in range(n - 1, -1, -1):
+                seq = self._slots[slot]
+                seq.tokens.append(int(toks[slot]))
+                self.telemetry.on_token()
+                if seq.ctx is not None:
+                    # Per decode-step slot event: which step, which
+                    # program bucket pair — the trace shows exactly
+                    # which steps this sequence rode and with how much
+                    # co-batched company.
+                    seq.ctx.instant(
+                        "decode.step", slot=slot, token=len(seq.tokens),
+                        batch_bucket=b, kv_bucket=kv, live=n,
+                        step_s=round(dt, 6),
+                    )
+                self._settle(slot, seq, now)
+
+    def _settle(self, slot: int, seq: _Sequence, now: float) -> None:
+        """After a round's tokens are appended: retire and complete a
+        sequence that hit EOS or its budget, evict one past its hard
+        deadline, leave the rest in their slots."""
+        t = seq.tokens[-1]
+        # Retire the slot BEFORE waking the waiter: the client thread
+        # resumes to consistent accounting (outstanding_tokens of a
+        # finished sequence is already 0, its slot already free).
+        if t == self.eos_id or len(seq.tokens) >= seq.max_new_tokens:
+            if seq.ctx is not None and t == self.eos_id:
+                seq.ctx.instant(
+                    "decode.eos", slot=slot, tokens=len(seq.tokens)
+                )
+            self._retire(slot)
+            self._complete(seq)
+        elif (
+            self.hard_deadline
+            and seq.deadline_s is not None
+            and now > seq.deadline_s
+        ):
+            self.telemetry.on_evicted()
+            self._retire(slot)
+            self._evict_seq(
+                seq, slot,
+                f"per-token SLO deadline exceeded after "
+                f"{len(seq.tokens)}/{seq.max_new_tokens} tokens",
+            )
 
     def _retire(self, slot: int) -> None:
-        with self._dev():
-            last = self._n_live - 1
-            if slot != last:
-                self._arena = self._jit_move(
-                    self._arena, np.int32(last), np.int32(slot)
-                )
-                if self._spec:
-                    self._d_arena = self._d_jit_move(
-                        self._d_arena, np.int32(last), np.int32(slot)
+        last = self._n_live - 1
+        with self._phase(
+            "retire", seq=self._slots[slot].seq_id, slot=slot,
+            moved=int(slot != last),
+        ):
+            with self._dev():
+                if slot != last:
+                    self._arena = self._jit_move(
+                        self._arena, np.int32(last), np.int32(slot)
                     )
-            self._arena = self._jit_clear(self._arena, np.int32(last))
-            if self._spec:
-                self._d_arena = self._d_jit_clear(
-                    self._d_arena, np.int32(last)
-                )
-        with self._lock:
-            if slot != self._n_live - 1:
-                self._slots[slot] = self._slots[self._n_live - 1]
-            self._slots[self._n_live - 1] = None
-            self._n_live -= 1
+                    if self._spec:
+                        self._d_arena = self._d_jit_move(
+                            self._d_arena, np.int32(last), np.int32(slot)
+                        )
+                self._arena = self._jit_clear(self._arena, np.int32(last))
+                if self._spec:
+                    self._d_arena = self._d_jit_clear(
+                        self._d_arena, np.int32(last)
+                    )
+            with self._lock:
+                if slot != last:
+                    self._slots[slot] = self._slots[last]
+                self._slots[last] = None
+                self._n_live -= 1
 
     def _release_prefix(self, seq: _Sequence) -> None:
         """Drop this sequence's reader reference on its prefix-cache
@@ -1366,6 +1494,8 @@ class DecodeTelemetry:
         self._prefix_hit_pages = self._prefix_pages = None
         self._spec_proposed = self._spec_accept = None
         self._spec_ratio = None
+        self._phase_s = self._phase_n = None
+        self._queue_wait = self._ttft = None
         if registry is None:
             return
         from tpu_pipelines.observability.metrics import fine_latency_buckets
@@ -1417,11 +1547,11 @@ class DecodeTelemetry:
             "EWMA wall time of one continuous-batch decode step.",
             labels=lab,
         ).labels(self.replica)
-        # Fine sqrt(2) ladder (metrics.fine_latency_buckets): a decode
-        # step runs in the tens-to-hundreds of µs, BELOW the default x2
-        # ladder's 100µs floor — on the default ladder every per-token
-        # observation piled into the first two buckets and a scraped
-        # quantile was meaningless.
+        # Fine sqrt(2) ladder (metrics.fine_latency_buckets, 25µs to
+        # ~1.6s): per-token latency spans from a tiny model's tens of µs
+        # to a large one's tens of ms (T5-large on a v5e: a 34 ms step,
+        # ~50 ms per token at the tail), and the default x2 ladder both
+        # floors at 100µs and quantizes a scraped quantile by up to 2x.
         self._per_token = registry.histogram(
             "serving_decode_per_token_latency_seconds",
             "Completed-generation latency divided by tokens emitted — "
@@ -1469,6 +1599,37 @@ class DecodeTelemetry:
             "Lifetime speculative acceptance rate (accepted / proposed).",
             labels=lab,
         ).labels(self.replica)
+        phase_lab = ("replica", "phase")
+        seconds = registry.counter(
+            "serving_decode_engine_seconds_total",
+            "Self seconds of the engine's worker thread by phase (a "
+            "nested phase's time is taken out of its parent's): the "
+            "phases add up to the thread's lifetime.", labels=phase_lab,
+        )
+        occurrences = registry.counter(
+            "serving_decode_engine_phase_total",
+            "Occurrences of each phase of the engine's worker thread.",
+            labels=phase_lab,
+        )
+        self._phase_s = {
+            p: seconds.labels(self.replica, p) for p in ENGINE_PHASES
+        }
+        self._phase_n = {
+            p: occurrences.labels(self.replica, p) for p in ENGINE_PHASES
+        }
+        self._queue_wait = registry.histogram(
+            "serving_decode_queue_wait_seconds",
+            "Submit to the admission turn that took the sequence off "
+            "the queue (fine sqrt(2) buckets; _sum and _count exact).",
+            labels=lab, buckets=fine_latency_buckets(),
+        ).labels(self.replica)
+        self._ttft = registry.histogram(
+            "serving_decode_ttft_seconds",
+            "Submit to the sequence's first token on the host: queue "
+            "wait plus prefill, or plus nothing on a prefix-cache hit "
+            "(fine sqrt(2) buckets; _sum and _count exact).",
+            labels=lab, buckets=fine_latency_buckets(),
+        ).labels(self.replica)
 
     def on_step(self, dt, ewma, live, bucket, pages, active) -> None:
         if self._steps is None:
@@ -1488,6 +1649,19 @@ class DecodeTelemetry:
             return
         self._seqs.inc()
         self._per_token.observe(latency_s / max(1, n_tokens))
+
+    def on_phase(self, phase: str, seconds: float) -> None:
+        if self._phase_s is not None:
+            self._phase_s[phase].inc(seconds)
+            self._phase_n[phase].inc()
+
+    def on_admitted(self, queue_wait_s: float) -> None:
+        if self._queue_wait is not None:
+            self._queue_wait.observe(queue_wait_s)
+
+    def on_first_token(self, ttft_s: float) -> None:
+        if self._ttft is not None:
+            self._ttft.observe(ttft_s)
 
     def on_evicted(self) -> None:
         if self._evicted is not None:
