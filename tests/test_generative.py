@@ -885,6 +885,414 @@ def test_engine_t5_decode_opts_bitwise_identity(tiny_t5):
     assert engine.spec_proposed > 0
 
 
+# -------------------------------------- the arena in place (ISSUE 26)
+
+
+def _greedy_rows(model, params, reqs, L, eos_id):
+    """Isolated ``make_greedy_generate`` stream of each prompt."""
+    from tpu_pipelines.models.t5 import make_greedy_generate
+
+    greedy = make_greedy_generate(model, max_decode_len=L, eos_id=eos_id)
+    rows = []
+    for r in reqs:
+        toks, _ = greedy(params, r[None], np.ones((1, len(r)), np.int32))
+        rows.append([int(t) for t in np.asarray(toks)[0]])
+    return rows
+
+
+# (prompt index, max_new_tokens) in queue order, for three slots and
+# KV buckets 4 / 8 / 16.  A, B, C are admitted together; B leaves the
+# MIDDLE slot at 3 tokens (move + clear) and D lands in the slot that
+# freed; A and C leave together at 5 with D two tokens behind them, so
+# the deepest live position falls back under a bucket boundary it had
+# crossed, and D then grows through both boundaries.  E and F repeat
+# B's prompt: a stored prefix entry is inserted a second and third time.
+_STAGGERED = [(0, 5), (1, 3), (2, 5), (3, 12), (1, 4), (1, 7)]
+
+
+@pytest.mark.parametrize("prefix_entries", [0, 8])
+@pytest.mark.parametrize("page_size", [0, 4])
+def test_engine_in_place_arena_matches_isolated_greedy_t5(
+    tiny_t5, page_size, prefix_entries
+):
+    """Every program updates the arena it is handed in place (donated
+    state; the step writes back only the positions it produced), and
+    the streams stay those of isolated greedy decode, row for row:
+    across KV buckets downward and upward, through a middle-slot
+    retirement and an admission into the freed slot, with a prefix
+    entry inserted more than once."""
+    from tpu_pipelines.models.t5 import make_continuous_decode_fns
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    model, params = tiny_t5
+    L, eos = 16, 10_000                 # EOS outside the vocabulary
+    fns = make_continuous_decode_fns(
+        model, max_decode_len=L, eos_id=eos, max_input_len=6
+    )
+    rng = np.random.default_rng(26)
+    prompts = [
+        rng.integers(2, 40, size=(n,)).astype(np.int32)
+        for n in (3, 6, 2, 5)
+    ]
+    iso = _greedy_rows(model, params, prompts, L, eos)
+
+    gate = threading.Event()            # hold the first round back
+    engine = GenerativeEngine(
+        fns, params, max_batch_size=3, page_size=page_size,
+        prefix_cache_entries=prefix_entries, fault_hook=gate.wait,
+    )
+    steps, moves = [], []
+    try:
+        engine.warm()
+        step_for, move = engine._step_for, engine._jit_move
+        engine._step_for = lambda b, kv: (
+            steps.append((b, kv)) or step_for(b, kv)
+        )
+        engine._jit_move = lambda arena, src, dst: (
+            moves.append((int(src), int(dst))) or move(arena, src, dst)
+        )
+        handles = [
+            engine.submit_nowait(prompts[i], max_new_tokens=m)
+            for i, m in _STAGGERED
+        ]
+        gate.set()
+        outs = [h.wait(60.0) for h in handles]
+    finally:
+        gate.set()
+        engine.close()
+    assert engine.compiles_after_warm == 0
+    for (i, m), out in zip(_STAGGERED, outs):
+        assert [int(t) for t in out] == iso[i][:m], (i, m)
+    # The schedule the comment above describes did run.
+    assert moves[0] == (2, 1)           # C into B's middle slot
+    kvs = [kv for _, kv in steps]
+    if page_size:
+        assert kvs[:6] == [4, 4, 4, 8, 4, 8] and kvs[-1] == 16
+    else:
+        assert set(kvs) == {L}
+    if prefix_entries:
+        assert engine._prefix.hits == 2 and engine._prefix.misses == 4
+
+
+def _arena_is_blank(engine, arena) -> bool:
+    import jax
+
+    cache, tok, pos, live, enc, mask = arena
+    return (
+        not np.asarray(live).any()
+        and not np.asarray(pos).any()
+        and (np.asarray(tok) == engine.pad_id).all()
+        and (np.asarray(mask) == 1).all()
+        and not np.asarray(enc).any()
+        and not any(
+            np.asarray(x).any() for x in jax.tree_util.tree_leaves(cache)
+        )
+    )
+
+
+@pytest.mark.parametrize("warms", [1, 2])
+def test_engine_warm_leaves_a_blank_arena_and_every_program_cached(warms):
+    """``warm()`` runs every program in place on the arena and hands
+    traffic a blank one — once or twice over — under the cache keys it
+    warmed: traffic then builds no bucket program
+    (``compiles_after_warm``) and misses no jit cache either."""
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    engine = GenerativeEngine(
+        make_stub_fns(), {}, max_batch_size=4, page_size=4, spec_tokens=2,
+        prefix_cache_entries=4,
+    )
+    try:
+        for _ in range(warms):
+            engine.warm()
+            assert _arena_is_blank(engine, engine._arena)
+            assert _arena_is_blank(engine, engine._d_arena)
+        programs = [
+            engine._jit_prefill, engine._jit_insert, engine._jit_move,
+            engine._jit_clear, engine._jit_accept, engine._d_jit_insert,
+            engine._d_jit_move, engine._d_jit_clear,
+            *engine._step_fns.values(), *engine._d_step_fns.values(),
+            *engine._verify_fns.values(),
+        ]
+        assert len(programs) == 8 + 3 * 3 * 3
+        warmed = [f._cache_size() for f in programs]
+        rng = np.random.default_rng(5)
+        reqs = [
+            (
+                rng.integers(1, VOCAB, size=(1 + i % 3,)).astype(np.int32),
+                int(rng.integers(2, 12)),
+            )
+            for i in range(10)
+        ]
+        handles = [
+            engine.submit_nowait(inp, max_new_tokens=m) for inp, m in reqs
+        ]
+        outs = [h.wait(30.0) for h in handles]
+        assert [f._cache_size() for f in programs] == warmed
+    finally:
+        engine.close()
+    assert engine.compiles_after_warm == 0
+    for (inp, m), out in zip(reqs, outs):
+        assert [int(t) for t in out] == ref_stream(inp, m)
+
+
+def test_engine_warm_refuses_an_engine_with_work_in_flight():
+    """Warming runs on the arena itself, so it needs an engine with
+    nothing live or queued; the sequence in flight is not disturbed."""
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    gate = threading.Event()
+    engine = GenerativeEngine(
+        make_stub_fns(), {}, max_batch_size=2, fault_hook=gate.wait
+    )
+    try:
+        engine.warm()
+        inp = np.array([3, 5], np.int32)
+        h = engine.submit_nowait(inp, max_new_tokens=6)
+        with pytest.raises(RuntimeError, match="live or queued"):
+            engine.warm()
+        gate.set()
+        assert [int(t) for t in h.wait(30.0)] == ref_stream(inp, 6)
+        engine.warm()                   # idle again: allowed
+    finally:
+        gate.set()
+        engine.close()
+
+
+@pytest.mark.parametrize("contract", ["stub", "t5"])
+def test_engine_speculative_paged_accepts_exactly_the_greedy_stream(
+    contract, tiny_t5
+):
+    """The speculative lanes on in-place arenas, across KV buckets: the
+    verify program writes back its ``k`` positions per row (the
+    contract's ``verify`` on T5, ``k`` chained steps on the stub) and
+    the emitted stream is the greedy one."""
+    from tpu_pipelines.models.t5 import make_continuous_decode_fns
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    L = 16
+    rng = np.random.default_rng(27)
+    if contract == "t5":
+        model, params = tiny_t5
+        fns = make_continuous_decode_fns(
+            model, max_decode_len=L, eos_id=10_000, max_input_len=6
+        )
+        lo, hi = 2, 40
+    else:
+        fns, params, lo, hi = make_stub_fns(max_decode_len=L), {}, 1, VOCAB
+    reqs = [
+        (
+            rng.integers(lo, hi, size=(int(rng.integers(2, 7)),))
+            .astype(np.int32),
+            int(rng.integers(2, L + 1)),
+        )
+        for _ in range(8)
+    ]
+    if contract == "t5":
+        rows = _greedy_rows(model, params, [r for r, _ in reqs], L, 10_000)
+        want = [row[:m] for row, (_, m) in zip(rows, reqs)]
+    else:
+        want = [ref_stream(r, m, max_decode_len=L) for r, m in reqs]
+    engine = GenerativeEngine(
+        fns, params, max_batch_size=2, page_size=4, spec_tokens=3,
+    )
+    try:
+        engine.warm()
+        handles = [
+            engine.submit_nowait(r, max_new_tokens=m) for r, m in reqs
+        ]
+        outs = [h.wait(60.0) for h in handles]
+    finally:
+        engine.close()
+    assert engine.compiles_after_warm == 0
+    assert [[int(t) for t in out] for out in outs] == want
+    assert engine.spec_accepted > 0
+    if contract == "stub":              # ints: the draft IS the target
+        assert engine.spec_accepted == engine.spec_proposed
+
+
+def test_engine_worker_death_lets_go_of_the_arena():
+    """A program that fails may have taken its donated arena with it:
+    the dead worker keeps no arena, fails what was in flight and takes
+    no more."""
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    boom = threading.Event()
+
+    def hook():
+        if boom.is_set():
+            raise RuntimeError("injected device fault")
+
+    engine = GenerativeEngine(
+        make_stub_fns(), {}, max_batch_size=2, fault_hook=hook
+    )
+    try:
+        engine.warm()
+        inp = np.array([2, 7], np.int32)
+        assert [int(t) for t in engine.submit(inp, max_new_tokens=4)] \
+            == ref_stream(inp, 4)
+        assert engine._arena is not None
+        boom.set()
+        h = engine.submit_nowait(inp, max_new_tokens=4)
+        with pytest.raises(RuntimeError, match="injected device fault"):
+            h.wait(30.0)
+        assert engine._arena is None and engine._d_arena is None
+        with pytest.raises(RuntimeError, match="worker died"):
+            engine.submit_nowait(inp, max_new_tokens=4)
+    finally:
+        engine.close()
+
+
+# Which positional argument of each program is the arena, and how many
+# of the arena's leaves the program does not read at all: ``accept``
+# replaces ``tok`` and ``pos`` wholesale, so jax prunes the old ones
+# from the lowered program's arguments.
+_ARENA_PROGRAMS = {
+    "insert": (0, 0), "move": (0, 0), "clear": (0, 0), "accept": (0, 2),
+    "step": (1, 0), "step, whole arena": (1, 0), "verify": (1, 0),
+    "verify, chained steps": (1, 0),
+    "draft insert": (0, 0), "draft move": (0, 0), "draft clear": (0, 0),
+    "draft step": (1, 0), "draft accept": (0, 2),
+}
+
+
+@pytest.fixture(scope="module")
+def lowered_arena_programs(tiny_t5):
+    """``{name: (lowered program, its arguments)}`` for every program
+    that takes an arena, target lane and draft lane, on the tiny T5
+    (its contract has ``verify``) plus the stub's chained-steps verify."""
+    from tpu_pipelines.models.t5 import make_continuous_decode_fns
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    model, params = tiny_t5
+    fns = make_continuous_decode_fns(
+        model, max_decode_len=8, eos_id=1, max_input_len=6
+    )
+    out = {}
+    engine = GenerativeEngine(
+        fns, params, max_batch_size=4, page_size=2, spec_tokens=2
+    )
+    stub = GenerativeEngine(
+        make_stub_fns(), {"offset": 1}, max_batch_size=4, page_size=4,
+        spec_tokens=2,
+    )
+    try:
+        engine._ensure_arena()          # lowering needs no warm program
+        stub._ensure_arena()
+        zin = np.zeros((1, 6), np.int32)
+        c1, e1, _ = engine._jit_prefill(engine.params, zin, zin)
+        dc1, de1, _ = engine._d_jit_prefill(engine.draft_params, zin, zin)
+        slot, one = np.int32(0), np.int32(1)
+        ztok = np.zeros((4,), np.int32)
+        zk = np.zeros((4, 2), np.int32)
+        a, d = engine._arena, engine._d_arena
+        calls = {
+            "insert": (engine._jit_insert, (a, c1, e1, zin, one, slot)),
+            "move": (engine._jit_move, (a, slot, slot)),
+            "clear": (engine._jit_clear, (a, slot)),
+            "accept": (engine._jit_accept, (a, ztok, ztok)),
+            "step": (engine._step_for(2, 4), (engine.params, a)),
+            "step, whole arena": (
+                engine._step_for(4, 8), (engine.params, a)),
+            "verify": (engine._verify_for(2, 4), (engine.params, a, zk)),
+            "verify, chained steps": (
+                stub._verify_for(2, 4), (stub.params, stub._arena, zk)),
+            "draft insert": (
+                engine._d_jit_insert, (d, dc1, de1, zin, one, slot)),
+            "draft move": (engine._d_jit_move, (d, slot, slot)),
+            "draft clear": (engine._d_jit_clear, (d, slot)),
+            "draft step": (
+                engine._d_step_for(2, 4), (engine.draft_params, d)),
+            "draft accept": (engine._jit_accept, (d, ztok, ztok)),
+        }
+        for name, (prog, args) in calls.items():
+            out[name] = (prog.lower(*args), args)
+        out["prefill"] = (
+            engine._jit_prefill.lower(engine.params, zin, zin), ())
+    finally:
+        engine.close()
+        stub.close()
+    return out
+
+
+@pytest.mark.parametrize("name", sorted(_ARENA_PROGRAMS))
+def test_arena_program_aliases_every_state_leaf(
+    name, lowered_arena_programs
+):
+    """The engagement check of the in-place arena, static and exact:
+    the program donates its arena and nothing else (not the parameters,
+    not the prefill results ``insert`` copies from), and the lowered
+    program aliases every arena leaf it takes to an output of its own."""
+    import re
+
+    import jax
+
+    lowered, args = lowered_arena_programs[name]
+    state_at, unread = _ARENA_PROGRAMS[name]
+    want = []
+    for i, arg in enumerate(args):
+        want += [i == state_at] * len(jax.tree_util.tree_leaves(arg))
+    got = [
+        info.donated for info in jax.tree_util.tree_leaves(lowered.args_info)
+    ]
+    assert got == want
+    text = lowered.as_text()
+    aliased = re.findall(r"tf\.aliasing_output = (\d+)", text)
+    assert len(aliased) == sum(want) - unread
+    assert len(set(aliased)) == len(aliased)
+    assert "jax.buffer_donor" not in text   # donated, aliased to nothing
+
+
+def test_prefill_donates_nothing(lowered_arena_programs):
+    import jax
+
+    lowered, _ = lowered_arena_programs["prefill"]
+    assert not any(
+        i.donated for i in jax.tree_util.tree_leaves(lowered.args_info)
+    )
+    assert "tf.aliasing_output" not in lowered.as_text()
+
+
+def test_arena_programs_run_in_place_and_kill_the_arena_they_took():
+    """At run time: the arena handed to a program is dead afterwards,
+    and the arena it returns lies in the same buffers."""
+    import jax
+
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    engine = GenerativeEngine(
+        make_stub_fns(), {}, max_batch_size=4, page_size=4
+    )
+    try:
+        engine.warm()
+        zin = np.ones((1, engine.max_input_len), np.int32)
+        c1, e1, t0 = engine._jit_prefill(engine.params, zin, zin)
+        slot, ztok = np.int32(1), np.zeros((4,), np.int32)
+        leaves = jax.tree_util.tree_leaves
+        # (program, arena leaves it does not read): accept replaces tok
+        # and pos wholesale, so the old ones are neither taken nor reused.
+        for call, unread in (
+            (lambda a: engine._jit_insert(
+                a, c1, e1, zin, np.int32(int(t0)), slot), 0),
+            (lambda a: engine._jit_move(a, slot, np.int32(0)), 0),
+            (lambda a: engine._step_for(2, 4)(engine.params, a)[0], 0),
+            (lambda a: engine._step_for(4, 12)(engine.params, a)[0], 0),
+            (lambda a: engine._jit_clear(a, slot), 0),
+            (lambda a: engine._jit_accept(a, ztok, ztok), 2),
+        ):
+            old = leaves(engine._arena)
+            where = [x.unsafe_buffer_pointer() for x in old]
+            engine._arena = call(engine._arena)
+            assert sum(not x.is_deleted() for x in old) == unread
+            there = [x.unsafe_buffer_pointer() for x in leaves(engine._arena)]
+            assert sum(p != q for p, q in zip(where, there)) == unread
+        # The prefill results insert copied from are still alive: a
+        # prefix-cache entry is inserted many times.
+        assert not any(x.is_deleted() for x in leaves((c1, e1)))
+    finally:
+        engine.close()
+
+
 def test_flash_decode_kernel_matches_dense():
     """The single-query flash-decode kernel (online-softmax over KV
     blocks) matches dense cache attention with per-row validity masks and

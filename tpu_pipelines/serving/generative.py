@@ -18,7 +18,12 @@ static-shape substrate):
     position, live flag, encoder output and mask.  Live sequences occupy
     the compacted prefix ``[0, n_live)``; a departure moves the last live
     row into the hole (one scatter), an arrival lands at ``n_live`` (one
-    scatter) — no host-side repacking of the cache, ever.
+    scatter) — no host-side repacking of the cache, ever.  There is ONE
+    arena and it is updated where it lies: every program that takes it
+    has it donated (``_jit_program``), so a scatter of one row writes
+    one row and a step writes its bucket, not a fresh copy of
+    everything else; ``self._arena`` is only ever rebound to what a
+    program returned.
   * **Bucketed steps.**  Each decode step runs one pre-compiled program
     keyed ``(batch_bucket, kv_bucket)``: the batch bucket is the smallest
     power-of-two >= the live count (serving/batching.py's bucket rule),
@@ -151,14 +156,25 @@ PROGRAM_NAMES = (
 
 def _jit_program(fn):
     """``jax.jit`` for one of the engine's device programs, held to the
-    names a trace reader looks for."""
+    names a trace reader looks for.  A program that takes the arena (its
+    ``state`` argument) is given it for good: the argument is donated,
+    every leaf of it is aliased to the matching leaf of the state the
+    program returns, and the program updates the arena where it lies
+    instead of writing a new one.  The caller's old arena is dead after
+    the call (reading it raises), so every call site reads
+    ``arena = prog(arena, ...)``.  Nothing else is donated: not the
+    parameters, not the prefill results ``insert`` copies a row from (a
+    prefix-cache entry is inserted many times)."""
+    import inspect
+
     import jax
 
     if "jit_" + fn.__name__ not in PROGRAM_NAMES:
         raise ValueError(
             f"engine program {fn.__name__!r} is not in PROGRAM_NAMES"
         )
-    return jax.jit(fn)
+    takes_arena = "state" in inspect.signature(fn).parameters
+    return jax.jit(fn, donate_argnames=("state",) if takes_arena else ())
 
 
 class EngineOverloaded(RuntimeError):
@@ -276,6 +292,32 @@ def _is_enc_leaf(path) -> bool:
     """Cross-attention K/V leaves keep the ENCODER length on axis 1 (not
     the decode cache length) and are never written by a decode step."""
     return any("cached_enc" in str(getattr(p, "key", p)) for p in path)
+
+
+def _bucket_of(cache, b: int, kv: int):
+    """The arena's cache cut to one step program's static bucket: the
+    first ``b`` rows, and of the decode K/V their first ``kv``
+    positions."""
+    import jax
+
+    return jax.tree_util.tree_map_with_path(
+        lambda p, x: x[:b] if _is_enc_leaf(p) else x[:b, :kv], cache
+    )
+
+
+def _write_back(cache, new_sub, b: int, kv: int):
+    """The arena's cache with ``new_sub``, what the contract's ``step`` /
+    ``verify`` returned for the bucket ``_bucket_of(cache, b, kv)``, set
+    back where the bucket was cut from.  The arena is donated, so this
+    is one in-place write of the bucket per decode K/V leaf, and over
+    the whole arena (``b`` and ``kv`` its own sizes) none at all:
+    ``new_sub`` then IS the arena, written into by the step itself."""
+    import jax
+
+    return jax.tree_util.tree_map_with_path(
+        lambda p, a, n: a if _is_enc_leaf(p) else a.at[:b, :kv].set(n),
+        cache, new_sub,
+    )
 
 
 class _PrefixEntry:
@@ -644,26 +686,20 @@ class GenerativeEngine:
         self._jit_accept = _jit_program(accept)
 
     def _build_step(self, b: int, kv: int, fns):
-        import jax
         import jax.numpy as jnp
 
         pad = self.pad_id
 
         def run(params, state):
             cache, tok, pos, live, encoded, enc_mask = state
-            sub = jax.tree_util.tree_map_with_path(
-                lambda p, x: x[:b] if _is_enc_leaf(p) else x[:b, :kv], cache
-            )
             new_sub, logits = fns.step(
-                params, sub, tok[:b], pos[:b], encoded[:b], enc_mask[:b], kv
+                params, _bucket_of(cache, b, kv), tok[:b], pos[:b],
+                encoded[:b], enc_mask[:b], kv,
             )
             nxt = jnp.where(
                 live[:b], jnp.argmax(logits, -1).astype(jnp.int32), pad
             )
-            cache = jax.tree_util.tree_map_with_path(
-                lambda p, a, n: a if _is_enc_leaf(p) else a.at[:b, :kv].set(n),
-                cache, new_sub,
-            )
+            cache = _write_back(cache, new_sub, b, kv)
             tok = tok.at[:b].set(nxt)
             pos = pos.at[:b].set(pos[:b] + live[:b].astype(jnp.int32))
             return (cache, tok, pos, live, encoded, enc_mask), nxt
@@ -677,7 +713,6 @@ class GenerativeEngine:
         it — same math, k launches).  Returns the updated cache plus
         greedy picks ``g[b, k]`` where ``g[:, j]`` is the target's choice
         at position ``pos + j`` given the fed tokens."""
-        import jax
         import jax.numpy as jnp
 
         fns = self.fns
@@ -688,9 +723,7 @@ class GenerativeEngine:
             # toks[b, k]: column 0 is each row's current last emitted
             # token, columns 1..k-1 the draft's first k-1 proposals.
             cache, tok, pos, live, encoded, enc_mask = state
-            sub = jax.tree_util.tree_map_with_path(
-                lambda p, x: x[:b] if _is_enc_leaf(p) else x[:b, :kv], cache
-            )
+            sub = _bucket_of(cache, b, kv)
             if verify is not None:
                 new_sub, logits = verify(
                     params, sub, toks[:b], pos[:b],
@@ -707,10 +740,7 @@ class GenerativeEngine:
                     outs.append(lg)
                 logits = jnp.stack(outs, axis=1)
             g = jnp.argmax(logits, -1).astype(jnp.int32)  # [b, k]
-            cache = jax.tree_util.tree_map_with_path(
-                lambda p, a, n: a if _is_enc_leaf(p) else a.at[:b, :kv].set(n),
-                cache, new_sub,
-            )
+            cache = _write_back(cache, new_sub, b, kv)
             return (cache, tok, pos, live, encoded, enc_mask), g
 
         return _jit_program(run)
@@ -810,56 +840,69 @@ class GenerativeEngine:
         move / clear, and one step per ``(batch_bucket, kv_bucket)``.
         The fleet's canary gate runs this BEFORE a version becomes
         eligible — the decode analog of the predict-bucket warmup — so a
-        hot-swap never pays an XLA compile mid-traffic.  Results are
-        discarded; the arena is untouched (jax arrays are immutable).
+        hot-swap never pays an XLA compile mid-traffic.  Every arena
+        program runs in place on the arena it is handed (``_jit_program``
+        donates it), so the calls below thread the engine's own arena
+        through one another and traffic is then handed a fresh blank
+        one, built the way the first was: same device, same commitment,
+        same program cache keys.  That is why warming needs an engine
+        with nothing in flight (``RuntimeError`` otherwise), and why
+        the engine's lock is held throughout: a submit waits.
         Arguments mirror the traffic paths exactly — host numpy inputs,
         the committed arena — so every call lands on the SAME program
         cache key traffic will use (see _ensure_arena on placement)."""
-        with self._dev():
+        with self._lock, self._dev():
+            if self._n_live or self._queue:
+                raise RuntimeError(
+                    "warm() runs its programs on the arena itself: "
+                    "the engine must have no sequence live or queued"
+                )
             self._ensure_arena()
             zin = np.full((1, self.max_input_len), self.pad_id, np.int32)
             zmask = np.zeros((1, self.max_input_len), np.int32)
-            cache1, encoded1, tok0 = self._jit_prefill(
-                self.params, zin, zmask
-            )
-            # tok0 goes to insert as a HOST int32: the prefix-cache hit
-            # path has only the entry's host token, and warm/miss/hit
-            # must all land on the same insert program cache key.
-            self._jit_insert(
-                self._arena, cache1, encoded1, zmask,
-                np.int32(int(tok0)), np.int32(0),
-            )
-            self._jit_move(self._arena, np.int32(0), np.int32(0))
-            self._jit_clear(self._arena, np.int32(0))
-            for b in self.batch_buckets:
-                for kv in self.kv_buckets:
-                    self._step_for(b, kv)(self.params, self._arena)
+            slot = np.int32(0)
             B = self.max_batch_size
             ztok = np.full((B,), self.pad_id, np.int32)
             zpos = np.zeros((B,), np.int32)
-            self._jit_accept(self._arena, ztok, zpos)
+
+            def lane(arena, params, prefill, insert, move, clear, step_for):
+                cache1, encoded1, tok0 = prefill(params, zin, zmask)
+                # tok0 goes to insert as a HOST int32: the prefix-cache
+                # hit path has only the entry's host token, and
+                # warm/miss/hit must all land on the same insert
+                # program cache key.
+                arena = insert(
+                    arena, cache1, encoded1, zmask, np.int32(int(tok0)), slot
+                )
+                arena = clear(move(arena, slot, slot), slot)
+                for b in self.batch_buckets:
+                    for kv in self.kv_buckets:
+                        arena, _ = step_for(b, kv)(params, arena)
+                return self._jit_accept(arena, ztok, zpos)
+
+            self._arena = lane(
+                self._arena, self.params, self._jit_prefill,
+                self._jit_insert, self._jit_move, self._jit_clear,
+                self._step_for,
+            )
             if self._spec:
-                dc1, de1, dt0 = self._d_jit_prefill(
-                    self.draft_params, zin, zmask
+                self._d_arena = lane(
+                    self._d_arena, self.draft_params, self._d_jit_prefill,
+                    self._d_jit_insert, self._d_jit_move,
+                    self._d_jit_clear, self._d_step_for,
                 )
-                self._d_jit_insert(
-                    self._d_arena, dc1, de1, zmask,
-                    np.int32(int(dt0)), np.int32(0),
-                )
-                self._d_jit_move(self._d_arena, np.int32(0), np.int32(0))
-                self._d_jit_clear(self._d_arena, np.int32(0))
-                self._jit_accept(self._d_arena, ztok, zpos)
                 zk = np.full(
                     (B, self.spec_tokens), self.pad_id, np.int32
                 )
                 for b in self.batch_buckets:
                     for kv in self.kv_buckets:
-                        self._d_step_for(b, kv)(
-                            self.draft_params, self._d_arena
-                        )
-                        self._verify_for(b, kv)(
+                        self._arena, _ = self._verify_for(b, kv)(
                             self.params, self._arena, zk
                         )
+            # Let go of the warmed arena before its blank successor is
+            # allocated: the chip need not hold two.
+            self._arena = self._d_arena = None
+            self._ensure_arena()
         self._warmed = True
 
     # ------------------------------------------------------------- client
@@ -1047,6 +1090,10 @@ class GenerativeEngine:
             log.exception("generative engine worker died")
             with self._lock:
                 self._dead = True
+                # The program that failed may have taken the arena with
+                # it (a donated argument is gone whether or not its
+                # program ran to the end): nothing may use it again.
+                self._arena = self._d_arena = None
                 pending = list(self._queue) + [
                     s for s in self._slots[: self._n_live] if s is not None
                 ]
