@@ -6,6 +6,8 @@ Present:
   - resnet: ResNet-18/34/50/101/152, NHWC bfloat16 (config 2)
   - bert: BERT-base encoder + classifier/MLM heads (config 3)
   - t5: T5-small encoder-decoder seq2seq (config 4)
+  - evabyte: byte-level decoder-only LM on EVA chunked linear attention
+    (served through serving/generative.py; window ring + chunk table)
   - transformer: shared sharded blocks (TP over 'model', ring-attention SP
     over 'seq') used by bert/t5
 

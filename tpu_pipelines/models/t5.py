@@ -333,13 +333,27 @@ def make_continuous_decode_fns(
         per-row masking makes the result independent of ``klen`` as long
         as every live position fits);
       - geometry/vocabulary constants (``max_decode_len``, ``eos_id``,
-        ``pad_id``, ``max_input_len``) the engine sizes its arena from.
+        ``pad_id``, ``max_input_len``) the engine sizes its arena from;
+      - what the engine may not guess: the two kinds of cache array
+        (``cache_kinds``, ``cache_kind_of(path)``: self-attention K/V by
+        decode position, which a step writes, beside the cross-attention
+        K/V at the encoder length, which it only reads), and a
+        sequence's first decode position (``first_decode_pos``: 1, behind
+        the BOS that ``prefill`` consumed).
 
     Exported modules opt their payloads into generative serving by
     defining ``make_decode_fns(model, hyperparameters)`` returning this
     (trainer/export.py wires it onto ``LoadedModel.decode_fns``).
     """
     from types import SimpleNamespace
+
+    from tpu_pipelines.serving.generative import CacheKind
+
+    def cache_kind_of(path) -> str:
+        # models/transformer.py names the cross-attention K/V
+        # ``cached_enc_key`` / ``cached_enc_value``.
+        cross = any("cached_enc" in str(getattr(p, "key", p)) for p in path)
+        return "cross_kv" if cross else "decode_kv"
 
     def prefill(params, inputs, input_mask=None):
         return prefill_decode(
@@ -373,6 +387,12 @@ def make_continuous_decode_fns(
         prefill=prefill,
         step=step,
         verify=verify,
+        cache_kinds={
+            "decode_kv": CacheKind(by_position=True, written=True),
+            "cross_kv": CacheKind(by_position=False, written=False),
+        },
+        cache_kind_of=cache_kind_of,
+        first_decode_pos=lambda input_mask: 1,
         max_decode_len=int(max_decode_len),
         eos_id=int(eos_id),
         pad_id=int(pad_id),

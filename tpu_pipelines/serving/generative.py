@@ -13,10 +13,14 @@ Mechanics (the vLLM/PagedAttention shape of the idea, on the repo's
 static-shape substrate):
 
   * **Arena.**  One device-resident state pool sized ``max_batch_size``:
-    the flax decode cache (self-attention K/V at ``max_decode_len``,
-    cross-attention K/V at the encoder length), per-slot last token,
-    position, live flag, encoder output and mask.  Live sequences occupy
-    the compacted prefix ``[0, n_live)``; a departure moves the last live
+    the decode cache, per-slot last token, position, live flag, encoder
+    output and mask.  What the cache's arrays are is the contract's to
+    say (``CacheKind``): an encoder-decoder's self-attention K/V at
+    ``max_decode_len`` beside its cross-attention K/V at the encoder
+    length; a windowed decoder's ring of exact positions beside its
+    table of chunk summaries, neither indexed by decode position.
+    Live sequences occupy the compacted prefix ``[0, n_live)``; a
+    departure moves the last live
     row into the hole (one scatter), an arrival lands at ``n_live`` (one
     scatter) — no host-side repacking of the cache, ever.  There is ONE
     arena and it is updated where it lies: every program that takes it
@@ -72,12 +76,17 @@ same ``make_decode_fns`` contract, each off by default:
     ``prefill_chunk_pages`` credits, and an admission costs the prompt's
     page count (1 for a prefix-cache hit) — so a burst of long-prompt
     arrivals is spread across decode steps instead of running
-    back-to-back and stalling every live sequence's token deadline.  On
-    this substrate one prompt's prefill is a single device program (the
-    encoder is bidirectional — not token-chunkable without changing the
-    math), so chunking bounds the admission work *between* steps; the
-    compiled programs are identical with the knob on or off, which keeps
-    token streams bitwise-identical either way.
+    back-to-back and stalling every live sequence's token deadline.
+    Whether ONE prompt's prefill can be cut is the contract's to say.  A
+    contract with a whole-prompt ``prefill`` (an encoder is
+    bidirectional: not token-chunkable without changing the math) runs
+    it as a single device program, and chunking bounds the admission
+    work *between* steps; a contract with ``prefill_window`` (a causal
+    decoder) is prefilled a window of ``prefill_window_len`` tokens per
+    program call, a page is a window, each call costs one credit, and
+    decode steps of the live rows run between one prompt's windows.
+    The compiled programs are identical with the knob on or off, which
+    keeps token streams bitwise-identical either way.
   * **Speculative decoding** (``spec_tokens k > 0``).  A draft model
     (any ``make_decode_fns`` contract sharing the target's geometry;
     ``draft_fns=None`` means self-draft — the target drafts for itself,
@@ -121,7 +130,7 @@ import logging
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
@@ -137,9 +146,12 @@ log = logging.getLogger("tpu_pipelines.serving")
 # ``serving_decode_engine_{seconds,phase}_total`` and, prefixed with
 # ``engine.``, as span names in a profile.  Every moment of the worker's
 # life belongs to exactly one (a nested phase's time is taken out of its
-# parent's), so the seven sums add up to the thread's lifetime.
+# parent's), so the sums add up to the thread's lifetime.  ``prefill`` is
+# a whole-prompt prefill, ``prefill.window`` one window of a contract
+# that is prefilled by window; an engine books one of the two.
 ENGINE_PHASES = (
     "idle", "admit", "prefill", "insert", "step", "emit", "retire",
+    "prefill.window",
 )
 
 # The engine's device programs as a profile's "XLA Modules" line names
@@ -152,6 +164,44 @@ PROGRAM_NAMES = (
     "jit_prefill", "jit_insert", "jit_move", "jit_clear", "jit_run",
     "jit_accept",
 )
+# The one program more that a contract prefilled by window has, in place
+# of ``jit_prefill`` (benchmark/layer_metrics/eva_prefill_mfu.serve and
+# prefill_device_share.serve read it).
+WINDOW_PROGRAM_NAME = "jit_prefill_window"
+
+
+class CacheKind(NamedTuple):
+    """What a decode contract states about one kind of cache array
+    (``fns.cache_kinds[fns.cache_kind_of(path)]``), every array being
+    ``[slots, entries, ...]``.
+
+    ``by_position``: axis 1 is the decode position.  A step's
+    ``(b, kv)`` bucket is then the first ``kv`` entries of the first
+    ``b`` rows, and entries at or past a row's position hold nothing (the
+    accept program zeroes them).  Otherwise what is valid in a row is the
+    contract's own business, and a step is handed its ``b`` rows whole.
+    ``written``: a step returns the array changed, and the engine sets it
+    back into the arena; otherwise a step only reads it.
+    ``in_place``: a step is handed the array of EVERY slot, reads and
+    writes the first ``b`` rows where they lie and returns the array: no
+    bucket is cut out and none set back.  For arrays too large to copy
+    a bucket of at every step."""
+
+    by_position: bool
+    written: bool
+    in_place: bool = False
+
+
+# A contract that states nothing: every array is K/V by decode position.
+_POSITION_KV = CacheKind(by_position=True, written=True)
+
+
+def _kind_reader(fns):
+    """``path -> CacheKind`` for one contract's cache leaves."""
+    kinds = getattr(fns, "cache_kinds", None)
+    if kinds is None:
+        return lambda path: _POSITION_KV
+    return lambda path: kinds[fns.cache_kind_of(path)]
 
 
 def _jit_program(fn):
@@ -169,12 +219,16 @@ def _jit_program(fn):
 
     import jax
 
-    if "jit_" + fn.__name__ not in PROGRAM_NAMES:
+    if "jit_" + fn.__name__ not in PROGRAM_NAMES + (WINDOW_PROGRAM_NAME,):
         raise ValueError(
             f"engine program {fn.__name__!r} is not in PROGRAM_NAMES"
         )
-    takes_arena = "state" in inspect.signature(fn).parameters
-    return jax.jit(fn, donate_argnames=("state",) if takes_arena else ())
+    # ``row_cache`` is the one row a window-by-window prefill builds up:
+    # given for good like the arena, and for the same reason.
+    donated = tuple(
+        name for name in ("state", "row_cache")
+        if name in inspect.signature(fn).parameters)
+    return jax.jit(fn, donate_argnames=donated)
 
 
 class EngineOverloaded(RuntimeError):
@@ -249,6 +303,10 @@ class _Sequence:
     admitted_s: Optional[float] = None
     first_token_s: Optional[float] = None
     done_s: Optional[float] = None
+    # Position of the first token a decode step feeds: 1 behind a BOS,
+    # the prompt's length under a decoder-only contract (set where the
+    # engine keeps the account of what steps read, ``step_account``).
+    first_pos: int = 1
 
     def finish(self, error: Optional[BaseException] = None) -> None:
         if self._done.is_set():
@@ -288,36 +346,42 @@ def kv_bucket_sizes(max_decode_len: int, page_size: int) -> List[int]:
     return sorted(set(out))
 
 
-def _is_enc_leaf(path) -> bool:
-    """Cross-attention K/V leaves keep the ENCODER length on axis 1 (not
-    the decode cache length) and are never written by a decode step."""
-    return any("cached_enc" in str(getattr(p, "key", p)) for p in path)
-
-
-def _bucket_of(cache, b: int, kv: int):
+def _bucket_of(cache, b: int, kv: int, kind_of):
     """The arena's cache cut to one step program's static bucket: the
-    first ``b`` rows, and of the decode K/V their first ``kv``
-    positions."""
+    first ``b`` rows, and of the arrays indexed by decode position their
+    first ``kv`` entries; an array its steps work on in place is handed
+    over whole (``kind_of``: ``_kind_reader`` of the contract)."""
     import jax
 
-    return jax.tree_util.tree_map_with_path(
-        lambda p, x: x[:b] if _is_enc_leaf(p) else x[:b, :kv], cache
-    )
+    def cut(p, x):
+        kind = kind_of(p)
+        if kind.in_place:
+            return x
+        return x[:b, :kv] if kind.by_position else x[:b]
+
+    return jax.tree_util.tree_map_with_path(cut, cache)
 
 
-def _write_back(cache, new_sub, b: int, kv: int):
+def _write_back(cache, new_sub, b: int, kv: int, kind_of):
     """The arena's cache with ``new_sub``, what the contract's ``step`` /
     ``verify`` returned for the bucket ``_bucket_of(cache, b, kv)``, set
-    back where the bucket was cut from.  The arena is donated, so this
-    is one in-place write of the bucket per decode K/V leaf, and over
-    the whole arena (``b`` and ``kv`` its own sizes) none at all:
-    ``new_sub`` then IS the arena, written into by the step itself."""
+    back where the bucket was cut from; an array the contract's steps
+    only read stays as it is.  The arena is donated, so this is one
+    in-place write of the bucket per written array, and over the whole
+    arena (``b`` and ``kv`` its own sizes, or an array worked on in
+    place) none at all: ``new_sub`` then IS the arena, written into by
+    the step itself."""
     import jax
 
-    return jax.tree_util.tree_map_with_path(
-        lambda p, a, n: a if _is_enc_leaf(p) else a.at[:b, :kv].set(n),
-        cache, new_sub,
-    )
+    def put(p, a, n):
+        kind = kind_of(p)
+        if not kind.written:
+            return a
+        if kind.in_place:
+            return n
+        return a.at[:b, :kv].set(n) if kind.by_position else a.at[:b].set(n)
+
+    return jax.tree_util.tree_map_with_path(put, cache, new_sub)
 
 
 class _PrefixEntry:
@@ -507,10 +571,28 @@ class GenerativeEngine:
             self.page_size if 0 < self.page_size < self.max_decode_len
             else self.max_decode_len
         )
-        # Prompt-side page unit (prefix hashing + admission credits):
-        # the configured page size, or the whole prompt when unpaged.
-        self._ppage = (
+        # A contract prefilled by window (``prefill_window``) has no
+        # whole-prompt prefill program; 0 = a whole-prompt ``prefill``.
+        self._window_len = int(getattr(fns, "prefill_window_len", 0))
+        # Prompt-side page unit (prefix hashing + admission credits): one
+        # prefill window, else the configured page size, or the whole
+        # prompt when unpaged.
+        self._ppage = self._window_len or (
             self.page_size if self.page_size > 0 else self.max_input_len
+        )
+        if self._window_len and (prefix_cache_entries or spec_tokens):
+            # Both keep or mirror what ONE prefill program returned; the
+            # windows of a prompt build one row's cache up in place.
+            raise ValueError(
+                "a contract prefilled by window takes neither the prefix "
+                "cache nor speculative decoding"
+            )
+        self._kind_of = _kind_reader(fns)
+        self._account = getattr(fns, "step_account", None)
+        # What ``insert`` is handed as a row's encoder output where the
+        # contract has no whole-prompt prefill to return one.
+        self._no_encoded = np.zeros(
+            (1,) + tuple(getattr(fns, "encoded_shape", (0,))), np.float32
         )
         self.prefix_cache_entries = max(0, int(prefix_cache_entries))
         self._prefix = (
@@ -575,6 +657,14 @@ class GenerativeEngine:
         self._jit_move = None
         self._jit_clear = None
         self._jit_accept = None
+        self._jit_prefill_window = None
+        # The one row that a prompt's windows are prefilled into (a
+        # contract with ``prefill_window``): built up in place, copied
+        # into a slot by ``insert``, used again for the next prompt.
+        self._row_cache = None
+        # (sequence, windows done): the queue's head while its windows
+        # run; it leaves the queue with its last one.
+        self._partial: Optional[Tuple[_Sequence, int]] = None
         # Draft lane (speculative decoding): a second arena mirroring
         # every slot, stepped by the draft contract's own programs.
         self._d_arena = None
@@ -584,6 +674,7 @@ class GenerativeEngine:
         self._d_jit_insert = None
         self._d_jit_move = None
         self._d_jit_clear = None
+        self._d_jit_accept = None
 
         self._worker = threading.Thread(target=self._run, daemon=True)
         self._worker.start()
@@ -599,11 +690,19 @@ class GenerativeEngine:
 
     # ------------------------------------------------------- compiled fns
 
-    def _lane_jits(self, fns) -> Tuple[Any, Any, Any, Any]:
-        """(prefill, insert, move, clear) jits for one decode contract —
-        the target lane always, plus the draft lane when speculative."""
+    def _lane_jits(self, fns) -> Tuple[Any, Any, Any, Any, Any]:
+        """(prefill, insert, move, clear, accept) jits for one decode
+        contract — the target lane always, plus the draft lane when
+        speculative.  ``prefill`` is None for a contract that is
+        prefilled by window."""
         import jax
         import jax.numpy as jnp
+
+        kind_of = _kind_reader(fns)
+        # A sequence's first decode position, from its prompt's mask
+        # [1, max_input_len]: 1 (behind a BOS at 0) unless the contract
+        # states otherwise.
+        first_pos = getattr(fns, "first_decode_pos", lambda input_mask: 1)
 
         def prefill(params, inputs, input_mask):
             cache, encoded, logits = fns.prefill(params, inputs, input_mask)
@@ -618,7 +717,7 @@ class GenerativeEngine:
             return (
                 cache,
                 tok.at[slot].set(tok0),
-                pos.at[slot].set(1),
+                pos.at[slot].set(first_pos(enc_mask)),
                 live.at[slot].set(True),
                 enc.at[slot].set(encoded[0].astype(enc.dtype)),
                 mask.at[slot].set(jnp.asarray(enc_mask[0], mask.dtype)),
@@ -641,26 +740,6 @@ class GenerativeEngine:
                 mask,
             )
 
-        return (
-            _jit_program(prefill), _jit_program(insert),
-            _jit_program(move), _jit_program(clear),
-        )
-
-    def _build_jits(self) -> None:
-        import jax
-
-        (
-            self._jit_prefill, self._jit_insert,
-            self._jit_move, self._jit_clear,
-        ) = self._lane_jits(self.fns)
-        if self._spec:
-            (
-                self._d_jit_prefill, self._d_jit_insert,
-                self._d_jit_move, self._d_jit_clear,
-            ) = self._lane_jits(self.draft_fns)
-
-        import jax.numpy as jnp
-
         def accept(state, new_tok, new_pos):
             # Speculative accept / step-sync: replace the whole tok/pos
             # vectors with host-composed values (dead rows carry
@@ -674,7 +753,7 @@ class GenerativeEngine:
             cache, tok, pos, live, enc, mask = state
 
             def scrub(path, a):
-                if _is_enc_leaf(path):
+                if not kind_of(path).by_position:
                     return a
                 valid = jnp.arange(a.shape[1]) < new_pos[:, None]
                 v = valid.reshape(valid.shape + (1,) * (a.ndim - 2))
@@ -683,23 +762,53 @@ class GenerativeEngine:
             cache = jax.tree_util.tree_map_with_path(scrub, cache)
             return (cache, new_tok, new_pos, live, enc, mask)
 
-        self._jit_accept = _jit_program(accept)
+        return (
+            _jit_program(prefill) if hasattr(fns, "prefill") else None,
+            _jit_program(insert), _jit_program(move), _jit_program(clear),
+            _jit_program(accept),
+        )
+
+    def _build_jits(self) -> None:
+        import jax.numpy as jnp
+
+        (
+            self._jit_prefill, self._jit_insert, self._jit_move,
+            self._jit_clear, self._jit_accept,
+        ) = self._lane_jits(self.fns)
+        if self._spec:
+            (
+                self._d_jit_prefill, self._d_jit_insert, self._d_jit_move,
+                self._d_jit_clear, self._d_jit_accept,
+            ) = self._lane_jits(self.draft_fns)
+        if self._window_len:
+            fns = self.fns
+
+            def prefill_window(params, row_cache, tokens, n_valid, index):
+                # One window of one prompt against the row being built;
+                # the token is the prompt's first new one when this was
+                # its last window.
+                row_cache, logits = fns.prefill_window(
+                    params, row_cache, tokens, n_valid, index)
+                return row_cache, jnp.argmax(logits[0], -1).astype(jnp.int32)
+
+            self._jit_prefill_window = _jit_program(prefill_window)
 
     def _build_step(self, b: int, kv: int, fns):
         import jax.numpy as jnp
 
         pad = self.pad_id
+        kind_of = _kind_reader(fns)
 
         def run(params, state):
             cache, tok, pos, live, encoded, enc_mask = state
             new_sub, logits = fns.step(
-                params, _bucket_of(cache, b, kv), tok[:b], pos[:b],
+                params, _bucket_of(cache, b, kv, kind_of), tok[:b], pos[:b],
                 encoded[:b], enc_mask[:b], kv,
             )
             nxt = jnp.where(
                 live[:b], jnp.argmax(logits, -1).astype(jnp.int32), pad
             )
-            cache = _write_back(cache, new_sub, b, kv)
+            cache = _write_back(cache, new_sub, b, kv, kind_of)
             tok = tok.at[:b].set(nxt)
             pos = pos.at[:b].set(pos[:b] + live[:b].astype(jnp.int32))
             return (cache, tok, pos, live, encoded, enc_mask), nxt
@@ -723,7 +832,7 @@ class GenerativeEngine:
             # toks[b, k]: column 0 is each row's current last emitted
             # token, columns 1..k-1 the draft's first k-1 proposals.
             cache, tok, pos, live, encoded, enc_mask = state
-            sub = _bucket_of(cache, b, kv)
+            sub = _bucket_of(cache, b, kv, self._kind_of)
             if verify is not None:
                 new_sub, logits = verify(
                     params, sub, toks[:b], pos[:b],
@@ -740,7 +849,7 @@ class GenerativeEngine:
                     outs.append(lg)
                 logits = jnp.stack(outs, axis=1)
             g = jnp.argmax(logits, -1).astype(jnp.int32)  # [b, k]
-            cache = _write_back(cache, new_sub, b, kv)
+            cache = _write_back(cache, new_sub, b, kv, self._kind_of)
             return (cache, tok, pos, live, encoded, enc_mask), g
 
         return _jit_program(run)
@@ -789,7 +898,7 @@ class GenerativeEngine:
         import jax
         import jax.numpy as jnp
 
-        if self._jit_prefill is None:
+        if self._jit_insert is None:
             self._build_jits()
         with self._dev():
             # Commit params AND the arena to one device up front.  The
@@ -811,10 +920,18 @@ class GenerativeEngine:
             B = self.max_batch_size
 
             def blank_arena(prefill_jit, params):
-                cache1, encoded1, _ = prefill_jit(params, zin, zmask)
-                cache = jax.tree_util.tree_map(
-                    lambda x: jnp.zeros((B,) + x.shape[1:], x.dtype), cache1
-                )
+                if self._window_len:
+                    # No whole-prompt prefill to read the shapes off:
+                    # the contract gives the cache's arrays itself, and
+                    # the encoder rows it keeps (none: ``(0,)``).
+                    cache = self.fns.blank_cache(B)
+                    encoded1 = self._no_encoded
+                else:
+                    cache1, encoded1, _ = prefill_jit(params, zin, zmask)
+                    cache = jax.tree_util.tree_map(
+                        lambda x: jnp.zeros((B,) + x.shape[1:], x.dtype),
+                        cache1,
+                    )
                 # Free rows keep an all-ONES encoder mask: cross-attention
                 # over their zero K/V then averages zeros instead of
                 # softmaxing an all-masked row into NaN.  Live rows
@@ -829,6 +946,10 @@ class GenerativeEngine:
                 ), dev)
 
             self._arena = blank_arena(self._jit_prefill, self.params)
+            if self._window_len and self._row_cache is None:
+                self._row_cache = jax.device_put(
+                    self.fns.blank_cache(1), dev
+                )
             if self._spec:
                 self.draft_params = jax.device_put(self.draft_params, dev)
                 self._d_arena = blank_arena(
@@ -836,8 +957,9 @@ class GenerativeEngine:
                 )
 
     def warm(self) -> None:
-        """Pre-compile every program traffic can pose: prefill, insert /
-        move / clear, and one step per ``(batch_bucket, kv_bucket)``.
+        """Pre-compile every program traffic can pose: prefill (or one
+        prefill window), insert / move / clear, and one step per
+        ``(batch_bucket, kv_bucket)``.
         The fleet's canary gate runs this BEFORE a version becomes
         eligible — the decode analog of the predict-bucket warmup — so a
         hot-swap never pays an XLA compile mid-traffic.  Every arena
@@ -865,8 +987,17 @@ class GenerativeEngine:
             ztok = np.full((B,), self.pad_id, np.int32)
             zpos = np.zeros((B,), np.int32)
 
-            def lane(arena, params, prefill, insert, move, clear, step_for):
-                cache1, encoded1, tok0 = prefill(params, zin, zmask)
+            def lane(arena, params, prefill, insert, move, clear, accept,
+                     step_for):
+                if self._window_len:
+                    self._row_cache, tok0 = self._jit_prefill_window(
+                        params, self._row_cache,
+                        np.full((1, self._window_len), self.pad_id, np.int32),
+                        np.int32(1), np.int32(0),
+                    )
+                    cache1, encoded1 = self._row_cache, self._no_encoded
+                else:
+                    cache1, encoded1, tok0 = prefill(params, zin, zmask)
                 # tok0 goes to insert as a HOST int32: the prefix-cache
                 # hit path has only the entry's host token, and
                 # warm/miss/hit must all land on the same insert
@@ -878,18 +1009,18 @@ class GenerativeEngine:
                 for b in self.batch_buckets:
                     for kv in self.kv_buckets:
                         arena, _ = step_for(b, kv)(params, arena)
-                return self._jit_accept(arena, ztok, zpos)
+                return accept(arena, ztok, zpos)
 
             self._arena = lane(
                 self._arena, self.params, self._jit_prefill,
                 self._jit_insert, self._jit_move, self._jit_clear,
-                self._step_for,
+                self._jit_accept, self._step_for,
             )
             if self._spec:
                 self._d_arena = lane(
                     self._d_arena, self.draft_params, self._d_jit_prefill,
                     self._d_jit_insert, self._d_jit_move,
-                    self._d_jit_clear, self._d_step_for,
+                    self._d_jit_clear, self._d_jit_accept, self._d_step_for,
                 )
                 zk = np.full(
                     (B, self.spec_tokens), self.pad_id, np.int32
@@ -1030,6 +1161,12 @@ class GenerativeEngine:
             self._release_prefix(seq)
             self._trace_end(seq, "evicted")
             seq.finish(final_error or GenerationEvicted("engine closed"))
+        if not self._worker.is_alive():
+            # A closed engine holds no device memory: its programs'
+            # closures and the engine refer to each other, so the arena
+            # would otherwise live until the collector finds the cycle
+            # (6.9 GB of a 16 GB chip under a long-context contract).
+            self._arena = self._d_arena = self._row_cache = None
 
     # ------------------------------------------------------------- worker
 
@@ -1141,6 +1278,8 @@ class GenerativeEngine:
         """One turn of admission, for the sequence at the head of the
         queue.  False when the head stays queued: chunked prefill has no
         credits for it (or ``close`` emptied the queue meanwhile)."""
+        if self._window_len:
+            return self._admit_window(span)
         with self._lock:
             if not self._queue or self._n_live >= self.max_batch_size:
                 return False
@@ -1205,38 +1344,94 @@ class GenerativeEngine:
                     entry = self._prefix.insert(
                         key, pages, t0, cache1, enc1, d_cache1, d_enc1
                     )
-            seq.first_token_s = time.monotonic()
-            self.telemetry.on_first_token(
-                seq.first_token_s - seq.arrival_s
+            return self._seat(seq, cache1, enc1, t0, entry, d_cache1, d_enc1)
+
+    def _admit_window(self, span) -> bool:
+        """One turn of admission under a contract that is prefilled by
+        window: ONE window of the prompt at the head of the queue, into
+        the engine's row cache.  The head stays queued while its windows
+        run (each costs one chunked-prefill credit, so decode steps of
+        the live rows run in between) and takes its slot with the last.
+        The prompt is its mask's count of tokens from the left."""
+        W = self._window_len
+        with self._lock:
+            if not self._queue or self._n_live >= self.max_batch_size:
+                return False
+            seq = self._queue[0]
+            if self.prefill_chunk_pages > 0 and self._n_live > 0:
+                if self._admit_credits < 1:
+                    return False
+                self._admit_credits -= 1
+        if self._partial is None or self._partial[0] is not seq:
+            self._partial = (seq, 0)
+            seq.admitted_s = time.monotonic()
+            self.telemetry.on_admitted(seq.admitted_s - seq.arrival_s)
+        index = self._partial[1]
+        n_prompt = seq.first_pos = int((seq.input_mask > 0).sum())
+        count = min(W, n_prompt - index * W)
+        span.set_metadata(seq=seq.seq_id, prefix_hit=0, window=index)
+        with self._dev():
+            self._ensure_arena()
+            with self._phase(
+                "prefill.window", seq=seq.seq_id, window=index, tokens=count,
+            ):
+                tokens = np.full((1, W), self.pad_id, np.int32)
+                tokens[0, :count] = seq.inputs[index * W:index * W + count]
+                self._row_cache, tok0 = self._jit_prefill_window(
+                    self.params, self._row_cache, tokens,
+                    np.int32(count), np.int32(index),
+                )
+                self.telemetry.on_prefill_window(count)
+                if (index + 1) * W < n_prompt:
+                    self._partial = (seq, index + 1)
+                    return True
+                # The last window: the one device-to-host read of this
+                # prompt's prefill.
+                t0 = int(tok0)
+            self._partial = None
+            with self._lock:
+                if not self._queue or self._queue[0] is not seq:
+                    return False      # ``close`` took the queue meanwhile
+                self._queue.popleft()
+            return self._seat(
+                seq, self._row_cache, self._no_encoded, t0, None, None, None
             )
-            seq.tokens.append(t0)
-            if t0 == self.eos_id or seq.max_new_tokens <= 1:
-                if self._prefix is not None:
-                    self.telemetry.on_prefix_pages(
-                        self._prefix.pages_in_use()
-                    )
-                self._complete(seq)
-                return True
-            if entry is not None:
-                self._prefix.acquire(entry)
-                seq.prefix_entry = entry
+
+    def _seat(self, seq, cache1, enc1, t0, entry, d_cache1, d_enc1) -> bool:
+        """A prefilled sequence's first token, and its slot in the arena
+        unless that token already ended it (under ``self._dev()``)."""
+        seq.first_token_s = time.monotonic()
+        self.telemetry.on_first_token(
+            seq.first_token_s - seq.arrival_s
+        )
+        seq.tokens.append(t0)
+        if t0 == self.eos_id or seq.max_new_tokens <= 1:
+            if self._prefix is not None:
                 self.telemetry.on_prefix_pages(
                     self._prefix.pages_in_use()
                 )
-            slot = self._n_live
-            with self._phase("insert", seq=seq.seq_id, slot=slot):
-                self._arena = self._jit_insert(
-                    self._arena, cache1, enc1, seq.input_mask[None],
-                    np.int32(t0), np.int32(slot),
+            self._complete(seq)
+            return True
+        if entry is not None:
+            self._prefix.acquire(entry)
+            seq.prefix_entry = entry
+            self.telemetry.on_prefix_pages(
+                self._prefix.pages_in_use()
+            )
+        slot = self._n_live
+        with self._phase("insert", seq=seq.seq_id, slot=slot):
+            self._arena = self._jit_insert(
+                self._arena, cache1, enc1, seq.input_mask[None],
+                np.int32(t0), np.int32(slot),
+            )
+            if self._spec:
+                # The draft lane mirrors the slot: its own prefill
+                # cache, but the TARGET's first token — the draft
+                # always consumes the verified stream.
+                self._d_arena = self._d_jit_insert(
+                    self._d_arena, d_cache1, d_enc1,
+                    seq.input_mask[None], np.int32(t0), np.int32(slot),
                 )
-                if self._spec:
-                    # The draft lane mirrors the slot: its own prefill
-                    # cache, but the TARGET's first token — the draft
-                    # always consumes the verified stream.
-                    self._d_arena = self._d_jit_insert(
-                        self._d_arena, d_cache1, d_enc1,
-                        seq.input_mask[None], np.int32(t0), np.int32(slot),
-                    )
         if seq.ctx is not None:
             # Slot event: the sequence joined the continuous batch —
             # the wait it paid in the queue is arrival -> now.
@@ -1350,7 +1545,7 @@ class GenerativeEngine:
                 self._arena = self._jit_accept(
                     self._arena, new_tok, new_pos
                 )
-                self._d_arena = self._jit_accept(
+                self._d_arena = self._d_jit_accept(
                     self._d_arena, new_tok, new_pos
                 )
             for slot in range(n - 1, -1, -1):
@@ -1394,7 +1589,7 @@ class GenerativeEngine:
                         new_tok[i] = int(toks[i])
                         new_pos[i] = len(s.tokens) + 1
                 with self._dev():
-                    self._d_arena = self._jit_accept(
+                    self._d_arena = self._d_jit_accept(
                         self._d_arena, new_tok, new_pos
                     )
             dt = time.perf_counter() - t0
@@ -1411,6 +1606,13 @@ class GenerativeEngine:
             self.telemetry.on_step(
                 dt, self.step_ewma_s, n, b, pages, int(n)
             )
+            if self._account is not None:
+                # What this step read of each kind of cache, by the
+                # contract's own account of the rows' positions.
+                self.telemetry.on_cache(self._account([
+                    s.first_pos + len(s.tokens) - 1
+                    for s in self._slots[:n] if s is not None
+                ]))
         with self._phase("emit", live=n):
             now = time.monotonic()
             for slot in range(n - 1, -1, -1):
@@ -1543,6 +1745,9 @@ class DecodeTelemetry:
         self._spec_ratio = None
         self._phase_s = self._phase_n = None
         self._queue_wait = self._ttft = None
+        self._prefill_tokens = self._prefill_windows = None
+        self._rollovers = self._summaries = None
+        self._cache_bytes = self._cache_read = None
         if registry is None:
             return
         from tpu_pipelines.observability.metrics import fine_latency_buckets
@@ -1664,6 +1869,37 @@ class DecodeTelemetry:
         self._phase_n = {
             p: occurrences.labels(self.replica, p) for p in ENGINE_PHASES
         }
+        self._prefill_tokens = registry.counter(
+            "serving_decode_prefill_tokens_total",
+            "Prompt tokens prefilled a window at a time.", labels=lab,
+        ).labels(self.replica)
+        self._prefill_windows = registry.counter(
+            "serving_decode_prefill_windows_total",
+            "Prefill-window programs run: a prompt of L tokens costs "
+            "ceil(L / window).", labels=lab,
+        ).labels(self.replica)
+        self._rollovers = registry.counter(
+            "serving_decode_window_rollovers_total",
+            "Decode steps of a row that began a new attention window "
+            "(the ring is written from its start again).", labels=lab,
+        ).labels(self.replica)
+        self._summaries = registry.counter(
+            "serving_decode_chunk_summaries_total",
+            "Chunk summaries stored by decode steps (one per row per "
+            "chunk closed).", labels=lab,
+        ).labels(self.replica)
+        kind_lab = ("replica", "kind")
+        self._cache_bytes = registry.gauge(
+            "serving_decode_cache_bytes",
+            "Bytes of each kind of cache that the live rows of the most "
+            "recent decode step read (their valid entries).",
+            labels=kind_lab,
+        )
+        self._cache_read = registry.counter(
+            "serving_decode_cache_read_bytes_total",
+            "serving_decode_cache_bytes summed over the decode steps run.",
+            labels=kind_lab,
+        )
         self._queue_wait = registry.histogram(
             "serving_decode_queue_wait_seconds",
             "Submit to the admission turn that took the sequence off "
@@ -1677,6 +1913,23 @@ class DecodeTelemetry:
             "(fine sqrt(2) buckets; _sum and _count exact).",
             labels=lab, buckets=fine_latency_buckets(),
         ).labels(self.replica)
+
+    def on_prefill_window(self, n_tokens: int) -> None:
+        if self._prefill_windows is not None:
+            self._prefill_windows.inc()
+            self._prefill_tokens.inc(n_tokens)
+
+    def on_cache(self, account: Dict[str, Any]) -> None:
+        """One decode step's account by the contract (``step_account``):
+        bytes of each kind of cache its rows read, and the events among
+        its rows."""
+        if self._cache_bytes is None:
+            return
+        for kind, n_bytes in account["cache_bytes"].items():
+            self._cache_bytes.labels(self.replica, kind).set(n_bytes)
+            self._cache_read.labels(self.replica, kind).inc(n_bytes)
+        self._rollovers.inc(account["window_rollovers"])
+        self._summaries.inc(account["chunk_summaries"])
 
     def on_step(self, dt, ewma, live, bucket, pages, active) -> None:
         if self._steps is None:
