@@ -372,3 +372,136 @@ def test_fsdp_window_shards_state_on_v5e_2x2(mesh4):
     body = _scan_body(text)
     assert "all-gather" in body
     assert "reduce-scatter" in body or "all-reduce" in body
+
+
+# ------------------------------------------- the decode step's K/V write
+
+
+_SHAPE = r"[a-z0-9]+\[[\d,]*\](?:\{[^}]*\})?"
+
+
+def _entry_layouts(text):
+    """``(parameters, results, {result index: parameter number})`` of a
+    compiled module: each side's shapes with their layouts, in order, and
+    the input-output aliases, all from the module's first line."""
+    head = text[:text.index("\n")]
+    opening = "entry_computation_layout={("
+    layout = head[head.index(opening) + len(opening):]
+    params, results = layout.split(")->", 1)
+    results = re.split(r"\}, [a-z_]+=", results)[0]   # the next attribute
+    aliases = {
+        int(out): int(param) for out, param in re.findall(
+            r"\{(\d+)\}: \((\d+), \{\}, (?:may|must)-alias\)", head)
+    }
+    return re.findall(_SHAPE, params), re.findall(_SHAPE, results), aliases
+
+
+DECODE_WRITE_CASES = [
+    # (id, rows, positions, fed tokens per row)
+    ("step_64x256", 64, 256, 1),
+    ("step_32x128", 32, 128, 1),
+    ("verify4_64x256", 64, 256, 4),
+]
+
+
+ARENA = dict(rows=64, positions=256, enc_len=128, heads=16, head_dim=64)
+
+
+@pytest.fixture(scope="module")
+def t5_large_engine_shapes():
+    """``(fns, params, state)``: T5-large's decode contract at two layers,
+    and the shapes of its parameters and of a 64 x 256 engine arena."""
+    from tpu_pipelines.models.t5 import (
+        build_t5_model, make_continuous_decode_fns,
+    )
+
+    rows, enc_len = ARENA["rows"], ARENA["enc_len"]
+    model = build_t5_model(dict(
+        vocab_size=32128, d_model=1024, n_layers=2, n_heads=ARENA["heads"],
+        head_dim=ARENA["head_dim"], d_ff=4096, dropout_rate=0.0,
+    ))
+    fns = make_continuous_decode_fns(
+        model, max_decode_len=ARENA["positions"], eos_id=32200,
+        max_input_len=enc_len,
+    )
+    inputs = jnp.zeros((rows, enc_len), jnp.int32)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.key(0), {
+            "inputs": inputs, "targets": inputs[:, :4],
+            "input_mask": inputs,
+        })["params"]
+    )
+    cache, encoded, _ = jax.eval_shape(
+        lambda p: fns.prefill(p, inputs, inputs), params
+    )
+    state = (
+        cache, jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
+        jnp.zeros((rows,), bool), encoded, inputs,
+    )
+    return fns, params, state
+
+
+@pytest.mark.parametrize(
+    "b,kv,qlen", [c[1:] for c in DECODE_WRITE_CASES],
+    ids=[c[0] for c in DECODE_WRITE_CASES],
+)
+def test_decode_kv_write_keeps_the_cache_where_it_lies_on_v5e(
+    one_chip, t5_large_engine_shapes, b, kv, qlen
+):
+    """The engine's own step and verify programs at T5-large widths (two
+    decoder layers, arena 64 x 256 donated): the chip keeps a
+    ``bf16[b, kv, 16, 64]`` cache leaf position-minor, and the write of
+    the step's K/V must leave it so.  A per-row scatter made the compiler
+    re-lay the whole bucket out and back, four copies a layer (PERF.md §6,
+    PR 28); a row-wise ``dynamic_update_slice`` still does in every bucket
+    smaller than the arena."""
+    from types import SimpleNamespace
+
+    from tpu_pipelines.serving import generative as gen
+
+    fns, params, state = t5_large_engine_shapes
+    rows, positions = ARENA["rows"], ARENA["positions"]
+    heads, head_dim = ARENA["heads"], ARENA["head_dim"]
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip), tree
+    )
+    args = (on_chip(params), on_chip(state))
+    if qlen == 1:
+        program = gen.GenerativeEngine._build_step(
+            SimpleNamespace(pad_id=0), b, kv, fns
+        )
+    else:
+        program = gen.GenerativeEngine._build_verify(
+            SimpleNamespace(
+                fns=fns, spec_tokens=qlen, _kind_of=gen._kind_reader(fns)
+            ), b, kv,
+        )
+        args += (_sds((rows, qlen), jnp.int32, one_chip),)
+    compiled = program.lower(*args).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+
+    # Every self-attention K/V leaf of the arena is aliased to a result
+    # that lies as the parameter lies.
+    arena_leaf = f"bf16[{rows},{positions},{heads},{head_dim}]"
+    param_layouts, result_layouts, aliases = _entry_layouts(text)
+    leaves = [
+        i for i, s in enumerate(param_layouts) if s.startswith(arena_leaf)
+    ]
+    assert len(leaves) == 2 * 2                     # K and V, two layers
+    result_of = {param: out for out, param in aliases.items()}
+    for i in leaves:
+        assert i in result_of, f"cache parameter {i} is not aliased"
+        assert result_layouts[result_of[i]] == param_layouts[i]
+
+    # Nothing of the bucket's shape is copied, transposed or scattered
+    # into, and nothing of that shape lies any other way than the arena.
+    bucket = re.escape(f"bf16[{b},{kv},{heads},{head_dim}]")
+    moved = re.findall(
+        rf"= {bucket}\S* (?:copy|transpose|scatter)\(.*", text
+    )
+    assert not moved, (len(moved), moved[:2])
+    lies = lambda shape: shape[shape.index("{") + 1:].split(":")[0]
+    arena_lies = {lies(param_layouts[i]) for i in leaves}
+    assert len(arena_lies) == 1
+    assert set(re.findall(bucket + r"\{([\d,]+)", text)) == arena_lies

@@ -353,6 +353,38 @@ def apply_with_moe_aux(model, variables, *args, **kwargs):
     return out, aux
 
 
+def _write_rows_at(cache, new, pos):
+    """``cache [b, kv, heads, head_dim]`` with row ``i``'s new block
+    ``new[i] [qlen, heads, head_dim]`` written at positions
+    ``pos[i] .. pos[i] + qlen - 1``: the decode step's K/V (``qlen`` 1) or
+    a verify window's, each row at its own position.
+
+    A select on a one-hot of the position, not a scatter.  The chip keeps
+    a by-position cache position-minor (``{1,3,2,0}`` for bf16 with a
+    ``head_dim`` of 64, half a lane row), which is how the attention reads
+    it.  ``cache.at[rows, pos].set(new)`` lowers to a scatter that wants
+    the written window minor, so the compiler copies the whole bucket to
+    ``{3,2,1,0}``, scatters ``b`` rows of 2 KB into it and copies it
+    back, per layer, for keys and for values: two thirds of the step at
+    64 x 256.  A row-wise ``dynamic_update_slice`` under ``vmap`` does the
+    same in every bucket smaller than the arena.  The select has no
+    preferred layout and is fused into the attention's own pass over the
+    cache: one write of the leaf, no second read (PERF.md §6, PR 28;
+    tests/test_tpu_compile.py holds the compiled programs to it).
+
+    A position outside ``[0, kv)`` is dropped, as ``.at[].set`` drops
+    it: a dead slot's stale ``pos`` past a smaller bucket, or the tail of
+    a verify window past the end, writes nothing (``dynamic_update_slice``
+    would clamp it onto the row's last positions).  Every row whose output
+    the engine uses lies inside; a row writes only into itself."""
+    at = jnp.arange(cache.shape[1])[None, :] - pos[:, None]      # [b, kv]
+    for j in range(new.shape[1]):
+        cache = jnp.where(
+            (at == j)[:, :, None, None], new[:, j:j + 1], cache
+        )
+    return cache
+
+
 class MultiHeadAttention(nn.Module):
     """Self/cross attention; TP over heads, optional ring SP over sequence.
 
@@ -438,9 +470,11 @@ class MultiHeadAttention(nn.Module):
             #
             # ``decode_pos`` may be a scalar (every row at the same step:
             # the greedy/beam scan) or a [b] vector (continuous batching:
-            # each sequence in the batch sits at its OWN step, so the
-            # update is a per-row scatter and the validity mask is
-            # per-row).  Both paths compute identical per-row math.
+            # each sequence in the batch sits at its OWN step, so each
+            # row's K/V go to its own position, by ``_write_rows_at``'s
+            # select: the chip keeps the cache position-minor and a
+            # scatter would re-lay it out at every step; the validity
+            # mask is per-row).  Both paths compute identical per-row math.
             if max_decode_len is None:
                 raise ValueError("decode_pos requires max_decode_len")
             b = q.shape[0]
@@ -467,26 +501,24 @@ class MultiHeadAttention(nn.Module):
                     (b, max_decode_len),
                 )
             elif q.shape[1] == 1:
-                rows = jnp.arange(b)
-                cached_k.value = cached_k.value.at[rows, pos].set(k[:, 0])
-                cached_v.value = cached_v.value.at[rows, pos].set(v[:, 0])
+                cached_k.value = _write_rows_at(cached_k.value, k, pos)
+                cached_v.value = _write_rows_at(cached_v.value, v, pos)
                 valid = jnp.arange(max_decode_len)[None, :] <= pos[:, None]
             else:
                 # Speculative verify: ``qlen`` candidate tokens per row,
                 # row i's queries occupying positions
-                # ``pos[i] .. pos[i]+qlen-1`` — one scatter of a window
-                # per row, then per-QUERY causal validity (query j sees
-                # cache positions <= pos+j).  dense_attention's kv_mask
+                # ``pos[i] .. pos[i]+qlen-1`` — the same select, one
+                # window per row, then per-QUERY causal validity (query j
+                # sees cache positions <= pos+j).  dense_attention's kv_mask
                 # is per-row, so the per-query window folds into the
                 # additive bias instead; same NEG_INF -> exact-zero
                 # weight semantics as every other mask here.
                 from tpu_pipelines.parallel.ring_attention import NEG_INF
 
-                rows = jnp.arange(b)
                 qlen = q.shape[1]
                 idx = pos[:, None] + jnp.arange(qlen)[None, :]  # [b, q]
-                cached_k.value = cached_k.value.at[rows[:, None], idx].set(k)
-                cached_v.value = cached_v.value.at[rows[:, None], idx].set(v)
+                cached_k.value = _write_rows_at(cached_k.value, k, pos)
+                cached_v.value = _write_rows_at(cached_v.value, v, pos)
                 win = (
                     jnp.arange(max_decode_len)[None, None, :]
                     <= idx[:, :, None]
