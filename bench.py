@@ -2683,10 +2683,10 @@ def bench_generative_serving(smoke: bool) -> dict:
     carries the same long prompt (the shared-system-prompt regime) with a
     short reply budget, served twice on separate fleets from the same
     payload — optimisations ON (refcounted prefix caching + chunked
-    prefill + self-draft speculative decoding) vs the plain PR-11 engine.
+    prefill) vs the plain PR-11 engine.
     Green requires >= 1.3x useful tokens/s at no-worse client
     p99-per-token, and the fleet's own scrape supplies the prefix-cache
-    hit rate and speculative acceptance rate for the report.
+    hit rate for the report.
     """
     import queue as queue_mod
     import tempfile
@@ -2931,8 +2931,7 @@ def bench_generative_serving(smoke: bool) -> dict:
         # reply budgets.  With the prefix cache on, only the first
         # admission pays the encoder+prefill; every later one rescatters
         # the cached pages.  Chunked prefill keeps the (rare) misses from
-        # stalling live decoders, and self-draft speculation exercises the
-        # draft/verify path end-to-end (acceptance must scrape as 1.0).
+        # stalling live decoders.
         hp_c = {**hp, "max_input_len": 48, "max_decode_len": 32}
         in_c = hp_c["max_input_len"]
         n_c = 24 if smoke else 80
@@ -2980,7 +2979,6 @@ def bench_generative_serving(smoke: bool) -> dict:
 
         c_on, scrape_c = prefix_pass(
             "pfx", prefix_cache_entries=8, prefill_chunk_pages=4,
-            spec_tokens=2,
         )
         c_off, _ = prefix_pass("plain")
 
@@ -3006,20 +3004,13 @@ def bench_generative_serving(smoke: bool) -> dict:
         round(a["tok_s"] / b["tok_s"], 2)
         if a["tok_s"] and b["tok_s"] else None
     )
-    # Pass C verdicts off the optimised fleet's own scrape: hit rate over
-    # admissions, acceptance over proposals.
+    # Pass C verdict off the optimised fleet's own scrape: hit rate over
+    # admissions.
     pfx_hits = _parse_prom_counter(scrape_c, "serving_decode_prefix_hit_total")
     pfx_miss = _parse_prom_counter(scrape_c, "serving_decode_prefix_miss_total")
-    spec_prop = _parse_prom_counter(
-        scrape_c, "serving_decode_spec_proposed_total"
-    )
-    spec_acc = _parse_prom_counter(scrape_c, "serving_decode_spec_accept_total")
     prefix_hit_rate = (
         round(pfx_hits / (pfx_hits + pfx_miss), 3)
         if (pfx_hits + pfx_miss) else None
-    )
-    spec_accept_rate = (
-        round(spec_acc / spec_prop, 3) if spec_prop else None
     )
     prefix_speedup = (
         round(c_on["tok_s"] / c_off["tok_s"], 2)
@@ -3051,11 +3042,8 @@ def bench_generative_serving(smoke: bool) -> dict:
             "plain_engine": c_off,
             "speedup": prefix_speedup,
             "prefix_hit_rate": prefix_hit_rate,
-            "spec_accept_rate": spec_accept_rate,
             "prefix_hits": int(pfx_hits),
             "prefix_misses": int(pfx_miss),
-            "spec_proposed": int(spec_prop),
-            "spec_accepted": int(spec_acc),
         },
         "warmup": a_warm["codes"],
         "decode_tok_s": a["tok_s"],
@@ -5165,12 +5153,11 @@ def _compact(report: dict) -> dict:
         )
         compact["decode_5xx"] = gs.get("decode_5xx")
         # ISSUE 16 headline: long-shared-prefix speedup from the decode-
-        # path optimisations, plus the two rates that explain it.
+        # path optimisations, plus the rate that explains it.
         sp = gs.get("shared_prefix")
         if isinstance(sp, dict):
             compact["prefix_speedup"] = sp.get("speedup")
             compact["prefix_hit_rate"] = sp.get("prefix_hit_rate")
-            compact["spec_accept_rate"] = sp.get("spec_accept_rate")
     cont = (report.get("continuous") or {}).get("taxi_spans")
     if isinstance(cont, dict) and "green" in cont:
         compact["continuous_green"] = bool(cont.get("green"))
@@ -5452,8 +5439,8 @@ def main() -> None:
     # whole-request A/B on identical mixed-length traffic + zero-5xx
     # hot-swap with generations in flight, off the fleet's own scrape.
     # +60 s vs r5 (ISSUE 16): the long-shared-prefix pass runs the same
-    # traffic on an optimised (prefix cache + chunked prefill + spec)
-    # fleet and a plain one.
+    # traffic on an optimised (prefix cache + chunked prefill) fleet and a
+    # plain one.
     leg(
         "generative_serving", bench_generative_serving,
         est_cost_s=180, retries=1,

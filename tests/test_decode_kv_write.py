@@ -56,53 +56,40 @@ def _assert_same_bits(got, want, err_msg=""):
 
 
 def _new_rows(layer, params, x):
-    """This step's K and V, ``[b, qlen, heads, head_dim]`` each: what the
-    scalar-position path stores at position 0 of a blank cache, one query
-    position at a time."""
-    b, qlen = x.shape[:2]
+    """This step's K and V, ``[b, 1, heads, head_dim]`` each: what the
+    scalar-position path stores at position 0 of a blank cache."""
     blank = {
-        name: jnp.zeros((b, 1, HEADS, HEAD_DIM), jnp.bfloat16)
+        name: jnp.zeros((x.shape[0], 1, HEADS, HEAD_DIM), jnp.bfloat16)
         for name in ("cached_key", "cached_value")
     }
-    per_pos = [
-        _apply(layer, params, blank, x[:, j:j + 1], 0)[1]
-        for j in range(qlen)
-    ]
-    return {
-        name: np.concatenate([c[name] for c in per_pos], axis=1)
-        for name in blank
-    }
+    return _apply(layer, params, blank, x, 0)[1]
 
 
 def _reference(cache, new, pos):
-    """Plain numpy: row ``i``'s block at ``pos[i] ..``, a position outside
+    """Plain numpy: row ``i``'s entry at ``pos[i]``, a position outside
     the cache dropped."""
     want = {name: np.array(leaf) for name, leaf in cache.items()}
     for name, leaf in want.items():
         for i, p in enumerate(pos):
-            for j in range(new[name].shape[1]):
-                if 0 <= p + j < leaf.shape[1]:
-                    leaf[i, p + j] = new[name][i, j]
+            if 0 <= p < leaf.shape[1]:
+                leaf[i, p] = new[name][i, 0]
     return want
 
 
 CASES = {
     # every row at one position: also the scalar-position path, bit for bit
-    "one_position": (1, [7] * B),
-    "mixed_with_both_ends": (1, [0, KV - 1, 3, KV - 1, 9]),
-    "window_ending_at_kv": (4, [KV - 4, 0, 5, KV - 4, 2]),
+    "one_position": [7] * B,
+    "mixed_with_both_ends": [0, KV - 1, 3, KV - 1, 9],
     # a dead slot's stale position beside live rows: dropped
-    "stale_position_past_kv": (1, [4, KV + 9, 11, 0, KV]),
-    # the in-range part of a window is written, the rest dropped
-    "window_past_the_end": (4, [KV - 2, 1, KV - 1, 6, KV + 3]),
+    "stale_position_past_kv": [4, KV + 9, 11, 0, KV],
 }
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_rows_are_written_where_numpy_writes_them(attn, case):
     layer, params, cache = attn
-    qlen, pos = CASES[case]
-    x = jax.random.normal(jax.random.key(4), (B, qlen, D_MODEL), jnp.float32)
+    pos = CASES[case]
+    x = jax.random.normal(jax.random.key(4), (B, 1, D_MODEL), jnp.float32)
     out, got = _apply(layer, params, cache, x, jnp.asarray(pos, jnp.int32))
     want = _reference(cache, _new_rows(layer, params, x), pos)
     for name in want:
@@ -112,21 +99,6 @@ def test_rows_are_written_where_numpy_writes_them(attn, case):
         _assert_same_bits(out, s_out)
         for name in want:
             _assert_same_bits(got[name], s_got[name])
-
-
-def test_a_window_is_the_same_write_as_chained_single_rows(attn):
-    layer, params, cache = attn
-    qlen, pos = CASES["window_ending_at_kv"]
-    x = jax.random.normal(jax.random.key(5), (B, qlen, D_MODEL), jnp.float32)
-    _, window = _apply(layer, params, cache, x, jnp.asarray(pos, jnp.int32))
-    chained = cache
-    for j in range(qlen):
-        _, chained = _apply(
-            layer, params, chained, x[:, j:j + 1],
-            jnp.asarray(pos, jnp.int32) + j,
-        )
-    for name in window:
-        _assert_same_bits(window[name], chained[name])
 
 
 def test_a_dead_row_with_a_stale_position_harms_no_live_row():

@@ -299,9 +299,8 @@ def test_the_contract_states_what_the_engine_may_not_guess(f32):
     assert shapes == {(3, WINDOW, 4, 16), (3, 160 // CHUNK, 4, 16)}
     assert int(fns.first_decode_pos(np.array([[1, 1, 1, 0, 0]]))) == 3
     assert not hasattr(fns, "prefill")
-    for knob in ({"prefix_cache_entries": 2}, {"spec_tokens": 2}):
-        with pytest.raises(ValueError, match="prefilled by window"):
-            GenerativeEngine(fns, params, **knob)
+    with pytest.raises(ValueError, match="prefilled by window"):
+        GenerativeEngine(fns, params, prefix_cache_entries=2)
 
 
 def test_who_sees_what_in_a_hand_sized_case():

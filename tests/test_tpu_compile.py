@@ -397,10 +397,12 @@ def _entry_layouts(text):
 
 
 DECODE_WRITE_CASES = [
-    # (id, rows, positions, fed tokens per row)
-    ("step_64x256", 64, 256, 1),
-    ("step_32x128", 32, 128, 1),
-    ("verify4_64x256", 64, 256, 4),
+    # (id, rows, positions): the whole arena, a bucket inside it, and the
+    # two buckets the paced cell spends its time in (PERF.md §5)
+    ("step_64x256", 64, 256),
+    ("step_32x128", 32, 128),
+    ("step_16x128", 16, 128),
+    ("step_16x256", 16, 256),
 ]
 
 
@@ -442,13 +444,13 @@ def t5_large_engine_shapes():
 
 
 @pytest.mark.parametrize(
-    "b,kv,qlen", [c[1:] for c in DECODE_WRITE_CASES],
+    "b,kv", [c[1:] for c in DECODE_WRITE_CASES],
     ids=[c[0] for c in DECODE_WRITE_CASES],
 )
 def test_decode_kv_write_keeps_the_cache_where_it_lies_on_v5e(
-    one_chip, t5_large_engine_shapes, b, kv, qlen
+    one_chip, t5_large_engine_shapes, b, kv
 ):
-    """The engine's own step and verify programs at T5-large widths (two
+    """The engine's own step program at T5-large widths (two
     decoder layers, arena 64 x 256 donated): the chip keeps a
     ``bf16[b, kv, 16, 64]`` cache leaf position-minor, and the write of
     the step's K/V must leave it so.  A per-row scatter made the compiler
@@ -465,19 +467,10 @@ def test_decode_kv_write_keeps_the_cache_where_it_lies_on_v5e(
     on_chip = lambda tree: jax.tree.map(
         lambda x: _sds(x.shape, x.dtype, one_chip), tree
     )
-    args = (on_chip(params), on_chip(state))
-    if qlen == 1:
-        program = gen.GenerativeEngine._build_step(
-            SimpleNamespace(pad_id=0), b, kv, fns
-        )
-    else:
-        program = gen.GenerativeEngine._build_verify(
-            SimpleNamespace(
-                fns=fns, spec_tokens=qlen, _kind_of=gen._kind_reader(fns)
-            ), b, kv,
-        )
-        args += (_sds((rows, qlen), jnp.int32, one_chip),)
-    compiled = program.lower(*args).compile()
+    program = gen.GenerativeEngine._build_step(
+        SimpleNamespace(pad_id=0), b, kv, fns
+    )
+    compiled = program.lower(on_chip(params), on_chip(state)).compile()
     _fits(compiled)
     text = compiled.as_text()
 

@@ -89,13 +89,10 @@ class RelativePositionBias(nn.Module):
                     table[rows], (0, 2, 1)
                 )[:, :, None, :].astype(jnp.float32)
             else:
-                # Speculative verify window: ``row`` is [b, q] — query j
-                # of sequence i sits at position row[i, j].  Gather a
-                # bucket row per query: bias [b, h, q, klen].
-                rows = jnp.take(buckets, row, axis=0)      # [b, q, klen]
-                return jnp.transpose(
-                    table[rows], (0, 3, 1, 2)
-                ).astype(jnp.float32)
+                raise ValueError(
+                    "row is one position, or one per sequence; got shape "
+                    f"{row.shape}"
+                )
         # [q, k, h] -> [1, h, q, k] additive bias
         return jnp.transpose(table[buckets], (2, 0, 1))[None].astype(jnp.float32)
 
@@ -123,18 +120,13 @@ class T5Stack(nn.Module):
             # One-token decode step: bias is the single row of the full
             # [max_decode_len, max_decode_len] relative-position matrix at
             # this step's position; the causal structure comes from the
-            # attention cache's <=pos validity mask.  A multi-token
-            # decoder input with per-row positions is the speculative
-            # verify window: query j of row i sits at decode_pos[i] + j,
-            # so the gather widens to one bias row per query (the window
-            # mask lives in the attention layer).
-            row = jnp.asarray(decode_pos, jnp.int32)
-            if row.ndim == 1 and x.shape[1] > 1:
-                row = row[:, None] + jnp.arange(x.shape[1])[None, :]
+            # attention cache's <=pos validity mask.  Per-row positions
+            # (a vector) come with one token per row; the attention
+            # layer refuses anything else.
             bias = RelativePositionBias(
                 n_heads=self.n_heads, bidirectional=not self.causal,
                 name="rel_pos",
-            )(max_decode_len, max_decode_len, row=row)
+            )(max_decode_len, max_decode_len, row=decode_pos)
             kv_mask = None
         else:
             bias = RelativePositionBias(
@@ -369,24 +361,9 @@ def make_continuous_decode_fns(
         )
         return mut["cache"], logits[:, 0]
 
-    def verify(params, cache, toks, pos, encoded, enc_mask, klen: int):
-        # Speculative verify: score ``k`` fed tokens per row in ONE
-        # decoder pass — toks[b, k] at positions pos..pos+k-1 (the
-        # attention layer scatters the window and applies the per-query
-        # causal mask).  Returns logits [b, k, V]; the engine keeps the
-        # accepted prefix and the position-validity mask hides the rest.
-        variables = {"params": params, "cache": cache}
-        logits, mut = model.apply(
-            variables, toks, encoded, enc_mask=enc_mask,
-            decode_pos=pos, max_decode_len=klen,
-            method=T5.decode, mutable=["cache"],
-        )
-        return mut["cache"], logits
-
     return SimpleNamespace(
         prefill=prefill,
         step=step,
-        verify=verify,
         cache_kinds={
             "decode_kv": CacheKind(by_position=True, written=True),
             "cross_kv": CacheKind(by_position=False, written=False),

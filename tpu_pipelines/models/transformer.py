@@ -213,9 +213,10 @@ class MlpBlock(nn.Module):
     # Where dropout lands, matching each family's canonical recipe:
     # "output" (BERT: HF BertOutput drops the d_model-wide projection) or
     # "hidden" (T5: DenseReluDense drops the d_ff-wide activation).  The
-    # site is also a throughput lever — dropout RNG+mask measured ~16% of
-    # the BERT-base fine-tune step on v5e, and the output site has 4x fewer
-    # mask elements than the hidden site at BERT geometry.
+    # site is also a throughput lever — dropout RNG+mask is a measured
+    # share of the BERT-base fine-tune step on v5e (PERF.md §5), and the
+    # output site has 4x fewer mask elements than the hidden site at BERT
+    # geometry.
     dropout_site: str = "output"
 
     @nn.compact
@@ -354,10 +355,9 @@ def apply_with_moe_aux(model, variables, *args, **kwargs):
 
 
 def _write_rows_at(cache, new, pos):
-    """``cache [b, kv, heads, head_dim]`` with row ``i``'s new block
-    ``new[i] [qlen, heads, head_dim]`` written at positions
-    ``pos[i] .. pos[i] + qlen - 1``: the decode step's K/V (``qlen`` 1) or
-    a verify window's, each row at its own position.
+    """``cache [b, kv, heads, head_dim]`` with row ``i``'s new entry
+    ``new[i] [1, heads, head_dim]`` written at position ``pos[i]``: the
+    decode step's K/V, each row at its own position.
 
     A select on a one-hot of the position, not a scatter.  The chip keeps
     a by-position cache position-minor (``{1,3,2,0}`` for bf16 with a
@@ -373,16 +373,12 @@ def _write_rows_at(cache, new, pos):
     tests/test_tpu_compile.py holds the compiled programs to it).
 
     A position outside ``[0, kv)`` is dropped, as ``.at[].set`` drops
-    it: a dead slot's stale ``pos`` past a smaller bucket, or the tail of
-    a verify window past the end, writes nothing (``dynamic_update_slice``
-    would clamp it onto the row's last positions).  Every row whose output
-    the engine uses lies inside; a row writes only into itself."""
+    it: a dead slot's stale ``pos`` past a smaller bucket writes nothing
+    (``dynamic_update_slice`` would clamp it onto the row's last
+    position).  Every row whose output the engine uses lies inside; a row
+    writes only into itself."""
     at = jnp.arange(cache.shape[1])[None, :] - pos[:, None]      # [b, kv]
-    for j in range(new.shape[1]):
-        cache = jnp.where(
-            (at == j)[:, :, None, None], new[:, j:j + 1], cache
-        )
-    return cache
+    return jnp.where((at == 0)[:, :, None, None], new, cache)
 
 
 class MultiHeadAttention(nn.Module):
@@ -487,7 +483,6 @@ class MultiHeadAttention(nn.Module):
                 (b, max_decode_len, self.n_heads, self.head_dim), v.dtype,
             )
             pos = jnp.asarray(decode_pos, jnp.int32)
-            verify_window = False
             if pos.ndim == 0:
                 cached_k.value = jax.lax.dynamic_update_slice_in_dim(
                     cached_k.value, k, pos, axis=1
@@ -505,34 +500,11 @@ class MultiHeadAttention(nn.Module):
                 cached_v.value = _write_rows_at(cached_v.value, v, pos)
                 valid = jnp.arange(max_decode_len)[None, :] <= pos[:, None]
             else:
-                # Speculative verify: ``qlen`` candidate tokens per row,
-                # row i's queries occupying positions
-                # ``pos[i] .. pos[i]+qlen-1`` — the same select, one
-                # window per row, then per-QUERY causal validity (query j
-                # sees cache positions <= pos+j).  dense_attention's kv_mask
-                # is per-row, so the per-query window folds into the
-                # additive bias instead; same NEG_INF -> exact-zero
-                # weight semantics as every other mask here.
-                from tpu_pipelines.parallel.ring_attention import NEG_INF
-
-                qlen = q.shape[1]
-                idx = pos[:, None] + jnp.arange(qlen)[None, :]  # [b, q]
-                cached_k.value = _write_rows_at(cached_k.value, k, pos)
-                cached_v.value = _write_rows_at(cached_v.value, v, pos)
-                win = (
-                    jnp.arange(max_decode_len)[None, None, :]
-                    <= idx[:, :, None]
-                )                                               # [b, q, kv]
-                wbias = jnp.where(win, 0.0, NEG_INF)[:, None]   # [b,1,q,kv]
-                bias = wbias if bias is None else bias + wbias
-                valid = None
-                verify_window = True
+                raise ValueError(
+                    "per-row decode positions come with one token per "
+                    f"row, got {q.shape[1]}"
+                )
             impl = self.attn_impl
-            if verify_window:
-                # flash_decode_attention is a single-query kernel; the
-                # verify window runs dense (it is one fused step per
-                # round, not the per-token hot path).
-                impl = "dense"
             if impl == "auto":
                 # Decode-regime choice: the single-query step is bandwidth-
                 # bound on the KV cache, a different balance from training

@@ -200,23 +200,14 @@ class SLOMonitor:
             ).values()
         )
         # Decode-speed lever counters (informational, not burn inputs):
-        # windowed deltas let an operator read prefix-hit and speculative
-        # acceptance rates off the same evaluate() table the bench drill
-        # records as evidence.
+        # windowed deltas let an operator read the prefix-hit rate off the
+        # same evaluate() table the bench drill records as evidence.
         prefix_hits = sum(
             int(v) for v in series("serving_decode_prefix_hit_total").values()
         )
         prefix_misses = sum(
             int(v)
             for v in series("serving_decode_prefix_miss_total").values()
-        )
-        spec_proposed = sum(
-            int(v)
-            for v in series("serving_decode_spec_proposed_total").values()
-        )
-        spec_accepted = sum(
-            int(v)
-            for v in series("serving_decode_spec_accept_total").values()
         )
         # Live drift plane (observability/drift.py): the burn input is
         # the worst per-feature distance gauge, paired with the sampled
@@ -233,7 +224,6 @@ class SLOMonitor:
             "req_total": req_total, "err_5xx": err_5xx,  # tpp: disable=TPP214 (dict keys)
             "shed": shed, "compiles": compiles,
             "prefix_hits": prefix_hits, "prefix_misses": prefix_misses,
-            "spec_proposed": spec_proposed, "spec_accepted": spec_accepted,
             "drift_distance": max(drift_vals) if drift_vals else 0.0,
             "monitor_sampled": monitor_sampled,
         }

@@ -70,7 +70,6 @@ class ServingFleet:
         slo_ms_per_token: float = 0.0,
         prefix_cache_entries: int = 0,
         prefill_chunk_pages: int = 0,
-        spec_tokens: int = 0,
         swap_probation_s: float = -1.0,
         supervisor_interval_s: float = 0.0,
         supervisor_queue_age_s: float = 0.0,
@@ -155,14 +154,11 @@ class ServingFleet:
                     "page_size": decode_page_size,
                     "max_queue_tokens": max_queue_tokens,
                     "slo_ms_per_token": slo_ms_per_token,
-                    # ISSUE 16 decode levers, all off at 0 (see
+                    # ISSUE 16 decode levers, both off at 0 (see
                     # serving/generative.py): refcounted prefix caching,
-                    # credit-metered chunked prefill, speculative decode
-                    # width.  Replica.prepare_engine threads the
-                    # payload's draft lane when spec_tokens > 0.
+                    # credit-metered chunked prefill.
                     "prefix_cache_entries": prefix_cache_entries,
                     "prefill_chunk_pages": prefill_chunk_pages,
-                    "spec_tokens": spec_tokens,
                 },
             }
         supervised = supervisor_interval_s > 0

@@ -362,13 +362,7 @@ class Replica:
         from tpu_pipelines.serving.generative import GenerativeEngine
 
         kwargs = dict(self._generative_cfg.get("engine_kwargs", {}))
-        if int(kwargs.get("spec_tokens", 0) or 0) > 0:
-            # Speculative decoding: use the payload's exported draft lane
-            # (make_draft_decode_fns) when it ships one; otherwise the
-            # engine self-drafts — correct but speed-neutral, so the
-            # fleet still serves payloads without a draft model.
-            kwargs["draft_fns"] = getattr(loaded, "draft_decode_fns", None)
-            kwargs["draft_params"] = getattr(loaded, "draft_params", None)
+
         def _engine_fault_hook(_self=self):
             # Generative traffic never touches the batcher's predict
             # path, so the engine carries its own injection seam — a

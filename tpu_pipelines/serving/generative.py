@@ -55,8 +55,11 @@ static-shape substrate):
     evicts a sequence that blows it (``GenerationEvicted``), freeing its
     slot for work that can still meet SLO.
 
-Decode optimisations (ISSUE 16) — three composable levers behind the
-same ``make_decode_fns`` contract, each off by default:
+Decode optimisations (ISSUE 16) — two composable levers behind the
+same ``make_decode_fns`` contract, each off by default.  (A third,
+speculative decoding on a second, mirrored arena, only ever drafted with
+the target itself and was removed; a real draft comes back as proposals
+from the served model's own extra heads on the one arena, ROADMAP R7.)
 
   * **Prefix caching** (``prefix_cache_entries > 0``).  Prompts are
     hashed as a chain of ``page_size``-granular token blocks
@@ -87,30 +90,14 @@ same ``make_decode_fns`` contract, each off by default:
     decode steps of the live rows run between one prompt's windows.
     The compiled programs are identical with the knob on or off, which
     keeps token streams bitwise-identical either way.
-  * **Speculative decoding** (``spec_tokens k > 0``).  A draft model
-    (any ``make_decode_fns`` contract sharing the target's geometry;
-    ``draft_fns=None`` means self-draft — the target drafts for itself,
-    the trivial 100%%-acceptance case) runs ``k`` chained steps on its
-    own mirrored arena, then the target scores all ``k`` fed positions
-    (current token + the first k-1 proposals) in ONE bucketed program
-    (``fns.verify`` when the contract exports it, e.g. ``models/t5.py``;
-    otherwise ``k`` fused ``fns.step`` launches — same math) and
-    the engine emits the accepted prefix plus the target's own token at
-    the first mismatch — every emitted token is either verified equal to
-    the target's greedy choice or IS the target's greedy choice, so a
-    wrong draft costs speed, never correctness.  Rejected tail KV needs
-    no rollback: position validity masks it at exact zero weight and
-    later writes overwrite it.  Acceptance counters join the
-    ``serving_decode_*`` family (``serving_decode_spec_accept_*``).
 
 Metrics (``serving_decode_*``, labeled per replica; catalog in
 docs/SERVING.md): steps/s, tokens/s, batch occupancy, cache pages in
 use, active/queued sequences + outstanding tokens, per-token latency
 histogram, evictions, step-time EWMA (what the router reads), prefix
-cache hits/misses/resident pages, speculative proposals/acceptances,
-and the worker thread's time by phase (``ENGINE_PHASES``: exact sums of
-self seconds and occurrences) with each request's queue wait and time
-to first token.
+cache hits/misses/resident pages, and the worker thread's time by
+phase (``ENGINE_PHASES``: exact sums of self seconds and occurrences)
+with each request's queue wait and time to first token.
 
 Tracing: every phase of the worker thread is also a
 ``jax.profiler.TraceAnnotation`` named ``engine.<phase>`` (plus
@@ -156,13 +143,12 @@ ENGINE_PHASES = (
 
 # The engine's device programs as a profile's "XLA Modules" line names
 # them: ``jit_`` + the ``__name__`` of the function handed to jax.jit.
-# ``jit_run`` is both the bucketed step and the speculative verify.
-# Readers of traces find programs by these names (the benchmark's
-# decode_step_hbm_share.serve reads ``jit_run``): renaming one of the
-# inner functions is a change to this tuple and to those readers.
+# ``jit_run`` is the bucketed step.  Readers of traces find programs by
+# these names (the benchmark's decode_step_hbm_share.serve reads
+# ``jit_run``): renaming one of the inner functions is a change to this
+# tuple and to those readers.
 PROGRAM_NAMES = (
     "jit_prefill", "jit_insert", "jit_move", "jit_clear", "jit_run",
-    "jit_accept",
 )
 # The one program more that a contract prefilled by window has, in place
 # of ``jit_prefill`` (benchmark/layer_metrics/eva_prefill_mfu.serve and
@@ -177,8 +163,8 @@ class CacheKind(NamedTuple):
 
     ``by_position``: axis 1 is the decode position.  A step's
     ``(b, kv)`` bucket is then the first ``kv`` entries of the first
-    ``b`` rows, and entries at or past a row's position hold nothing (the
-    accept program zeroes them).  Otherwise what is valid in a row is the
+    ``b`` rows, and entries at or past a row's position hold nothing
+    (nothing wrote them).  Otherwise what is valid in a row is the
     contract's own business, and a step is handed its ``b`` rows whole.
     ``written``: a step returns the array changed, and the engine sets it
     back into the arena; otherwise a step only reads it.
@@ -363,8 +349,8 @@ def _bucket_of(cache, b: int, kv: int, kind_of):
 
 
 def _write_back(cache, new_sub, b: int, kv: int, kind_of):
-    """The arena's cache with ``new_sub``, what the contract's ``step`` /
-    ``verify`` returned for the bucket ``_bucket_of(cache, b, kv)``, set
+    """The arena's cache with ``new_sub``, what the contract's ``step``
+    returned for the bucket ``_bucket_of(cache, b, kv)``, set
     back where the bucket was cut from; an array the contract's steps
     only read stays as it is.  The arena is donated, so this is one
     in-place write of the bucket per written array, and over the whole
@@ -391,20 +377,16 @@ class _PrefixEntry:
     reports and admission credits are charged in."""
 
     __slots__ = (
-        "key", "pages", "readers", "tok0", "cache", "encoded",
-        "draft_cache", "draft_encoded", "tick",
+        "key", "pages", "readers", "tok0", "cache", "encoded", "tick",
     )
 
-    def __init__(self, key, pages, tok0, cache, encoded,
-                 draft_cache=None, draft_encoded=None):
+    def __init__(self, key, pages, tok0, cache, encoded):
         self.key = key
         self.pages = int(pages)
         self.readers = 0
         self.tok0 = int(tok0)
         self.cache = cache
         self.encoded = encoded
-        self.draft_cache = draft_cache
-        self.draft_encoded = draft_encoded
         self.tick = 0
 
 
@@ -468,15 +450,10 @@ class PrefixCache:
         self._tick += 1
         entry.tick = self._tick
 
-    def insert(
-        self, key, pages, tok0, cache, encoded,
-        draft_cache=None, draft_encoded=None,
-    ) -> _PrefixEntry:
+    def insert(self, key, pages, tok0, cache, encoded) -> _PrefixEntry:
         entry = self._entries.get(key)
         if entry is None:
-            entry = _PrefixEntry(
-                key, pages, tok0, cache, encoded, draft_cache, draft_encoded
-            )
+            entry = _PrefixEntry(key, pages, tok0, cache, encoded)
             self._entries[key] = entry
         self.touch(entry)
         self.trim()
@@ -540,9 +517,6 @@ class GenerativeEngine:
         hard_deadline: bool = False,
         prefix_cache_entries: int = 0,
         prefill_chunk_pages: int = 0,
-        spec_tokens: int = 0,
-        draft_fns: Any = None,
-        draft_params: Any = None,
         device: Any = None,
         telemetry: Optional["DecodeTelemetry"] = None,
         registry=None,
@@ -580,14 +554,12 @@ class GenerativeEngine:
         self._ppage = self._window_len or (
             self.page_size if self.page_size > 0 else self.max_input_len
         )
-        if self._window_len and (prefix_cache_entries or spec_tokens):
-            # Both keep or mirror what ONE prefill program returned; the
+        if self._window_len and prefix_cache_entries:
+            # The cache keeps what ONE prefill program returned; the
             # windows of a prompt build one row's cache up in place.
             raise ValueError(
-                "a contract prefilled by window takes neither the prefix "
-                "cache nor speculative decoding"
+                "a contract prefilled by window takes no prefix cache"
             )
-        self._kind_of = _kind_reader(fns)
         self._account = getattr(fns, "step_account", None)
         # What ``insert`` is handed as a row's encoder output where the
         # contract has no whole-prompt prefill to return one.
@@ -601,33 +573,6 @@ class GenerativeEngine:
         )
         self.prefill_chunk_pages = max(0, int(prefill_chunk_pages))
         self._admit_credits = 0
-        self.spec_tokens = max(0, int(spec_tokens))
-        self._spec = self.spec_tokens > 0
-        if self._spec:
-            # draft_fns=None = self-draft: the target proposes for itself
-            # on a mirrored arena — zero speedup, 100% acceptance, the
-            # machinery's trivial correctness case.
-            self.draft_fns = draft_fns if draft_fns is not None else fns
-            self.draft_params = (
-                draft_params if draft_params is not None else params
-            )
-            d = self.draft_fns
-            if (
-                int(d.max_decode_len) != self.max_decode_len
-                or int(d.eos_id) != self.eos_id
-                or int(d.pad_id) != self.pad_id
-                or int(getattr(d, "max_input_len", self.max_input_len))
-                != self.max_input_len
-            ):
-                raise ValueError(
-                    "draft decode contract must share the target's "
-                    "geometry (max_decode_len/eos_id/pad_id/max_input_len)"
-                )
-        else:
-            self.draft_fns = None
-            self.draft_params = None
-        self.spec_proposed = 0
-        self.spec_accepted = 0
         self.telemetry = telemetry or DecodeTelemetry(registry, replica)
 
         self._lock = threading.Lock()
@@ -656,7 +601,6 @@ class GenerativeEngine:
         self._jit_insert = None
         self._jit_move = None
         self._jit_clear = None
-        self._jit_accept = None
         self._jit_prefill_window = None
         # The one row that a prompt's windows are prefilled into (a
         # contract with ``prefill_window``): built up in place, copied
@@ -665,16 +609,6 @@ class GenerativeEngine:
         # (sequence, windows done): the queue's head while its windows
         # run; it leaves the queue with its last one.
         self._partial: Optional[Tuple[_Sequence, int]] = None
-        # Draft lane (speculative decoding): a second arena mirroring
-        # every slot, stepped by the draft contract's own programs.
-        self._d_arena = None
-        self._d_step_fns: Dict[Tuple[int, int], Any] = {}
-        self._verify_fns: Dict[Tuple[int, int], Any] = {}
-        self._d_jit_prefill = None
-        self._d_jit_insert = None
-        self._d_jit_move = None
-        self._d_jit_clear = None
-        self._d_jit_accept = None
 
         self._worker = threading.Thread(target=self._run, daemon=True)
         self._worker.start()
@@ -690,15 +624,14 @@ class GenerativeEngine:
 
     # ------------------------------------------------------- compiled fns
 
-    def _lane_jits(self, fns) -> Tuple[Any, Any, Any, Any, Any]:
-        """(prefill, insert, move, clear, accept) jits for one decode
-        contract — the target lane always, plus the draft lane when
-        speculative.  ``prefill`` is None for a contract that is
-        prefilled by window."""
+    def _build_jits(self) -> None:
+        """The prefill (or prefill-window), insert, move and clear
+        programs of the engine's contract; the bucketed steps are built
+        as they are asked for (``_step_for``)."""
         import jax
         import jax.numpy as jnp
 
-        kind_of = _kind_reader(fns)
+        fns = self.fns
         # A sequence's first decode position, from its prompt's mask
         # [1, max_input_len]: 1 (behind a BOS at 0) unless the contract
         # states otherwise.
@@ -740,48 +673,12 @@ class GenerativeEngine:
                 mask,
             )
 
-        def accept(state, new_tok, new_pos):
-            # Speculative accept / step-sync: replace the whole tok/pos
-            # vectors with host-composed values (dead rows carry
-            # pad_id/0, matching clear's convention), and SCRUB cache
-            # positions >= new_pos to exact zero.  Attention already
-            # masks those positions, so for a masked contract this is a
-            # value-level no-op (kept entries multiply by 1) — but it
-            # makes "rejected speculative KV never reaches a logit" an
-            # enforced invariant of the arena rather than a property
-            # each decode contract must supply.
-            cache, tok, pos, live, enc, mask = state
-
-            def scrub(path, a):
-                if not kind_of(path).by_position:
-                    return a
-                valid = jnp.arange(a.shape[1]) < new_pos[:, None]
-                v = valid.reshape(valid.shape + (1,) * (a.ndim - 2))
-                return a * v.astype(a.dtype)
-
-            cache = jax.tree_util.tree_map_with_path(scrub, cache)
-            return (cache, new_tok, new_pos, live, enc, mask)
-
-        return (
-            _jit_program(prefill) if hasattr(fns, "prefill") else None,
-            _jit_program(insert), _jit_program(move), _jit_program(clear),
-            _jit_program(accept),
-        )
-
-    def _build_jits(self) -> None:
-        import jax.numpy as jnp
-
-        (
-            self._jit_prefill, self._jit_insert, self._jit_move,
-            self._jit_clear, self._jit_accept,
-        ) = self._lane_jits(self.fns)
-        if self._spec:
-            (
-                self._d_jit_prefill, self._d_jit_insert, self._d_jit_move,
-                self._d_jit_clear, self._d_jit_accept,
-            ) = self._lane_jits(self.draft_fns)
+        if hasattr(fns, "prefill"):
+            self._jit_prefill = _jit_program(prefill)
+        self._jit_insert = _jit_program(insert)
+        self._jit_move = _jit_program(move)
+        self._jit_clear = _jit_program(clear)
         if self._window_len:
-            fns = self.fns
 
             def prefill_window(params, row_cache, tokens, n_valid, index):
                 # One window of one prompt against the row being built;
@@ -794,6 +691,8 @@ class GenerativeEngine:
             self._jit_prefill_window = _jit_program(prefill_window)
 
     def _build_step(self, b: int, kv: int, fns):
+        # ``fns`` stays a parameter: the benchmark's tests wrap this
+        # method under this signature.
         import jax.numpy as jnp
 
         pad = self.pad_id
@@ -815,47 +714,8 @@ class GenerativeEngine:
 
         return _jit_program(run)
 
-    def _build_verify(self, b: int, kv: int):
-        """One bucketed target-verify program: score ``k = spec_tokens``
-        candidate positions in ONE device step via the contract's
-        ``verify`` (or ``k`` fused single-steps when the contract lacks
-        it — same math, k launches).  Returns the updated cache plus
-        greedy picks ``g[b, k]`` where ``g[:, j]`` is the target's choice
-        at position ``pos + j`` given the fed tokens."""
-        import jax.numpy as jnp
-
-        fns = self.fns
-        k = self.spec_tokens
-        verify = getattr(fns, "verify", None)
-
-        def run(params, state, toks):
-            # toks[b, k]: column 0 is each row's current last emitted
-            # token, columns 1..k-1 the draft's first k-1 proposals.
-            cache, tok, pos, live, encoded, enc_mask = state
-            sub = _bucket_of(cache, b, kv, self._kind_of)
-            if verify is not None:
-                new_sub, logits = verify(
-                    params, sub, toks[:b], pos[:b],
-                    encoded[:b], enc_mask[:b], kv,
-                )
-            else:
-                outs = []
-                new_sub = sub
-                for j in range(k):
-                    new_sub, lg = fns.step(
-                        params, new_sub, toks[:b, j], pos[:b] + j,
-                        encoded[:b], enc_mask[:b], kv,
-                    )
-                    outs.append(lg)
-                logits = jnp.stack(outs, axis=1)
-            g = jnp.argmax(logits, -1).astype(jnp.int32)  # [b, k]
-            cache = _write_back(cache, new_sub, b, kv, self._kind_of)
-            return (cache, tok, pos, live, encoded, enc_mask), g
-
-        return _jit_program(run)
-
-    def _program_for(self, cache, build, kind, b: int, kv: int):
-        fn = cache.get((b, kv))
+    def _step_for(self, b: int, kv: int):
+        fn = self._step_fns.get((b, kv))
         if fn is None:
             if self._warmed:
                 # The warmup contract: every (batch, kv) bucket program is
@@ -865,30 +725,12 @@ class GenerativeEngine:
                 self.compiles_after_warm += 1
                 self.telemetry.on_compile_after_warm()
                 log.warning(
-                    "generative engine: compiling %s (%d, %d) AFTER "
-                    "warmup — bucket missed by warm()", kind, b, kv,
+                    "generative engine: compiling step (%d, %d) AFTER "
+                    "warmup — bucket missed by warm()", b, kv,
                 )
-            fn = build(b, kv)
-            cache[(b, kv)] = fn
+            fn = self._build_step(b, kv, self.fns)
+            self._step_fns[(b, kv)] = fn
         return fn
-
-    def _step_for(self, b: int, kv: int):
-        return self._program_for(
-            self._step_fns, lambda b, kv: self._build_step(b, kv, self.fns),
-            "step", b, kv,
-        )
-
-    def _d_step_for(self, b: int, kv: int):
-        return self._program_for(
-            self._d_step_fns,
-            lambda b, kv: self._build_step(b, kv, self.draft_fns),
-            "draft step", b, kv,
-        )
-
-    def _verify_for(self, b: int, kv: int):
-        return self._program_for(
-            self._verify_fns, self._build_verify, "verify", b, kv,
-        )
 
     # ------------------------------------------------------------- arena
 
@@ -918,43 +760,36 @@ class GenerativeEngine:
             zin = jnp.full((1, self.max_input_len), self.pad_id, jnp.int32)
             zmask = jnp.zeros((1, self.max_input_len), jnp.int32)
             B = self.max_batch_size
-
-            def blank_arena(prefill_jit, params):
-                if self._window_len:
-                    # No whole-prompt prefill to read the shapes off:
-                    # the contract gives the cache's arrays itself, and
-                    # the encoder rows it keeps (none: ``(0,)``).
-                    cache = self.fns.blank_cache(B)
-                    encoded1 = self._no_encoded
-                else:
-                    cache1, encoded1, _ = prefill_jit(params, zin, zmask)
-                    cache = jax.tree_util.tree_map(
-                        lambda x: jnp.zeros((B,) + x.shape[1:], x.dtype),
-                        cache1,
+            if self._window_len:
+                # No whole-prompt prefill to read the shapes off: the
+                # contract gives the cache's arrays itself, and the
+                # encoder rows it keeps (none: ``(0,)``).
+                cache = self.fns.blank_cache(B)
+                encoded1 = self._no_encoded
+                if self._row_cache is None:
+                    self._row_cache = jax.device_put(
+                        self.fns.blank_cache(1), dev
                     )
-                # Free rows keep an all-ONES encoder mask: cross-attention
-                # over their zero K/V then averages zeros instead of
-                # softmaxing an all-masked row into NaN.  Live rows
-                # overwrite it on insert.
-                return jax.device_put((
-                    cache,
-                    jnp.full((B,), self.pad_id, jnp.int32),
-                    jnp.zeros((B,), jnp.int32),
-                    jnp.zeros((B,), bool),
-                    jnp.zeros((B,) + encoded1.shape[1:], encoded1.dtype),
-                    jnp.ones((B, self.max_input_len), jnp.int32),
-                ), dev)
-
-            self._arena = blank_arena(self._jit_prefill, self.params)
-            if self._window_len and self._row_cache is None:
-                self._row_cache = jax.device_put(
-                    self.fns.blank_cache(1), dev
+            else:
+                cache1, encoded1, _ = self._jit_prefill(
+                    self.params, zin, zmask
                 )
-            if self._spec:
-                self.draft_params = jax.device_put(self.draft_params, dev)
-                self._d_arena = blank_arena(
-                    self._d_jit_prefill, self.draft_params
+                cache = jax.tree_util.tree_map(
+                    lambda x: jnp.zeros((B,) + x.shape[1:], x.dtype),
+                    cache1,
                 )
+            # Free rows keep an all-ONES encoder mask: cross-attention
+            # over their zero K/V then averages zeros instead of
+            # softmaxing an all-masked row into NaN.  Live rows
+            # overwrite it on insert.
+            self._arena = jax.device_put((
+                cache,
+                jnp.full((B,), self.pad_id, jnp.int32),
+                jnp.zeros((B,), jnp.int32),
+                jnp.zeros((B,), bool),
+                jnp.zeros((B,) + encoded1.shape[1:], encoded1.dtype),
+                jnp.ones((B, self.max_input_len), jnp.int32),
+            ), dev)
 
     def warm(self) -> None:
         """Pre-compile every program traffic can pose: prefill (or one
@@ -973,6 +808,8 @@ class GenerativeEngine:
         Arguments mirror the traffic paths exactly — host numpy inputs,
         the committed arena — so every call lands on the SAME program
         cache key traffic will use (see _ensure_arena on placement)."""
+        import jax
+
         with self._lock, self._dev():
             if self._n_live or self._queue:
                 raise RuntimeError(
@@ -983,56 +820,39 @@ class GenerativeEngine:
             zin = np.full((1, self.max_input_len), self.pad_id, np.int32)
             zmask = np.zeros((1, self.max_input_len), np.int32)
             slot = np.int32(0)
-            B = self.max_batch_size
-            ztok = np.full((B,), self.pad_id, np.int32)
-            zpos = np.zeros((B,), np.int32)
-
-            def lane(arena, params, prefill, insert, move, clear, accept,
-                     step_for):
-                if self._window_len:
-                    self._row_cache, tok0 = self._jit_prefill_window(
-                        params, self._row_cache,
-                        np.full((1, self._window_len), self.pad_id, np.int32),
-                        np.int32(1), np.int32(0),
-                    )
-                    cache1, encoded1 = self._row_cache, self._no_encoded
-                else:
-                    cache1, encoded1, tok0 = prefill(params, zin, zmask)
-                # tok0 goes to insert as a HOST int32: the prefix-cache
-                # hit path has only the entry's host token, and
-                # warm/miss/hit must all land on the same insert
-                # program cache key.
-                arena = insert(
-                    arena, cache1, encoded1, zmask, np.int32(int(tok0)), slot
+            if self._window_len:
+                self._row_cache, tok0 = self._jit_prefill_window(
+                    self.params, self._row_cache,
+                    np.full((1, self._window_len), self.pad_id, np.int32),
+                    np.int32(1), np.int32(0),
                 )
-                arena = clear(move(arena, slot, slot), slot)
-                for b in self.batch_buckets:
-                    for kv in self.kv_buckets:
-                        arena, _ = step_for(b, kv)(params, arena)
-                return accept(arena, ztok, zpos)
-
-            self._arena = lane(
-                self._arena, self.params, self._jit_prefill,
-                self._jit_insert, self._jit_move, self._jit_clear,
-                self._jit_accept, self._step_for,
+                cache1, encoded1 = self._row_cache, self._no_encoded
+            else:
+                cache1, encoded1, tok0 = self._jit_prefill(
+                    self.params, zin, zmask
+                )
+            # tok0 goes to insert as a HOST int32: the prefix-cache hit
+            # path has only the entry's host token, and warm/miss/hit
+            # must all land on the same insert program cache key.
+            self._arena = self._jit_insert(
+                self._arena, cache1, encoded1, zmask, np.int32(int(tok0)),
+                slot,
             )
-            if self._spec:
-                self._d_arena = lane(
-                    self._d_arena, self.draft_params, self._d_jit_prefill,
-                    self._d_jit_insert, self._d_jit_move,
-                    self._d_jit_clear, self._d_jit_accept, self._d_step_for,
-                )
-                zk = np.full(
-                    (B, self.spec_tokens), self.pad_id, np.int32
-                )
-                for b in self.batch_buckets:
-                    for kv in self.kv_buckets:
-                        self._arena, _ = self._verify_for(b, kv)(
-                            self.params, self._arena, zk
-                        )
-            # Let go of the warmed arena before its blank successor is
-            # allocated: the chip need not hold two.
-            self._arena = self._d_arena = None
+            self._arena = self._jit_clear(
+                self._jit_move(self._arena, slot, slot), slot
+            )
+            for b in self.batch_buckets:
+                for kv in self.kv_buckets:
+                    self._arena, _ = self._step_for(b, kv)(
+                        self.params, self._arena
+                    )
+            # Let go of the warmed arena (and of the prefill result that
+            # was inserted) before its blank successor is allocated: the
+            # chip need not hold two.  A buffer is freed only once the
+            # last program that uses it has finished, so wait for that.
+            jax.block_until_ready(self._arena)
+            del cache1, encoded1
+            self._arena = None
             self._ensure_arena()
         self._warmed = True
 
@@ -1166,7 +986,7 @@ class GenerativeEngine:
             # closures and the engine refer to each other, so the arena
             # would otherwise live until the collector finds the cycle
             # (6.9 GB of a 16 GB chip under a long-context contract).
-            self._arena = self._d_arena = self._row_cache = None
+            self._arena = self._row_cache = None
 
     # ------------------------------------------------------------- worker
 
@@ -1209,9 +1029,9 @@ class GenerativeEngine:
                     self._fault_hook()
                 self._admit()
                 if self._n_live:
-                    self._decode_round()
+                    self._step_once()
                     if self.prefill_chunk_pages > 0:
-                        # Each decode round EARNS admission credits
+                        # Each decode step EARNS admission credits
                         # (chunked prefill's meter), capped at one full
                         # prompt so idle decode can't bank a stall-sized
                         # prefill burst.
@@ -1230,7 +1050,7 @@ class GenerativeEngine:
                 # The program that failed may have taken the arena with
                 # it (a donated argument is gone whether or not its
                 # program ran to the end): nothing may use it again.
-                self._arena = self._d_arena = None
+                self._arena = None
                 pending = list(self._queue) + [
                     s for s in self._slots[: self._n_live] if s is not None
                 ]
@@ -1240,20 +1060,6 @@ class GenerativeEngine:
                 self._release_prefix(seq)
                 self._trace_end(seq, "error")
                 seq.finish(e)
-
-    def _decode_round(self) -> None:
-        """One scheduling round: a speculative draft/verify round when
-        enabled and every live position has ``spec_tokens`` of cache
-        headroom, else one fused single-token step."""
-        if self._spec:
-            n = self._n_live
-            deepest = max(
-                len(s.tokens) for s in self._slots[:n] if s is not None
-            )
-            if deepest + self.spec_tokens <= self.max_decode_len:
-                self._spec_round()
-                return
-        self._step_once()
 
     def _prompt_pages(self, seq: _Sequence) -> int:
         n_valid = int((seq.input_mask > 0).sum())
@@ -1313,13 +1119,11 @@ class GenerativeEngine:
         self.telemetry.on_admitted(seq.admitted_s - seq.arrival_s)
         with self._dev():
             self._ensure_arena()
-            d_cache1 = d_enc1 = None
             if entry is not None:
                 self._prefix.hits += 1
                 self._prefix.touch(entry)
                 self.telemetry.on_prefix_hit(entry.pages)
                 cache1, enc1 = entry.cache, entry.encoded
-                d_cache1, d_enc1 = entry.draft_cache, entry.draft_encoded
                 t0 = entry.tok0
             else:
                 with self._phase(
@@ -1333,18 +1137,11 @@ class GenerativeEngine:
                     # everything queued ahead of this prefill on the
                     # device (arena scatters, a step) is waited for here.
                     t0 = int(tok0)
-                    if self._spec:
-                        d_cache1, d_enc1, _ = self._d_jit_prefill(
-                            self.draft_params,
-                            seq.inputs[None], seq.input_mask[None],
-                        )
                 if self._prefix is not None:
                     self._prefix.misses += 1
                     self.telemetry.on_prefix_miss()
-                    entry = self._prefix.insert(
-                        key, pages, t0, cache1, enc1, d_cache1, d_enc1
-                    )
-            return self._seat(seq, cache1, enc1, t0, entry, d_cache1, d_enc1)
+                    entry = self._prefix.insert(key, pages, t0, cache1, enc1)
+            return self._seat(seq, cache1, enc1, t0, entry)
 
     def _admit_window(self, span) -> bool:
         """One turn of admission under a contract that is prefilled by
@@ -1394,10 +1191,10 @@ class GenerativeEngine:
                     return False      # ``close`` took the queue meanwhile
                 self._queue.popleft()
             return self._seat(
-                seq, self._row_cache, self._no_encoded, t0, None, None, None
+                seq, self._row_cache, self._no_encoded, t0, None
             )
 
-    def _seat(self, seq, cache1, enc1, t0, entry, d_cache1, d_enc1) -> bool:
+    def _seat(self, seq, cache1, enc1, t0, entry) -> bool:
         """A prefilled sequence's first token, and its slot in the arena
         unless that token already ended it (under ``self._dev()``)."""
         seq.first_token_s = time.monotonic()
@@ -1424,14 +1221,6 @@ class GenerativeEngine:
                 self._arena, cache1, enc1, seq.input_mask[None],
                 np.int32(t0), np.int32(slot),
             )
-            if self._spec:
-                # The draft lane mirrors the slot: its own prefill
-                # cache, but the TARGET's first token — the draft
-                # always consumes the verified stream.
-                self._d_arena = self._d_jit_insert(
-                    self._d_arena, d_cache1, d_enc1,
-                    seq.input_mask[None], np.int32(t0), np.int32(slot),
-                )
         if seq.ctx is not None:
             # Slot event: the sequence joined the continuous batch —
             # the wait it paid in the queue is arrival -> now.
@@ -1445,111 +1234,6 @@ class GenerativeEngine:
             self._slots[slot] = seq
             self._n_live += 1
         return True
-
-    def _spec_round(self) -> None:
-        """One speculative round: ``k`` chained draft steps propose,
-        ONE bucketed target program scores all ``k`` fed positions
-        (``_build_verify``), and each row emits the accepted draft
-        prefix plus the target's own token at the first mismatch —
-        1..k verified-greedy tokens per target step.  The k-th draft
-        proposal is never judged (the verify window is full): its step
-        runs anyway so the draft cache covers every position the round
-        can emit — without it the draft lane keeps a permanent KV hole
-        at the last emitted position and acceptance collapses.
-        Rejected-tail KV in both arenas is scrubbed to exact zero by
-        the accept program (see ``_build_jits``)."""
-        from jax.profiler import TraceAnnotation
-
-        n = self._n_live
-        k = self.spec_tokens
-        B = self.max_batch_size
-        with self._phase("step") as span:
-            b = next(bk for bk in self.batch_buckets if bk >= n)
-            deepest = max(
-                len(s.tokens) for s in self._slots[:n] if s is not None
-            )
-            kv = next(kb for kb in self.kv_buckets if kb >= deepest + k)
-            span.set_metadata(live=n, b=b, kv=kv)
-            toks = np.full((B, k), self.pad_id, np.int32)
-            for i in range(n):
-                s = self._slots[i]
-                if s is not None:
-                    toks[i, 0] = s.tokens[-1]
-            t_start = time.perf_counter()
-            with self._dev():
-                d_fn = self._d_step_for(b, kv)
-                for j in range(1, k + 1):
-                    self._d_arena, nxt = d_fn(
-                        self.draft_params, self._d_arena
-                    )
-                    if j < k:
-                        with TraceAnnotation("engine.step.wait"):
-                            toks[:b, j] = np.asarray(nxt)
-                self._arena, g = self._verify_for(b, kv)(
-                    self.params, self._arena, toks
-                )
-                with TraceAnnotation("engine.step.wait"):
-                    gh = np.asarray(g)  # [b, k] — the device->host sync
-            dt = time.perf_counter() - t_start
-            if self.step_ewma_s is None:
-                self.step_ewma_s = dt
-            else:
-                a_ = self.STEP_EWMA_ALPHA
-                self.step_ewma_s = (1 - a_) * self.step_ewma_s + a_ * dt
-            self.steps_run += 1
-        with self._phase("emit", live=n):
-            now = time.monotonic()
-            proposed = accepted = 0
-            new_tok = np.full((B,), self.pad_id, np.int32)
-            new_pos = np.zeros((B,), np.int32)
-            for i in range(n):
-                seq = self._slots[i]
-                a = 0
-                while a < k - 1 and toks[i, a + 1] == gh[i, a]:
-                    a += 1
-                proposed += k - 1
-                accepted += a
-                emitted = 0
-                for j in range(a + 1):
-                    t = int(gh[i, j])
-                    seq.tokens.append(t)
-                    emitted += 1
-                    self.telemetry.on_token()
-                    if (
-                        t == self.eos_id
-                        or len(seq.tokens) >= seq.max_new_tokens
-                    ):
-                        break
-                new_tok[i] = seq.tokens[-1]
-                new_pos[i] = len(seq.tokens)
-                if seq.ctx is not None:
-                    seq.ctx.instant(
-                        "decode.spec", slot=i, token=len(seq.tokens),
-                        accepted=a, emitted=emitted,
-                        batch_bucket=b, kv_bucket=kv, live=n,
-                        step_s=round(dt, 6),
-                    )
-            self.spec_proposed += proposed
-            self.spec_accepted += accepted
-            self.telemetry.on_spec(proposed, accepted)
-            pages = sum(
-                -(-(len(s.tokens) + 1) // self._page)
-                for s in self._slots[:n] if s is not None
-            )
-            self.telemetry.on_step(
-                dt, self.step_ewma_s, n, b, pages, int(n)
-            )
-            with self._dev():
-                # Wholesale tok/pos sync of BOTH lanes to the emitted
-                # stream (rows past n carry pad/0, clear's convention).
-                self._arena = self._jit_accept(
-                    self._arena, new_tok, new_pos
-                )
-                self._d_arena = self._d_jit_accept(
-                    self._d_arena, new_tok, new_pos
-                )
-            for slot in range(n - 1, -1, -1):
-                self._settle(slot, self._slots[slot], now)
 
     def _step_once(self) -> None:
         from jax.profiler import TraceAnnotation
@@ -1566,32 +1250,9 @@ class GenerativeEngine:
             t0 = time.perf_counter()
             with self._dev():
                 self._arena, nxt = fn(self.params, self._arena)
-                if self._spec:
-                    # Keep the draft lane's KV stream gap-free even on
-                    # the single-step fallback path (headroom near the
-                    # cache end): the draft consumes the same tok/pos
-                    # mirror, its own next-token guess is then
-                    # overwritten by the accept-sync below.
-                    self._d_arena, _ = self._d_step_for(b, kv)(
-                        self.draft_params, self._d_arena
-                    )
                 with TraceAnnotation("engine.step.wait"):
                     # the one device->host sync per step
                     toks = np.asarray(nxt)
-            if self._spec:
-                new_tok = np.full(
-                    (self.max_batch_size,), self.pad_id, np.int32
-                )
-                new_pos = np.zeros((self.max_batch_size,), np.int32)
-                for i in range(n):
-                    s = self._slots[i]
-                    if s is not None:
-                        new_tok[i] = int(toks[i])
-                        new_pos[i] = len(s.tokens) + 1
-                with self._dev():
-                    self._d_arena = self._d_jit_accept(
-                        self._d_arena, new_tok, new_pos
-                    )
             dt = time.perf_counter() - t0
             if self.step_ewma_s is None:
                 self.step_ewma_s = dt
@@ -1632,7 +1293,7 @@ class GenerativeEngine:
                 self._settle(slot, seq, now)
 
     def _settle(self, slot: int, seq: _Sequence, now: float) -> None:
-        """After a round's tokens are appended: retire and complete a
+        """After a step's token is appended: retire and complete a
         sequence that hit EOS or its budget, evict one past its hard
         deadline, leave the rest in their slots."""
         t = seq.tokens[-1]
@@ -1670,15 +1331,7 @@ class GenerativeEngine:
                     self._arena = self._jit_move(
                         self._arena, np.int32(last), np.int32(slot)
                     )
-                    if self._spec:
-                        self._d_arena = self._d_jit_move(
-                            self._d_arena, np.int32(last), np.int32(slot)
-                        )
                 self._arena = self._jit_clear(self._arena, np.int32(last))
-                if self._spec:
-                    self._d_arena = self._d_jit_clear(
-                        self._d_arena, np.int32(last)
-                    )
             with self._lock:
                 if slot != last:
                     self._slots[slot] = self._slots[last]
@@ -1741,8 +1394,6 @@ class DecodeTelemetry:
         self._compiles = None
         self._prefix_hits = self._prefix_misses = None
         self._prefix_hit_pages = self._prefix_pages = None
-        self._spec_proposed = self._spec_accept = None
-        self._spec_ratio = None
         self._phase_s = self._phase_n = None
         self._queue_wait = self._ttft = None
         self._prefill_tokens = self._prefill_windows = None
@@ -1836,19 +1487,6 @@ class DecodeTelemetry:
             "serving_decode_prefix_pages_in_use",
             "Prompt pages resident in the prefix cache (readers pin "
             "entries past capacity until the last one retires).",
-            labels=lab,
-        ).labels(self.replica)
-        self._spec_proposed = registry.counter(
-            "serving_decode_spec_proposed_total",
-            "Draft tokens proposed by speculative decoding.", labels=lab,
-        ).labels(self.replica)
-        self._spec_accept = registry.counter(
-            "serving_decode_spec_accept_total",
-            "Draft tokens the target verified and accepted.", labels=lab,
-        ).labels(self.replica)
-        self._spec_ratio = registry.gauge(
-            "serving_decode_spec_accept_ratio",
-            "Lifetime speculative acceptance rate (accepted / proposed).",
             labels=lab,
         ).labels(self.replica)
         phase_lab = ("replica", "phase")
@@ -1991,14 +1629,3 @@ class DecodeTelemetry:
     def on_prefix_pages(self, pages: int) -> None:
         if self._prefix_pages is not None:
             self._prefix_pages.set(pages)
-
-    def on_spec(self, proposed: int, accepted: int) -> None:
-        if self._spec_proposed is None:
-            return
-        if proposed:
-            self._spec_proposed.inc(proposed)
-        if accepted:
-            self._spec_accept.inc(accepted)
-        p = self._spec_proposed.get()
-        if p:
-            self._spec_ratio.set(self._spec_accept.get() / p)

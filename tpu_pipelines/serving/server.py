@@ -71,15 +71,12 @@ ENV_MODEL_TYPE = "TPP_SERVING_MODEL_TYPE"
 ENV_PAGE_SIZE = "TPP_SERVING_PAGE_SIZE"
 ENV_MAX_TOKENS = "TPP_SERVING_MAX_TOKENS"
 ENV_SLO_MS_PER_TOKEN = "TPP_SERVING_SLO_MS_PER_TOKEN"
-# Decode-speed levers (serving/generative.py, all off at 0): resident
-# prefix-cache entries (refcounted prefill reuse for shared prompts),
+# Decode-speed levers (serving/generative.py, both off at 0): resident
+# prefix-cache entries (refcounted prefill reuse for shared prompts) and
 # prefill pages admitted per decode step (chunked prefill's credit
-# meter), and the speculative-decoding window (draft proposals verified
-# per target step; the payload's make_draft_decode_fns supplies the
-# draft, else the engine self-drafts).
+# meter).
 ENV_PREFIX_CACHE = "TPP_SERVING_PREFIX_CACHE"
 ENV_PREFILL_CHUNK = "TPP_SERVING_PREFILL_CHUNK"
-ENV_SPEC_TOKENS = "TPP_SERVING_SPEC_TOKENS"
 # Self-healing fleet (ISSUE 17): probe interval > 0 turns the
 # ReplicaSupervisor on (heartbeat + queue-age probes, circuit breakers,
 # failover, rebuild-in-place); queue-age is the wedge threshold (0 =
@@ -168,7 +165,6 @@ class ModelServer:
         slo_ms_per_token: float = -1.0,
         prefix_cache_entries: int = 0,
         prefill_chunk_pages: int = 0,
-        spec_tokens: int = 0,
         request_trace_mode: str = "",
         trace_dir: str = "",
         slo_monitor_interval_s: float = -1.0,
@@ -204,8 +200,6 @@ class ModelServer:
             prefix_cache_entries = int(_env_number(ENV_PREFIX_CACHE, 0))
         if prefill_chunk_pages <= 0:
             prefill_chunk_pages = int(_env_number(ENV_PREFILL_CHUNK, 0))
-        if spec_tokens <= 0:
-            spec_tokens = int(_env_number(ENV_SPEC_TOKENS, 0))
         if supervisor_interval_s < 0:
             supervisor_interval_s = _env_number(ENV_SUPERVISOR_S, 0.0)
         if supervisor_queue_age_s < 0:
@@ -229,7 +223,6 @@ class ModelServer:
         self.slo_ms_per_token = max(0.0, slo_ms_per_token)
         self.prefix_cache_entries = max(0, prefix_cache_entries)
         self.prefill_chunk_pages = max(0, prefill_chunk_pages)
-        self.spec_tokens = max(0, spec_tokens)
         self._lock = threading.Lock()
         # Serializes reload(): concurrent version swaps would race the
         # load-outside-lock / swap-under-lock dance.  Never held while
@@ -353,7 +346,6 @@ class ModelServer:
                 slo_ms_per_token=self.slo_ms_per_token,
                 prefix_cache_entries=self.prefix_cache_entries,
                 prefill_chunk_pages=self.prefill_chunk_pages,
-                spec_tokens=self.spec_tokens,
                 swap_probation_s=swap_probation_s,
                 supervisor_interval_s=self.supervisor_interval_s,
                 supervisor_queue_age_s=self.supervisor_queue_age_s,

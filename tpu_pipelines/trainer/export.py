@@ -195,14 +195,6 @@ class LoadedModel:
     # — prefill/step + geometry the generative fleet model type builds its
     # per-replica engines from.  None = whole-request generate only.
     decode_fns: Any = None
-    # Speculative-decoding draft lane (serving/generative.py spec_tokens):
-    # present when the exported module defines
-    # ``make_draft_decode_fns(model, hyperparameters)`` returning
-    # ``(draft_fns, draft_params)`` — a smaller model speaking the same
-    # decode contract with the SAME geometry constants.  None = the
-    # engine self-drafts (or speculation stays off).
-    draft_decode_fns: Any = None
-    draft_params: Any = None
     # The two halves of `predict`, exposed for exporters (serving/
     # saved_model.py): host string stage (numpy, identity when no transform)
     # and the device computation (numeric transform fused with the forward
@@ -533,22 +525,11 @@ def load_exported_model(uri: str) -> LoadedModel:
         None if quantized else getattr(module, "make_decode_fns", None)
     )
     decode_fns = None
-    draft_decode_fns = draft_params = None
     if decode_builder is not None:
         # Continuous-batching contract for the generative fleet model
         # type; params stay engine arguments (never closed over), same
         # discipline as make_generate_step.
         decode_fns = decode_builder(model, spec.get("hyperparameters", {}))
-        draft_builder = getattr(module, "make_draft_decode_fns", None)
-        if draft_builder is not None:
-            # Draft lane for speculative decoding: the module supplies a
-            # smaller model speaking the same contract (and geometry)
-            # plus its own params — e.g. a distilled T5 checkpoint
-            # shipped inside the payload.  The engine only consumes this
-            # when the fleet enables ``spec_tokens``.
-            draft_decode_fns, draft_params = draft_builder(
-                model, spec.get("hyperparameters", {})
-            )
 
     return LoadedModel(
         params=params,
@@ -563,8 +544,6 @@ def load_exported_model(uri: str) -> LoadedModel:
         device_step=device_step,
         generate=generate,
         decode_fns=decode_fns,
-        draft_decode_fns=draft_decode_fns,
-        draft_params=draft_params,
         dtype=dtype,
         # Resident bytes of the tree actually held in memory (after the
         # bf16 load cast / with int8 + scales), not the on-disk figure.
