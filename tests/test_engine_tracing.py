@@ -14,7 +14,7 @@ import time
 import numpy as np
 import pytest
 
-from test_generative import VOCAB, make_stub_fns
+from test_generative import EOS, VOCAB, make_stub_fns
 
 pytestmark = pytest.mark.generative
 
@@ -89,16 +89,25 @@ def test_phase_occurrences_are_what_the_engine_did(engine_kw):
     count = phase_series(reg, "serving_decode_engine_phase_total")
     get = lambda name: reg.get(name).labels("0").get()
     assert count["step"] == get("serving_decode_steps_total")
-    assert count["step"] == engine.steps_run == count["emit"]
+    assert count["step"] == engine.steps_run
+    # an ``emit`` follows every step; one more stands around each read of
+    # first tokens that had no step to go behind
+    blocking = reg.get("serving_decode_first_token_reads_total").labels(
+        "0", "blocking").get()
+    assert count["step"] <= count["emit"] <= count["step"] + blocking
     hits = engine._prefix.hits if engine._prefix is not None else 0
     assert count["prefill"] == len(handles) - hits
     if engine._prefix is not None:
         assert hits > 0
         assert hits == get("serving_decode_prefix_hit_total")
-    # A sequence whose first token ended it never took a slot; every
-    # other one was inserted once and retired once.
-    took_a_slot = sum(1 for h in handles if len(h.result) > 1)
-    assert 0 < took_a_slot
+    # A sequence with a budget of one token never took a slot; every
+    # other one was inserted once and retired once.  (One whose first
+    # token is EOS takes a slot too unless a prefix entry already held
+    # that token on the host: the prefill's token is not read before
+    # the insert.  This traffic has none.)
+    assert all(h.result[0] != EOS for h in handles)
+    took_a_slot = sum(1 for h in handles if h.max_new_tokens > 1)
+    assert 0 < took_a_slot <= len(handles)
     assert count["insert"] == count["retire"] == took_a_slot
     if engine.prefill_chunk_pages:
         # a turn that leaves the head queued for lack of credits counts
@@ -253,9 +262,15 @@ def test_spans_in_a_profiler_session_and_the_same_counters_without(tmp_path):
     assert set(by_name) == {
         "engine.idle", "engine.admit", "engine.prefill", "engine.insert",
         "engine.step", "engine.step.wait", "engine.emit", "engine.retire",
+        "engine.prefill.wait",
     }
     for name in ("admit", "prefill", "insert", "retire", "step", "emit"):
         assert len(by_name["engine." + name]) == on[name], name
+
+    def within(span, parents):
+        return any(
+            p0 <= span[0] and span[1] <= p1
+            for p0, p1, _ in by_name["engine." + parents])
 
     def inside(child, parent, same_seq):
         for c0, c1, c_stats in by_name[child]:
@@ -269,10 +284,32 @@ def test_spans_in_a_profiler_session_and_the_same_counters_without(tmp_path):
     inside("engine.insert", "engine.admit", True)
     inside("engine.retire", "engine.emit", False)
     inside("engine.step.wait", "engine.step", False)
-    # top-level phases follow one another on the one thread
+    # A first token is read with a step queued behind its prefill, or,
+    # where nothing was there to step, in an ``emit`` of its own; one
+    # read per admission that no prefix entry answered.
+    assert all(
+        within(w, "step") or within(w, "emit")
+        for w in by_name["engine.prefill.wait"])
+    reads = reg_on.snapshot()[
+        "serving_decode_first_token_reads_total"]["series"]
+    assert len(by_name["engine.prefill.wait"]) == (
+        reads.get(("0", "behind_step"), 0) + reads.get(("0", "blocking"), 0)
+    ) == len(handles)
+    # a request's wait begins after its own prefill was dispatched
+    dispatched = {
+        stats["seq"]: end for _, end, stats in by_name["engine.prefill"]}
+    assert all(
+        dispatched[stats["seq"]] <= start
+        for start, _, stats in by_name["engine.prefill.wait"])
+    # top-level phases follow one another on the one thread; an
+    # admission turn is top-level only while no row is live, and
+    # otherwise lies behind the dispatch of a step, inside its span
+    behind = [a for a in by_name["engine.admit"] if within(a, "step")]
+    assert behind and len(behind) < len(by_name["engine.admit"])
     top = sorted(
         (s, e) for n in ("idle", "admit", "step", "emit")
-        for s, e, _ in by_name["engine." + n])
+        for s, e, *_ in by_name["engine." + n]
+        if (s, e) not in {(a[0], a[1]) for a in behind})
     assert all(a[1] <= b[0] for a, b in zip(top, top[1:]))
 
     # one identifier per request, shared by its spans
@@ -284,6 +321,7 @@ def test_spans_in_a_profiler_session_and_the_same_counters_without(tmp_path):
         want = {"engine.admit"}
         if len(h.result) > 1:
             want |= {"engine.insert", "engine.retire"}
+        want |= {"engine.prefill.wait"}
         assert want <= spans <= want | {"engine.prefill"}, (h.seq_id, spans)
     assert all(
         set(stats) == {"live", "b", "kv"} and 1 <= stats["live"] <= stats["b"]

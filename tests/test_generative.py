@@ -784,7 +784,8 @@ def _greedy_rows(model, params, reqs, L, eos_id):
 # (prompt index, max_new_tokens) in queue order, for three slots and
 # KV buckets 4 / 8 / 16.  A, B, C are admitted together; B leaves the
 # MIDDLE slot at 3 tokens (move + clear) and D lands in the slot that
-# freed; A and C leave together at 5 with D two tokens behind them, so
+# freed, one step later (an admission goes behind the step of the rows
+# that are seated); A and C leave together at 5 with D behind them, so
 # the deepest live position falls back under a bucket boundary it had
 # crossed, and D then grows through both boundaries.  E and F repeat
 # B's prompt: a stored prefix entry is inserted a second and third time.
@@ -848,11 +849,448 @@ def test_engine_in_place_arena_matches_isolated_greedy_t5(
     assert moves[0] == (2, 1)           # C into B's middle slot
     kvs = [kv for _, kv in steps]
     if page_size:
-        assert kvs[:6] == [4, 4, 4, 8, 4, 8] and kvs[-1] == 16
+        assert kvs[:7] == [4, 4, 4, 8, 4, 4, 8] and kvs[-1] == 16
+        # the step after B left ran with its slot still empty
+        assert [b for b, _ in steps][:4] == [3, 3, 2, 3]
     else:
         assert set(kvs) == {L}
     if prefix_entries:
         assert engine._prefix.hits == 2 and engine._prefix.misses == 4
+
+
+# ----------------------------- admit without waiting (ISSUE 30)
+#
+# A prefill's first token stays on the device: ``insert`` takes it as the
+# device scalar it is, the round's step is dispatched behind every
+# prefill and insert of the round, and only then does the host read:
+# first tokens, then the step's.  Both contracts: a whole-prompt prefill
+# (tiny T5) and prefill by window (tiny EvaByte).
+
+
+def _t5_kit(tiny_t5):
+    from tpu_pipelines.models.t5 import make_continuous_decode_fns
+
+    model, params = tiny_t5
+    L, none = 12, 10_000                # ``none``: outside the vocabulary
+    rng = np.random.default_rng(30)
+    prompts = [
+        rng.integers(2, 40, size=(n,)).astype(np.int32)
+        for n in (3, 6, 2, 5, 4)
+    ]
+    rows = _greedy_rows(model, params, prompts, L, none)
+    return SimpleNamespace(
+        params=params, prompts=prompts, windowed=False,
+        greedy=lambda i, m: rows[i][:m],
+        fns=lambda eos: make_continuous_decode_fns(
+            model, max_decode_len=L, eos_id=eos, max_input_len=6),
+    )
+
+
+def _evabyte_kit():
+    import test_evabyte as eva
+    from tpu_pipelines.models.evabyte import make_continuous_decode_fns
+
+    model, params = eva.build()
+    # 1 to 3 windows of 32: a prompt's last window hands the token on
+    prompts = [eva.prompt(300 + i, n) for i, n in enumerate(
+        (40, 7, 64, 33, 90))]
+    fns = eva.decode_fns(model)
+    rows = [
+        eva.through_the_cache(model, params, fns, p, 12)[0].tolist()
+        for p in prompts
+    ]
+    return SimpleNamespace(
+        params=params, prompts=prompts, windowed=True,
+        greedy=lambda i, m: rows[i][:m],
+        fns=lambda eos: make_continuous_decode_fns(
+            model, max_decode_len=eva.MAX_OUT, eos_id=eos,
+            max_input_len=eva.MAX_IN),
+    )
+
+
+@pytest.fixture(scope="module", params=["t5", "evabyte"])
+def kit(request):
+    """One model's decode contract by EOS id, its prompts, and the
+    model's own greedy stream of each prompt (no EOS)."""
+    if request.param == "t5":
+        return _t5_kit(request.getfixturevalue("tiny_t5"))
+    return _evabyte_kit()
+
+
+def _own_stream(kit, i, m, eos):
+    """What the model alone emits for prompt ``i``: greedy, cut after
+    the first EOS."""
+    row = kit.greedy(i, m)
+    return row[: row.index(eos) + 1] if eos in row else row
+
+
+class _Log:
+    """The engine's programs wrapped: every dispatch and every
+    device-to-host read the worker makes, in order.  A prefill's token
+    and a step's tokens come back inside proxies that log when the host
+    converts them (``int(tok0)``, ``np.asarray(nxt)``: the only ways
+    the engine reads), and ``insert`` is handed what the proxy holds."""
+
+    class _Proxy:
+        def __init__(self, log, what, value):
+            self._log, self._what, self.value = log, what, value
+            self._made_at = len(log)    # events before its program's
+
+        def __int__(self):
+            self._log.append(("read", self._what, self._made_at))
+            return int(self.value)
+
+        def __array__(self, *a, **k):
+            self._log.append(("read", self._what, self._made_at))
+            return np.asarray(self.value)
+
+    @staticmethod
+    def is_device_array(x) -> bool:
+        import jax
+
+        return isinstance(x, jax.Array)
+
+    def __init__(self, engine, before_step=None):
+        self.events = events = []
+        self.insert_tok0 = []
+        engine._ensure_arena()          # the programs exist
+        proxy = self._Proxy
+        prefill, window = engine._jit_prefill, engine._jit_prefill_window
+        insert, step_for = engine._jit_insert, engine._step_for
+
+        def logged(name, fn):
+            def call(*a):
+                events.append(("dispatch", name))
+                return fn(*a)
+            return call
+
+        def wrap_prefill(*a):
+            tok = proxy(events, "tok0", None)
+            events.append(("dispatch", "prefill"))
+            cache, enc, tok.value = prefill(*a)
+            return cache, enc, tok
+
+        def wrap_window(*a):
+            tok = proxy(events, "tok0", None)
+            events.append(("dispatch", "prefill_window"))
+            row, tok.value = window(*a)
+            return row, tok
+
+        def wrap_insert(arena, cache, enc, mask, tok0, slot):
+            events.append(("dispatch", "insert"))
+            tok0 = tok0.value if isinstance(tok0, proxy) else tok0
+            self.insert_tok0.append(tok0)
+            return insert(arena, cache, enc, mask, tok0, slot)
+
+        def wrap_step_for(b, kv):
+            fn = step_for(b, kv)
+
+            def run(params, arena):
+                if before_step is not None:
+                    before_step()
+                tok = proxy(events, "nxt", None)
+                events.append(("dispatch", "run"))
+                arena, tok.value = fn(params, arena)
+                return arena, tok
+            return run
+
+        if prefill is not None:
+            engine._jit_prefill = wrap_prefill
+        if window is not None:
+            engine._jit_prefill_window = wrap_window
+        engine._jit_insert = wrap_insert
+        engine._jit_move = logged("move", engine._jit_move)
+        engine._jit_clear = logged("clear", engine._jit_clear)
+        engine._step_for = wrap_step_for
+
+
+def _first_reads(reg):
+    from tpu_pipelines.serving.generative import FIRST_TOKEN_READS
+
+    series = reg.snapshot()[
+        "serving_decode_first_token_reads_total"]["series"]
+    assert set(series) <= {("0", r) for r in FIRST_TOKEN_READS}
+    return {r: series.get(("0", r), 0.0) for r in FIRST_TOKEN_READS}
+
+
+# (prompt, budget) of the first burst: a first token that is EOS (prompt
+# 0's, by the choice of EOS), budgets of 1 and 2, one prompt twice in one
+# round (with a prefix cache: a miss, and a hit on an entry whose token
+# nobody has read yet), and more admissions than one before the first
+# step.  The second burst repeats prompts whose entries now hold a host
+# token: a hit that reads nothing, one of them an EOS known at once.
+_BURST_1 = [(0, 8), (1, 1), (2, 2), (3, 6), (3, 6), (4, 9)]
+_BURST_2 = [(3, 5), (0, 8), (4, 3)]
+
+
+@pytest.mark.parametrize("hard_deadline", [False, True],
+                         ids=["no-deadline", "hard-deadline"])
+@pytest.mark.parametrize("lever", [False, True],
+                         ids=["plain", "cache-or-credits"])
+def test_engine_admits_without_waiting_and_serves_the_models_own_tokens(
+    kit, lever, hard_deadline
+):
+    """Per request, the tokens on the handle are the model's own greedy
+    decode, whatever the first token's read was moved behind; under a
+    hard deadline nobody can meet, what an evicted handle holds is that
+    stream's beginning.  ``lever``: the option of admission that the
+    contract takes, a prefix cache (whole-prompt prefill) or
+    chunked-prefill credits (prefill by window, which takes no cache)."""
+    from tpu_pipelines.observability.metrics import MetricsRegistry
+    from tpu_pipelines.serving.generative import (
+        GenerationEvicted,
+        GenerativeEngine,
+    )
+
+    prefix_entries = 8 if lever and not kit.windowed else 0
+    credits = 1 if lever and kit.windowed else 0
+    eos = kit.greedy(0, 1)[0]           # prompt 0 ends at its first token
+    gate = threading.Event()            # hold the first round back
+    reg = MetricsRegistry()
+    engine = GenerativeEngine(
+        kit.fns(eos), kit.params, max_batch_size=4, page_size=4,
+        prefix_cache_entries=prefix_entries, prefill_chunk_pages=credits,
+        hard_deadline=hard_deadline,
+        slo_ms_per_token=1e-6 if hard_deadline else 0.0,
+        fault_hook=gate.wait, registry=reg,
+    )
+    try:
+        engine.warm()
+        log = _Log(engine)
+        first = [
+            engine.submit_nowait(kit.prompts[i], max_new_tokens=m)
+            for i, m in _BURST_1
+        ]
+        gate.set()
+        for h in first:
+            h._done.wait(120.0)
+        second = [
+            engine.submit_nowait(kit.prompts[i], max_new_tokens=m)
+            for i, m in _BURST_2
+        ]
+        for h in second:
+            h._done.wait(120.0)
+    finally:
+        gate.set()
+        engine.close()
+    assert engine.compiles_after_warm == 0
+    evicted = 0
+    for (i, m), h in zip(_BURST_1 + _BURST_2, first + second):
+        own = _own_stream(kit, i, m, eos)
+        assert h._done.is_set()
+        if h.error is None:
+            assert [int(t) for t in h.result] == own, (i, m)
+        else:
+            assert isinstance(h.error, GenerationEvicted), h.error
+            assert hard_deadline and len(own) > 2
+            # evicted after the one step it rode: the stream's beginning
+            assert h.tokens == own[:2], (i, m)
+            evicted += 1
+        # (d) the first token reached the host on every handle, after
+        # the request was taken off the queue
+        assert h.arrival_s <= h.admitted_s <= h.first_token_s <= h.done_s
+    assert (evicted > 0) == hard_deadline
+    # More admissions than one went ahead of the first step (credits
+    # let one prompt in per step once a row is live).
+    names = [ev[1] for ev in log.events if ev[0] == "dispatch"]
+    assert names[: names.index("run")].count("insert") >= (
+        1 if credits else 2)
+    reads = _first_reads(reg)
+    admitted = reg.snapshot()[
+        "serving_decode_queue_wait_seconds"]["series"][("0",)]["count"]
+    assert sum(reads.values()) == admitted == len(_BURST_1 + _BURST_2)
+    assert reads["blocking"] == 0
+    if prefix_entries:
+        # the same-round repeat is a hit whose token nobody had read
+        assert engine._prefix.hits == 4 and engine._prefix.misses == 5
+        assert reads["known"] == 3
+    else:
+        assert reads["known"] == 0
+
+
+def test_no_read_between_the_dispatches_of_a_round(kit):
+    """The order of a round, with the programs wrapped: its step, then
+    the prefills and inserts it admits, dispatched back to back with no
+    device-to-host read between them; then the reads, each first token's
+    with a step dispatched after its prefill (the thread never waits for
+    a prefill with nothing queued behind it), then the step's own; and
+    ``insert`` is handed the prefill's own device scalar, never a host
+    integer."""
+    from tpu_pipelines.observability.metrics import MetricsRegistry
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    gate = threading.Event()
+    reg = MetricsRegistry()
+    engine = GenerativeEngine(
+        kit.fns(10_000), kit.params, max_batch_size=2, page_size=4,
+        fault_hook=gate.wait, registry=reg,
+    )
+    budgets = [5, 3, 6, 2, 4]
+    try:
+        engine.warm()
+        log = _Log(engine)
+        handles = [
+            engine.submit_nowait(p, max_new_tokens=m)
+            for p, m in zip(kit.prompts, budgets)
+        ]
+        gate.set()
+        outs = [h.wait(120.0) for h in handles]
+    finally:
+        gate.set()
+        engine.close()
+    for i, (m, out) in enumerate(zip(budgets, outs)):
+        assert [int(t) for t in out] == kit.greedy(i, m)
+    # "idle": nothing of a round queued yet; "queued": its step is, and
+    # admissions may follow it; "reading": the round's one wait.
+    state = "idle"
+    reads = {"tok0": 0, "nxt": 0}
+    for at, (kind, name, *made_at) in enumerate(log.events):
+        if kind == "read":
+            assert state in ("queued", "reading"), (at, log.events)
+            state = "reading"
+            reads[name] += 1
+            # its step, or for a first token a step, came after the
+            # program that made it
+            assert ("dispatch", "run") in log.events[made_at[0]:at]
+        elif name == "run":
+            state = "queued"
+        elif name in ("move", "clear"):
+            state = "idle"
+        else:                           # prefill, prefill_window, insert
+            assert state != "reading", (at, log.events)
+    assert reads["tok0"] == len(handles)
+    assert reads["nxt"] == engine.steps_run > 0
+    assert len(log.insert_tok0) == len(handles)
+    assert all(
+        log.is_device_array(t) and t.shape == () and t.dtype == np.int32
+        for t in log.insert_tok0)
+    assert _first_reads(reg) == {
+        "behind_step": len(handles), "known": 0, "blocking": 0}
+    # Under the cache keys warm() left: the one insert program.
+    assert engine.compiles_after_warm == 0
+
+
+def _killed_with_unread_first_tokens(kit, how):
+    """Two requests ride a step; two more are admitted behind the
+    second, and the worker stops at the dispatch of the third, the one
+    their first tokens would have been read behind: ``how`` "dies" (the
+    program raises: a device fault, an injected kill) or "hangs" (it
+    never returns and ``close`` gives up on the thread).  -> handles."""
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    second, go, third, hold = (threading.Event() for _ in range(4))
+    state = {"steps": 0}
+
+    def before_step():
+        state["steps"] += 1
+        if state["steps"] == 2:
+            second.set()
+            go.wait(30.0)               # two more arrive meanwhile
+        elif state["steps"] == 3:
+            third.set()
+            if how == "dies":
+                raise RuntimeError("injected kill")
+            hold.wait(30.0)
+
+    gate = threading.Event()            # both of a pair, or neither
+    engine = GenerativeEngine(
+        kit.fns(10_000), kit.params, max_batch_size=4, page_size=4,
+        fault_hook=gate.wait,
+    )
+    try:
+        engine.warm()
+        _Log(engine, before_step)
+        handles = [
+            engine.submit_nowait(kit.prompts[i], max_new_tokens=8)
+            for i in (0, 1)
+        ]
+        gate.set()
+        assert second.wait(60.0)
+        handles += [
+            engine.submit_nowait(kit.prompts[i], max_new_tokens=8)
+            for i in (2, 3)
+        ]
+        go.set()
+        assert third.wait(60.0)
+        if how == "hangs":
+            engine.close(timeout_s=0.2)
+    finally:
+        for event in (gate, go, hold):
+            event.set()
+        engine.close()
+    return handles
+
+
+@pytest.mark.parametrize("how", ["dies", "hangs"])
+def test_an_unread_first_token_does_not_outlive_its_engine(kit, how):
+    """A dying worker and ``close()`` finish every handle, also one
+    whose first token the host never read; what ``DecodeSessionLost``
+    would carry of it (the fleet builds ``partial_tokens`` from the
+    handles' ``tokens``) is what the host has read: nothing."""
+    from tpu_pipelines.serving.generative import (
+        DecodeSessionLost,
+        GenerationEvicted,
+    )
+
+    handles = _killed_with_unread_first_tokens(kit, how)
+    for h in handles:
+        assert h._done.wait(30.0)
+        if how == "dies":
+            assert "injected kill" in str(h.error)
+        else:
+            assert isinstance(h.error, GenerationEvicted)
+    lost = DecodeSessionLost(
+        handles[0].error,
+        partial_tokens=[[int(t) for t in h.tokens] for h in handles],
+        unfinished=sum(1 for h in handles if h.result is None),
+    )
+    assert lost.unfinished == 4
+    for i, (h, part) in enumerate(zip(handles, lost.partial_tokens)):
+        assert part == kit.greedy(i, len(part))
+    # the first two rode two steps; the last two were seated, unread
+    assert [len(p) for p in lost.partial_tokens] == [3, 3, 0, 0]
+    assert all(h.first_unread for h in handles[2:])
+    assert all(h.first_token_s is None for h in handles[2:])
+    assert all(h.first_token_s is not None for h in handles[:2])
+
+
+def test_first_token_reads_add_up_to_the_admissions():
+    """``serving_decode_first_token_reads_total``: one count per
+    admission, under ``blocking`` only where nothing was there to step
+    (a budget of one token on an idle engine), under ``known`` only
+    where a prefix entry already held the token on the host."""
+    from tpu_pipelines.observability.metrics import MetricsRegistry
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    reg = MetricsRegistry()
+    engine = GenerativeEngine(
+        make_stub_fns(), {}, max_batch_size=2, prefix_cache_entries=4,
+        registry=reg,
+    )
+    a, b = np.asarray([3, 5], np.int32), np.asarray([2, 7, 1], np.int32)
+    try:
+        engine.warm()
+        # alone on an idle engine, ended by its first token: blocking
+        assert [int(t) for t in engine.submit(a, max_new_tokens=1)] == (
+            ref_stream(a, 1))
+        # the entry holds the token now: known, nothing read
+        assert [int(t) for t in engine.submit(a, max_new_tokens=6)] == (
+            ref_stream(a, 6))
+        # a miss that rides a step: behind_step
+        assert [int(t) for t in engine.submit(b, max_new_tokens=6)] == (
+            ref_stream(b, 6))
+        assert [int(t) for t in engine.submit(b, max_new_tokens=1)] == (
+            ref_stream(b, 1))
+    finally:
+        engine.close()
+    reads = _first_reads(reg)
+    assert reads == {"behind_step": 1, "known": 2, "blocking": 1}
+    admitted = reg.snapshot()[
+        "serving_decode_queue_wait_seconds"]["series"][("0",)]["count"]
+    assert sum(reads.values()) == admitted == 4
+    assert reg.snapshot()["serving_decode_ttft_seconds"]["series"][
+        ("0",)]["count"] == 4
+    assert 'serving_decode_first_token_reads_total{' in reg.to_prometheus()
 
 
 def _arena_is_blank(engine, arena) -> bool:

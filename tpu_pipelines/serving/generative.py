@@ -28,6 +28,23 @@ static-shape substrate):
     one row and a step writes its bucket, not a fresh copy of
     everything else; ``self._arena`` is only ever rebound to what a
     program returned.
+  * **One wait a round.**  The worker thread never waits for a prefill
+    with nothing queued behind it.  A round with rows live dispatches,
+    back to back and with no read in between, the step of the rows that
+    are seated and then, behind it, every prefill and insert it admits:
+    the host's part of an admission (the dispatch of a prefill alone is
+    milliseconds) runs while the device steps, and a freed slot stays
+    empty for that one step.  A prefill's first token stays on the
+    device and ``insert`` takes it as the device scalar it is.  Then the
+    round reads: the first tokens of the admissions dispatched AHEAD of
+    this step (behind the step before: long since there), then the
+    step's tokens.  What the host does with a first token (onto the
+    handle, ``first_token_s``, EOS, the prefix entry's host copy) it
+    does at that read; until then a seated row counts as holding one
+    token (``_Sequence.held``).
+    ``serving_decode_first_token_reads_total`` says how each
+    admission's token came: ``behind_step``, ``known`` (a prefix-cache
+    hit) or ``blocking`` (nothing was there to step).
   * **Bucketed steps.**  Each decode step runs one pre-compiled program
     keyed ``(batch_bucket, kv_bucket)``: the batch bucket is the smallest
     power-of-two >= the live count (serving/batching.py's bucket rule),
@@ -101,7 +118,8 @@ with each request's queue wait and time to first token.
 
 Tracing: every phase of the worker thread is also a
 ``jax.profiler.TraceAnnotation`` named ``engine.<phase>`` (plus
-``engine.step.wait``, the step's device-to-host read).  Annotations are
+``engine.prefill.wait`` and ``engine.step.wait``, the device-to-host
+reads of a first token and of a step's tokens).  Annotations are
 recorded only while a profiler session runs, into the same trace and on
 the same clock as the device's operations; the device side of a phase
 is its program's event, under the names in ``PROGRAM_NAMES``
@@ -140,6 +158,10 @@ ENGINE_PHASES = (
     "idle", "admit", "prefill", "insert", "step", "emit", "retire",
     "prefill.window",
 )
+
+# How an admission's first token reaches the host: the ``read`` label of
+# ``serving_decode_first_token_reads_total``.
+FIRST_TOKEN_READS = ("behind_step", "known", "blocking")
 
 # The engine's device programs as a profile's "XLA Modules" line names
 # them: ``jit_`` + the ``__name__`` of the function handed to jax.jit.
@@ -254,8 +276,9 @@ class DecodeSessionLost(RuntimeError):
 @dataclass
 class _Sequence:
     """Host-side bookkeeping for one generation (the engine's unit of
-    scheduling).  ``tokens`` mirrors the device state: its length IS the
-    sequence's next decode position.
+    scheduling).  ``tokens`` mirrors the device state: ``held``, its
+    length plus the first token while only the device has it
+    (``first_unread``), IS the sequence's next decode position.
 
     The request's timeline rides on the handle: ``arrival_s`` (submit),
     ``admitted_s`` (the ``_admit`` turn that took it off the queue),
@@ -293,6 +316,16 @@ class _Sequence:
     # the prompt's length under a decoder-only contract (set where the
     # engine keeps the account of what steps read, ``step_account``).
     first_pos: int = 1
+    # Admitted, its prefill dispatched, its first token not yet read to
+    # the host: the token is in the arena (``insert`` took it as the
+    # device scalar it was), not in ``tokens``.
+    first_unread: bool = False
+
+    @property
+    def held(self) -> int:
+        """Tokens this sequence holds, the unread first one included:
+        what scheduling counts (bucket, pages, tokens owed)."""
+        return len(self.tokens) + self.first_unread
 
     def finish(self, error: Optional[BaseException] = None) -> None:
         if self._done.is_set():
@@ -377,14 +410,20 @@ class _PrefixEntry:
     reports and admission credits are charged in."""
 
     __slots__ = (
-        "key", "pages", "readers", "tok0", "cache", "encoded", "tick",
+        "key", "pages", "readers", "tok0", "tok0_host", "cache", "encoded",
+        "tick",
     )
 
     def __init__(self, key, pages, tok0, cache, encoded):
         self.key = key
         self.pages = int(pages)
         self.readers = 0
-        self.tok0 = int(tok0)
+        # The first token as the prefill program returned it, which is
+        # what ``insert`` is handed on a hit as on a miss; and on the
+        # host once the miss that made the entry has read it (a hit
+        # after that reads nothing).
+        self.tok0 = tok0
+        self.tok0_host: Optional[int] = None
         self.cache = cache
         self.encoded = encoded
         self.tick = 0
@@ -609,6 +648,10 @@ class GenerativeEngine:
         # (sequence, windows done): the queue's head while its windows
         # run; it leaves the queue with its last one.
         self._partial: Optional[Tuple[_Sequence, int]] = None
+        # (sequence, first token on the device, prefix entry or None) of
+        # the admissions whose first token the host has not read yet, in
+        # the order of their prefills (worker thread only).
+        self._unread: "collections.deque[tuple]" = collections.deque()
 
         self._worker = threading.Thread(target=self._run, daemon=True)
         self._worker.start()
@@ -831,12 +874,13 @@ class GenerativeEngine:
                 cache1, encoded1, tok0 = self._jit_prefill(
                     self.params, zin, zmask
                 )
-            # tok0 goes to insert as a HOST int32: the prefix-cache hit
-            # path has only the entry's host token, and warm/miss/hit
-            # must all land on the same insert program cache key.
+            # tok0 goes to insert as the DEVICE scalar the prefill
+            # returned, never read: a miss hands it on so, a prefix-cache
+            # hit hands on the entry's (``_PrefixEntry.tok0``), and the
+            # program cache keys on an argument's placement, so a host
+            # int32 here would leave traffic's first insert to compile.
             self._arena = self._jit_insert(
-                self._arena, cache1, encoded1, zmask, np.int32(int(tok0)),
-                slot,
+                self._arena, cache1, encoded1, zmask, tok0, slot,
             )
             self._arena = self._jit_clear(
                 self._jit_move(self._arena, slot, slot), slot
@@ -863,20 +907,20 @@ class GenerativeEngine:
         every queued sequence's full budget — the admission-control and
         routing unit."""
         with self._lock:
-            live = sum(
-                max(0, s.max_new_tokens - len(s.tokens))
-                for s in self._slots[: self._n_live] if s is not None
-            )
-            queued = sum(s.max_new_tokens for s in self._queue)
-        return live + queued
+            return self.outstanding_tokens_locked()
 
     def active_sequences(self) -> int:
         with self._lock:
             return self._n_live + len(self._queue)
 
     def idle(self) -> bool:
+        # An unread first token is an unfinished request even where it
+        # took no slot (a budget of one token): not idle, not to be
+        # closed under it.
         with self._lock:
-            return self._n_live == 0 and not self._queue
+            return (
+                self._n_live == 0 and not self._queue and not self._unread
+            )
 
     def submit_nowait(
         self,
@@ -938,7 +982,7 @@ class GenerativeEngine:
     def outstanding_tokens_locked(self) -> int:
         # Caller holds self._lock (the condition's underlying lock).
         live = sum(
-            max(0, s.max_new_tokens - len(s.tokens))
+            max(0, s.max_new_tokens - s.held)
             for s in self._slots[: self._n_live] if s is not None
         )
         return live + sum(s.max_new_tokens for s in self._queue)
@@ -971,11 +1015,7 @@ class GenerativeEngine:
             self._cond.notify_all()
         self._worker.join(timeout=timeout_s)
         with self._lock:
-            pending = list(self._queue) + [
-                s for s in self._slots[: self._n_live] if s is not None
-            ]
-            self._queue.clear()
-            self._n_live = 0
+            pending = self._take_unfinished()
             self._slots = [None] * self.max_batch_size
         for seq in pending:
             self._release_prefix(seq)
@@ -1020,6 +1060,7 @@ class GenerativeEngine:
                         not self._closed
                         and not self._queue
                         and self._n_live == 0
+                        and not self._unread
                     ):
                         with self._phase("idle"):
                             self._cond.wait()
@@ -1027,8 +1068,9 @@ class GenerativeEngine:
                         return
                 if self._fault_hook is not None:
                     self._fault_hook()
-                self._admit()
                 if self._n_live:
+                    # The step first, for the rows that are seated:
+                    # admission's host work goes behind it.
                     self._step_once()
                     if self.prefill_chunk_pages > 0:
                         # Each decode step EARNS admission credits
@@ -1043,6 +1085,17 @@ class GenerativeEngine:
                             cap,
                             self._admit_credits + self.prefill_chunk_pages,
                         )
+                    continue
+                # No row to step yet: admit; the rows that are seated
+                # ride the next round's step, and their first tokens are
+                # read behind it.
+                self._admit()
+                if not self._n_live and self._unread:
+                    # Still nothing to step: what was admitted (now, or
+                    # behind the last step) ends at its first token,
+                    # and its read has nothing to go behind.
+                    with self._phase("emit", live=0), self._dev():
+                        self._read_first_tokens("blocking")
         except Exception as e:  # noqa: BLE001 — device fault: fail loudly
             log.exception("generative engine worker died")
             with self._lock:
@@ -1051,15 +1104,30 @@ class GenerativeEngine:
                 # it (a donated argument is gone whether or not its
                 # program ran to the end): nothing may use it again.
                 self._arena = None
-                pending = list(self._queue) + [
-                    s for s in self._slots[: self._n_live] if s is not None
-                ]
-                self._queue.clear()
-                self._n_live = 0
+                pending = self._take_unfinished()
             for seq in pending:
                 self._release_prefix(seq)
                 self._trace_end(seq, "error")
                 seq.finish(e)
+
+    def _take_unfinished(self) -> List[_Sequence]:
+        """Every sequence the engine still owes an end, taken off the
+        queue, out of the slots and off the list of unread first tokens
+        (caller holds ``self._lock``).  A first token that was never
+        read stays on the device: the handle's ``tokens`` are what the
+        host has seen, which is what a recovery re-prefills from."""
+        pending = list(self._queue) + [
+            s for s in self._slots[: self._n_live] if s is not None
+        ]
+        # A request with a budget of one token never took a slot: its
+        # first token unread, it is on that list alone.
+        pending += [
+            seq for seq, _, _ in self._unread if seq.max_new_tokens <= 1
+        ]
+        self._queue.clear()
+        self._unread.clear()
+        self._n_live = 0
+        return pending
 
     def _prompt_pages(self, seq: _Sequence) -> int:
         n_valid = int((seq.input_mask > 0).sum())
@@ -1071,7 +1139,15 @@ class GenerativeEngine:
         drain.  One prefill (encoder + step-0 decode, the greedy math)
         per admitted sequence — or an arena scatter alone when the
         prefix cache already holds this prompt — metered by chunked-
-        prefill credits when live sequences could starve."""
+        prefill credits when live sequences could starve.
+
+        Admission only DISPATCHES, and while rows are live it does so
+        behind a step that is already queued (``_step_once`` calls it),
+        so the device works through the host's part of it.  A prefill's
+        first token goes to ``insert`` as the device scalar it is and
+        onto ``self._unread``; the host reads it once the NEXT step is
+        queued behind it, so the device always has work queued behind
+        what the thread waits for."""
         # The unlocked look at the queue only keeps a round with nothing
         # to admit from counting as an ``admit`` turn; ``_admit_one``
         # decides under the lock.
@@ -1123,25 +1199,24 @@ class GenerativeEngine:
                 self._prefix.hits += 1
                 self._prefix.touch(entry)
                 self.telemetry.on_prefix_hit(entry.pages)
-                cache1, enc1 = entry.cache, entry.encoded
-                t0 = entry.tok0
+                cache1, enc1, tok0 = entry.cache, entry.encoded, entry.tok0
             else:
                 with self._phase(
                     "prefill", seq=seq.seq_id,
                     prompt_tokens=int((seq.input_mask > 0).sum()),
                 ):
+                    # Dispatched, not waited for: the token is read once
+                    # the round's step is queued behind it.
                     cache1, enc1, tok0 = self._jit_prefill(
                         self.params, seq.inputs[None], seq.input_mask[None]
                     )
-                    # The device-to-host read the admission blocks on:
-                    # everything queued ahead of this prefill on the
-                    # device (arena scatters, a step) is waited for here.
-                    t0 = int(tok0)
                 if self._prefix is not None:
                     self._prefix.misses += 1
                     self.telemetry.on_prefix_miss()
-                    entry = self._prefix.insert(key, pages, t0, cache1, enc1)
-            return self._seat(seq, cache1, enc1, t0, entry)
+                    entry = self._prefix.insert(
+                        key, pages, tok0, cache1, enc1
+                    )
+            return self._seat(seq, cache1, enc1, tok0, entry)
 
     def _admit_window(self, span) -> bool:
         """One turn of admission under a contract that is prefilled by
@@ -1182,44 +1257,44 @@ class GenerativeEngine:
                 if (index + 1) * W < n_prompt:
                     self._partial = (seq, index + 1)
                     return True
-                # The last window: the one device-to-host read of this
-                # prompt's prefill.
-                t0 = int(tok0)
+            # The last window: its token is the prompt's first new one.
             self._partial = None
             with self._lock:
                 if not self._queue or self._queue[0] is not seq:
                     return False      # ``close`` took the queue meanwhile
                 self._queue.popleft()
             return self._seat(
-                seq, self._row_cache, self._no_encoded, t0, None
+                seq, self._row_cache, self._no_encoded, tok0, None
             )
 
-    def _seat(self, seq, cache1, enc1, t0, entry) -> bool:
-        """A prefilled sequence's first token, and its slot in the arena
-        unless that token already ended it (under ``self._dev()``)."""
-        seq.first_token_s = time.monotonic()
-        self.telemetry.on_first_token(
-            seq.first_token_s - seq.arrival_s
-        )
-        seq.tokens.append(t0)
-        if t0 == self.eos_id or seq.max_new_tokens <= 1:
-            if self._prefix is not None:
-                self.telemetry.on_prefix_pages(
-                    self._prefix.pages_in_use()
-                )
-            self._complete(seq)
-            return True
+    def _seat(self, seq, cache1, enc1, tok0, entry) -> bool:
+        """A prefilled sequence's slot in the arena (under
+        ``self._dev()``).  ``tok0`` is its first token as the prefill
+        program returned it, on the device; the host knows its value
+        only where a prefix-cache entry has kept it, and otherwise
+        reads it later (``_read_first_tokens``).  A sequence that the
+        host already knows to end at its first token takes no slot: a
+        known EOS, or a budget of one token."""
+        if self._prefix is not None:
+            self.telemetry.on_prefix_pages(self._prefix.pages_in_use())
+        if entry is not None and entry.tok0_host is not None:
+            self._first_token(seq, entry.tok0_host, "known")
+            if self._ended(seq):
+                self._complete(seq)
+                return True
+        else:
+            seq.first_unread = True
+            self._unread.append((seq, tok0, entry))
+            if seq.max_new_tokens <= 1:
+                return True             # ends where its token is read
         if entry is not None:
             self._prefix.acquire(entry)
             seq.prefix_entry = entry
-            self.telemetry.on_prefix_pages(
-                self._prefix.pages_in_use()
-            )
         slot = self._n_live
         with self._phase("insert", seq=seq.seq_id, slot=slot):
             self._arena = self._jit_insert(
                 self._arena, cache1, enc1, seq.input_mask[None],
-                np.int32(t0), np.int32(slot),
+                tok0, np.int32(slot),
             )
         if seq.ctx is not None:
             # Slot event: the sequence joined the continuous batch —
@@ -1235,14 +1310,61 @@ class GenerativeEngine:
             self._n_live += 1
         return True
 
+    def _ended(self, seq: _Sequence) -> bool:
+        """Whether the last token the host has of ``seq`` ended it."""
+        return (
+            seq.tokens[-1] == self.eos_id
+            or len(seq.tokens) >= seq.max_new_tokens
+        )
+
+    def _first_token(self, seq: _Sequence, t0: int, read: str) -> None:
+        """``seq``'s first token has reached the host (``read``: how, the
+        label of ``serving_decode_first_token_reads_total``)."""
+        seq.first_unread = False
+        seq.first_token_s = time.monotonic()
+        self.telemetry.on_first_token(seq.first_token_s - seq.arrival_s)
+        self.telemetry.on_first_token_read(read)
+        seq.tokens.append(t0)
+
+    def _read_first_tokens(
+        self, read: str, count: Optional[int] = None
+    ) -> None:
+        """Read to the host the oldest ``count`` (all by default) of the
+        first tokens still on the device alone, in the order of their
+        prefills (``read``: ``behind_step`` with a step queued behind
+        them, ``blocking`` with nothing).  Each read returns when its
+        prefill has run.  A sequence that took no slot (a budget of one
+        token) ends here; one whose first token is EOS has a row, which
+        the step's ``emit`` retires."""
+        from jax.profiler import TraceAnnotation
+
+        for _ in range(len(self._unread) if count is None else count):
+            seq, tok0, entry = self._unread[0]
+            with TraceAnnotation("engine.prefill.wait", seq=seq.seq_id):
+                t0 = int(tok0)
+            self._unread.popleft()
+            if entry is not None:
+                entry.tok0_host = t0
+            self._first_token(seq, t0, read)
+            if seq.max_new_tokens <= 1:
+                self._complete(seq)
+
     def _step_once(self) -> None:
+        """One round with rows live: dispatch their step, admit behind
+        it, read, emit.  The thread's one wait comes with the step
+        queued: for the first tokens of the prefills dispatched AHEAD of
+        this step (admitted behind the step before, or with nothing
+        live), then for the step's own.  What is admitted behind this
+        step rides the next one, so a freed slot stays empty for one
+        step, and the host's part of an admission (the dispatch of a
+        prefill alone is milliseconds) costs the device nothing."""
         from jax.profiler import TraceAnnotation
 
         n = self._n_live
         with self._phase("step") as span:
             b = next(bk for bk in self.batch_buckets if bk >= n)
             deepest = max(
-                len(s.tokens) for s in self._slots[:n] if s is not None
+                s.held for s in self._slots[:n] if s is not None
             )
             kv = next(k for k in self.kv_buckets if k >= deepest + 1)
             span.set_metadata(live=n, b=b, kv=kv)
@@ -1250,8 +1372,13 @@ class GenerativeEngine:
             t0 = time.perf_counter()
             with self._dev():
                 self._arena, nxt = fn(self.params, self._arena)
+                ahead = len(self._unread)
+                self._admit()
+                # The round's one place to wait: first the tokens of the
+                # prefills ahead of the step (each is there when its
+                # prefill is), then the step's own.
+                self._read_first_tokens("behind_step", ahead)
                 with TraceAnnotation("engine.step.wait"):
-                    # the one device->host sync per step
                     toks = np.asarray(nxt)
             dt = time.perf_counter() - t0
             if self.step_ewma_s is None:
@@ -1261,7 +1388,7 @@ class GenerativeEngine:
                 self.step_ewma_s = (1 - a) * self.step_ewma_s + a * dt
             self.steps_run += 1
             pages = sum(
-                -(-(len(s.tokens) + 1) // self._page)
+                -(-(s.held + 1) // self._page)
                 for s in self._slots[:n] if s is not None
             )
             self.telemetry.on_step(
@@ -1271,13 +1398,19 @@ class GenerativeEngine:
                 # What this step read of each kind of cache, by the
                 # contract's own account of the rows' positions.
                 self.telemetry.on_cache(self._account([
-                    s.first_pos + len(s.tokens) - 1
+                    s.first_pos + s.held - 1
                     for s in self._slots[:n] if s is not None
                 ]))
         with self._phase("emit", live=n):
             now = time.monotonic()
             for slot in range(n - 1, -1, -1):
                 seq = self._slots[slot]
+                if self._ended(seq):
+                    # Its first token, read behind this step, was EOS:
+                    # what the step computed for the row is dropped and
+                    # the row retired.
+                    self._settle(slot, seq, now)
+                    continue
                 seq.tokens.append(int(toks[slot]))
                 self.telemetry.on_token()
                 if seq.ctx is not None:
@@ -1296,12 +1429,11 @@ class GenerativeEngine:
         """After a step's token is appended: retire and complete a
         sequence that hit EOS or its budget, evict one past its hard
         deadline, leave the rest in their slots."""
-        t = seq.tokens[-1]
         # Retire the slot BEFORE waking the waiter: the client thread
         # resumes to consistent accounting (outstanding_tokens of a
         # finished sequence is already 0, its slot already free).
-        if t == self.eos_id or len(seq.tokens) >= seq.max_new_tokens:
-            if seq.ctx is not None and t == self.eos_id:
+        if self._ended(seq):
+            if seq.ctx is not None and seq.tokens[-1] == self.eos_id:
                 seq.ctx.instant(
                     "decode.eos", slot=slot, tokens=len(seq.tokens)
                 )
@@ -1395,7 +1527,7 @@ class DecodeTelemetry:
         self._prefix_hits = self._prefix_misses = None
         self._prefix_hit_pages = self._prefix_pages = None
         self._phase_s = self._phase_n = None
-        self._queue_wait = self._ttft = None
+        self._queue_wait = self._ttft = self._first_reads = None
         self._prefill_tokens = self._prefill_windows = None
         self._rollovers = self._summaries = None
         self._cache_bytes = self._cache_read = None
@@ -1551,6 +1683,22 @@ class DecodeTelemetry:
             "(fine sqrt(2) buckets; _sum and _count exact).",
             labels=lab, buckets=fine_latency_buckets(),
         ).labels(self.replica)
+
+        reads = registry.counter(
+            "serving_decode_first_token_reads_total",
+            "How each admission's first token reached the host: "
+            "behind_step (read with the round's step queued behind its "
+            "prefill), known (a prefix-cache hit: nothing read), "
+            "blocking (read with nothing queued behind it).",
+            labels=("replica", "read"),
+        )
+        self._first_reads = {
+            r: reads.labels(self.replica, r) for r in FIRST_TOKEN_READS
+        }
+
+    def on_first_token_read(self, read: str) -> None:
+        if self._first_reads is not None:
+            self._first_reads[read].inc()
 
     def on_prefill_window(self, n_tokens: int) -> None:
         if self._prefill_windows is not None:
