@@ -498,3 +498,80 @@ def test_decode_kv_write_keeps_the_cache_where_it_lies_on_v5e(
     arena_lies = {lies(param_layouts[i]) for i in leaves}
     assert len(arena_lies) == 1
     assert set(re.findall(bucket + r"\{([\d,]+)", text)) == arena_lies
+
+
+@pytest.mark.parametrize("tokens", [1, 128, 256])
+def test_grouped_expert_products_compile_for_v5e(one_chip, tokens):
+    """The Pallas grouped product of models/pangu_moe.py at the published
+    widths (16 experts held, 7680 -> 2048 -> 7680, 8 choices a token) with
+    the tiles the model hands it: a tiling the chip's compiler refuses, or
+    one that wants more fast memory than a kernel may use, shows here.  On
+    the CPU backend the model takes XLA's grouped product, so the kernel
+    is compiled by its own name."""
+    from tpu_pipelines.models import pangu_moe as pm
+
+    m = -(-tokens * 8 // pm.ROW_TILE) * pm.ROW_TILE
+    sizes = _sds((16,), jnp.int32, one_chip)
+
+    def both(rows, w_in, hidden, w_out, sizes):
+        return (pm.megablox(rows, w_in, sizes, pm.TILE_IN),
+                pm.megablox(hidden, w_out, sizes, pm.TILE_OUT))
+
+    compiled = jax.jit(both).lower(
+        _sds((m, 7680), jnp.bfloat16, one_chip),
+        _sds((16, 7680, 2048), jnp.bfloat16, one_chip),
+        _sds((m, 2048), jnp.bfloat16, one_chip),
+        _sds((16, 2048, 7680), jnp.bfloat16, one_chip), sizes,
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 ** 2
+
+
+def test_latent_row_write_keeps_the_cache_where_it_lies_on_v5e(one_chip):
+    """The engine's own step program for the latent-cache contract
+    (models/pangu_moe.py) at the published widths, two layers (one dense,
+    one with its 16 experts), the whole 128 x 2,560 arena handed over in
+    place and donated: the chip keeps ``bf16[128, 2560, 576]``
+    position-minor, and the 128 row-wise ``dynamic_update_slice`` of a
+    step leave it so.  ``.at[rows, pos].set`` compiled to two copies of
+    the whole array a layer (PERF.md §6, PR 31)."""
+    from types import SimpleNamespace
+
+    from tpu_pipelines.models import pangu_moe as pm
+    from tpu_pipelines.serving import generative as gen
+
+    rows, positions = 128, 2560
+    model = pm.build_pangu_moe_model(dict(
+        vocab_size=19200, n_layers=2, n_dense_layers=1, experts_held=16,
+        n_mtp=0))
+    fns = pm.make_continuous_decode_fns(
+        model, max_decode_len=1536, eos_id=19200, max_input_len=1024)
+    assert fns.cache_positions == positions
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), {"inputs": jnp.zeros((1, 8), jnp.int32)})["params"])
+    state = (
+        jax.eval_shape(lambda: fns.blank_cache(rows)),
+        jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
+        jnp.zeros((rows,), bool), jnp.zeros((rows, 0), jnp.float32),
+        jnp.zeros((rows, 1024), jnp.int32),
+    )
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+    program = gen.GenerativeEngine._build_step(
+        SimpleNamespace(pad_id=0), rows, positions, fns)
+    compiled = program.lower(on_chip(params), on_chip(state)).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    leaf = f"bf16[{rows},{positions},576]"
+    param_layouts, result_layouts, aliases = _entry_layouts(text)
+    leaves = [i for i, s in enumerate(param_layouts) if s.startswith(leaf)]
+    assert len(leaves) == 2
+    result_of = {param: out for out, param in aliases.items()}
+    for i in leaves:
+        assert result_layouts[result_of[i]] == param_layouts[i]
+    moved = re.findall(
+        rf"= {re.escape(leaf)}\S* (?:copy|transpose|scatter)\(.*", text)
+    assert not moved, (len(moved), moved[:2])
+    # the step's tally rides behind its tokens: one result of 128 + 16
+    assert f"s32[{rows + 16}]" in text

@@ -8,6 +8,9 @@ Present:
   - t5: T5-small encoder-decoder seq2seq (config 4)
   - evabyte: byte-level decoder-only LM on EVA chunked linear attention
     (served through serving/generative.py; window ring + chunk table)
+  - pangu_moe: decoder-only LM on latent attention and top-k routed experts
+    with a shared one, as one chip's share of the experts (served through
+    serving/generative.py; a latent cache by position)
   - transformer: shared sharded blocks (TP over 'model', ring-attention SP
     over 'seq') used by bert/t5
 

@@ -1,0 +1,572 @@
+"""openPangu-Ultra-MoE: a decoder-only language model on latent attention
+and routed experts, as one chip's share of an expert-parallel deployment.
+
+Residual blocks without biases and with SANDWICH norms (an RMSNorm before
+and after each sub-layer, four gains a block):
+
+    h <- h + RMSNorm(Attn(RMSNorm(h; g1)); g2)
+    h <- h + RMSNorm(FFN(RMSNorm(h; g3)); g4)
+
+The residual stream and the logits are float32, the matrix products
+bfloat16 with float32 accumulation, attention scores, their softmax and
+the router float32.
+
+Latent attention keeps ONE row of ``kv_lora_rank + qk_rope_head_dim``
+numbers per position and layer, whatever the head count: the normed
+latent ``c`` that every head's keys and values are expanded from, and one
+rotary key ``k_r`` that all heads share.  Two paths compute the one
+function (tests/test_pangu_moe.py holds them equal):
+
+  * ``expanded`` (prefill, the whole-sequence pass): keys and values are
+    expanded per head from the latents of the positions attended, and
+    attention is the ordinary kind with key width ``nope + rope`` and
+    value width ``v``;
+  * ``absorbed`` (the decode step): ``W_uk`` goes into the query and
+    ``W_uv`` into the output, and the row attends over the latents as
+    they lie in the cache; expanding them would cost ``heads * (nope + v)
+    * kv_lora_rank`` multiply-adds a cached position at every step.
+
+The routed-expert layer is told which experts it holds
+(``experts_held`` from ``expert_offset``).  It scores all ``n_experts``
+with a sigmoid, takes the ``experts_per_token`` largest over ALL of them,
+normalises their weights to ``routed_scaling_factor``, and computes the
+shared expert plus the part of the sum that its own experts give; what
+the absent experts would add is the other chips' to compute.  No token is
+dropped and no capacity is set: the assignments to held experts are
+sorted by expert and go through a grouped product (``grouped_product``:
+the Pallas ``megablox`` kernel on the chip), so the cost follows the
+assignments.
+
+Entry points: ``prefill_window`` (one window of a prompt against a row's
+cache), ``decode_step`` (one token per live row, each at its own
+position), ``__call__`` (a whole sequence, no cache: what the tests
+compare with the plain reference) and, with ``n_mtp``, the multi-token
+prediction module on top of it.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from types import SimpleNamespace
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from tpu_pipelines.models.evabyte import NEG_INF, GatedMlp, rope
+
+
+@dataclasses.dataclass(frozen=True)
+class PanguConfig:
+    """The widths and counts of one model, as every module reads them.
+    The defaults are openPangu-Ultra-MoE-718B as published, with every
+    expert held."""
+
+    vocab_size: int = 153600
+    d_model: int = 7680
+    n_layers: int = 61
+    n_dense_layers: int = 3
+    n_heads: int = 128
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 128
+    d_ff: int = 18432
+    d_expert: int = 2048
+    n_experts: int = 256
+    experts_held: int = 256
+    expert_offset: int = 0
+    experts_per_token: int = 8
+    n_shared_experts: int = 1
+    routed_scaling_factor: float = 2.5
+    rope_theta: float = 25600000.0
+    rms_norm_eps: float = 1e-5
+    n_mtp: int = 1
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    @property
+    def row_width(self) -> int:
+        """Numbers a cached position holds in one layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+
+# Rows a tile of the grouped product holds.  An expert here sees a few
+# rows a step (4 of 128 at 8 choices in 256 experts), and a tile is the
+# least it costs: as low as the bfloat16 layout allows with room to spare.
+ROW_TILE = 32
+# Weight tiles (rows of the contraction, columns) of the two products into
+# the expert width and of the one out of it, (tk, tn): 3 MB each, so that a
+# tile's read, not the grid's turn-over, is what a step of the kernel costs.
+TILE_IN, TILE_OUT = (768, 2048), (2048, 768)
+
+
+def grouped_product(rows, weights, sizes, tile):
+    """``rows [m, k]`` sorted by group, ``weights [groups, k, n]``,
+    ``sizes [groups]`` -> ``[m, n]`` float32: rows ``[sum(sizes[:g]),
+    sum(sizes[:g + 1]))`` times ``weights[g]``; a row behind the last
+    group holds nothing that may be used.  ``m`` is a multiple of
+    ``ROW_TILE``.  On the chip the Pallas ``megablox`` kernel, which
+    visits only the row tiles that a group touches; elsewhere XLA's own
+    grouped product (tests/test_pangu_moe.py holds the two equal)."""
+    if jax.default_backend() != "tpu":
+        return jax.lax.ragged_dot(
+            rows, weights, sizes, preferred_element_type=jnp.float32)
+    return megablox(rows, weights, sizes, tile)
+
+
+def megablox(rows, weights, sizes, tile, interpret: bool = False):
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    k, n = weights.shape[1:]
+    return gmm(
+        rows, weights, sizes, preferred_element_type=jnp.float32,
+        tiling=(ROW_TILE, min(tile[0], k), min(tile[1], n)),
+        interpret=interpret)
+
+
+class RMSNorm(nn.Module):
+    """``x / sqrt(mean(x^2) + eps) * g`` in float32."""
+
+    eps: float
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        g = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                       self.param_dtype)
+        x = x.astype(jnp.float32)
+        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(ms + self.eps) * g.astype(jnp.float32)
+
+
+class LatentAttention(nn.Module):
+    cfg: PanguConfig
+
+    def setup(self):
+        c = self.cfg
+        dense = lambda n, name: nn.Dense(
+            n, use_bias=False, dtype=c.dtype, param_dtype=c.param_dtype,
+            name=name)
+        norm = lambda name: RMSNorm(c.rms_norm_eps, c.param_dtype, name=name)
+        h, r = c.n_heads, c.kv_lora_rank
+        init = nn.initializers.lecun_normal()
+        self.q_down = dense(c.q_lora_rank, "q_down")
+        self.q_norm = norm("q_norm")
+        self.q_up = self.param(
+            "q_up", init,
+            (c.q_lora_rank, h, c.qk_nope_head_dim + c.qk_rope_head_dim),
+            c.param_dtype)
+        self.kv_down = dense(c.row_width, "kv_down")
+        self.kv_norm = norm("kv_norm")
+        # W_ukv by its two halves: a head's keys and its values.
+        self.k_up = self.param(
+            "k_up", init, (r, h, c.qk_nope_head_dim), c.param_dtype)
+        self.v_up = self.param(
+            "v_up", init, (r, h, c.v_head_dim), c.param_dtype)
+        self.o_proj = dense(c.d_model, "o_proj")
+        self.scale = (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+
+    def project(self, x, pos):
+        """x [b, l, d_model], pos [b, l] -> the queries' two parts
+        ``q_nope [b, l, h, nope]`` and ``q_rope [b, l, h, rope]`` (rotated)
+        and the cache rows ``[b, l, kv_lora_rank + rope]``: the normed
+        latent, then the one rotary key."""
+        c = self.cfg
+        x = x.astype(c.dtype)
+        c_q = self.q_norm(self.q_down(x)).astype(c.dtype)
+        q = jnp.einsum("blr,rhd->blhd", c_q, self.q_up.astype(c.dtype))
+        q_rope = rope(q[..., c.qk_nope_head_dim:], pos, c.rope_theta)
+        down = self.kv_down(x)
+        latent = self.kv_norm(down[..., :c.kv_lora_rank])
+        k_r = rope(
+            down[..., None, c.kv_lora_rank:], pos, c.rope_theta)[:, :, 0]
+        rows = jnp.concatenate([latent, k_r], -1).astype(c.dtype)
+        return q[..., :c.qk_nope_head_dim], q_rope.astype(c.dtype), rows
+
+    def expanded(self, q_nope, q_rope, rows, ok):
+        """Ordinary attention over keys and values expanded from
+        ``rows [b, lk, r + rope]``; ``ok [b, lq, lk]``.
+        -> [b, lq, h * v]."""
+        with jax.named_scope("mla.attend"):
+            dtype, r = self.cfg.dtype, self.cfg.kv_lora_rank
+            f32 = dict(preferred_element_type=jnp.float32)
+            latent, k_r = rows[..., :r], rows[..., r:]
+            k_nope = jnp.einsum(
+                "bkr,rhd->bkhd", latent, self.k_up.astype(dtype))
+            v = jnp.einsum("bkr,rhd->bkhd", latent, self.v_up.astype(dtype))
+            score = (
+                jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope, **f32)
+                + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_r, **f32)
+            ) * self.scale
+            p = jax.nn.softmax(jnp.where(ok[:, None], score, NEG_INF), -1)
+            out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(dtype), v)
+            return out.reshape(out.shape[:2] + (-1,))
+
+    def absorbed(self, q_nope, q_rope, rows, ok):
+        """One query a row over the latents themselves.  q_nope
+        [b, h, nope], q_rope [b, h, rope], rows [b, lk, r + rope], ok
+        [b, lk] -> [b, h * v]."""
+        with jax.named_scope("mla.attend"):
+            dtype, r = self.cfg.dtype, self.cfg.kv_lora_rank
+            f32 = dict(preferred_element_type=jnp.float32)
+            q_lat = jnp.einsum(
+                "bhd,rhd->bhr", q_nope, self.k_up.astype(dtype))
+            q = jnp.concatenate([q_lat, q_rope], -1)
+            score = jnp.einsum("bhr,bkr->bhk", q, rows, **f32) * self.scale
+            p = jax.nn.softmax(jnp.where(ok[:, None], score, NEG_INF), -1)
+            # Over the whole row, the rotary key's columns with it: a
+            # slice of the cache would be a copy of it.
+            o_lat = jnp.einsum("bhk,bkr->bhr", p.astype(dtype), rows)
+            out = jnp.einsum(
+                "bhr,rhd->bhd", o_lat[..., :r], self.v_up.astype(dtype))
+            return out.reshape(out.shape[0], -1)
+
+    def full(self, x, pos):
+        """A whole sequence under a causal mask, no cache."""
+        q_nope, q_rope, rows = self.project(x, pos)
+        ok = pos[:, :, None] >= pos[:, None, :]
+        return self.o_proj(self.expanded(q_nope, q_rope, rows, ok))
+
+    def window(self, x, index, cache, span: int):
+        """One window of one row.  x [1, W, d_model]; ``cache
+        [1, positions, r + rope]`` gets the window's rows at
+        ``[index * W, index * W + W)``, and the window attends over the
+        first ``span`` positions (every prompt's windows lie inside), up
+        to each query's own.  What lies past the prompt's end is masked
+        or rewritten by the decode steps that follow."""
+        w = x.shape[1]
+        pos = index * w + jnp.arange(w)[None]
+        q_nope, q_rope, rows = self.project(x, pos)
+        cache = jax.lax.dynamic_update_slice_in_dim(
+            cache, rows, index * w, axis=1)
+        ok = pos[:, :, None] >= jnp.arange(span)[None, None, :]
+        out = self.expanded(q_nope, q_rope, cache[:, :span], ok)
+        return self.o_proj(out), cache
+
+    def step(self, x, pos, cache, klen: int):
+        """One token per row.  x [b, d_model], pos [b]; ``cache
+        [slots, positions, r + rope]`` with ``slots >= b``: rows
+        ``[0, b)`` are written at their own positions where they lie and
+        attend over their first ``klen`` positions."""
+        b = x.shape[0]
+        q_nope, q_rope, rows = self.project(x[:, None], pos[:, None])
+        for r in range(b):
+            cache = jax.lax.dynamic_update_slice(
+                cache, rows[r][None], (r, pos[r], 0))
+        ok = jnp.arange(klen)[None, :] <= pos[:, None]
+        out = self.absorbed(
+            q_nope[:, 0], q_rope[:, 0], cache[:b, :klen], ok)
+        return self.o_proj(out), cache
+
+
+class RoutedExperts(nn.Module):
+    """The shared expert and this chip's share of the routed ones.
+    x [n, d_model] -> (y [n, d_model], picked [n, experts_held]): the
+    layer's partial output, and which held experts each token chose."""
+
+    cfg: PanguConfig
+
+    def setup(self):
+        c = self.cfg
+        init = nn.initializers.variance_scaling(
+            1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=0)
+        e, d, f = c.experts_held, c.d_model, c.d_expert
+        self.router = self.param(
+            "router", nn.initializers.lecun_normal(), (d, c.n_experts),
+            c.param_dtype)
+        self.shared = GatedMlp(
+            d, c.n_shared_experts * f, c.dtype, c.param_dtype, name="shared")
+        self.experts_gate = self.param(
+            "experts_gate", init, (e, d, f), c.param_dtype)
+        self.experts_up = self.param(
+            "experts_up", init, (e, d, f), c.param_dtype)
+        self.experts_down = self.param(
+            "experts_down", init, (e, f, d), c.param_dtype)
+
+    def route(self, x):
+        """-> weights [n, k] and expert ids [n, k] over ALL experts."""
+        with jax.named_scope("moe.route"):
+            sigma = jax.nn.sigmoid(jnp.dot(
+                x.astype(jnp.float32), self.router.astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST))
+            top, ids = jax.lax.top_k(sigma, self.cfg.experts_per_token)
+            weights = self.cfg.routed_scaling_factor * top / jnp.sum(
+                top, -1, keepdims=True)
+            return weights, ids
+
+    def __call__(self, x):
+        c = self.cfg
+        k, e = c.experts_per_token, c.experts_held
+        weights, ids = self.route(x)
+        local = ids - c.expert_offset
+        held = (local >= 0) & (local < e)
+        x = x.astype(c.dtype)
+        picked = jnp.sum(
+            local[:, :, None] == jnp.arange(e)[None, None, :], 1,
+            dtype=jnp.int32)
+        with jax.named_scope("moe.experts"):
+            # Every (token, choice) pair is a row; the pairs of held
+            # experts sorted by expert, the others behind them in a group
+            # that no product visits.
+            group = jnp.where(held, local, e).reshape(-1)
+            group = jnp.pad(
+                group, (0, -group.size % ROW_TILE), constant_values=e)
+            order = jnp.argsort(group)
+            sizes = jnp.sum(picked, 0)
+            xs = x[jnp.minimum(order // k, x.shape[0] - 1)]
+            grouped = lambda rows, w, tile: grouped_product(
+                rows, w.astype(c.dtype), sizes, tile)
+            hidden = jax.nn.silu(grouped(xs, self.experts_gate, TILE_IN)) \
+                * grouped(xs, self.experts_up, TILE_IN)
+            ys = grouped(hidden.astype(c.dtype), self.experts_down, TILE_OUT)
+            # Back in the order of the pairs, each times its weight; a row
+            # outside every group holds whatever the product left there.
+            ys = ys[jnp.argsort(order)[:held.size]].reshape(
+                x.shape[0], k, -1)
+            routed = jnp.sum(
+                jnp.where(held[..., None], ys * weights[..., None], 0.0), 1)
+        return self.shared(x).astype(jnp.float32) + routed, picked
+
+
+class PanguBlock(nn.Module):
+    cfg: PanguConfig
+    routed: bool
+
+    def setup(self):
+        c = self.cfg
+        norm = lambda name: RMSNorm(c.rms_norm_eps, c.param_dtype, name=name)
+        self.attn_norm = norm("attn_norm")
+        self.attn_post_norm = norm("attn_post_norm")
+        self.ffn_norm = norm("ffn_norm")
+        self.ffn_post_norm = norm("ffn_post_norm")
+        self.attn = LatentAttention(c, name="attn")
+        self.ffn = RoutedExperts(c, name="ffn") if self.routed else GatedMlp(
+            c.d_model, c.d_ff, c.dtype, c.param_dtype, name="ffn")
+
+    def _rest(self, h, a):
+        """-> the stream after both sub-layers, and which held experts
+        each token chose (none of them in a dense block)."""
+        h = h + self.attn_post_norm(a)
+        x = self.ffn_norm(h)
+        if self.routed:
+            y, picked = self.ffn(x.reshape(-1, x.shape[-1]))
+            y = y.reshape(x.shape)
+            picked = picked.reshape(x.shape[:-1] + (-1,))
+        else:
+            y = self.ffn(x)
+            picked = jnp.zeros(x.shape[:-1] + (0,), jnp.int32)
+        return h + self.ffn_post_norm(y), picked
+
+    def full(self, h, pos):
+        return self._rest(h, self.attn.full(self.attn_norm(h), pos))[0]
+
+    def window(self, h, index, cache, span: int):
+        a, cache = self.attn.window(self.attn_norm(h), index, cache, span)
+        return self._rest(h, a)[0], cache
+
+    def step(self, h, pos, cache, klen: int):
+        a, cache = self.attn.step(self.attn_norm(h), pos, cache, klen)
+        h, picked = self._rest(h, a)
+        return h, cache, picked
+
+
+class PanguMoE(nn.Module):
+    """batch {inputs [b, l]} -> logits [b, l, vocab]; with ``n_mtp`` also
+    the prediction module's [b, l - 1, vocab], whose row ``t`` predicts
+    token ``t + 2`` from the stream at ``t`` and token ``t + 1``."""
+
+    cfg: PanguConfig
+
+    def setup(self):
+        c = self.cfg
+        self.embed = nn.Embed(
+            c.vocab_size, c.d_model, param_dtype=c.param_dtype, name="embed")
+        self.blocks = [
+            PanguBlock(c, routed=i >= c.n_dense_layers, name=f"layer_{i}")
+            for i in range(c.n_layers)
+        ]
+        norm = lambda name: RMSNorm(c.rms_norm_eps, c.param_dtype, name=name)
+        self.final_norm = norm("final_norm")
+        self.head = self.param(
+            "head", nn.initializers.lecun_normal(),
+            (c.d_model, c.vocab_size), c.param_dtype)
+        if c.n_mtp:
+            self.mtp_h_norm = norm("mtp_h_norm")
+            self.mtp_e_norm = norm("mtp_e_norm")
+            self.mtp_proj = nn.Dense(
+                c.d_model, use_bias=False, dtype=c.dtype,
+                param_dtype=c.param_dtype, name="mtp_proj")
+            self.mtp_block = PanguBlock(c, routed=True, name="mtp_block")
+
+    def blank_cache(self, batch: int, positions: int):
+        """Per layer one array of latent rows, ``[batch, positions,
+        kv_lora_rank + qk_rope_head_dim]``."""
+        c = self.cfg
+        return {
+            f"layer_{i}": {"latent": jnp.zeros(
+                (batch, positions, c.row_width), c.dtype)}
+            for i in range(c.n_layers)
+        }
+
+    def head_logits(self, h):
+        """Float32 logits: the product in the compute dtype, accumulated
+        and handed out in float32."""
+        return jnp.dot(
+            self.final_norm(h).astype(self.cfg.dtype),
+            self.head.astype(self.cfg.dtype),
+            preferred_element_type=jnp.float32)
+
+    def prefill_window(self, tokens, n_valid, index, cache, span: int):
+        """One window of a prompt: ``n_valid`` of the ``W`` tokens count.
+        -> the row's cache and the logits [1, vocab] at the last valid
+        position (the prompt's first new token when this is its last
+        window)."""
+        h = self.embed(tokens).astype(jnp.float32)
+        new = {}
+        for i, block in enumerate(self.blocks):
+            h, rows = block.window(
+                h, index, cache[f"layer_{i}"]["latent"], span)
+            new[f"layer_{i}"] = {"latent": rows}
+        last = jax.lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
+        return new, self.head_logits(last[:, 0])
+
+    def decode_step(self, tok, pos, cache, klen: int):
+        """tok, pos [b] -> cache, logits [b, vocab], and which held
+        experts each row chose, [b, expert layers * experts_held], layer
+        by layer."""
+        h = self.embed(tok).astype(jnp.float32)
+        new, picked = {}, []
+        for i, block in enumerate(self.blocks):
+            h, rows, chose = block.step(
+                h, pos, cache[f"layer_{i}"]["latent"], klen)
+            new[f"layer_{i}"] = {"latent": rows}
+            picked.append(chose)
+        return new, self.head_logits(h), jnp.concatenate(picked, -1)
+
+    def __call__(self, batch: Dict[str, Any], *, deterministic: bool = True):
+        inputs = jnp.asarray(batch["inputs"], jnp.int32)
+        pos = jnp.broadcast_to(jnp.arange(inputs.shape[1]), inputs.shape)
+        h = self.embed(inputs).astype(jnp.float32)
+        for block in self.blocks:
+            h = block.full(h, pos)
+        logits = self.head_logits(h)
+        if not self.cfg.n_mtp:
+            return logits
+        # h'_t = W_p [norm(h_t) ; norm(Emb(x_{t+1}))], one expert block,
+        # then the model's own final norm and head.
+        both = jnp.concatenate([
+            self.mtp_h_norm(h[:, :-1]),
+            self.mtp_e_norm(self.embed(inputs[:, 1:]).astype(jnp.float32)),
+        ], -1)
+        h2 = self.mtp_block.full(
+            self.mtp_proj(both.astype(self.cfg.dtype)).astype(jnp.float32),
+            pos[:, :-1])
+        return logits, self.head_logits(h2)
+
+
+def build_pangu_moe_model(hparams: Dict, mesh=None) -> PanguMoE:
+    """``hparams``: fields of ``PanguConfig`` (the published model where
+    left out), ``compute_dtype`` and ``param_dtype``; other keys (the
+    names a driver reads, such as ``head_dim``) are passed over."""
+    hp = dict(hparams or {})
+    fields = {f.name: f.type for f in dataclasses.fields(PanguConfig)}
+    cfg = PanguConfig(
+        **{k: (float if fields[k] == "float" else int)(v)
+           for k, v in hp.items()
+           if k in fields and k not in ("dtype", "param_dtype")},
+        dtype=jnp.dtype(hp.get("compute_dtype", "bfloat16")),
+        param_dtype=jnp.dtype(hp.get("param_dtype", "bfloat16")),
+    )
+    if not 0 <= cfg.expert_offset <= cfg.n_experts - cfg.experts_held:
+        raise ValueError(
+            "the experts held must lie inside the router's outputs")
+    return PanguMoE(cfg)
+
+
+def make_continuous_decode_fns(
+    model: PanguMoE,
+    *,
+    max_decode_len: int = 32,
+    eos_id: int = 1,
+    pad_id: int = 0,
+    max_input_len: int = 64,
+    prefill_window_len: int = 256,
+):
+    """The decode contract of serving/generative.py for a decoder-only
+    model whose cache is indexed by position and holds the prompt.
+
+    As the contract of models/evabyte.py (``prefill_window``,
+    ``blank_cache``, ``first_decode_pos``, no encoder rows), and:
+
+      - one kind of cache, ``latent``: per layer ``[slots, positions,
+        kv_lora_rank + qk_rope_head_dim]``, indexed by position from the
+        prompt's first token on, written by every step, worked on in
+        place: ``step`` is handed every slot's rows, writes row ``i`` at
+        ``pos[i]`` and attends over its first ``klen`` positions;
+      - ``cache_positions``: how many positions a row holds, prompt and
+        new tokens together; the engine's kv buckets cover
+        ``first_decode_pos + tokens held``;
+      - ``step`` returns a third value, ``[b, step_tally_len]`` int32:
+        per row, which held experts it chose in each expert layer.  The
+        engine sums it over the live rows and hands the sums to
+        ``step_account(positions, tally)``.
+    """
+    from tpu_pipelines.serving.generative import CacheKind
+
+    c = model.cfg
+    w = int(prefill_window_len)
+    span = -(-int(max_input_len) // w) * w
+    positions = max(span, int(max_input_len) + int(max_decode_len))
+    row_bytes = c.n_layers * c.row_width * jnp.dtype(c.dtype).itemsize
+    expert_layers, held = c.n_layers - c.n_dense_layers, c.experts_held
+
+    def prefill_window(params, cache, tokens, n_valid, index):
+        return model.apply(
+            {"params": params}, tokens, n_valid, index, cache, span,
+            method=PanguMoE.prefill_window)
+
+    def step(params, cache, tok, pos, encoded, enc_mask, klen: int):
+        return model.apply(
+            {"params": params}, tok, pos, cache, klen,
+            method=PanguMoE.decode_step)
+
+    def blank_cache(batch: int):
+        return model.blank_cache(batch, positions)
+
+    def step_account(at, tally):
+        """``at``: the live rows' positions; ``tally``: assignments to
+        each held expert, layer by layer."""
+        layers = [
+            tally[i * held:(i + 1) * held] for i in range(expert_layers)]
+        busy = [t for t in layers if sum(t)]
+        return {
+            "cache_bytes": {"latent": sum(t + 1 for t in at) * row_bytes},
+            "expert_assignments": int(sum(tally)),
+            # the fullest held expert over the mean, expert layers averaged
+            "expert_load_ratio": (
+                sum(max(t) * held / sum(t) for t in busy) / len(busy)
+                if busy else None),
+        }
+
+    return SimpleNamespace(
+        step=step,
+        step_tally_len=expert_layers * held,
+        prefill_window=prefill_window,
+        prefill_window_len=w,
+        blank_cache=blank_cache,
+        cache_positions=positions,
+        cache_kinds={
+            "latent": CacheKind(True, written=True, in_place=True)},
+        cache_kind_of=lambda path: "latent",
+        first_decode_pos=lambda input_mask: jnp.sum(
+            jnp.asarray(input_mask, jnp.int32)),
+        encoded_shape=(0,),
+        step_account=step_account,
+        max_decode_len=int(max_decode_len),
+        eos_id=int(eos_id),
+        pad_id=int(pad_id),
+        max_input_len=int(max_input_len),
+    )

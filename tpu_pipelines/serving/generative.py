@@ -18,7 +18,9 @@ static-shape substrate):
     say (``CacheKind``): an encoder-decoder's self-attention K/V at
     ``max_decode_len`` beside its cross-attention K/V at the encoder
     length; a windowed decoder's ring of exact positions beside its
-    table of chunk summaries, neither indexed by decode position.
+    table of chunk summaries, neither indexed by decode position; a
+    latent-attention decoder's one row of latents a position, indexed
+    by position from the prompt's first token on (``cache_positions``).
     Live sequences occupy the compacted prefix ``[0, n_live)``; a
     departure moves the last live
     row into the hole (one scatter), an arrival lands at ``n_live`` (one
@@ -49,9 +51,11 @@ static-shape substrate):
     keyed ``(batch_bucket, kv_bucket)``: the batch bucket is the smallest
     power-of-two >= the live count (serving/batching.py's bucket rule),
     the KV bucket the smallest page multiple covering the deepest live
-    position.  ``warm()`` compiles every combination up front — the
-    fleet's canary gate calls it BEFORE a version becomes eligible, so no
-    decode step pays an XLA compile mid-traffic (``compiles_after_warm``
+    position (counted from the prompt's first token where the cache
+    holds the prompt: ``_depth``).  ``warm()`` compiles every
+    combination up front — the fleet's canary gate calls it BEFORE a
+    version becomes eligible, so no decode step pays an XLA compile
+    mid-traffic (``compiles_after_warm``
     is the auditable contract).  Pages are an allocation/accounting unit:
     ``serving_decode_cache_pages_in_use`` is what capacity planning reads.
   * **Identity.**  The per-row decode math is exactly the scalar-position
@@ -579,10 +583,17 @@ class GenerativeEngine:
         self.hard_deadline = bool(hard_deadline)
         self.device = device
         self.batch_buckets = bucket_sizes(self.max_batch_size)
-        self.kv_buckets = kv_bucket_sizes(self.max_decode_len, self.page_size)
+        # Positions a by-position array holds.  A decoder-only contract
+        # that keeps its prompt there too states them
+        # (``cache_positions``), and a row's depth then counts from the
+        # prompt's first token (``_depth``); otherwise the cache begins
+        # behind a BOS and holds the emitted tokens alone.
+        positions = getattr(fns, "cache_positions", None)
+        self._prompt_cached = positions is not None
+        positions = int(positions or self.max_decode_len)
+        self.kv_buckets = kv_bucket_sizes(positions, self.page_size)
         self._page = (
-            self.page_size if 0 < self.page_size < self.max_decode_len
-            else self.max_decode_len
+            self.page_size if 0 < self.page_size < positions else positions
         )
         # A contract prefilled by window (``prefill_window``) has no
         # whole-prompt prefill program; 0 = a whole-prompt ``prefill``.
@@ -740,10 +751,15 @@ class GenerativeEngine:
 
         pad = self.pad_id
         kind_of = _kind_reader(fns)
+        # Numbers a step hands back per row beside its token (which held
+        # experts the row chose; ``step_tally_len`` of them): summed over
+        # the live rows here, read with the tokens, handed to
+        # ``step_account``.
+        tallied = bool(getattr(fns, "step_tally_len", 0))
 
         def run(params, state):
             cache, tok, pos, live, encoded, enc_mask = state
-            new_sub, logits = fns.step(
+            new_sub, logits, *tally = fns.step(
                 params, _bucket_of(cache, b, kv, kind_of), tok[:b], pos[:b],
                 encoded[:b], enc_mask[:b], kv,
             )
@@ -753,7 +769,13 @@ class GenerativeEngine:
             cache = _write_back(cache, new_sub, b, kv, kind_of)
             tok = tok.at[:b].set(nxt)
             pos = pos.at[:b].set(pos[:b] + live[:b].astype(jnp.int32))
-            return (cache, tok, pos, live, encoded, enc_mask), nxt
+            out = nxt
+            if tallied:
+                # The live rows' tally rides behind the tokens: one
+                # array, one device-to-host read.
+                out = jnp.concatenate([nxt, jnp.sum(
+                    jnp.where(live[:b, None], tally[0], 0), 0, jnp.int32)])
+            return (cache, tok, pos, live, encoded, enc_mask), out
 
         return _jit_program(run)
 
@@ -1349,6 +1371,15 @@ class GenerativeEngine:
             if seq.max_new_tokens <= 1:
                 self._complete(seq)
 
+    def _depth(self, seq: _Sequence) -> int:
+        """Positions of a by-position cache that ``seq`` holds once the
+        step being dispatched has written its own: the emitted tokens
+        behind a BOS, or behind the prompt where the cache holds that
+        too (``first_pos`` is then the prompt's length).  What the kv
+        bucket has to cover and what ``serving_decode_cache_pages_in_use``
+        counts."""
+        return (seq.first_pos if self._prompt_cached else 1) + seq.held
+
     def _step_once(self) -> None:
         """One round with rows live: dispatch their step, admit behind
         it, read, emit.  The thread's one wait comes with the step
@@ -1364,9 +1395,9 @@ class GenerativeEngine:
         with self._phase("step") as span:
             b = next(bk for bk in self.batch_buckets if bk >= n)
             deepest = max(
-                s.held for s in self._slots[:n] if s is not None
+                self._depth(s) for s in self._slots[:n] if s is not None
             )
-            kv = next(k for k in self.kv_buckets if k >= deepest + 1)
+            kv = next(k for k in self.kv_buckets if k >= deepest)
             span.set_metadata(live=n, b=b, kv=kv)
             fn = self._step_for(b, kv)
             t0 = time.perf_counter()
@@ -1388,7 +1419,7 @@ class GenerativeEngine:
                 self.step_ewma_s = (1 - a) * self.step_ewma_s + a * dt
             self.steps_run += 1
             pages = sum(
-                -(-(s.held + 1) // self._page)
+                -(-self._depth(s) // self._page)
                 for s in self._slots[:n] if s is not None
             )
             self.telemetry.on_step(
@@ -1396,11 +1427,12 @@ class GenerativeEngine:
             )
             if self._account is not None:
                 # What this step read of each kind of cache, by the
-                # contract's own account of the rows' positions.
+                # contract's own account of the rows' positions and of
+                # the step's tally (empty where it hands none back).
                 self.telemetry.on_cache(self._account([
                     s.first_pos + s.held - 1
                     for s in self._slots[:n] if s is not None
-                ]))
+                ], toks[b:].tolist()))
         with self._phase("emit", live=n):
             now = time.monotonic()
             for slot in range(n - 1, -1, -1):
@@ -1531,6 +1563,8 @@ class DecodeTelemetry:
         self._prefill_tokens = self._prefill_windows = None
         self._rollovers = self._summaries = None
         self._cache_bytes = self._cache_read = None
+        self._expert_assignments = None
+        self._expert_load_sum = self._expert_load_count = None
         if registry is None:
             return
         from tpu_pipelines.observability.metrics import fine_latency_buckets
@@ -1670,6 +1704,22 @@ class DecodeTelemetry:
             "serving_decode_cache_bytes summed over the decode steps run.",
             labels=kind_lab,
         )
+        self._expert_assignments = registry.counter(
+            "serving_decode_expert_assignments_total",
+            "Assignments of live rows to the experts this replica holds, "
+            "summed over expert layers and decode steps.", labels=lab,
+        ).labels(self.replica)
+        self._expert_load_sum = registry.counter(
+            "serving_decode_expert_load_ratio_sum",
+            "Per decode step, the fullest held expert's assignments over "
+            "the mean of the held experts (expert layers averaged), "
+            "summed over the steps counted by "
+            "serving_decode_expert_load_ratio_count.", labels=lab,
+        ).labels(self.replica)
+        self._expert_load_count = registry.counter(
+            "serving_decode_expert_load_ratio_count",
+            "Decode steps with an assignment to a held expert.", labels=lab,
+        ).labels(self.replica)
         self._queue_wait = registry.histogram(
             "serving_decode_queue_wait_seconds",
             "Submit to the admission turn that took the sequence off "
@@ -1714,8 +1764,13 @@ class DecodeTelemetry:
         for kind, n_bytes in account["cache_bytes"].items():
             self._cache_bytes.labels(self.replica, kind).set(n_bytes)
             self._cache_read.labels(self.replica, kind).inc(n_bytes)
-        self._rollovers.inc(account["window_rollovers"])
-        self._summaries.inc(account["chunk_summaries"])
+        self._rollovers.inc(account.get("window_rollovers", 0))
+        self._summaries.inc(account.get("chunk_summaries", 0))
+        self._expert_assignments.inc(account.get("expert_assignments", 0))
+        ratio = account.get("expert_load_ratio")
+        if ratio is not None:
+            self._expert_load_sum.inc(ratio)
+            self._expert_load_count.inc()
 
     def on_step(self, dt, ewma, live, bucket, pages, active) -> None:
         if self._steps is None:
