@@ -14,7 +14,7 @@ import time
 import numpy as np
 import pytest
 
-from test_generative import EOS, VOCAB, make_stub_fns
+from test_generative import EOS, VOCAB, _wait_idle, make_stub_fns
 
 pytestmark = pytest.mark.generative
 
@@ -51,6 +51,7 @@ def run_traffic(n_requests=12, seed=7, pause_s=0.0, **engine_kw):
                 time.sleep(pause_s)     # let the worker go idle in between
         for h in handles:
             h.wait(30.0)
+        _wait_idle(engine)      # an EOS ending leaves a step unread
     finally:
         engine.close()
     return engine, reg, handles, time.perf_counter() - t0
@@ -90,7 +91,12 @@ def test_phase_occurrences_are_what_the_engine_did(engine_kw):
     get = lambda name: reg.get(name).labels("0").get()
     assert count["step"] == get("serving_decode_steps_total")
     assert count["step"] == engine.steps_run
-    # an ``emit`` follows every step; one more stands around each read of
+    # every step was dispatched once, behind the one before it or alone
+    dispatched = reg.snapshot()[
+        "serving_decode_step_dispatch_total"]["series"]
+    assert sum(dispatched.values()) == count["step"]
+    assert dispatched[("0", "alone")] >= 1
+    # an ``emit`` reads every step; one more stands around each read of
     # first tokens that had no step to go behind
     blocking = reg.get("serving_decode_first_token_reads_total").labels(
         "0", "blocking").get()
@@ -282,8 +288,31 @@ def test_spans_in_a_profiler_session_and_the_same_counters_without(tmp_path):
 
     inside("engine.prefill", "engine.admit", True)
     inside("engine.insert", "engine.admit", True)
-    inside("engine.retire", "engine.emit", False)
-    inside("engine.step.wait", "engine.step", False)
+    # A row leaves by count inside the round that dispatched its last
+    # step, or inside the ``emit`` that read its EOS.
+    assert all(
+        within(r, "step") or within(r, "emit")
+        for r in by_name["engine.retire"])
+    # A step is read inside an ``emit``, which says which step it reads;
+    # every step dispatched was read, once, in the order of dispatch,
+    # and but for a run's last steps with the next one dispatched first.
+    inside("engine.step.wait", "engine.emit", False)
+    step_of = lambda evs: [stats["step"] for _, _, stats in sorted(evs)]
+    steps = step_of(by_name["engine.step"])
+    assert steps == list(range(1, len(steps) + 1))
+    assert step_of(by_name["engine.step.wait"]) == steps
+    assert step_of(
+        [e for e in by_name["engine.emit"] if e[2]["live"]]) == steps
+    dispatched_at = {
+        stats["step"]: start for start, _, stats in by_name["engine.step"]}
+    ahead = [
+        stats["step"] for start, _, stats in by_name["engine.step.wait"]
+        if dispatched_at.get(stats["step"] + 1, float("inf")) < start]
+    queued = [stats["queued"] for _, _, stats in sorted(
+        by_name["engine.step"])]
+    assert [k + 1 for k in ahead] == [
+        k for k, q in zip(steps, queued) if q == "behind_step"]
+    assert ahead and queued[0] == "alone"
     # A first token is read with a step queued behind its prefill, or,
     # where nothing was there to step, in an ``emit`` of its own; one
     # read per admission that no prefix entry answered.
@@ -302,10 +331,15 @@ def test_spans_in_a_profiler_session_and_the_same_counters_without(tmp_path):
         dispatched[stats["seq"]] <= start
         for start, _, stats in by_name["engine.prefill.wait"])
     # top-level phases follow one another on the one thread; an
-    # admission turn is top-level only while no row is live, and
-    # otherwise lies behind the dispatch of a step, inside its span
-    behind = [a for a in by_name["engine.admit"] if within(a, "step")]
-    assert behind and len(behind) < len(by_name["engine.admit"])
+    # admission turn or an ``emit`` is top-level only while no row is
+    # live, and otherwise lies behind the dispatch of a step, inside
+    # its span
+    behind = [
+        x for n in ("admit", "emit") for x in by_name["engine." + n]
+        if within(x, "step")]
+    for n in ("admit", "emit"):
+        mine = [x for x in by_name["engine." + n] if x in behind]
+        assert mine and len(mine) < len(by_name["engine." + n])
     top = sorted(
         (s, e) for n in ("idle", "admit", "step", "emit")
         for s, e, *_ in by_name["engine." + n]
@@ -324,7 +358,8 @@ def test_spans_in_a_profiler_session_and_the_same_counters_without(tmp_path):
         want |= {"engine.prefill.wait"}
         assert want <= spans <= want | {"engine.prefill"}, (h.seq_id, spans)
     assert all(
-        set(stats) == {"live", "b", "kv"} and 1 <= stats["live"] <= stats["b"]
+        set(stats) == {"step", "live", "b", "kv", "queued"}
+        and 1 <= stats["live"] <= stats["b"]
         for _, _, stats in by_name["engine.step"])
     assert all(
         stats["prompt_tokens"] in (2, 3, 4, 5)

@@ -601,13 +601,14 @@ def test_engine_pages_accounting_under_admit_retire_move_mix():
     real_on_step = engine.telemetry.on_step
 
     def spy(dt, ewma, live, bucket, pages, active):
-        # Same worker thread: the slot table is consistent here.  Lengths
-        # EXCLUDE the token this step is about to append — the published
-        # figure covers the post-step cache footprint, hence the +1.
-        lengths = [
-            len(s.tokens)
-            for s in engine._slots[:live] if s is not None
-        ]
+        # Same worker thread, at the read of the step: the rows are the
+        # step's own snapshot (the slot table has moved on: the thread
+        # runs a step ahead).  Lengths EXCLUDE the token this step is
+        # about to append — the published figure covers the post-step
+        # cache footprint, hence the +1.
+        rows = engine._flights[0].rows
+        assert len(rows) == live
+        lengths = [len(s.tokens) for s in rows]
         observed.append((int(pages), tuple(lengths)))
         return real_on_step(dt, ewma, live, bucket, pages, active)
 
@@ -783,12 +784,13 @@ def _greedy_rows(model, params, reqs, L, eos_id):
 
 # (prompt index, max_new_tokens) in queue order, for three slots and
 # KV buckets 4 / 8 / 16.  A, B, C are admitted together; B leaves the
-# MIDDLE slot at 3 tokens (move + clear) and D lands in the slot that
-# freed, one step later (an admission goes behind the step of the rows
-# that are seated); A and C leave together at 5 with D behind them, so
-# the deepest live position falls back under a bucket boundary it had
-# crossed, and D then grows through both boundaries.  E and F repeat
-# B's prompt: a stored prefix entry is inserted a second and third time.
+# MIDDLE slot when the step of its third token is dispatched (move +
+# clear: retired by count) and D lands in the slot that freed in that
+# same round, so no step runs with it empty; A and C leave together at
+# 5 with D behind them, so the deepest live position falls back under a
+# bucket boundary it had crossed, and D then grows through both
+# boundaries.  E and F repeat B's prompt: a stored prefix entry is
+# inserted a second and third time.
 _STAGGERED = [(0, 5), (1, 3), (2, 5), (3, 12), (1, 4), (1, 7)]
 
 
@@ -849,9 +851,10 @@ def test_engine_in_place_arena_matches_isolated_greedy_t5(
     assert moves[0] == (2, 1)           # C into B's middle slot
     kvs = [kv for _, kv in steps]
     if page_size:
-        assert kvs[:7] == [4, 4, 4, 8, 4, 4, 8] and kvs[-1] == 16
-        # the step after B left ran with its slot still empty
-        assert [b for b, _ in steps][:4] == [3, 3, 2, 3]
+        assert kvs[:6] == [4, 4, 4, 8, 4, 8] and kvs[-1] == 16
+        # B's slot was free for the round of B's last step: D rode the
+        # next one, and none ran with the slot empty
+        assert [b for b, _ in steps][:4] == [3, 3, 3, 3]
     else:
         assert set(kvs) == {L}
     if prefix_entries:
@@ -1013,6 +1016,15 @@ def _first_reads(reg):
     return {r: series.get(("0", r), 0.0) for r in FIRST_TOKEN_READS}
 
 
+def _step_dispatches(reg):
+    from tpu_pipelines.serving.generative import STEP_DISPATCHES
+
+    series = reg.snapshot()[
+        "serving_decode_step_dispatch_total"]["series"]
+    assert set(series) <= {("0", q) for q in STEP_DISPATCHES}
+    return {q: series.get(("0", q), 0.0) for q in STEP_DISPATCHES}
+
+
 # (prompt, budget) of the first burst: a first token that is EOS (prompt
 # 0's, by the choice of EOS), budgets of 1 and 2, one prompt twice in one
 # round (with a prefix cache: a miss, and a hit on an entry whose token
@@ -1109,13 +1121,15 @@ def test_engine_admits_without_waiting_and_serves_the_models_own_tokens(
 
 
 def test_no_read_between_the_dispatches_of_a_round(kit):
-    """The order of a round, with the programs wrapped: its step, then
-    the prefills and inserts it admits, dispatched back to back with no
-    device-to-host read between them; then the reads, each first token's
-    with a step dispatched after its prefill (the thread never waits for
-    a prefill with nothing queued behind it), then the step's own; and
-    ``insert`` is handed the prefill's own device scalar, never a host
-    integer."""
+    """The order of a round, with the programs wrapped: its step, the
+    retirements by count, the read of the step BEFORE it, then the
+    prefills and inserts it admits, dispatched back to back with no
+    device-to-host read between them, then the first tokens of the
+    prefills ahead of the step.  Every read comes with a step
+    dispatched after the program that made its value (the thread never
+    waits for a program with nothing queued behind it), but for the
+    run's last step; and ``insert`` is handed the prefill's own device
+    scalar, never a host integer."""
     from tpu_pipelines.observability.metrics import MetricsRegistry
     from tpu_pipelines.serving.generative import GenerativeEngine
 
@@ -1140,26 +1154,40 @@ def test_no_read_between_the_dispatches_of_a_round(kit):
         engine.close()
     for i, (m, out) in enumerate(zip(budgets, outs)):
         assert [int(t) for t in out] == kit.greedy(i, m)
-    # "idle": nothing of a round queued yet; "queued": its step is, and
-    # admissions may follow it; "reading": the round's one wait.
-    state = "idle"
+    # ``unread``: steps dispatched whose tokens the host has not read.
+    # Two of them: the older is read next, before anything but the
+    # retirements of the rows whose budget the newer one fills.
+    unread = 0
     reads = {"tok0": 0, "nxt": 0}
+    ahead = 0                           # steps read with the next queued
     for at, (kind, name, *made_at) in enumerate(log.events):
         if kind == "read":
-            assert state in ("queued", "reading"), (at, log.events)
-            state = "reading"
             reads[name] += 1
-            # its step, or for a first token a step, came after the
-            # program that made it
-            assert ("dispatch", "run") in log.events[made_at[0]:at]
+            # steps dispatched since the program that made the value
+            # (a step's own dispatch is the first of them)
+            since = log.events[made_at[0]:at].count(("dispatch", "run"))
+            if name == "tok0":
+                assert unread >= 1 and since >= 1, (at, log.events)
+            else:
+                assert unread in (1, 2) and since == unread, (at, log.events)
+                ahead += unread == 2
+                unread -= 1
         elif name == "run":
-            state = "queued"
-        elif name in ("move", "clear"):
-            state = "idle"
-        else:                           # prefill, prefill_window, insert
-            assert state != "reading", (at, log.events)
+            assert unread <= 1, (at, log.events)
+            unread += 1
+        elif name not in ("move", "clear"):  # prefill(_window), insert
+            assert unread <= 1, (at, log.events)
+    assert unread == 0
     assert reads["tok0"] == len(handles)
     assert reads["nxt"] == engine.steps_run > 0
+    # a row was live from the first admission to the last token: every
+    # step but the first went behind one unread, every read but the
+    # last had the next step queued
+    assert ahead == engine.steps_run - 1
+    assert _step_dispatches(reg) == {
+        "behind_step": engine.steps_run - 1, "alone": 1}
+    assert reg.get(
+        "serving_decode_wasted_row_steps_total").labels("0").get() == 0
     assert len(log.insert_tok0) == len(handles)
     assert all(
         log.is_device_array(t) and t.shape == () and t.dtype == np.int32
@@ -1173,9 +1201,10 @@ def test_no_read_between_the_dispatches_of_a_round(kit):
 def _killed_with_unread_first_tokens(kit, how):
     """Two requests ride a step; two more are admitted behind the
     second, and the worker stops at the dispatch of the third, the one
-    their first tokens would have been read behind: ``how`` "dies" (the
-    program raises: a device fault, an injected kill) or "hangs" (it
-    never returns and ``close`` gives up on the thread).  -> handles."""
+    that the second step and their first tokens would have been read
+    behind: ``how`` "dies" (the program raises: a device fault, an
+    injected kill) or "hangs" (it never returns and ``close`` gives up
+    on the thread).  -> handles."""
     from tpu_pipelines.serving.generative import GenerativeEngine
 
     second, go, third, hold = (threading.Event() for _ in range(4))
@@ -1224,9 +1253,11 @@ def _killed_with_unread_first_tokens(kit, how):
 @pytest.mark.parametrize("how", ["dies", "hangs"])
 def test_an_unread_first_token_does_not_outlive_its_engine(kit, how):
     """A dying worker and ``close()`` finish every handle, also one
-    whose first token the host never read; what ``DecodeSessionLost``
-    would carry of it (the fleet builds ``partial_tokens`` from the
-    handles' ``tokens``) is what the host has read: nothing."""
+    whose first token the host never read and one with a step in
+    flight; what ``DecodeSessionLost`` would carry of it (the fleet
+    builds ``partial_tokens`` from the handles' ``tokens``) is what the
+    host has read: of the unread first token nothing, of the step in
+    flight not its token."""
     from tpu_pipelines.serving.generative import (
         DecodeSessionLost,
         GenerationEvicted,
@@ -1247,8 +1278,11 @@ def test_an_unread_first_token_does_not_outlive_its_engine(kit, how):
     assert lost.unfinished == 4
     for i, (h, part) in enumerate(zip(handles, lost.partial_tokens)):
         assert part == kit.greedy(i, len(part))
-    # the first two rode two steps; the last two were seated, unread
-    assert [len(p) for p in lost.partial_tokens] == [3, 3, 0, 0]
+    # the first two rode two steps, the second of them still unread
+    # (the third goes ahead of its read); the last two were seated,
+    # unread
+    assert [len(p) for p in lost.partial_tokens] == [2, 2, 0, 0]
+    assert [h.in_flight for h in handles] == [1, 1, 0, 0]
     assert all(h.first_unread for h in handles[2:])
     assert all(h.first_token_s is None for h in handles[2:])
     assert all(h.first_token_s is not None for h in handles[:2])
@@ -1622,6 +1656,357 @@ def test_sweep_decode_times_block_k(monkeypatch):
     assert res["best"] is not None
     assert res["best"]["block_k"] in (64, 128)
     assert all("ms" in r or "error" in r for r in res["swept"])
+
+
+# ------------------------------------ one step ahead (ISSUE 34)
+#
+# The worker dispatches step k + 1 before it reads step k's tokens: a
+# step needs only counts of the host.  A row whose budget a step fills
+# leaves as that step is dispatched; an EOS is learnt one step late and
+# its row rides that one step for nothing.  Tokens are handed out by the
+# snapshot of the rows taken at the step's dispatch.
+
+
+def _pangu_kit():
+    import test_pangu_moe as pg
+    from tpu_pipelines.models.pangu_moe import make_continuous_decode_fns
+
+    model, params = pg.build()
+    # 1 to 4 windows of 16; the by-position cache holds the prompt
+    prompts = [pg.prompt(400 + i, n) for i, n in enumerate(
+        (40, 7, 16, 33, 50))]
+    fns = pg.decode_fns(model)
+    rows = [
+        pg.through_the_cache(params, fns, p, 12)[0].tolist()
+        for p in prompts
+    ]
+    return SimpleNamespace(
+        params=params, prompts=prompts, windowed=True,
+        greedy=lambda i, m: rows[i][:m],
+        fns=lambda eos: make_continuous_decode_fns(
+            model, max_decode_len=pg.MAX_OUT, eos_id=eos,
+            max_input_len=pg.MAX_IN, prefill_window_len=pg.WINDOW),
+        # one cached position of one layer, in bytes (f32), and the layers
+        latent_row_bytes=pg.ROW * 4 * pg.HP["n_layers"],
+    )
+
+
+_AHEAD_KITS = {}
+
+
+def _ahead_kit(which, request):
+    """The kits of ``kit`` and tiny Pangu, each built once a module."""
+    if which not in _AHEAD_KITS:
+        _AHEAD_KITS[which] = (
+            _t5_kit(request.getfixturevalue("tiny_t5")) if which == "t5"
+            else _evabyte_kit() if which == "evabyte" else _pangu_kit())
+    return _AHEAD_KITS[which]
+
+
+# An EOS INSIDE the vocabulary, by model: a token some of the five greedy
+# streams reach in mid-stream and others never do.
+_EOS_INSIDE = {"t5": 29, "evabyte": 29, "pangu": 88}
+# (prompt, budget): three slots; the first six are queued when the first
+# round runs, the last three arrive while rows are live.  Budgets of 2
+# and 3 leave within a step or two of being seated.
+_MIXED_1 = [(0, 9), (1, 9), (2, 5), (3, 12), (4, 3), (1, 6)]
+_MIXED_2 = [(2, 2), (0, 4), (3, 7)]
+
+
+@pytest.mark.parametrize("which,engine_kw", [
+    ("t5", dict(page_size=0)),
+    ("t5", dict(page_size=4)),
+    ("t5", dict(page_size=0, prefix_cache_entries=8)),
+    ("t5", dict(page_size=4, prefix_cache_entries=8)),
+    ("evabyte", dict(page_size=4)),
+    ("evabyte", dict(page_size=4, prefill_chunk_pages=1)),
+    ("pangu", dict(page_size=16, prefill_chunk_pages=1)),
+    ("pangu", dict(page_size=0)),
+], ids=[
+    "t5-unpaged", "t5-paged", "t5-unpaged-prefix", "t5-paged-prefix",
+    "evabyte-windows", "evabyte-credits", "pangu-paged-credits",
+    "pangu-unpaged",
+])
+def test_one_step_ahead_streams_are_isolated_greedy(
+    which, engine_kw, request
+):
+    """EOS inside the vocabulary and budget endings under mid-stream
+    admissions: every stream is the model's own greedy decode cut at its
+    EOS; every step was dispatched once, all but the first of a busy
+    spell behind one still unread; an EOS ending wasted exactly one
+    row-step and a budget ending none; and where the contract keeps an
+    account (``step_account``), it is of the positions the rows held
+    when their step was DISPATCHED, the wasted rides included."""
+    from tpu_pipelines.observability.metrics import MetricsRegistry
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    kit = _ahead_kit(which, request)
+    eos = _EOS_INSIDE[which]
+    reqs = _MIXED_1 + _MIXED_2
+    own = [_own_stream(kit, i, m, eos) for i, m in reqs]
+    by_eos = [o[-1] == eos and len(o) < m for o, (_, m) in zip(own, reqs)]
+    # the choice of EOS still does what it was chosen for
+    assert any(e and len(o) > 2 for e, o in zip(by_eos, own))
+    assert sum(not e for e in by_eos) >= 3
+    gate = threading.Event()
+    reg = MetricsRegistry()
+    engine = GenerativeEngine(
+        kit.fns(eos), kit.params, max_batch_size=3,
+        fault_hook=gate.wait, registry=reg, **engine_kw,
+    )
+    try:
+        engine.warm()
+        first = [
+            engine.submit_nowait(kit.prompts[i], max_new_tokens=m)
+            for i, m in _MIXED_1
+        ]
+        gate.set()
+        first[0].wait(120.0)
+        second = [
+            engine.submit_nowait(kit.prompts[i], max_new_tokens=m)
+            for i, m in _MIXED_2
+        ]
+        outs = [[int(t) for t in h.wait(120.0)] for h in first + second]
+        _wait_idle(engine)
+    finally:
+        gate.set()
+        engine.close()
+    assert outs == own
+    assert engine.compiles_after_warm == 0
+    get = lambda name, *lab: reg.get(name).labels("0", *lab).get()
+    steps = get("serving_decode_steps_total")
+    dispatched = _step_dispatches(reg)
+    assert sum(dispatched.values()) == steps == engine.steps_run
+    assert 1 <= dispatched["alone"] <= 3 and dispatched["behind_step"] > 0
+    # Never seated: a budget of one token (none here), or a first token
+    # that a prefix entry already held on the host and that was EOS.
+    seated = get("serving_decode_engine_phase_total", "insert")
+    assert get("serving_decode_engine_phase_total", "retire") == seated
+    wasted = get("serving_decode_wasted_row_steps_total")
+    assert wasted == sum(by_eos) - (len(reqs) - seated)
+    # every row of every step emitted a token or is counted as wasted
+    tokens = get("serving_decode_tokens_total")
+    assert tokens == sum(len(o) - 1 for o in own)
+    ridden = [
+        len(kit.prompts[i]) + t
+        for (i, _), o, e in zip(reqs, own, by_eos)
+        for t in range(len(o) - 1 + e)
+    ]
+    assert len(ridden) == tokens + sum(by_eos)
+    if which == "pangu":
+        # a step at position t reads t + 1 cached positions a layer
+        assert wasted == sum(by_eos)
+        assert get("serving_decode_cache_read_bytes_total", "latent") == (
+            sum(t + 1 for t in ridden) * kit.latent_row_bytes)
+        assert 0 < get("serving_decode_expert_load_ratio_count") <= steps
+
+
+def _wait_idle(engine, timeout_s=30.0):
+    """The last step dispatched may be unread when the last handle is
+    done (a row that ended by EOS rode it): idle once it is read."""
+    deadline = time.monotonic() + timeout_s
+    while not engine.idle() and time.monotonic() < deadline:
+        time.sleep(0.001)
+    assert engine.idle()
+
+
+def _stub_seeds(want_eos, n, budget=12):
+    """``n`` one-token prompts of the stub chain whose stream of
+    ``budget`` tokens ends by EOS in mid-stream (at its third token or
+    later), or never reaches EOS."""
+    found = []
+    for seed in range(1, 200):
+        ref = ref_stream(np.asarray([seed], np.int32), budget)
+        ends = ref[-1] == EOS and len(ref) < budget
+        if (ends and len(ref) >= 3) if want_eos else (
+                EOS not in ref and len(ref) == budget):
+            found.append(np.asarray([seed], np.int32))
+            if len(found) == n:
+                return found
+    raise AssertionError("the stub chain has no such seeds")
+
+
+def _rows_by_step(engine):
+    """Spy on the dispatch of every step: the request numbers that sat in
+    its rows, in row order (the worker thread calls ``_step_for`` with
+    the rows it is about to step)."""
+    steps = []
+    step_for = engine._step_for
+
+    def spy(b, kv):
+        steps.append(tuple(
+            s.seq_id for s in engine._slots[:engine._n_live]))
+        return step_for(b, kv)
+
+    engine._step_for = spy
+    return steps
+
+
+@pytest.mark.parametrize("ending", ["budget", "eos"])
+def test_a_budget_ending_wastes_nothing_and_an_eos_ending_one_row_step(
+    ending
+):
+    """Two slots, a queue.  ``budget``: a row leaves as the step of its
+    last token is dispatched and the queue's head is seated in that same
+    round, so no step runs with a slot empty while anyone waits, and no
+    row rides a step after its end.  ``eos``: the row rides exactly one
+    step more than it emitted tokens for, and the slot is taken in the
+    round that read the EOS."""
+    from tpu_pipelines.observability.metrics import MetricsRegistry
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    plain = _stub_seeds(False, 5)
+    if ending == "budget":
+        reqs = list(zip(plain, [3, 6, 4, 2, 5]))
+    else:
+        reqs = [(_stub_seeds(True, 1)[0], 12)] + list(
+            zip(plain[:3], [9, 4, 5]))
+    refs = [ref_stream(inp, m) for inp, m in reqs]
+    gate = threading.Event()
+    reg = MetricsRegistry()
+    engine = GenerativeEngine(
+        make_stub_fns(), {}, max_batch_size=2, page_size=4,
+        fault_hook=gate.wait, registry=reg,
+    )
+    try:
+        engine.warm()
+        steps = _rows_by_step(engine)
+        handles = [
+            engine.submit_nowait(inp, max_new_tokens=m) for inp, m in reqs
+        ]
+        gate.set()
+        outs = [[int(t) for t in h.wait(30.0)] for h in handles]
+        _wait_idle(engine)
+    finally:
+        gate.set()
+        engine.close()
+    assert outs == refs
+    get = lambda name, *lab: reg.get(name).labels("0", *lab).get()
+    ridden = {
+        h.seq_id: sum(h.seq_id in rows for rows in steps) for h in handles}
+    lives = [len(rows) for rows in steps]
+    assert get("serving_decode_steps_total") == len(steps)
+    if ending == "budget":
+        assert get("serving_decode_wasted_row_steps_total") == 0
+        assert ridden == {h.seq_id: len(h.result) - 1 for h in handles}
+        # full until the queue is empty: a request's first token is not a
+        # step's, so the last one seated makes the tail of one row
+        assert lives == sorted(lives, reverse=True) and lives[0] == 2
+        assert sum(lives) == sum(len(o) - 1 for o in outs)
+        # seats in order of arrival, each in the round its slot freed:
+        # request 3 rides the step after request 1's last (its third
+        # token's: the second step), request 2 moved into its slot
+        assert steps[:3] == [(1, 2), (1, 2), (2, 3)]
+    else:
+        assert refs[0][-1] == EOS and 3 <= len(refs[0]) < 12
+        assert get("serving_decode_wasted_row_steps_total") == 1
+        assert ridden[handles[0].seq_id] == len(refs[0])
+        assert all(
+            ridden[h.seq_id] == len(h.result) - 1 for h in handles[1:])
+        # the step after its last ride already has its successor seated
+        last = max(k for k, rows in enumerate(steps) if 1 in rows)
+        assert steps[last + 1] == (2, 3)
+    assert _step_dispatches(reg) == {
+        "behind_step": len(steps) - 1, "alone": 1}
+
+
+def test_rows_moved_after_a_dispatch_still_get_their_own_tokens():
+    """Three rows; the one in slot 0 leaves as the first step is
+    dispatched (a budget of two), so slot 2's row is moved into slot 0
+    with that step's tokens unread: the read hands them out by the
+    step's own snapshot of the rows, not by where the rows are now."""
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    seeds = _stub_seeds(False, 3)
+    reqs = list(zip(seeds, [2, 7, 9]))
+    gate = threading.Event()
+    engine = GenerativeEngine(
+        make_stub_fns(), {}, max_batch_size=3, page_size=0,
+        fault_hook=gate.wait,
+    )
+    try:
+        engine.warm()
+        log = _Log(engine)
+        handles = [
+            engine.submit_nowait(inp, max_new_tokens=m) for inp, m in reqs
+        ]
+        gate.set()
+        outs = [[int(t) for t in h.wait(30.0)] for h in handles]
+    finally:
+        gate.set()
+        engine.close()
+    assert outs == [ref_stream(inp, m) for inp, m in reqs]
+    # the first step's dispatch, the move, and only then its read
+    first_run = log.events.index(("dispatch", "run"))
+    move = log.events.index(("dispatch", "move"))
+    read = next(
+        at for at, ev in enumerate(log.events)
+        if ev[:2] == ("read", "nxt"))
+    assert first_run < move < read
+    assert log.events[read][2] == first_run   # it is that step's tokens
+
+
+@pytest.mark.parametrize("how", ["read", "close", "fault"])
+def test_a_step_in_flight_is_owed(how):
+    """One request with a budget of two: its row leaves as its one step
+    is dispatched, so the engine holds no row, no queue and no unread
+    first token, and still owes the step's token.  ``idle()`` says so;
+    ``read``: the next round reads it and the handle completes;
+    ``close``: the handle is evicted with what the host has seen;
+    ``fault``: a fault hook that raises kills the worker, which fails
+    the handle the same way."""
+    from tpu_pipelines.serving.generative import (
+        GenerationEvicted,
+        GenerativeEngine,
+    )
+
+    inp = _stub_seeds(False, 1)[0]
+    ref = ref_stream(inp, 2)
+    reached, release = threading.Event(), threading.Event()
+    rounds = {"n": 0}
+
+    def hook():
+        # round 1 admits, round 2 steps, round 3 would read the step
+        rounds["n"] += 1
+        if rounds["n"] == 3:
+            reached.set()
+            release.wait(30.0)
+            if how == "fault":
+                raise RuntimeError("injected kill")
+
+    engine = GenerativeEngine(
+        make_stub_fns(), {}, max_batch_size=2, fault_hook=hook)
+    try:
+        engine.warm()
+        h = engine.submit_nowait(inp, max_new_tokens=2)
+        assert reached.wait(30.0)
+        assert engine.steps_run == 0 and len(engine._flights) == 1
+        assert h.slot is None and h.in_flight == 1 and h.held == 2
+        assert h.tokens == ref[:1] and not h._done.is_set()
+        assert engine.active_sequences() == 0
+        assert engine.outstanding_tokens() == 0
+        assert not engine.idle()
+        if how == "close":
+            engine.close(timeout_s=0.2)
+        release.set()
+        assert h._done.wait(30.0)
+        if how == "read":
+            assert [int(t) for t in h.result] == ref
+            _wait_idle(engine)
+            assert engine.steps_run == 1
+        else:
+            assert h.result is None and h.tokens == ref[:1]
+            if how == "close":
+                assert isinstance(h.error, GenerationEvicted)
+            else:
+                assert "injected kill" in str(h.error)
+                engine._worker.join(30.0)
+                assert engine._arena is None and not engine._flights
+                with pytest.raises(RuntimeError, match="worker died"):
+                    engine.submit_nowait(inp, max_new_tokens=2)
+    finally:
+        release.set()
+        engine.close()
 
 
 # ------------------------------------------------- fleet / REST surface

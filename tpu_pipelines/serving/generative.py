@@ -30,21 +30,34 @@ static-shape substrate):
     one row and a step writes its bucket, not a fresh copy of
     everything else; ``self._arena`` is only ever rebound to what a
     program returned.
-  * **One wait a round.**  The worker thread never waits for a prefill
-    with nothing queued behind it.  A round with rows live dispatches,
-    back to back and with no read in between, the step of the rows that
-    are seated and then, behind it, every prefill and insert it admits:
-    the host's part of an admission (the dispatch of a prefill alone is
-    milliseconds) runs while the device steps, and a freed slot stays
-    empty for that one step.  A prefill's first token stays on the
-    device and ``insert`` takes it as the device scalar it is.  Then the
-    round reads: the first tokens of the admissions dispatched AHEAD of
-    this step (behind the step before: long since there), then the
-    step's tokens.  What the host does with a first token (onto the
-    handle, ``first_token_s``, EOS, the prefix entry's host copy) it
-    does at that read; until then a seated row counts as holding one
-    token (``_Sequence.held``).
-    ``serving_decode_first_token_reads_total`` says how each
+  * **One step ahead.**  A step needs only counts of the host, never
+    a token's value: the row bucket from the live count, the kv bucket
+    from the deepest row's count of tokens, the budgets; its token and
+    position inputs are in the arena.  So the worker thread runs one
+    decode step ahead of what it has read.  A round with rows live
+    dispatches step k, keeping with its output a snapshot of which
+    sequence sat in which row (``_Flight``); retires the rows whose
+    budget step k fills (retire by count: the last token is in step k's
+    output, which no arena program touches, and the slot is free for
+    this round's admission); reads step k - 1's tokens and hands them
+    out by THAT step's snapshot; admits behind step k (the host's part
+    of an admission, milliseconds for the dispatch of a prefill alone,
+    runs while the device steps); and reads the first tokens of the
+    admissions dispatched ahead of step k.  Every wait is for a program
+    the device has left behind or is about to, with step k queued:
+    between two decode steps the device never waits for the host.  An
+    EOS is learnt one step late: the row has ridden step k, whose token
+    for it is dropped (``serving_decode_wasted_row_steps_total``, one
+    row-step per EOS ending, none per budget ending).  A prefill's first
+    token stays on the device and ``insert`` takes it as the device
+    scalar it is; what the host does with it (onto the handle,
+    ``first_token_s``, EOS, the prefix entry's host copy) it does at its
+    read.  Until a token is read its row counts as holding it
+    (``_Sequence.held``), and the engine owes it (``idle``, ``close``,
+    a dying worker).
+    ``serving_decode_step_dispatch_total`` says how each step was
+    dispatched (``behind_step``: with the one before it unread;
+    ``alone``), ``serving_decode_first_token_reads_total`` how each
     admission's token came: ``behind_step``, ``known`` (a prefix-cache
     hit) or ``blocking`` (nothing was there to step).
   * **Bucketed steps.**  Each decode step runs one pre-compiled program
@@ -123,7 +136,8 @@ with each request's queue wait and time to first token.
 Tracing: every phase of the worker thread is also a
 ``jax.profiler.TraceAnnotation`` named ``engine.<phase>`` (plus
 ``engine.prefill.wait`` and ``engine.step.wait``, the device-to-host
-reads of a first token and of a step's tokens).  Annotations are
+reads of a first token and of a step's tokens; the latter says which
+step it reads, the one before the step just dispatched).  Annotations are
 recorded only while a profiler session runs, into the same trace and on
 the same clock as the device's operations; the device side of a phase
 is its program's event, under the names in ``PROGRAM_NAMES``
@@ -166,6 +180,12 @@ ENGINE_PHASES = (
 # How an admission's first token reaches the host: the ``read`` label of
 # ``serving_decode_first_token_reads_total``.
 FIRST_TOKEN_READS = ("behind_step", "known", "blocking")
+
+# How a step was dispatched: the ``queued`` label of
+# ``serving_decode_step_dispatch_total``.  ``behind_step``: with the
+# step before it still unread, so the device has it queued when that
+# one ends; ``alone``: with nothing in flight (a run's first step).
+STEP_DISPATCHES = ("behind_step", "alone")
 
 # The engine's device programs as a profile's "XLA Modules" line names
 # them: ``jit_`` + the ``__name__`` of the function handed to jax.jit.
@@ -280,9 +300,10 @@ class DecodeSessionLost(RuntimeError):
 @dataclass
 class _Sequence:
     """Host-side bookkeeping for one generation (the engine's unit of
-    scheduling).  ``tokens`` mirrors the device state: ``held``, its
-    length plus the first token while only the device has it
-    (``first_unread``), IS the sequence's next decode position.
+    scheduling).  ``tokens`` is what the host has read of the device's
+    state: ``held``, its length plus the tokens only the device has yet
+    (``first_unread``, ``in_flight``), IS the sequence's next decode
+    position.
 
     The request's timeline rides on the handle: ``arrival_s`` (submit),
     ``admitted_s`` (the ``_admit`` turn that took it off the queue),
@@ -324,12 +345,20 @@ class _Sequence:
     # the host: the token is in the arena (``insert`` took it as the
     # device scalar it was), not in ``tokens``.
     first_unread: bool = False
+    # Tokens of this sequence that dispatched steps hold and the host has
+    # not read yet: 1 while it rides the step in flight, 2 between the
+    # next step's dispatch and that read.
+    in_flight: int = 0
+    # Its row of the arena while it has one (the worker thread's; a
+    # retirement elsewhere may move it).
+    slot: Optional[int] = None
 
     @property
     def held(self) -> int:
-        """Tokens this sequence holds, the unread first one included:
-        what scheduling counts (bucket, pages, tokens owed)."""
-        return len(self.tokens) + self.first_unread
+        """Tokens this sequence holds, counting those only the device
+        has yet (the unread first one, those of steps in flight): what
+        scheduling counts (bucket, pages, budget, tokens owed)."""
+        return len(self.tokens) + self.first_unread + self.in_flight
 
     def finish(self, error: Optional[BaseException] = None) -> None:
         if self._done.is_set():
@@ -347,6 +376,21 @@ class _Sequence:
         if self.error is not None:
             raise self.error
         return self.result
+
+
+class _Flight(NamedTuple):
+    """A step that is dispatched and whose tokens the host has not read:
+    what the read needs of the moment of dispatch, because the rows may
+    have moved, left or been joined by others since."""
+
+    index: int                      # the engine's n-th step, from 1
+    nxt: Any                        # the step's output, on the device
+    rows: Tuple[_Sequence, ...]     # who sat in row 0, 1, ... of the step
+    positions: List[int]            # the position each row fed ([]: no account)
+    b: int
+    kv: int
+    pages: int                      # cache pages the rows covered
+    t0: float                       # ``perf_counter`` before the dispatch
 
 
 def kv_bucket_sizes(max_decode_len: int, page_size: int) -> List[int]:
@@ -663,6 +707,13 @@ class GenerativeEngine:
         # the admissions whose first token the host has not read yet, in
         # the order of their prefills (worker thread only).
         self._unread: "collections.deque[tuple]" = collections.deque()
+        # The steps dispatched and not read yet, oldest first: one while
+        # the thread runs ahead, two between a step's dispatch and the
+        # read of the step before it (appended and popped by the worker
+        # thread under the lock; ``idle`` and ``close`` look at it).
+        self._flights: "collections.deque[_Flight]" = collections.deque()
+        self._steps_dispatched = 0
+        self._last_read_s = 0.0
 
         self._worker = threading.Thread(target=self._run, daemon=True)
         self._worker.start()
@@ -936,12 +987,14 @@ class GenerativeEngine:
             return self._n_live + len(self._queue)
 
     def idle(self) -> bool:
-        # An unread first token is an unfinished request even where it
-        # took no slot (a budget of one token): not idle, not to be
-        # closed under it.
+        # An unread token is an unfinished request even where it holds
+        # no slot (a first token under a budget of one; the last token of
+        # a row retired when its step was dispatched): not idle, not to
+        # be closed under it.
         with self._lock:
             return (
-                self._n_live == 0 and not self._queue and not self._unread
+                self._n_live == 0 and not self._queue
+                and not self._unread and not self._flights
             )
 
     def submit_nowait(
@@ -1083,6 +1136,7 @@ class GenerativeEngine:
                         and not self._queue
                         and self._n_live == 0
                         and not self._unread
+                        and not self._flights
                     ):
                         with self._phase("idle"):
                             self._cond.wait()
@@ -1091,8 +1145,9 @@ class GenerativeEngine:
                 if self._fault_hook is not None:
                     self._fault_hook()
                 if self._n_live:
-                    # The step first, for the rows that are seated:
-                    # admission's host work goes behind it.
+                    # The step first, for the rows that are seated: the
+                    # read of the step before it and admission's host
+                    # work go behind it.
                     self._step_once()
                     if self.prefill_chunk_pages > 0:
                         # Each decode step EARNS admission credits
@@ -1108,9 +1163,12 @@ class GenerativeEngine:
                             self._admit_credits + self.prefill_chunk_pages,
                         )
                     continue
-                # No row to step yet: admit; the rows that are seated
-                # ride the next round's step, and their first tokens are
-                # read behind it.
+                # No row to step: the last step's tokens, if it is still
+                # unread (its rows all left as it was dispatched), then
+                # admission; the rows that are seated ride the next
+                # round's step, and their first tokens are read behind it.
+                with self._dev():
+                    self._land()
                 self._admit()
                 if not self._n_live and self._unread:
                     # Still nothing to step: what was admitted (now, or
@@ -1134,20 +1192,26 @@ class GenerativeEngine:
 
     def _take_unfinished(self) -> List[_Sequence]:
         """Every sequence the engine still owes an end, taken off the
-        queue, out of the slots and off the list of unread first tokens
-        (caller holds ``self._lock``).  A first token that was never
-        read stays on the device: the handle's ``tokens`` are what the
-        host has seen, which is what a recovery re-prefills from."""
-        pending = list(self._queue) + [
-            s for s in self._slots[: self._n_live] if s is not None
-        ]
-        # A request with a budget of one token never took a slot: its
-        # first token unread, it is on that list alone.
-        pending += [
-            seq for seq, _, _ in self._unread if seq.max_new_tokens <= 1
-        ]
+        queue, out of the slots, off the list of unread first tokens and
+        out of the steps in flight (caller holds ``self._lock``).  A
+        token that was never read stays on the device: the handle's
+        ``tokens`` are what the host has seen, which is what a recovery
+        re-prefills from."""
+        # A request can be owed an end and hold no slot: a budget of one
+        # token (on ``_unread`` alone), or a row retired when its last
+        # step was dispatched (in that step's snapshot alone).
+        owed = (
+            list(self._queue)
+            + [s for s in self._slots[: self._n_live] if s is not None]
+            + [seq for seq, _, _ in self._unread]
+            + [seq for flight in self._flights for seq in flight.rows]
+        )
+        pending = list({
+            id(s): s for s in owed if not s._done.is_set()
+        }.values())
         self._queue.clear()
         self._unread.clear()
+        self._flights.clear()
         self._n_live = 0
         return pending
 
@@ -1329,6 +1393,7 @@ class GenerativeEngine:
             )
         with self._lock:
             self._slots[slot] = seq
+            seq.slot = slot
             self._n_live += 1
         return True
 
@@ -1355,9 +1420,10 @@ class GenerativeEngine:
         first tokens still on the device alone, in the order of their
         prefills (``read``: ``behind_step`` with a step queued behind
         them, ``blocking`` with nothing).  Each read returns when its
-        prefill has run.  A sequence that took no slot (a budget of one
-        token) ends here; one whose first token is EOS has a row, which
-        the step's ``emit`` retires."""
+        prefill has run.  A sequence that ends at its first token ends
+        here: under a budget of one token it took no slot; where the
+        token is EOS it has a row and rides the step in flight, so the
+        row is retired now and that step's read drops its token."""
         from jax.profiler import TraceAnnotation
 
         for _ in range(len(self._unread) if count is None else count):
@@ -1368,8 +1434,8 @@ class GenerativeEngine:
             if entry is not None:
                 entry.tok0_host = t0
             self._first_token(seq, t0, read)
-            if seq.max_new_tokens <= 1:
-                self._complete(seq)
+            if self._ended(seq):
+                self._end(seq)
 
     def _depth(self, seq: _Sequence) -> int:
         """Positions of a by-position cache that ``seq`` holds once the
@@ -1381,67 +1447,105 @@ class GenerativeEngine:
         return (seq.first_pos if self._prompt_cached else 1) + seq.held
 
     def _step_once(self) -> None:
-        """One round with rows live: dispatch their step, admit behind
-        it, read, emit.  The thread's one wait comes with the step
-        queued: for the first tokens of the prefills dispatched AHEAD of
-        this step (admitted behind the step before, or with nothing
-        live), then for the step's own.  What is admitted behind this
-        step rides the next one, so a freed slot stays empty for one
-        step, and the host's part of an admission (the dispatch of a
-        prefill alone is milliseconds) costs the device nothing."""
-        from jax.profiler import TraceAnnotation
-
+        """One round with rows live, the thread one step ahead of what
+        it has read: dispatch the rows' step k, retire the rows whose
+        budget it fills, read and hand out step k - 1's tokens
+        (``_land``), admit behind step k, read the first tokens of the
+        prefills dispatched AHEAD of step k.  Step k needs only counts
+        of the host (the row bucket, the deepest row, the budgets); its
+        token and position inputs are in the arena.  Both waits of the
+        round are for programs the device has left behind or is about
+        to, with step k queued behind them: between two steps the device
+        never waits for the host.  What is admitted behind step k rides
+        step k + 1."""
         n = self._n_live
-        with self._phase("step") as span:
+        with self._phase("step") as span, self._dev():
+            rows = tuple(self._slots[:n])
+            depths = [self._depth(s) for s in rows]
             b = next(bk for bk in self.batch_buckets if bk >= n)
-            deepest = max(
-                self._depth(s) for s in self._slots[:n] if s is not None
+            kv = next(k for k in self.kv_buckets if k >= max(depths))
+            queued = "behind_step" if self._flights else "alone"
+            self._steps_dispatched += 1
+            span.set_metadata(
+                step=self._steps_dispatched, live=n, b=b, kv=kv,
+                queued=queued,
             )
-            kv = next(k for k in self.kv_buckets if k >= deepest)
-            span.set_metadata(live=n, b=b, kv=kv)
             fn = self._step_for(b, kv)
             t0 = time.perf_counter()
-            with self._dev():
-                self._arena, nxt = fn(self.params, self._arena)
-                ahead = len(self._unread)
-                self._admit()
-                # The round's one place to wait: first the tokens of the
-                # prefills ahead of the step (each is there when its
-                # prefill is), then the step's own.
-                self._read_first_tokens("behind_step", ahead)
-                with TraceAnnotation("engine.step.wait"):
-                    toks = np.asarray(nxt)
-            dt = time.perf_counter() - t0
+            self._arena, nxt = fn(self.params, self._arena)
+            self.telemetry.on_step_dispatch(queued)
+            flight = _Flight(
+                self._steps_dispatched, nxt, rows,
+                # The position each row fed, for the contract's account
+                # of the step (``step_account``), where it keeps one.
+                [s.first_pos + s.held - 1 for s in rows]
+                if self._account is not None else [],
+                b, kv, sum(-(-d // self._page) for d in depths), t0,
+            )
+            with self._lock:
+                self._flights.append(flight)
+                for seq in rows:
+                    seq.in_flight += 1
+            # Retire by count: a row whose budget this step fills needs
+            # no further step whatever its token is, and the token is in
+            # ``nxt``, which no arena program touches.  Its slot is free
+            # for this round's admission.
+            for slot in range(n - 1, -1, -1):
+                if rows[slot].held >= rows[slot].max_new_tokens:
+                    self._retire(slot)
+            if len(self._flights) > 1:
+                self._land()
+            ahead = len(self._unread)
+            self._admit()
+            # Each is there when its prefill is, and that ran before
+            # the step just dispatched.
+            self._read_first_tokens("behind_step", ahead)
+
+    def _land(self) -> None:
+        """Read the oldest step in flight (none: nothing to do) and hand
+        its tokens out by ITS snapshot of the rows: a row that moved
+        since, or left, still gets its own.  A sequence that ended
+        before this read (its previous token was EOS, its first was, it
+        was evicted) rode the step for nothing: its token is dropped and
+        the row-step counted as wasted.  One that ends here by EOS or by
+        a hard deadline has its row retired now, having ridden the step
+        dispatched since (the next read drops that one)."""
+        from jax.profiler import TraceAnnotation
+
+        if not self._flights:
+            return
+        flight = self._flights[0]
+        n, b, kv = len(flight.rows), flight.b, flight.kv
+        with self._phase("emit", step=flight.index, live=n):
+            with TraceAnnotation("engine.step.wait", step=flight.index):
+                toks = np.asarray(flight.nxt)
+            # The step period as callers see it: read to read while the
+            # thread runs ahead, dispatch to read for a step alone.
+            now_s = time.perf_counter()
+            dt = now_s - max(flight.t0, self._last_read_s)
+            self._last_read_s = now_s
             if self.step_ewma_s is None:
                 self.step_ewma_s = dt
             else:
                 a = self.STEP_EWMA_ALPHA
                 self.step_ewma_s = (1 - a) * self.step_ewma_s + a * dt
             self.steps_run += 1
-            pages = sum(
-                -(-self._depth(s) // self._page)
-                for s in self._slots[:n] if s is not None
-            )
             self.telemetry.on_step(
-                dt, self.step_ewma_s, n, b, pages, int(n)
+                dt, self.step_ewma_s, n, b, flight.pages, n
             )
             if self._account is not None:
                 # What this step read of each kind of cache, by the
                 # contract's own account of the rows' positions and of
                 # the step's tally (empty where it hands none back).
-                self.telemetry.on_cache(self._account([
-                    s.first_pos + s.held - 1
-                    for s in self._slots[:n] if s is not None
-                ], toks[b:].tolist()))
-        with self._phase("emit", live=n):
+                self.telemetry.on_cache(
+                    self._account(flight.positions, toks[b:].tolist()))
             now = time.monotonic()
+            wasted = 0
             for slot in range(n - 1, -1, -1):
-                seq = self._slots[slot]
-                if self._ended(seq):
-                    # Its first token, read behind this step, was EOS:
-                    # what the step computed for the row is dropped and
-                    # the row retired.
-                    self._settle(slot, seq, now)
+                seq = flight.rows[slot]
+                seq.in_flight -= 1
+                if seq._done.is_set():
+                    wasted += 1
                     continue
                 seq.tokens.append(int(toks[slot]))
                 self.telemetry.on_token()
@@ -1455,40 +1559,54 @@ class GenerativeEngine:
                         batch_bucket=b, kv_bucket=kv, live=n,
                         step_s=round(dt, 6),
                     )
-                self._settle(slot, seq, now)
+                self._settle(seq, now)
+            if wasted:
+                self.telemetry.on_wasted_row_steps(wasted)
+            with self._lock:
+                self._flights.popleft()
 
-    def _settle(self, slot: int, seq: _Sequence, now: float) -> None:
-        """After a step's token is appended: retire and complete a
-        sequence that hit EOS or its budget, evict one past its hard
-        deadline, leave the rest in their slots."""
-        # Retire the slot BEFORE waking the waiter: the client thread
-        # resumes to consistent accounting (outstanding_tokens of a
-        # finished sequence is already 0, its slot already free).
+    def _settle(self, seq: _Sequence, now: float) -> None:
+        """After a step's token is appended: end a sequence that hit
+        EOS or its budget, evict one past its hard deadline (its row,
+        like an EOS ending's, has ridden the step dispatched since),
+        leave the rest where they are."""
         if self._ended(seq):
-            if seq.ctx is not None and seq.tokens[-1] == self.eos_id:
-                seq.ctx.instant(
-                    "decode.eos", slot=slot, tokens=len(seq.tokens)
-                )
-            self._retire(slot)
-            self._complete(seq)
+            self._end(seq)
         elif (
             self.hard_deadline
             and seq.deadline_s is not None
             and now > seq.deadline_s
         ):
             self.telemetry.on_evicted()
-            self._retire(slot)
+            slot = seq.slot
+            if slot is not None:        # else its last step was in flight
+                self._retire(slot)
             self._evict_seq(
                 seq, slot,
                 f"per-token SLO deadline exceeded after "
                 f"{len(seq.tokens)}/{seq.max_new_tokens} tokens",
             )
 
+    def _end(self, seq: _Sequence) -> None:
+        """``seq``'s last token is on the host: complete it, its row
+        retired first if it still holds one (an EOS ending; a budget's
+        row left when its last step was dispatched)."""
+        # Retire the slot BEFORE waking the waiter: the client thread
+        # resumes to consistent accounting (outstanding_tokens of a
+        # finished sequence is already 0, its slot already free).
+        if seq.ctx is not None and seq.tokens[-1] == self.eos_id:
+            seq.ctx.instant(
+                "decode.eos", slot=seq.slot, tokens=len(seq.tokens)
+            )
+        if seq.slot is not None:
+            self._retire(seq.slot)
+        self._complete(seq)
+
     def _retire(self, slot: int) -> None:
         last = self._n_live - 1
+        seq = self._slots[slot]
         with self._phase(
-            "retire", seq=self._slots[slot].seq_id, slot=slot,
-            moved=int(slot != last),
+            "retire", seq=seq.seq_id, slot=slot, moved=int(slot != last),
         ):
             with self._dev():
                 if slot != last:
@@ -1498,8 +1616,10 @@ class GenerativeEngine:
                 self._arena = self._jit_clear(self._arena, np.int32(last))
             with self._lock:
                 if slot != last:
-                    self._slots[slot] = self._slots[last]
+                    moved = self._slots[slot] = self._slots[last]
+                    moved.slot = slot
                 self._slots[last] = None
+                seq.slot = None
                 self._n_live -= 1
 
     def _release_prefix(self, seq: _Sequence) -> None:
@@ -1560,6 +1680,7 @@ class DecodeTelemetry:
         self._prefix_hit_pages = self._prefix_pages = None
         self._phase_s = self._phase_n = None
         self._queue_wait = self._ttft = self._first_reads = None
+        self._dispatches = self._wasted = None
         self._prefill_tokens = self._prefill_windows = None
         self._rollovers = self._summaries = None
         self._cache_bytes = self._cache_read = None
@@ -1613,7 +1734,8 @@ class DecodeTelemetry:
         ).labels(self.replica)
         self._step_s = registry.gauge(
             "serving_decode_step_seconds",
-            "EWMA wall time of one continuous-batch decode step.",
+            "EWMA of the decode step period: from the later of a step's "
+            "dispatch and the previous step's read to its own read.",
             labels=lab,
         ).labels(self.replica)
         # Fine sqrt(2) ladder (metrics.fine_latency_buckets, 25µs to
@@ -1746,9 +1868,35 @@ class DecodeTelemetry:
             r: reads.labels(self.replica, r) for r in FIRST_TOKEN_READS
         }
 
+        dispatches = registry.counter(
+            "serving_decode_step_dispatch_total",
+            "Decode steps dispatched, by what the device had queued: "
+            "behind_step (the step before it still unread: the thread "
+            "runs one step ahead), alone (nothing in flight).  The two "
+            "add up to serving_decode_steps_total once every step is "
+            "read.", labels=("replica", "queued"),
+        )
+        self._dispatches = {
+            q: dispatches.labels(self.replica, q) for q in STEP_DISPATCHES
+        }
+        self._wasted = registry.counter(
+            "serving_decode_wasted_row_steps_total",
+            "Rows that rode a decode step after their sequence's end "
+            "(an EOS or an eviction is learnt one step late): row-steps "
+            "whose token was dropped.", labels=lab,
+        ).labels(self.replica)
+
     def on_first_token_read(self, read: str) -> None:
         if self._first_reads is not None:
             self._first_reads[read].inc()
+
+    def on_step_dispatch(self, queued: str) -> None:
+        if self._dispatches is not None:
+            self._dispatches[queued].inc()
+
+    def on_wasted_row_steps(self, n_rows: int) -> None:
+        if self._wasted is not None:
+            self._wasted.inc(n_rows)
 
     def on_prefill_window(self, n_tokens: int) -> None:
         if self._prefill_windows is not None:
