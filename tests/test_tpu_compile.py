@@ -575,3 +575,84 @@ def test_latent_row_write_keeps_the_cache_where_it_lies_on_v5e(one_chip):
     assert not moved, (len(moved), moved[:2])
     # the step's tally rides behind its tokens: one result of 128 + 16
     assert f"s32[{rows + 16}]" in text
+
+
+@pytest.mark.parametrize("tokens", [32, 512])
+def test_grouped_expert_products_at_4096_compile_for_v5e(one_chip, tokens):
+    """The same kernel at Command A+'s expert shape (16 experts held,
+    4,096 -> 4,096 -> 4,096) with the tile the model's table hands it for
+    that shape: a decode step's 32 rows x 8 choices and a prefill window's
+    512 x 8.  (32, 2048, 2048) ran out of fast memory on the chip
+    (PERF.md section 6, PR 35)."""
+    from tpu_pipelines.models import pangu_moe as pm
+
+    tile = pm.TILES[(4096, 4096)]
+    m = -(-tokens * 8 // pm.ROW_TILE) * pm.ROW_TILE
+    compiled = jax.jit(
+        lambda rows, w, sizes: pm.megablox(rows, w, sizes, tile)).lower(
+        _sds((m, 4096), jnp.bfloat16, one_chip),
+        _sds((16, 4096, 4096), jnp.bfloat16, one_chip),
+        _sds((16,), jnp.int32, one_chip),
+    ).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 ** 2
+
+
+def test_window_and_full_step_keeps_caches_and_weights_where_they_lie_on_v5e(
+        one_chip):
+    """The engine's own step program for the contract of
+    models/command_a.py at the published widths, one period of layers
+    (three rings and a full array a slot) with its 16 experts, the whole
+    32-slot arena of 18,432 positions handed over in place and donated:
+    every cache array comes back where it lay, the 256 row-wise writes
+    leave the key/value-heads-before-entries layout alone, and no
+    projection's weights are copied into another layout (without the
+    barrier behind the q, k and v products the compiler copied Wq, Wk and
+    Wv at every step: PERF.md section 6, PR 35)."""
+    from types import SimpleNamespace
+
+    from tpu_pipelines.models import command_a as ca
+    from tpu_pipelines.serving import generative as gen
+
+    rows, positions = 32, 18432
+    model = ca.build_command_a_model(dict(
+        vocab_size=32768, n_layers=4, experts_held=16))
+    fns = ca.make_continuous_decode_fns(
+        model, max_decode_len=2048, eos_id=32768, max_input_len=16384)
+    assert fns.cache_positions == positions
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), {"inputs": jnp.zeros((1, 8), jnp.int32)})["params"])
+    state = (
+        jax.eval_shape(lambda: fns.blank_cache(rows)),
+        jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
+        jnp.zeros((rows,), bool), jnp.zeros((rows, 0), jnp.float32),
+        jnp.zeros((rows, 16384), jnp.int32),
+    )
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+    program = gen.GenerativeEngine._build_step(
+        SimpleNamespace(pad_id=0), rows, positions, fns)
+    compiled = program.lower(on_chip(params), on_chip(state)).compile()
+    m = _fits(compiled)
+    assert m.temp_size_in_bytes < 1024 ** 3
+    text = compiled.as_text()
+    param_layouts, result_layouts, aliases = _entry_layouts(text)
+    result_of = {param: out for out, param in aliases.items()}
+    for leaf, count in ((f"bf16[{rows},8,{positions},128]", 2),
+                        (f"bf16[{rows},8,4096,128]", 6)):
+        leaves = [
+            i for i, s in enumerate(param_layouts) if s.startswith(leaf)]
+        assert len(leaves) == count
+        for i in leaves:
+            assert result_layouts[result_of[i]] == param_layouts[i]
+        moved = re.findall(
+            rf"= {re.escape(leaf)}\S* (?:copy|transpose|scatter)\(.*", text)
+        assert not moved, (len(moved), moved[:2])
+    # no weight is re-laid out: Wq and Wo, Wk and Wv, the shared experts
+    for weight in ("bf16[4096,16384]", "bf16[16384,4096]",
+                   "bf16[4096,1024]", "bf16[1024,4096]"):
+        moved = re.findall(
+            rf"= {re.escape(weight)}\S* (?:copy|transpose)\(.*", text)
+        assert not moved, (weight, len(moved), moved[:1])
+    # the step's tally rides behind its tokens: one result of 32 + 4 x 16
+    assert f"s32[{rows + 64}]" in text

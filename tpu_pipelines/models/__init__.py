@@ -11,6 +11,10 @@ Present:
   - pangu_moe: decoder-only LM on latent attention and top-k routed experts
     with a shared one, as one chip's share of the experts (served through
     serving/generative.py; a latent cache by position)
+  - command_a: decoder-only LM on window and full attention mixed 3 : 1
+    over grouped-query heads, a parallel block and sigmoid-routed experts
+    beside averaged shared ones (``build_command_a_model``; served through
+    serving/generative.py; rings and by-position arrays in one arena)
   - transformer: shared sharded blocks (TP over 'model', ring-attention SP
     over 'seq') used by bert/t5
 
@@ -18,3 +22,13 @@ Tabular models (taxi) take a dict of (transformed) feature arrays; array-input
 models (mnist, resnet) define an ``apply_fn`` hook in their trainer module file
 so the serving/export path can adapt the feature dict (see trainer/export.py).
 """
+
+
+def __getattr__(name):
+    # The builder a configuration file names, without importing flax with
+    # the package.
+    if name == "build_command_a_model":
+        from tpu_pipelines.models.command_a import build_command_a_model
+
+        return build_command_a_model
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

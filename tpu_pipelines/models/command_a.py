@@ -1,0 +1,572 @@
+"""Command A+ (``cohere2_moe``): a decoder-only language model whose layers
+are of two kinds in a fixed pattern, as one chip's share of an
+expert-parallel deployment.
+
+For layer ``l`` with stream ``h`` (float32), ONE bias-free LayerNorm a
+layer, ``x = (h - mean) / sqrt(var + eps) * g``, feeds attention and the
+expert layer side by side (the parallel block):
+
+    q = x Wq -> n_heads x head_dim;  k = x Wk, v = x Wv -> n_kv_heads x
+    head_dim; no biases, no q/k norm.  Query head i reads key/value head
+    i // (n_heads / n_kv_heads).  Scores q.k / sqrt(head_dim), softmax in
+    float32.
+    l % full_every < full_every - 1 (a WINDOW layer): q and k rotated by
+    interleaved pairs (2j, 2j + 1), angle t * theta ** (-2j / head_dim);
+    the query at t sees keys t - window_size + 1 .. t.
+    l % full_every == full_every - 1 (a FULL layer): no rotation, no
+    positions at all; the query at t sees keys 0 .. t.
+    a = concat(heads) Wo.
+    s = sigmoid(x Wr) over ALL n_experts (float32), the top
+    experts_per_token, weights s_i / sum_top s; expert e:
+    (silu(x G_e) * (x U_e)) D_e.  m = sum_top w_e expert_e(x) + the MEAN
+    of the n_shared_experts shared experts (one gated MLP of their joint
+    width whose output is divided by their number).
+    h <- h + a + m.
+
+After the last layer a LayerNorm, and logits = h E^T * logit_scale with
+``E`` the embedding (tied).  The residual stream, the norms, the scores,
+their softmax, the router and the logits are float32, the matrix
+products bfloat16 with float32 accumulation.
+
+The expert layer is ``RoutedExperts`` of models/pangu_moe.py, told which
+experts it holds: it routes over all, computes its own part of the sum
+and the shared experts; what the absent experts would add is the other
+chips' to compute.
+
+Two kinds of cache, by layer.  A window layer keeps a RING of
+``window_size`` entries, ``[slots, n_kv_heads, window_size, head_dim]``
+for keys (rotated) and for values: position ``t`` lies at ``t %
+window_size``, and an entry is valid by mask (a roll-over clears
+nothing).  A full layer keeps every position, ``[slots, n_kv_heads,
+positions, head_dim]``.  The key/value heads lie BEFORE the entries so
+that a step's products are batched over (row, key/value head) with the
+entries and the head's numbers as the matrix, as the arrays lie; with
+the heads behind the entries the chip pads 8 heads to 16.
+
+Entry points: ``prefill_window`` (one window of a prompt against a row's
+cache: the full layers attend over the cache by blocks of keys, as far
+as the window reaches; the window layers over the ring as it was plus
+the window's own keys, and then write the window's VALID keys into the
+ring, which wraps while a long prompt is prefilled), ``decode_step`` (one
+token per live row, each at its own position) and ``__call__`` (a whole
+sequence, no cache: what the tests compare with the plain reference).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from types import SimpleNamespace
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+from flax import linen as nn
+
+from tpu_pipelines.models.evabyte import NEG_INF
+from tpu_pipelines.models.pangu_moe import (
+    RoutedExperts, config_from, tally_account)
+
+
+@dataclasses.dataclass(frozen=True)
+class CommandAConfig:
+    """The widths and counts of one model, as every module reads them.
+    The defaults are command-a-plus-05-2026 as published, with every
+    expert held."""
+
+    vocab_size: int = 262144
+    d_model: int = 4096
+    n_layers: int = 32
+    n_heads: int = 128
+    n_kv_heads: int = 8
+    head_dim: int = 128
+    window_size: int = 4096
+    full_every: int = 4
+    d_expert: int = 4096
+    n_experts: int = 128
+    experts_held: int = 128
+    expert_offset: int = 0
+    experts_per_token: int = 8
+    n_shared_experts: int = 4
+    shared_average: bool = True
+    routed_scaling_factor: float = 1.0
+    rope_theta: float = 50000.0
+    layer_norm_eps: float = 1e-5
+    logit_scale: float = 1.0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    def is_full(self, layer: int) -> bool:
+        return layer % self.full_every == self.full_every - 1
+
+
+# Queries (positions) and keys a block of the blocked attention holds at
+# most: the float32 scores of a block are ``n_heads * QUERY_BLOCK *
+# KEY_BLOCK * 4`` bytes, 134 MB at 128 heads.
+QUERY_BLOCK, KEY_BLOCK = 256, 1024
+
+
+def block_of(n: int, most: int) -> int:
+    """The largest block of at most ``most`` that divides ``n``."""
+    return next(b for b in range(min(n, most), 0, -1) if n % b == 0)
+
+
+def rope_interleaved(x, pos, theta: float):
+    """Rotary position code in float32 by interleaved pairs: element
+    ``2j`` with ``2j + 1``.  x [b, l, ..., d], pos [b, l].  The partner of
+    an element is fetched by a roll along ``d``, not by a reshape to
+    pairs: the chip's compiler moves such a reshape onto the projection's
+    weights and re-lays them out at every call."""
+    d = x.shape[-1]
+    inv = theta ** (-(jnp.arange(d) // 2 * 2).astype(jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[..., None] * inv             # [b, l, d]
+    ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (d,))
+    x = x.astype(jnp.float32)
+    even = jnp.arange(d) % 2 == 0
+    partner = jnp.where(even, -jnp.roll(x, -1, -1), jnp.roll(x, 1, -1))
+    return x * jnp.cos(ang) + partner * jnp.sin(ang)
+
+
+class LayerNorm(nn.Module):
+    """``(x - mean) / sqrt(var + eps) * g`` in float32, no bias."""
+
+    eps: float
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        g = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                       self.param_dtype)
+        x = x.astype(jnp.float32)
+        x = x - jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        return x * jax.lax.rsqrt(var + self.eps) * g.astype(jnp.float32)
+
+
+def blocked_attention(q, k, v, ok_of, n_blocks, kb: int, dtype):
+    """Attention with an online softmax over ``n_blocks`` (a number or a
+    traced scalar) blocks of ``kb`` keys.  q [h, r, d] scaled, k and v
+    [h, s, d]; ``ok_of(j)`` -> [r, kb] bool, which keys of block ``j``
+    each row sees; every row sees a key in block 0.
+    -> [h, r, d] float32."""
+    f32 = dict(preferred_element_type=jnp.float32)
+
+    def body(j, carry):
+        top, total, acc = carry
+        kj = jax.lax.dynamic_slice_in_dim(k, j * kb, kb, 1)
+        vj = jax.lax.dynamic_slice_in_dim(v, j * kb, kb, 1)
+        ok = ok_of(j)[None]
+        score = jnp.where(
+            ok, jnp.einsum("hrd,hsd->hrs", q, kj, **f32), NEG_INF)
+        new_top = jnp.maximum(top, score.max(-1))
+        # A masked score is exp(-1e30 - top) = 0 once a row has seen a
+        # key; before that (``top`` still NEG_INF) what it adds is wiped
+        # by ``keep`` = 0 when the first key comes.
+        p = jnp.exp(score - new_top[..., None])
+        keep = jnp.exp(top - new_top)
+        return (
+            new_top, total * keep + p.sum(-1),
+            acc * keep[..., None] + jnp.einsum(
+                "hrs,hsd->hrd", p.astype(dtype), vj, **f32))
+
+    h, r, d = q.shape
+    top, total, acc = jax.lax.fori_loop(0, n_blocks, body, (
+        jnp.full((h, r), NEG_INF, jnp.float32),
+        jnp.zeros((h, r), jnp.float32), jnp.zeros((h, r, d), jnp.float32)))
+    return acc / total[..., None]
+
+
+class GroupedAttention(nn.Module):
+    """Grouped-query attention of one layer, of the kind ``full`` says."""
+
+    cfg: CommandAConfig
+    full: bool
+
+    def setup(self):
+        c = self.cfg
+        dense = lambda n, name: nn.Dense(
+            n, use_bias=False, dtype=c.dtype, param_dtype=c.param_dtype,
+            name=name)
+        self.q_proj = dense(c.n_heads * c.head_dim, "q_proj")
+        self.k_proj = dense(c.n_kv_heads * c.head_dim, "k_proj")
+        self.v_proj = dense(c.n_kv_heads * c.head_dim, "v_proj")
+        self.o_proj = dense(c.d_model, "o_proj")
+        self.span = "attn.full" if self.full else "attn.window"
+
+    def project(self, x, pos):
+        """x [b, l, d_model], pos [b, l] -> q [b, kv, g, l, d] (scaled),
+        k and v [b, kv, l, d]: query head ``i`` is ``(i // g, i % g)``;
+        the rotary code is on q and k of a window layer."""
+        c = self.cfg
+        x = x.astype(c.dtype)
+        b, l = x.shape[:2]
+        # The barrier keeps the products as they are written.  Without it
+        # the chip's compiler lays q, k and v out for the attention
+        # products, pushes that layout back through the projections and
+        # copies Wq, Wk and Wv into another layout at every call (1.6 ms
+        # of a 22.8 ms step, PERF.md section 6, PR 35).
+        q, k, v = jax.lax.optimization_barrier(
+            (self.q_proj(x), self.k_proj(x), self.v_proj(x)))
+        q = q.reshape(b, l, c.n_heads, c.head_dim)
+        k = k.reshape(b, l, c.n_kv_heads, c.head_dim)
+        v = v.reshape(b, l, c.n_kv_heads, c.head_dim)
+        if not self.full:
+            q = rope_interleaved(q, pos, c.rope_theta)
+            k = rope_interleaved(k, pos, c.rope_theta)
+        q = (q.astype(jnp.float32) * c.head_dim ** -0.5).astype(c.dtype)
+        q = q.reshape(b, l, c.n_kv_heads, -1, c.head_dim)
+        heads_first = lambda y: jnp.swapaxes(y.astype(c.dtype), 1, 2)
+        return (jnp.transpose(q, (0, 2, 3, 1, 4)), heads_first(k),
+                heads_first(v))
+
+    def merge(self, out, l: int):
+        """out [kv, g * l, d] -> the layer's output [l, d_model]."""
+        c = self.cfg
+        out = out.reshape(c.n_kv_heads, -1, l, c.head_dim)
+        out = jnp.transpose(out, (2, 0, 1, 3)).reshape(l, -1)
+        return self.o_proj(out.astype(c.dtype))
+
+    def sees(self, t, u):
+        """Whether the query at ``t`` sees the key at position ``u``
+        (arrays that broadcast); ``u < 0``: no key there."""
+        ok = (u <= t) & (u >= 0)
+        return ok if self.full else ok & (u > t - self.cfg.window_size)
+
+    def blocks(self, q, k, v, start, held, filled=None):
+        """One row's queries, a block of at most ``QUERY_BLOCK`` positions
+        at a time, over blocks of at most ``KEY_BLOCK`` keys.  q
+        [kv, g, l, d] at positions ``start + [0, l)``; k and v [kv, s, d],
+        of which entry ``j`` holds position ``held[j]``.  Only the first
+        ``filled`` entries can hold a key that is seen; where None, the
+        entries lie by position, and a block of queries goes no further
+        than its own last position.  Every query sees a key among the
+        first ``kb`` entries.  -> [l, d_model]."""
+        kv, g, l, d = q.shape
+        qb, kb = block_of(l, QUERY_BLOCK), block_of(k.shape[1], KEY_BLOCK)
+
+        def one(i):
+            t = start + i * qb + jnp.arange(qb)
+            rows = jax.lax.dynamic_slice_in_dim(q, i * qb, qb, 2)
+            ok_of = lambda j: jnp.tile(self.sees(
+                t[:, None],
+                jax.lax.dynamic_slice_in_dim(held, j * kb, kb)[None, :]),
+                (g, 1))
+            out = blocked_attention(
+                rows.reshape(kv, g * qb, d), k, v, ok_of,
+                (t[-1] if filled is None else filled - 1) // kb + 1, kb,
+                self.cfg.dtype)
+            return out.reshape(kv, g, qb, d)
+
+        with jax.named_scope(self.span):
+            out = jax.lax.map(one, jnp.arange(l // qb))     # [n, kv, g, qb, d]
+            out = jnp.transpose(out, (1, 2, 0, 3, 4)).reshape(kv, g * l, d)
+        return self.merge(out, l)
+
+    def whole(self, x, pos):
+        """A whole sequence of one row under the layer's mask, no cache.
+        x [1, l, d_model], pos [1, l] from 0."""
+        q, k, v = self.project(x, pos)
+        return self.blocks(q[0], k[0], v[0], 0, pos[0])[None]
+
+    def window(self, x, n_valid, index, cache):
+        """One window of one row.  x [1, P, d_model] at positions
+        ``index * P + [0, P)``, of which the first ``n_valid`` are the
+        prompt's; ``cache`` the layer's two arrays, one row.  What a full
+        layer writes past the prompt's end is masked or rewritten by the
+        decode steps that follow; a ring takes the valid keys only,
+        because a position past the end would lie over one that the
+        steps still see."""
+        p = x.shape[1]
+        start = index * p
+        q, k, v = self.project(x, start + jnp.arange(p)[None])
+        if self.full:
+            put = lambda a, new: jax.lax.dynamic_update_slice_in_dim(
+                a, new, start, axis=2)
+            cache = {"full_k": put(cache["full_k"], k),
+                     "full_v": put(cache["full_v"], v)}
+            out = self.blocks(
+                q[0], cache["full_k"][0], cache["full_v"][0], start,
+                jnp.arange(cache["full_k"].shape[2]))
+            return out[None], cache
+        w = self.cfg.window_size
+        # The window's own keys, then the ring as it was: entry j holds
+        # the last position before ``start`` that lies at j, and until
+        # the ring has wrapped only its first ``start`` entries hold one.
+        at = jnp.arange(w)
+        held = jnp.concatenate([
+            start + jnp.arange(p), start - 1 - (start - 1 - at) % w])
+        keys = jnp.concatenate([k[0], cache["ring_k"][0]], 1)
+        values = jnp.concatenate([v[0], cache["ring_v"][0]], 1)
+        out = self.blocks(
+            q[0], keys, values, start, held, p + jnp.minimum(start, w))
+        valid = (jnp.arange(p) < n_valid)[None, None, :, None]
+
+        def put(ring, new):
+            old = jax.lax.dynamic_slice_in_dim(ring, start % w, p, axis=2)
+            return jax.lax.dynamic_update_slice_in_dim(
+                ring, jnp.where(valid, new, old), start % w, axis=2)
+
+        return out[None], {"ring_k": put(cache["ring_k"], k),
+                           "ring_v": put(cache["ring_v"], v)}
+
+    def step(self, x, pos, cache, klen: int):
+        """One token per row.  x [b, d_model], pos [b]; cache leaves
+        [slots, kv, entries, d] with ``slots >= b``: rows ``[0, b)`` are
+        written at their own positions where they lie; a full layer
+        attends over its first ``klen`` positions, a window layer over
+        the ring."""
+        c = self.cfg
+        b = x.shape[0]
+        q, k, v = self.project(x[:, None], pos[:, None])
+        names = ("full_k", "full_v") if self.full else ("ring_k", "ring_v")
+        ck, cv = cache[names[0]], cache[names[1]]
+        entries = klen if self.full else c.window_size
+        at = pos if self.full else pos % c.window_size
+        # One write per row, not a scatter over rows: for a scatter the
+        # chip's compiler copies the whole array into another layout.
+        for r in range(b):
+            ck = jax.lax.dynamic_update_slice(ck, k[r][None], (r, 0, at[r], 0))
+            cv = jax.lax.dynamic_update_slice(cv, v[r][None], (r, 0, at[r], 0))
+        j = jnp.arange(entries)[None, :]
+        # the position each entry holds once this step's is written
+        u = j if self.full else pos[:, None] - (pos[:, None] - j) % entries
+        ok = self.sees(pos[:, None], u)[:, None, None, :]
+        with jax.named_scope(self.span):
+            f32 = dict(preferred_element_type=jnp.float32)
+            score = jnp.einsum(
+                "bkgd,bksd->bkgs", q[:, :, :, 0], ck[:b, :, :entries], **f32)
+            p = jax.nn.softmax(jnp.where(ok, score, NEG_INF), -1)
+            out = jnp.einsum(
+                "bkgs,bksd->bkgd", p.astype(c.dtype), cv[:b, :, :entries],
+                **f32)
+        out = self.o_proj(out.reshape(b, -1).astype(c.dtype))
+        return out, {names[0]: ck, names[1]: cv}
+
+
+class CommandABlock(nn.Module):
+    cfg: CommandAConfig
+    full: bool
+
+    def setup(self):
+        c = self.cfg
+        self.norm = LayerNorm(c.layer_norm_eps, c.param_dtype, name="norm")
+        self.attn = GroupedAttention(c, self.full, name="attn")
+        self.ffn = RoutedExperts(c, name="ffn")
+
+    def _both(self, h, x, a):
+        """-> the stream with both branches added, and which held experts
+        each token chose."""
+        m, picked = self.ffn(x.reshape(-1, x.shape[-1]))
+        h = h + a.astype(jnp.float32) + m.reshape(x.shape)
+        return h, picked.reshape(x.shape[:-1] + (-1,))
+
+    def whole(self, h, pos):
+        x = self.norm(h)
+        return self._both(h, x, self.attn.whole(x, pos))[0]
+
+    def window(self, h, n_valid, index, cache):
+        x = self.norm(h)
+        a, cache = self.attn.window(x, n_valid, index, cache)
+        return self._both(h, x, a)[0], cache
+
+    def step(self, h, pos, cache, klen: int):
+        x = self.norm(h)
+        a, cache = self.attn.step(x, pos, cache, klen)
+        h, picked = self._both(h, x, a)
+        return h, cache, picked
+
+
+class CommandA(nn.Module):
+    """batch {inputs [b, l]} -> logits [b, l, vocab]."""
+
+    cfg: CommandAConfig
+
+    def setup(self):
+        c = self.cfg
+        self.embed = nn.Embed(
+            c.vocab_size, c.d_model, param_dtype=c.param_dtype, name="embed")
+        self.blocks = [
+            CommandABlock(c, c.is_full(i), name=f"layer_{i}")
+            for i in range(c.n_layers)
+        ]
+        self.final_norm = LayerNorm(
+            c.layer_norm_eps, c.param_dtype, name="final_norm")
+
+    def blank_cache(self, batch: int, positions: int):
+        """Per window layer the ring's keys and values, per full layer
+        every position's: ``[batch, n_kv_heads, entries, head_dim]``."""
+        c = self.cfg
+        array = lambda n: jnp.zeros(
+            (batch, c.n_kv_heads, n, c.head_dim), c.dtype)
+        return {
+            f"layer_{i}": (
+                {"full_k": array(positions), "full_v": array(positions)}
+                if c.is_full(i) else
+                {"ring_k": array(c.window_size),
+                 "ring_v": array(c.window_size)})
+            for i in range(c.n_layers)
+        }
+
+    def head_logits(self, h):
+        """Float32 logits over the embedding's own rows (tied): the
+        product in the compute dtype, accumulated and handed out in
+        float32."""
+        c = self.cfg
+        return c.logit_scale * jnp.einsum(
+            "...d,vd->...v", self.final_norm(h).astype(c.dtype),
+            self.embed.embedding.astype(c.dtype),
+            preferred_element_type=jnp.float32)
+
+    def prefill_window(self, tokens, n_valid, index, cache):
+        """One window of a prompt: ``n_valid`` of the ``P`` tokens count.
+        -> the row's cache and the logits [1, vocab] at the last valid
+        position (the prompt's first new token when this is its last
+        window)."""
+        h = self.embed(tokens).astype(jnp.float32)
+        new = {}
+        for i, block in enumerate(self.blocks):
+            h, new[f"layer_{i}"] = block.window(
+                h, n_valid, index, cache[f"layer_{i}"])
+        last = jax.lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
+        return new, self.head_logits(last[:, 0])
+
+    def decode_step(self, tok, pos, cache, klen: int):
+        """tok, pos [b] -> cache, logits [b, vocab], and which held
+        experts each row chose, [b, n_layers * experts_held], layer by
+        layer."""
+        h = self.embed(tok).astype(jnp.float32)
+        new, picked = {}, []
+        for i, block in enumerate(self.blocks):
+            h, new[f"layer_{i}"], chose = block.step(
+                h, pos, cache[f"layer_{i}"], klen)
+            picked.append(chose)
+        return new, self.head_logits(h), jnp.concatenate(picked, -1)
+
+    def __call__(self, batch: Dict[str, Any], *, deterministic: bool = True):
+        inputs = jnp.asarray(batch["inputs"], jnp.int32)
+        b, n = inputs.shape
+        # whole key blocks: a sequence longer than one is padded to them
+        padded = n if n <= KEY_BLOCK else -(-n // KEY_BLOCK) * KEY_BLOCK
+        inputs = jnp.pad(inputs, ((0, 0), (0, padded - n)))
+        pos = jnp.arange(padded)[None]
+        rows = []
+        for r in range(b):
+            h = self.embed(inputs[r:r + 1]).astype(jnp.float32)
+            for block in self.blocks:
+                h = block.whole(h, pos)
+            rows.append(h[:, :n])
+        return self.head_logits(jnp.concatenate(rows, 0))
+
+
+def build_command_a_model(hparams: Dict, mesh=None) -> CommandA:
+    """``hparams``: fields of ``CommandAConfig`` (the published model
+    where left out), ``compute_dtype`` and ``param_dtype``; other keys
+    (the names a driver reads, such as ``d_ff``) are passed over."""
+    cfg = config_from(CommandAConfig, hparams)
+    if not 0 <= cfg.expert_offset <= cfg.n_experts - cfg.experts_held:
+        raise ValueError(
+            "the experts held must lie inside the router's outputs")
+    if cfg.n_heads % cfg.n_kv_heads:
+        raise ValueError("n_heads must be a multiple of n_kv_heads")
+    return CommandA(cfg)
+
+
+def make_continuous_decode_fns(
+    model: CommandA,
+    *,
+    max_decode_len: int = 32,
+    eos_id: int = 1,
+    pad_id: int = 0,
+    max_input_len: int = 64,
+    prefill_window_len: int = 512,
+):
+    """The decode contract of serving/generative.py for a decoder-only
+    model whose layers keep caches of TWO kinds in one arena.
+
+    As the contract of models/pangu_moe.py (``prefill_window``,
+    ``blank_cache``, ``cache_positions``, ``first_decode_pos``, no
+    encoder rows, ``step_tally_len``), and:
+
+      - ``window``, ``CacheKind(by_position=False)``: a window layer's
+        ring of ``window_size`` entries, written at ``pos %
+        window_size``, valid by mask.  It wraps while a prompt longer
+        than the window is prefilled and again while decoding.
+      - ``full``, ``CacheKind(by_position=True)``: a full layer's keys
+        and values at every position from the prompt's first token on;
+        ``step`` attends over the first ``klen`` of them.
+      - both worked on in place, both ``[slots, n_kv_heads, entries,
+        head_dim]``: the engine only ever indexes the slot axis of an
+        array it hands over whole.
+      - ``prefill_window_len`` divides ``window_size``: a window's keys
+        lie in the ring without a wrap inside them.
+      - ``step_account(positions, tally, bucket)``: per kind the entries
+        and bytes that are valid for the live rows, and the bytes that
+        the arrays of the step's ``(rows, klen)`` bucket span.
+    """
+    from tpu_pipelines.serving.generative import CacheKind
+
+    c = model.cfg
+    p, w = int(prefill_window_len), c.window_size
+    if w % p:
+        raise ValueError("prefill_window_len must divide window_size")
+    span = -(-int(max_input_len) // p) * p
+    positions = max(span, int(max_input_len) + int(max_decode_len))
+    n_full = sum(c.is_full(i) for i in range(c.n_layers))
+    n_ring = c.n_layers - n_full
+    entry_bytes = (
+        2 * c.n_kv_heads * c.head_dim * jnp.dtype(c.dtype).itemsize)
+    held = c.experts_held
+
+    def prefill_window(params, cache, tokens, n_valid, index):
+        return model.apply(
+            {"params": params}, tokens, n_valid, index, cache,
+            method=CommandA.prefill_window)
+
+    def step(params, cache, tok, pos, encoded, enc_mask, klen: int):
+        return model.apply(
+            {"params": params}, tok, pos, cache, klen,
+            method=CommandA.decode_step)
+
+    def blank_cache(batch: int):
+        return model.blank_cache(batch, positions)
+
+    def cache_kind_of(path) -> str:
+        leaf = str(getattr(path[-1], "key", path[-1]))
+        return "window" if leaf.startswith("ring") else "full"
+
+    def step_account(at, tally, bucket):
+        """``at``: the live rows' positions; ``tally``: assignments to
+        each held expert, layer by layer; ``bucket``: the step's rows
+        and positions."""
+        rows, klen = bucket
+        entries = {
+            "window": n_ring * sum(min(t + 1, w) for t in at),
+            "full": n_full * sum(t + 1 for t in at)}
+        return {
+            "cache_entries": entries,
+            "cache_bytes": {k: n * entry_bytes for k, n in entries.items()},
+            "cache_span_bytes": {
+                "window": n_ring * rows * w * entry_bytes,
+                "full": n_full * rows * klen * entry_bytes},
+            "window_rollovers": sum(t % w == 0 for t in at),
+            **tally_account(tally, held)}
+
+    return SimpleNamespace(
+        step=step,
+        step_tally_len=c.n_layers * held,
+        prefill_window=prefill_window,
+        prefill_window_len=p,
+        blank_cache=blank_cache,
+        cache_positions=positions,
+        cache_kinds={
+            "window": CacheKind(False, written=True, in_place=True),
+            "full": CacheKind(True, written=True, in_place=True)},
+        cache_kind_of=cache_kind_of,
+        first_decode_pos=lambda input_mask: jnp.sum(
+            jnp.asarray(input_mask, jnp.int32)),
+        encoded_shape=(0,),
+        step_account=step_account,
+        max_decode_len=int(max_decode_len),
+        eos_id=int(eos_id),
+        pad_id=int(pad_id),
+        max_input_len=int(max_input_len),
+    )

@@ -463,9 +463,10 @@ def make_continuous_decode_fns(
         would cost more than the step);
       - ``first_decode_pos(input_mask)``: a sequence's first decode
         position is its prompt's length; ``encoded`` has no rows;
-      - ``step_account(positions, tally)``: what one step over rows at
-        these positions reads of each kind and which events it holds,
-        for the telemetry (``tally`` is empty: the step hands none back).
+      - ``step_account(positions, tally, bucket)``: what one step over
+        rows at these positions reads of each kind and which events it
+        holds, for the telemetry (``tally`` is empty: the step hands none
+        back; ``bucket``, the step's rows and positions, is not read).
     """
     from tpu_pipelines.serving.generative import CacheKind
 
@@ -491,7 +492,7 @@ def make_continuous_decode_fns(
         leaf = str(getattr(path[-1], "key", path[-1]))
         return "window" if leaf.startswith("window") else "chunk"
 
-    def step_account(positions, tally=()):
+    def step_account(positions, tally=(), bucket=None):
         ring = sum(t % w + 1 for t in positions)
         table = sum((t // w) * (w // c) for t in positions)
         return {

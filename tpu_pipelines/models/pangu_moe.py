@@ -80,6 +80,7 @@ class PanguConfig:
     expert_offset: int = 0
     experts_per_token: int = 8
     n_shared_experts: int = 1
+    shared_average: bool = False
     routed_scaling_factor: float = 2.5
     rope_theta: float = 25600000.0
     rms_norm_eps: float = 1e-5
@@ -101,6 +102,14 @@ ROW_TILE = 32
 # the expert width and of the one out of it, (tk, tn): 3 MB each, so that a
 # tile's read, not the grid's turn-over, is what a step of the kernel costs.
 TILE_IN, TILE_OUT = (768, 2048), (2048, 768)
+# The tile of a product by its weights' shape ``(k, n)``, where a sweep on
+# the chip found one (openPangu's 7,680 x 2,048 experts: the two above;
+# Command A+'s 4,096 x 4,096: PERF.md section 6, PR 35); ``TILE_IN``
+# where none was swept.
+TILES = {
+    (7680, 2048): TILE_IN, (2048, 7680): TILE_OUT,
+    (4096, 4096): (4096, 512),
+}
 
 
 def grouped_product(rows, weights, sizes, tile):
@@ -263,11 +272,19 @@ class LatentAttention(nn.Module):
 
 
 class RoutedExperts(nn.Module):
-    """The shared expert and this chip's share of the routed ones.
+    """The shared experts and this chip's share of the routed ones.
     x [n, d_model] -> (y [n, d_model], picked [n, experts_held]): the
-    layer's partial output, and which held experts each token chose."""
+    layer's partial output, and which held experts each token chose.
 
-    cfg: PanguConfig
+    Shared by models/command_a.py.  What it reads of ``cfg``, all of it
+    data: ``d_model``, ``d_expert``, ``n_experts`` (the router's outputs),
+    ``experts_held`` from ``expert_offset``, ``experts_per_token``,
+    ``routed_scaling_factor`` (1 where the source has none),
+    ``n_shared_experts`` as ONE gated MLP of their joint width, whose
+    output is their sum, or with ``shared_average`` their mean; ``dtype``
+    and ``param_dtype``."""
+
+    cfg: Any
 
     def setup(self):
         c = self.cfg
@@ -317,18 +334,23 @@ class RoutedExperts(nn.Module):
             order = jnp.argsort(group)
             sizes = jnp.sum(picked, 0)
             xs = x[jnp.minimum(order // k, x.shape[0] - 1)]
-            grouped = lambda rows, w, tile: grouped_product(
-                rows, w.astype(c.dtype), sizes, tile)
-            hidden = jax.nn.silu(grouped(xs, self.experts_gate, TILE_IN)) \
-                * grouped(xs, self.experts_up, TILE_IN)
-            ys = grouped(hidden.astype(c.dtype), self.experts_down, TILE_OUT)
+            grouped = lambda rows, w: grouped_product(
+                rows, w.astype(c.dtype), sizes,
+                TILES.get(w.shape[1:], TILE_IN))
+            hidden = jax.nn.silu(grouped(xs, self.experts_gate)) \
+                * grouped(xs, self.experts_up)
+            ys = grouped(hidden.astype(c.dtype), self.experts_down)
             # Back in the order of the pairs, each times its weight; a row
             # outside every group holds whatever the product left there.
             ys = ys[jnp.argsort(order)[:held.size]].reshape(
                 x.shape[0], k, -1)
             routed = jnp.sum(
                 jnp.where(held[..., None], ys * weights[..., None], 0.0), 1)
-        return self.shared(x).astype(jnp.float32) + routed, picked
+        with jax.named_scope("moe.shared"):
+            shared = self.shared(x).astype(jnp.float32)
+            if c.shared_average:
+                shared = shared / c.n_shared_experts
+        return shared + routed, picked
 
 
 class PanguBlock(nn.Module):
@@ -467,19 +489,43 @@ class PanguMoE(nn.Module):
         return logits, self.head_logits(h2)
 
 
-def build_pangu_moe_model(hparams: Dict, mesh=None) -> PanguMoE:
-    """``hparams``: fields of ``PanguConfig`` (the published model where
-    left out), ``compute_dtype`` and ``param_dtype``; other keys (the
-    names a driver reads, such as ``head_dim``) are passed over."""
+def config_from(cls, hparams: Dict):
+    """``cls`` (a dataclass of widths and counts with ``dtype`` and
+    ``param_dtype``) from the keys of ``hparams`` that are its fields, each
+    as its field's type, and from ``compute_dtype`` / ``param_dtype``;
+    other keys are passed over."""
     hp = dict(hparams or {})
-    fields = {f.name: f.type for f in dataclasses.fields(PanguConfig)}
-    cfg = PanguConfig(
-        **{k: (float if fields[k] == "float" else int)(v)
-           for k, v in hp.items()
+    kinds = {"float": float, "bool": bool}
+    fields = {f.name: kinds.get(f.type, int) for f in dataclasses.fields(cls)}
+    return cls(
+        **{k: fields[k](v) for k, v in hp.items()
            if k in fields and k not in ("dtype", "param_dtype")},
         dtype=jnp.dtype(hp.get("compute_dtype", "bfloat16")),
         param_dtype=jnp.dtype(hp.get("param_dtype", "bfloat16")),
     )
+
+
+def tally_account(tally, held: int) -> Dict[str, Any]:
+    """What a step's tally says of the experts held: ``tally`` is the
+    live rows' assignments to each held expert, expert layer by expert
+    layer, ``held`` experts a layer."""
+    layers = [tally[i:i + held] for i in range(0, len(tally), held)]
+    busy = [t for t in layers if sum(t)]
+    return {
+        "expert_assignments": int(sum(tally)),
+        "experts_touched": sum(n > 0 for n in tally),
+        # the fullest held expert over the mean, expert layers averaged
+        "expert_load_ratio": (
+            sum(max(t) * held / sum(t) for t in busy) / len(busy)
+            if busy else None),
+    }
+
+
+def build_pangu_moe_model(hparams: Dict, mesh=None) -> PanguMoE:
+    """``hparams``: fields of ``PanguConfig`` (the published model where
+    left out), ``compute_dtype`` and ``param_dtype``; other keys (the
+    names a driver reads, such as ``head_dim``) are passed over."""
+    cfg = config_from(PanguConfig, hparams)
     if not 0 <= cfg.expert_offset <= cfg.n_experts - cfg.experts_held:
         raise ValueError(
             "the experts held must lie inside the router's outputs")
@@ -512,7 +558,7 @@ def make_continuous_decode_fns(
       - ``step`` returns a third value, ``[b, step_tally_len]`` int32:
         per row, which held experts it chose in each expert layer.  The
         engine sums it over the live rows and hands the sums to
-        ``step_account(positions, tally)``.
+        ``step_account(positions, tally, bucket)``.
     """
     from tpu_pipelines.serving.generative import CacheKind
 
@@ -536,20 +582,13 @@ def make_continuous_decode_fns(
     def blank_cache(batch: int):
         return model.blank_cache(batch, positions)
 
-    def step_account(at, tally):
+    def step_account(at, tally, bucket=None):
         """``at``: the live rows' positions; ``tally``: assignments to
-        each held expert, layer by layer."""
-        layers = [
-            tally[i * held:(i + 1) * held] for i in range(expert_layers)]
-        busy = [t for t in layers if sum(t)]
+        each held expert, layer by layer (``bucket``, the step's rows
+        and positions, is not read)."""
         return {
             "cache_bytes": {"latent": sum(t + 1 for t in at) * row_bytes},
-            "expert_assignments": int(sum(tally)),
-            # the fullest held expert over the mean, expert layers averaged
-            "expert_load_ratio": (
-                sum(max(t) * held / sum(t) for t in busy) / len(busy)
-                if busy else None),
-        }
+            **tally_account(tally, held)}
 
     return SimpleNamespace(
         step=step,

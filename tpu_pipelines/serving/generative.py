@@ -20,7 +20,9 @@ static-shape substrate):
     length; a windowed decoder's ring of exact positions beside its
     table of chunk summaries, neither indexed by decode position; a
     latent-attention decoder's one row of latents a position, indexed
-    by position from the prompt's first token on (``cache_positions``).
+    by position from the prompt's first token on (``cache_positions``);
+    a decoder whose layers differ in kind, rings of a window's keys and
+    values in three layers of four and every position's in the fourth.
     Live sequences occupy the compacted prefix ``[0, n_live)``; a
     departure moves the last live
     row into the hole (one scatter), an arrival lands at ``n_live`` (one
@@ -210,7 +212,10 @@ class CacheKind(NamedTuple):
     ``by_position``: axis 1 is the decode position.  A step's
     ``(b, kv)`` bucket is then the first ``kv`` entries of the first
     ``b`` rows, and entries at or past a row's position hold nothing
-    (nothing wrote them).  Otherwise what is valid in a row is the
+    (nothing wrote them).  (An ``in_place`` array is never cut: the
+    engine indexes its slot axis alone, ``kv`` reaches the step as a
+    number, and the axes behind the slot may lie as the step reads
+    them.)  Otherwise what is valid in a row is the
     contract's own business, and a step is handed its ``b`` rows whole.
     ``written``: a step returns the array changed, and the engine sets it
     back into the arena; otherwise a step only reads it.
@@ -1535,10 +1540,11 @@ class GenerativeEngine:
             )
             if self._account is not None:
                 # What this step read of each kind of cache, by the
-                # contract's own account of the rows' positions and of
-                # the step's tally (empty where it hands none back).
-                self.telemetry.on_cache(
-                    self._account(flight.positions, toks[b:].tolist()))
+                # contract's own account of the rows' positions, of the
+                # step's tally (empty where it hands none back) and of
+                # the bucket it ran in.
+                self.telemetry.on_cache(self._account(
+                    flight.positions, toks[b:].tolist(), (b, kv)))
             now = time.monotonic()
             wasted = 0
             for slot in range(n - 1, -1, -1):
@@ -1684,7 +1690,8 @@ class DecodeTelemetry:
         self._prefill_tokens = self._prefill_windows = None
         self._rollovers = self._summaries = None
         self._cache_bytes = self._cache_read = None
-        self._expert_assignments = None
+        self._cache_entries = self._cache_span = None
+        self._expert_assignments = self._experts_touched = None
         self._expert_load_sum = self._expert_load_count = None
         if registry is None:
             return
@@ -1826,10 +1833,30 @@ class DecodeTelemetry:
             "serving_decode_cache_bytes summed over the decode steps run.",
             labels=kind_lab,
         )
+        self._cache_entries = registry.gauge(
+            "serving_decode_cache_entries",
+            "Entries of each kind of cache that are valid for the live "
+            "rows of the most recent decode step, layers summed (a "
+            "contract whose layers differ in kind).", labels=kind_lab,
+        )
+        self._cache_span = registry.counter(
+            "serving_decode_cache_span_bytes_total",
+            "Bytes that the arrays of each kind span in the decode steps' "
+            "(rows, positions) buckets, summed over the steps run: what a "
+            "step that reads its arrays whole reads, beside "
+            "serving_decode_cache_read_bytes_total, what is valid.",
+            labels=kind_lab,
+        )
         self._expert_assignments = registry.counter(
             "serving_decode_expert_assignments_total",
             "Assignments of live rows to the experts this replica holds, "
             "summed over expert layers and decode steps.", labels=lab,
+        ).labels(self.replica)
+        self._experts_touched = registry.counter(
+            "serving_decode_experts_touched_total",
+            "Held experts with at least one assignment in a decode step "
+            "(the experts whose weights that step must read), summed over "
+            "expert layers and decode steps.", labels=lab,
         ).labels(self.replica)
         self._expert_load_sum = registry.counter(
             "serving_decode_expert_load_ratio_sum",
@@ -1912,9 +1939,14 @@ class DecodeTelemetry:
         for kind, n_bytes in account["cache_bytes"].items():
             self._cache_bytes.labels(self.replica, kind).set(n_bytes)
             self._cache_read.labels(self.replica, kind).inc(n_bytes)
+        for kind, n in account.get("cache_entries", {}).items():
+            self._cache_entries.labels(self.replica, kind).set(n)
+        for kind, n_bytes in account.get("cache_span_bytes", {}).items():
+            self._cache_span.labels(self.replica, kind).inc(n_bytes)
         self._rollovers.inc(account.get("window_rollovers", 0))
         self._summaries.inc(account.get("chunk_summaries", 0))
         self._expert_assignments.inc(account.get("expert_assignments", 0))
+        self._experts_touched.inc(account.get("experts_touched", 0))
         ratio = account.get("expert_load_ratio")
         if ratio is not None:
             self._expert_load_sum.inc(ratio)
