@@ -656,3 +656,68 @@ def test_window_and_full_step_keeps_caches_and_weights_where_they_lie_on_v5e(
         assert not moved, (weight, len(moved), moved[:1])
     # the step's tally rides behind its tokens: one result of 32 + 4 x 16
     assert f"s32[{rows + 64}]" in text
+
+
+def test_prefill_window_holds_its_scores_in_the_kernel_on_v5e(
+        one_chip, monkeypatch):
+    """The engine's window program for the same contract at the cell's
+    shapes: 512 positions of one prompt (8 key/value heads x 16 query
+    heads of 128) against the row being built, three rings of 4,096 and a
+    by-position array of 18,432.  Each layer's attention is ONE Pallas
+    kernel (ops/flash_attention.py ``grouped_attention``) beside the three
+    grouped products of its expert layer: the chip's compiler takes its
+    tiles and its share of the fast memory, no float32 block of scores
+    (8 key/value heads x 16 x 256 queries x a block of keys: the loop body
+    of the ``jnp`` blocks that the kernel took the place of) is left in
+    the program, and the row's arrays are donated, not copied.  The
+    program picks its kernels by the backend, which is the CPU here: the
+    test tells it the chip's."""
+    from tpu_pipelines.models import command_a as ca
+    from tpu_pipelines.serving import generative as gen
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    positions, p = 18432, 512
+    model = ca.build_command_a_model(dict(
+        vocab_size=32768, n_layers=4, experts_held=16))
+    fns = ca.make_continuous_decode_fns(
+        model, max_decode_len=2048, eos_id=32768, max_input_len=16384,
+        prefill_window_len=p)
+    assert fns.cache_positions == positions
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), {"inputs": jnp.zeros((1, 8), jnp.int32)})["params"])
+
+    def prefill_window(params, row_cache, tokens, n_valid, index):
+        row_cache, logits = fns.prefill_window(
+            params, row_cache, tokens, n_valid, index)
+        return row_cache, jnp.argmax(logits[0], -1).astype(jnp.int32)
+
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+    scalar = _sds((), jnp.int32, one_chip)
+    compiled = gen._jit_program(prefill_window).lower(
+        on_chip(params), on_chip(jax.eval_shape(lambda: fns.blank_cache(1))),
+        _sds((1, p), jnp.int32, one_chip), scalar, scalar).compile()
+    m = _fits(compiled)
+    assert m.temp_size_in_bytes < 256 * 1024 ** 2
+    text = compiled.as_text()
+    kernels = re.findall(r"custom_call_target=\"tpu_custom_call\".*", text)
+    assert sum("grouped_attention" in k for k in kernels) == 4
+    assert len(kernels) == 4 + 12      # and a layer's three grouped products
+    # what is left in float32 over 8 key/value heads x thousands of rows
+    # is a head's 128 numbers wide, not a block of keys
+    assert set(re.findall(r"f32\[8,\d{4,},(\d{3,})\]", text)) <= {"128"}
+    assert not re.findall(r"f32\[8,16,\d{3,},(?:[5-9]\d\d|\d{4,})\]", text)
+    # no loop under an attention's scope (the grouped products keep theirs)
+    assert not re.findall(r" while\(.*attn\.(?:window|full)", text)
+    param_layouts, result_layouts, aliases = _entry_layouts(text)
+    result_of = {param: out for out, param in aliases.items()}
+    for leaf, count in ((f"bf16[1,8,{positions},128]", 2),
+                        ("bf16[1,8,4096,128]", 6)):
+        leaves = [
+            i for i, s in enumerate(param_layouts) if s.startswith(leaf)]
+        assert len(leaves) == count
+        for i in leaves:
+            assert result_layouts[result_of[i]] == param_layouts[i]
+        moved = re.findall(
+            rf"= {re.escape(leaf)}\S* (?:copy|transpose|scatter)\(.*", text)
+        assert not moved, (len(moved), moved[:2])

@@ -44,12 +44,21 @@ entries and the head's numbers as the matrix, as the arrays lie; with
 the heads behind the entries the chip pads 8 heads to 16.
 
 Entry points: ``prefill_window`` (one window of a prompt against a row's
-cache: the full layers attend over the cache by blocks of keys, as far
-as the window reaches; the window layers over the ring as it was plus
-the window's own keys, and then write the window's VALID keys into the
-ring, which wraps while a long prompt is prefilled), ``decode_step`` (one
-token per live row, each at its own position) and ``__call__`` (a whole
-sequence, no cache: what the tests compare with the plain reference).
+cache: the full layers write the window's keys and attend over the array
+by position; the window layers over the window's own keys plus the ring
+as it was, and then write the window's VALID keys into the ring, which
+wraps while a long prompt is prefilled), ``decode_step`` (one token per
+live row, each at its own position) and ``__call__`` (a whole sequence,
+no cache: what the tests compare with the plain reference).
+
+A window's and a whole sequence's attention is ONE Pallas kernel a layer
+(``ops/flash_attention.py grouped_attention``; interpreted off the chip):
+the 16 query heads of a key/value head share each block of keys it
+fetches, a block's scores and the running softmax stay in the chip's fast
+memory, the mask is ``GroupedAttention.sees`` over the position each entry
+holds (a ring as it lies, an array whose tail is not yet written), and a
+block of entries that no query of a block sees is neither fetched nor
+computed.
 """
 
 from __future__ import annotations
@@ -65,6 +74,7 @@ from flax import linen as nn
 from tpu_pipelines.models.evabyte import NEG_INF
 from tpu_pipelines.models.pangu_moe import (
     RoutedExperts, config_from, tally_account)
+from tpu_pipelines.ops.flash_attention import grouped_attention
 
 
 @dataclasses.dataclass(frozen=True)
@@ -99,17 +109,6 @@ class CommandAConfig:
         return layer % self.full_every == self.full_every - 1
 
 
-# Queries (positions) and keys a block of the blocked attention holds at
-# most: the float32 scores of a block are ``n_heads * QUERY_BLOCK *
-# KEY_BLOCK * 4`` bytes, 134 MB at 128 heads.
-QUERY_BLOCK, KEY_BLOCK = 256, 1024
-
-
-def block_of(n: int, most: int) -> int:
-    """The largest block of at most ``most`` that divides ``n``."""
-    return next(b for b in range(min(n, most), 0, -1) if n % b == 0)
-
-
 def rope_interleaved(x, pos, theta: float):
     """Rotary position code in float32 by interleaved pairs: element
     ``2j`` with ``2j + 1``.  x [b, l, ..., d], pos [b, l].  The partner of
@@ -140,39 +139,6 @@ class LayerNorm(nn.Module):
         x = x - jnp.mean(x, axis=-1, keepdims=True)
         var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
         return x * jax.lax.rsqrt(var + self.eps) * g.astype(jnp.float32)
-
-
-def blocked_attention(q, k, v, ok_of, n_blocks, kb: int, dtype):
-    """Attention with an online softmax over ``n_blocks`` (a number or a
-    traced scalar) blocks of ``kb`` keys.  q [h, r, d] scaled, k and v
-    [h, s, d]; ``ok_of(j)`` -> [r, kb] bool, which keys of block ``j``
-    each row sees; every row sees a key in block 0.
-    -> [h, r, d] float32."""
-    f32 = dict(preferred_element_type=jnp.float32)
-
-    def body(j, carry):
-        top, total, acc = carry
-        kj = jax.lax.dynamic_slice_in_dim(k, j * kb, kb, 1)
-        vj = jax.lax.dynamic_slice_in_dim(v, j * kb, kb, 1)
-        ok = ok_of(j)[None]
-        score = jnp.where(
-            ok, jnp.einsum("hrd,hsd->hrs", q, kj, **f32), NEG_INF)
-        new_top = jnp.maximum(top, score.max(-1))
-        # A masked score is exp(-1e30 - top) = 0 once a row has seen a
-        # key; before that (``top`` still NEG_INF) what it adds is wiped
-        # by ``keep`` = 0 when the first key comes.
-        p = jnp.exp(score - new_top[..., None])
-        keep = jnp.exp(top - new_top)
-        return (
-            new_top, total * keep + p.sum(-1),
-            acc * keep[..., None] + jnp.einsum(
-                "hrs,hsd->hrd", p.astype(dtype), vj, **f32))
-
-    h, r, d = q.shape
-    top, total, acc = jax.lax.fori_loop(0, n_blocks, body, (
-        jnp.full((h, r), NEG_INF, jnp.float32),
-        jnp.zeros((h, r), jnp.float32), jnp.zeros((h, r, d), jnp.float32)))
-    return acc / total[..., None]
 
 
 class GroupedAttention(nn.Module):
@@ -218,12 +184,10 @@ class GroupedAttention(nn.Module):
         return (jnp.transpose(q, (0, 2, 3, 1, 4)), heads_first(k),
                 heads_first(v))
 
-    def merge(self, out, l: int):
-        """out [kv, g * l, d] -> the layer's output [l, d_model]."""
-        c = self.cfg
-        out = out.reshape(c.n_kv_heads, -1, l, c.head_dim)
-        out = jnp.transpose(out, (2, 0, 1, 3)).reshape(l, -1)
-        return self.o_proj(out.astype(c.dtype))
+    def merge(self, out):
+        """out [kv, g, l, d] -> the layer's output [l, d_model]."""
+        out = jnp.transpose(out, (2, 0, 1, 3)).reshape(out.shape[2], -1)
+        return self.o_proj(out.astype(self.cfg.dtype))
 
     def sees(self, t, u):
         """Whether the query at ``t`` sees the key at position ``u``
@@ -231,35 +195,18 @@ class GroupedAttention(nn.Module):
         ok = (u <= t) & (u >= 0)
         return ok if self.full else ok & (u > t - self.cfg.window_size)
 
-    def blocks(self, q, k, v, start, held, filled=None):
-        """One row's queries, a block of at most ``QUERY_BLOCK`` positions
-        at a time, over blocks of at most ``KEY_BLOCK`` keys.  q
+    def blocks(self, q, k, v, start, held):
+        """One row's queries over one row's entries, as ONE kernel
+        (ops/flash_attention.py ``grouped_attention``): the scores of a
+        block never leave the chip's fast memory, a block of keys is
+        fetched once for the ``g`` heads that share it, and one that no
+        query of a block sees is neither fetched nor computed.  q
         [kv, g, l, d] at positions ``start + [0, l)``; k and v [kv, s, d],
-        of which entry ``j`` holds position ``held[j]``.  Only the first
-        ``filled`` entries can hold a key that is seen; where None, the
-        entries lie by position, and a block of queries goes no further
-        than its own last position.  Every query sees a key among the
-        first ``kb`` entries.  -> [l, d_model]."""
-        kv, g, l, d = q.shape
-        qb, kb = block_of(l, QUERY_BLOCK), block_of(k.shape[1], KEY_BLOCK)
-
-        def one(i):
-            t = start + i * qb + jnp.arange(qb)
-            rows = jax.lax.dynamic_slice_in_dim(q, i * qb, qb, 2)
-            ok_of = lambda j: jnp.tile(self.sees(
-                t[:, None],
-                jax.lax.dynamic_slice_in_dim(held, j * kb, kb)[None, :]),
-                (g, 1))
-            out = blocked_attention(
-                rows.reshape(kv, g * qb, d), k, v, ok_of,
-                (t[-1] if filled is None else filled - 1) // kb + 1, kb,
-                self.cfg.dtype)
-            return out.reshape(kv, g, qb, d)
-
+        of which entry ``j`` holds position ``held[j]`` (negative: none).
+        Every query sees a key.  -> [l, d_model]."""
         with jax.named_scope(self.span):
-            out = jax.lax.map(one, jnp.arange(l // qb))     # [n, kv, g, qb, d]
-            out = jnp.transpose(out, (1, 2, 0, 3, 4)).reshape(kv, g * l, d)
-        return self.merge(out, l)
+            out = grouped_attention(q, k, v, held, start, self.sees)
+        return self.merge(out)
 
     def whole(self, x, pos):
         """A whole sequence of one row under the layer's mask, no cache.
@@ -289,15 +236,14 @@ class GroupedAttention(nn.Module):
             return out[None], cache
         w = self.cfg.window_size
         # The window's own keys, then the ring as it was: entry j holds
-        # the last position before ``start`` that lies at j, and until
-        # the ring has wrapped only its first ``start`` entries hold one.
+        # the last position before ``start`` that lies at j, a negative
+        # one (no key) until the ring has wrapped that far.
         at = jnp.arange(w)
         held = jnp.concatenate([
             start + jnp.arange(p), start - 1 - (start - 1 - at) % w])
         keys = jnp.concatenate([k[0], cache["ring_k"][0]], 1)
         values = jnp.concatenate([v[0], cache["ring_v"][0]], 1)
-        out = self.blocks(
-            q[0], keys, values, start, held, p + jnp.minimum(start, w))
+        out = self.blocks(q[0], keys, values, start, held)
         valid = (jnp.arange(p) < n_valid)[None, None, :, None]
 
         def put(ring, new):
@@ -444,16 +390,13 @@ class CommandA(nn.Module):
     def __call__(self, batch: Dict[str, Any], *, deterministic: bool = True):
         inputs = jnp.asarray(batch["inputs"], jnp.int32)
         b, n = inputs.shape
-        # whole key blocks: a sequence longer than one is padded to them
-        padded = n if n <= KEY_BLOCK else -(-n // KEY_BLOCK) * KEY_BLOCK
-        inputs = jnp.pad(inputs, ((0, 0), (0, padded - n)))
-        pos = jnp.arange(padded)[None]
+        pos = jnp.arange(n)[None]
         rows = []
         for r in range(b):
             h = self.embed(inputs[r:r + 1]).astype(jnp.float32)
             for block in self.blocks:
                 h = block.whole(h, pos)
-            rows.append(h[:, :n])
+            rows.append(h)
         return self.head_logits(jnp.concatenate(rows, 0))
 
 

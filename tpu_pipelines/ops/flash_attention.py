@@ -20,6 +20,13 @@ as (block_q, 128) lane-replicated tiles to satisfy TPU tiling.  Used by
 models/transformer.py when ``attn_impl="flash"``; ring attention
 (parallel/ring_attention.py) handles the sequence-parallel regime and
 composes the same math across chips.
+
+``grouped_attention`` (forward only, at the end of the file) is the same
+recurrence for a prefill window of a grouped-query decoder: the query
+heads of a key/value head share each block of keys a step fetches, and the
+mask is the caller's rule over the POSITION each entry holds (a ring as it
+lies, an array by position), so a block no query sees is skipped.
+models/command_a.py's prefill window and whole-sequence pass run it.
 """
 
 from __future__ import annotations
@@ -568,3 +575,175 @@ def flash_attention(
         q, k, v, jnp.asarray(kv_mask, jnp.int32), causal, block_q, block_k,
         bwd_block_q, bwd_block_k, interpret,
     )
+
+
+# ------------------------------------------------- grouped, by position
+
+# Queries of one head and keys that a step of ``grouped_attention``'s grid
+# holds at most, from a sweep on the chip at Command A+'s shapes (16 query
+# heads x 512 positions a key/value head against a window's own keys + a
+# ring, 4,608 entries, and against a by-position array of 18,432; PERF.md
+# section 6, PR 37): 512 queries fetch a block of keys once a window and
+# not twice; 256 keys a step cost twice the time (a step's turn-over is
+# then most of it), 1,024 and 2,048 hold more keys that no query sees and
+# do not divide the 4,608.
+GROUPED_BLOCK_Q, GROUPED_BLOCK_K = 512, 512
+# What the kernel may hold in VMEM: the g heads' float32 accumulator,
+# maximum and sum, their queries and results twice over (one block in use,
+# one in flight) and one head's scores; 128 MiB a core on v5e, 16 a kernel
+# unless it says so.
+_GROUPED_VMEM_BYTES = 64 * 1024 ** 2
+
+
+def _blocks_of(n: int, most: int, tile: int):
+    """-> (block, padded n): the fewest blocks of at most ``most`` that
+    hold ``n``, each a multiple of ``tile``."""
+    count = -(-n // most)
+    block = -(-n // (count * tile)) * tile
+    return block, count * block
+
+
+def _across(x, n: int):
+    """x [rows, LANES], every lane of a row the same -> [rows, n]."""
+    return x[:, :n] if n <= LANES else jnp.tile(x, (1, n // LANES))
+
+
+def _grouped_kernel(start_ref, fetch_ref, q_ref, k_ref, v_ref, held_ref,
+                    o_ref, acc_ref, m_ref, l_ref, *, sees):
+    g, block_q, d = q_ref.shape[1:]
+    block_k = k_ref.shape[1]
+    qi = pl.program_id(1)
+    kj = pl.program_id(2)
+    n_kv = pl.num_programs(2)
+
+    @pl.when(kj == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    # A step whose key block no query of the block sees was handed the
+    # last live block again (nothing is fetched) and computes nothing.
+    @pl.when(fetch_ref[qi * n_kv + kj] == kj)
+    def _step():
+        t = start_ref[0] + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+        # One mask for the g heads: a masked score is NEG_INF to the last
+        # bit (a score is far below float32's step there).
+        masked = jnp.where(sees(t, held_ref[...]), 0.0, NEG_INF)
+        k = k_ref[0]
+        v = v_ref[0]
+
+        # The heads one after another in ONE straight body, so that one
+        # head's products run beside another's softmax: 16 % less time
+        # than a rolled loop at 512 x 512 on the chip (PERF.md section 6,
+        # PR 37).
+        for i in range(g):
+            s = jax.lax.dot_general(                     # [bq, bk] on MXU
+                q_ref[0, i], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) + masked
+            m_prev = m_ref[i]                            # [bq, LANES]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # A masked score is exp(-1e30 - m) = 0 once a row has seen a
+            # key; before that (m still NEG_INF) what it adds is wiped by
+            # ``keep`` = 0 when the first key comes.
+            p = jnp.exp(s - _across(m_new, block_k))
+            keep = jnp.exp(m_prev - m_new)
+            l_ref[i] = l_ref[i] * keep + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[i] = acc_ref[i] * _across(keep, d) + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[i] = m_new
+
+    @pl.when(kj == n_kv - 1)
+    def _final():
+        for i in range(g):
+            o_ref[0, i] = (
+                acc_ref[i] / _across(l_ref[i], d)).astype(o_ref.dtype)
+
+
+def grouped_attention(q, k, v, held, start, sees):
+    """Attention of grouped query heads over entries that each hold a
+    stated position, masked by those positions alone (forward only).
+
+    ``q``: [kv_heads, g, l, head_dim], already scaled: the ``g`` query
+    heads of a key/value head, at positions ``start + [0, l)`` (``start``
+    a scalar, traced or not).  ``k``/``v``: [kv_heads, entries, head_dim].
+    ``held``: [entries] int32, the position each entry holds, negative
+    where it holds none.  ``sees(t, u)`` -> bool for int32 arrays that
+    broadcast: whether the query at ``t`` sees the key at position ``u``;
+    the caller's rule, traced into the kernel.  Where the entries lie does
+    not matter: a ring as it lies, wrapped or not, an array by position
+    with a tail not yet written, a window's own keys put in front.  Every
+    query sees at least one key.
+
+    Grid (key/value head, query block, key block), the key block the
+    sequential axis.  A step holds the ``g`` heads' ``block_q`` queries and
+    ONE block of keys and values, fetched once for all of them; each head's
+    [block_q, block_k] scores, their running maximum and sum and the
+    accumulator are float32 and never leave VMEM, the products take their
+    operands as they come (bfloat16 in, float32 out) and the weights enter
+    the second in ``v``'s dtype.  A key block that no query of a query
+    block sees (``sees`` over the pair, reduced outside the kernel and
+    handed over by scalar prefetch) is neither computed nor fetched: its
+    step's index map names the last live block again.  Any ``l`` and any
+    number of entries: blocks are the fewest of at most ``GROUPED_BLOCK_Q``
+    x ``GROUPED_BLOCK_K`` that hold them, and what they hold beyond is
+    padding at position -1.
+    -> [kv_heads, g, l, head_dim] in ``q``'s dtype.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    kv, g, l, d = q.shape
+    entries = k.shape[1]
+    # a block of queries tiles by sublanes (8 rows of 32 bits), a block of
+    # keys by the lanes of its scores and its positions
+    block_q, l_pad = _blocks_of(
+        l, GROUPED_BLOCK_Q, 32 // jnp.dtype(q.dtype).itemsize)
+    block_k, e_pad = _blocks_of(entries, GROUPED_BLOCK_K, LANES)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, l_pad - l), (0, 0)))
+    k, v = (jnp.pad(a, ((0, 0), (0, e_pad - entries), (0, 0)))
+            for a in (k, v))
+    held = jnp.pad(
+        held.astype(jnp.int32), (0, e_pad - entries), constant_values=-1)
+    start = jnp.asarray(start, jnp.int32)
+    n_q, n_kv = l_pad // block_q, e_pad // block_k
+    live = sees((start + jnp.arange(l_pad))[:, None], held[None, :]).reshape(
+        n_q, block_q, n_kv, block_k).any((1, 3))
+    last = jax.lax.cummax(
+        jnp.where(live, jnp.arange(n_kv, dtype=jnp.int32), -1), axis=1)
+    first = jnp.argmax(live, axis=1).astype(jnp.int32)[:, None]
+    fetch = jnp.where(last < 0, first, last).reshape(-1)
+
+    # the key block a step is handed: its own, or the last live one again
+    at = lambda i, j, fetch_ref: fetch_ref[i * n_kv + j]
+    q_spec = pl.BlockSpec(
+        (1, g, block_q, d), lambda h, i, j, *_: (h, 0, i, 0))
+    kv_spec = pl.BlockSpec(
+        (1, block_k, d),
+        lambda h, i, j, _, fetch_ref: (h, at(i, j, fetch_ref), 0))
+    held_spec = pl.BlockSpec(
+        (1, block_k), lambda h, i, j, _, fetch_ref: (0, at(i, j, fetch_ref)))
+    out = pl.pallas_call(
+        functools.partial(_grouped_kernel, sees=sees),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(kv, n_q, n_kv),
+            in_specs=[q_spec, kv_spec, kv_spec, held_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((g, block_q, d), jnp.float32),
+                pltpu.VMEM((g, block_q, LANES), jnp.float32),
+                pltpu.VMEM((g, block_q, LANES), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_GROUPED_VMEM_BYTES),
+        interpret=_resolve_interpret(None),
+        name="grouped_attention",
+    )(start.reshape(1), fetch, q, k, v, held[None, :])
+    return out[:, :, :l]
