@@ -14,6 +14,7 @@ from __future__ import annotations
 import importlib
 import threading
 import time
+import zlib
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -339,8 +340,12 @@ def check_served(ctx, checks, params, sample: List[Offered],
             raise RuntimeError(
                 f"request {item.request.index}: bad token stream")
         logits = passes["f32"](ref_params, inp, mask, tg)[:n]
-        gaps.append(np.asarray(
-            ref.token_gaps(logits, jnp.asarray(served))))
+        g = np.asarray(ref.token_gaps(logits, jnp.asarray(served)))
+        gaps.append(g)
+        say(f"  request {item.request.index}: prompt "
+            f"{len(item.request.prompt)}, served {n} (crc "
+            f"{zlib.crc32(served.tobytes())}), widest gap "
+            f"{float(g.max())!r}, mean {float(g.mean())!r}")
         for mode in low_gaps:
             low = passes[mode](ref_params, inp, mask, tg)[:n]
             first = jnp.argmax(low, axis=-1)
@@ -350,26 +355,62 @@ def check_served(ctx, checks, params, sample: List[Offered],
     allg = np.concatenate(gaps)
     say(f"check: {len(sample)} requests, {allg.size} served tokens, "
         f"{int((allg == 0).sum())} of them the reference's best")
-    say(f"  mean gap over the sample {float(allg.mean())!r} (not compared)")
-    checks.at_most("served_token_gap.widest", float(allg.max()),
-                   limits["served_token_gap.widest"])
+    compare_gaps(checks, "", allg, limits)
     for mode, parts in low_gaps.items():
-        g = np.concatenate(parts)
-        say(f"  control[{mode}] mean gap {float(g.mean())!r} (not compared)")
         control = harness.Checks()
-        control.at_most(f"control[{mode}].served_token_gap.widest",
-                        float(g.max()), limits["served_token_gap.widest"])
+        compare_gaps(control, f"control[{mode}].", np.concatenate(parts),
+                     limits)
         say(f"control[{mode}] correct: {control.ok}")
 
 
-def draw_sample(finished: List[Offered], seed: int, k: int) -> List[Offered]:
-    """``k`` finished requests drawn from the seed, the longest among
-    them."""
-    longest = max(finished, key=lambda o: (o.tokens, -o.request.index))
-    rest = [o for o in finished if o is not longest]
-    rng = np.random.default_rng([int(seed), 77])
-    picks = rng.choice(len(rest), size=min(k - 1, len(rest)), replace=False)
-    return [longest] + [rest[i] for i in sorted(picks)]
+# What ``check.limits`` of a configuration may name: a statistic of the
+# gaps of every served token of the sample.
+GAP_STATISTICS = {
+    "served_token_gap.widest": lambda g: float(g.max()),
+    "served_token_gap.mean": lambda g: float(g.mean()),
+    "served_token_gap.p99": lambda g: stats.percentile(g.tolist(), 99),
+}
+
+
+def compare_gaps(checks, prefix: str, gaps: np.ndarray,
+                 limits: Dict[str, float]) -> None:
+    """Hold every statistic that ``limits`` names to its limit, and print
+    the others beside them.  A name that is no statistic is an error."""
+    unknown = sorted(set(limits) - set(GAP_STATISTICS))
+    if unknown or not limits:
+        raise KeyError(
+            f"check.limits names {unknown}; the statistics of a served "
+            f"sample are {sorted(GAP_STATISTICS)}")
+    for name, statistic in GAP_STATISTICS.items():
+        value = statistic(gaps)
+        if name in limits:
+            checks.at_most(prefix + name, value, limits[name])
+        else:
+            say(f"  {prefix}{name}: {value!r} (not compared)")
+
+
+# The longest request of a sample is looked for among this share of the
+# indices offered: requests sent well before the window's end.
+WELL_INSIDE = 0.75
+
+
+def draw_sample(offered: List[Offered], finished: List[Offered], seed: int,
+                k: int) -> List[Offered]:
+    """``k`` of the ``finished`` requests, by request index: the one that
+    asked for the longest answer among the first three quarters of the
+    indices offered (the lowest index among equals), then the first
+    ``k - 1`` of an order over indices drawn from the seed.  The order
+    gives index ``i`` the same place whatever was offered or finished
+    behind it, so two runs on one seed sample the same requests unless one
+    of them finished a sampled request and the other did not."""
+    n = 1 + max(o.request.index for o in offered)
+    early = [o for o in finished if o.request.index < WELL_INSIDE * n]
+    longest = min(early or finished, key=lambda o: (
+        -o.request.max_new_tokens, o.request.index))
+    place = np.random.default_rng([int(seed), 77]).random(n)
+    rest = sorted((o for o in finished if o is not longest),
+                  key=lambda o: place[o.request.index])
+    return [longest] + rest[:k - 1]
 
 
 def run(ctx) -> Dict[str, Any]:
@@ -457,7 +498,11 @@ def run(ctx) -> Dict[str, Any]:
     del engine, built["engine"]      # the arena goes before the reference
     if finished:
         sample = draw_sample(
-            finished, ctx.seed, int(config["check"]["sample_requests"]))
+            load["offered"], finished, ctx.seed,
+            int(config["check"]["sample_requests"]))
+        say("check: sampled request indices",
+            [o.request.index for o in sample],
+            f"of {len(finished)} finished, {len(load['offered'])} offered")
         check_served(ctx, checks, built.pop("params"), sample, geometry)
     stage("served tokens compared with the reference")
 
@@ -470,6 +515,7 @@ def run(ctx) -> Dict[str, Any]:
             "setup_s": t_w0 - ctx.t_process_start,
         },
         "facts": facts, "memory_peak_bytes": peak_bytes,
+        "checks": checks.rows,
     }
     if device_trace is not None:
         traced_steps = [

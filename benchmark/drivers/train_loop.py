@@ -467,6 +467,7 @@ def run(ctx) -> Dict[str, Any]:
             "setup_s": t0 - ctx.t_process_start,
         },
         "facts": facts, "memory_peak_bytes": peak_bytes,
+        "checks": checks.rows,
     }
     if device_trace is not None:
         traced = [

@@ -39,17 +39,24 @@ def _series(family: str, registry=None) -> Optional[Dict]:
     return metric
 
 
-def by_phase(family: str, registry=None) -> Optional[Dict[str, float]]:
-    """``{phase: total}`` of one of the two per-phase counters, summed over
-    replicas; ``None`` where the program has no such counter."""
+def by_label(family: str, label: str,
+             registry=None) -> Optional[Dict[str, float]]:
+    """``{value of label: total}`` of a labelled counter, summed over its
+    other labels (the replicas); ``None`` where the program has no such
+    counter."""
     metric = _series(family, registry)
     if metric is None:
         return None
-    at = list(metric["labels"]).index("phase")
+    at = list(metric["labels"]).index(label)
     totals: Dict[str, float] = {}
     for key, value in metric["series"].items():
         totals[key[at]] = totals.get(key[at], 0.0) + float(value)
     return totals
+
+
+def by_phase(family: str, registry=None) -> Optional[Dict[str, float]]:
+    """``{phase: total}`` of one of the two per-phase counters."""
+    return by_label(family, "phase", registry)
 
 
 def mean_ms(family: str, registry=None) -> Optional[float]:

@@ -171,15 +171,28 @@ def out_dir(root: str, workload: str) -> str:
 def result_line(
     *, correct: bool, attempted: int, failed: int,
     metrics: Dict[str, Dict[str, Any]], device: Dict[str, Any],
-    breakdown: Optional[Dict] = None,
+    breakdown: Optional[Dict] = None, checks: Sequence[Dict] = (),
 ) -> str:
+    """``checks``: every number compared beside its limit, under a key of
+    its own that comes last in the line."""
     line: Dict[str, Any] = {
         "correct": bool(correct), "attempted": int(attempted),
         "failed": int(failed), "metrics": metrics, "device": device,
     }
     if breakdown is not None:
         line["breakdown"] = breakdown
+    line["checks"] = {
+        r["check"]: {
+            # a value that is no number fails its check; JSON has no NaN
+            "value": r["value"] if r["value"] == r["value"] else None,
+            "limit": r["limit"]}
+        for r in checks}
     return json.dumps(line)
+
+
+def check_line(row: Dict[str, Any]) -> str:
+    return (f"check {row['check']}: {row['value']!r} (limit "
+            f"{row['limit']!r}) {'ok' if row['ok'] else 'NOT CORRECT'}")
 
 
 class Checks:
@@ -192,8 +205,7 @@ class Checks:
         ok = bool(value == value and value <= limit)
         self.rows.append(
             {"check": name, "value": value, "limit": limit, "ok": ok})
-        say(f"check {name}: {value!r} (limit {limit!r}) "
-            f"{'ok' if ok else 'NOT CORRECT'}")
+        say(check_line(self.rows[-1]))
 
     @property
     def ok(self) -> bool:

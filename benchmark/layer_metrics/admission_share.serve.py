@@ -1,7 +1,9 @@
 """Share of the engine thread's working time that goes to taking requests
-in and out of the batch: self seconds of ``admit`` + ``prefill`` + ``insert``
-+ ``retire`` over those of every phase but ``idle``
-(``serving_decode_engine_seconds_total``).  The rest is ``step`` and ``emit``.
+in and out of the batch: self seconds of ``admit`` + ``prefill`` +
+``prefill.window`` (what a contract that prefills by windows books in place
+of ``prefill``) + ``insert`` + ``retire`` over those of every phase but
+``idle`` (``serving_decode_engine_seconds_total``).  The rest is ``step`` and
+``emit``.
 Totals of the whole run, not of the window: see benchmark/engine_counters.py."""
 
 LAYER = "engine scheduler"
@@ -9,7 +11,7 @@ UNIT = "%"
 MOVES = "serve_tokens_per_s"
 SOURCE = "program_counter"
 
-ADMISSION = ("admit", "prefill", "insert", "retire")
+ADMISSION = ("admit", "prefill", "prefill.window", "insert", "retire")
 
 
 def read(facts, registry=None):

@@ -27,12 +27,8 @@ def latent_bytes(registry=None):
     counts none (another contract)."""
     from benchmark import engine_counters
 
-    cache = engine_counters._series(CACHE_READ, registry)
-    if cache is None:
-        return None
-    at = list(cache["labels"]).index("kind")
-    total = sum(v for key, v in cache["series"].items() if key[at] == KIND)
-    return total or None
+    totals = engine_counters.by_label(CACHE_READ, "kind", registry)
+    return (totals or {}).get(KIND) or None
 
 
 def hparams(model):

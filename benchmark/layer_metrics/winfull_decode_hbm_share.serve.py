@@ -32,14 +32,8 @@ def by_kind(family, registry=None):
     contract."""
     from benchmark import engine_counters
 
-    cache = engine_counters._series(family, registry)
-    if cache is None:
-        return None
-    at = list(cache["labels"]).index("kind")
-    totals = {}
-    for key, value in cache["series"].items():
-        totals[key[at]] = totals.get(key[at], 0.0) + float(value)
-    return totals if set(totals) == set(KINDS) else None
+    totals = engine_counters.by_label(family, "kind", registry)
+    return totals if totals and set(totals) == set(KINDS) else None
 
 
 def total(family, registry=None):

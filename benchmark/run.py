@@ -104,8 +104,11 @@ def main(argv=None) -> int:
     print(harness.result_line(
         correct=out["correct"], attempted=out["attempted"],
         failed=out["failed"], metrics=metrics, device=device,
-        breakdown=breakdown,
+        breakdown=breakdown, checks=out["checks"],
     ), flush=True)
+    # The numbers compared, each beside its limit, end standard error too.
+    for row in out["checks"]:
+        print(harness.check_line(row), file=sys.stderr, flush=True)
     return 0
 
 

@@ -92,6 +92,67 @@ def test_gaps_are_labelled_by_the_hosts_spans():
     assert labelled["host, no span"] == pytest.approx(0.3)
 
 
+def _every_gap_against_every_span(gaps, host_spans, n=10,
+                                 unlabelled="host, no span"):
+    """``label_gaps`` as it stood until PR 39: the double loop."""
+    totals = {}
+    for a, b in gaps:
+        covered = 0.0
+        for label, s, e in host_spans:
+            overlap = min(b, e) - max(a, s)
+            if overlap > 0:
+                totals[label] = totals.get(label, 0.0) + overlap
+                covered += overlap
+        rest = (b - a) - covered
+        if rest > 0:
+            totals[unlabelled] = totals.get(unlabelled, 0.0) + rest
+    ranked = sorted(totals.items(), key=lambda kv: -kv[1])
+    return [[label, secs] for label, secs in ranked[:n]]
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_gaps_labelled_in_one_pass_read_what_the_double_loop_read(seed):
+    """Random gaps against random spans, in no order: spans that overlap
+    and nest, spans of no length, spans over several gaps, gaps that no
+    span covers and gaps outside every span.  The same totals to the last
+    bit, in the same ranking."""
+    rng = np.random.default_rng(seed)
+    for _ in range(25):
+        spans = []
+        for _ in range(int(rng.integers(0, 40))):
+            start = float(rng.uniform(0, 10))
+            length = float(rng.choice([0.0, 0.01, 0.3, 3.0, 8.0])
+                           * rng.random())
+            spans.append((str(rng.choice(list("abcde"))), start,
+                          start + length))
+        gaps = []
+        for _ in range(int(rng.integers(0, 60))):
+            start = float(rng.uniform(-1, 11))
+            gaps.append((start, start + float(
+                rng.choice([0.001, 0.1, 2.0]) * rng.random())))
+        assert trace_reduce.label_gaps(gaps, spans) == \
+            _every_gap_against_every_span(gaps, spans)
+        assert trace_reduce.label_gaps(gaps, spans, n=2) == \
+            _every_gap_against_every_span(gaps, spans, n=2)
+
+
+def test_a_traced_windows_worth_of_gaps_is_labelled_in_seconds():
+    """10**5 gaps against 10**3 spans: every gap against every span is
+    10**8 comparisons (a minute of this interpreter); the walk looks at a
+    span or two a gap."""
+    import time
+
+    spans = [("s%d" % (i % 2), i * 0.003, i * 0.003 + 0.0029)
+             for i in range(1000)]
+    gaps = [(i * 3e-5, i * 3e-5 + 1e-5) for i in range(100_000)]
+    t0 = time.perf_counter()
+    labelled = dict(trace_reduce.label_gaps(gaps, spans))
+    assert time.perf_counter() - t0 < 20.0
+    assert sum(labelled.values()) == pytest.approx(1.0)
+    # of every hundred gaps, three lie in the 0.1 ms between two spans
+    assert labelled["host, no span"] == pytest.approx(0.03)
+
+
 def test_reduce_trace_averages_planes_and_shifts_the_clock():
     planes = {
         "/device:TPU:0": {"XLA Ops": EVENTS, "XLA Modules": [("jit_run", 0, 1)]},

@@ -1,9 +1,8 @@
 """The six readers of the engine's own spans and counters (ISSUE 25), each
 on hand-built facts, and one traced rehearsal after which the four counter
-readers find something to read.  The readers are found by name like the
-others, but no manifest lists them yet: the fixture manifest has to name the
-same metrics as ``BENCHMARK.json``, and it is not a file a PR of this kind
-may change (PERF.md section 7).  Nothing here is a device number."""
+readers find something to read.  Both manifests list them since PR 39
+(``BENCHMARK.json`` and ``fixture/BENCHMARK.json``).  Nothing here is a
+device number."""
 
 import json
 import math
@@ -22,9 +21,8 @@ DEVICE_METRICS = ("prefill_device_ms.serve", "arena_admin_device_share.serve")
 CELL = "tiny-t5.prompt-heavy"
 
 
-# What each reader's manifest entry will say (ISSUE 25's table): unit, layer,
-# source, the end-to-end metric it moves.  test_benchmark_manifest.py holds a
-# listed reader to its entry; these are not listed yet, so this file does.
+# What each reader's manifest entry says (ISSUE 25's table): unit, layer,
+# source, the end-to-end metric it moves.
 ENTRIES = {
     "admission_share.serve":
         ("%", "engine scheduler", "program_counter", "serve_tokens_per_s"),
@@ -44,13 +42,16 @@ ENTRIES = {
 
 
 @pytest.mark.parametrize("name", COUNTER_METRICS + DEVICE_METRICS)
-def test_reader_is_ready_for_its_entry(name):
+def test_reader_is_the_issues_and_is_listed(name):
     reader = manifest.load_layer_metric(name)
     assert (reader.UNIT, reader.LAYER, reader.SOURCE, reader.MOVES) == \
         ENTRIES[name]
     spec = manifest.load()
-    assert reader.MOVES in {m["name"] for m in spec["end_to_end"]}
-    assert reader.LAYER in {m["layer"] for m in spec["per_layer"]}
+    entry = {m["name"]: m for m in spec["per_layer"]}[name]
+    assert entry["better"] == "lower"
+    # the two waits move the tail, which the paced cell alone reports
+    tail = reader.MOVES == "serve_ms_per_token_p95"
+    assert (entry["workloads"] == ["t5-large.decode-paced"]) == tail
     assert reader.read({}) is None
     with open(manifest.layer_metric_path(name)) as f:
         text = f.read()
@@ -95,6 +96,18 @@ def test_counter_readers(registry, name, value):
     assert reader.read({}, registry) is None          # not a serve cell
     # a program that has no such series: nothing, and no error
     assert reader.read(SERVE, MetricsRegistry()) is None
+
+
+def test_admission_share_counts_a_windowed_prefill(registry):
+    """A contract that prefills by windows books ``prefill.window`` in
+    place of ``prefill``: until PR 39 the share left it out and read 0.3 %
+    in EvaByte's cell, where the thread spends a third of its time there."""
+    from tpu_pipelines.serving.generative import DecodeTelemetry
+
+    DecodeTelemetry(registry, "0").on_phase("prefill.window", 10.0)
+    share = manifest.load_layer_metric("admission_share.serve")
+    # admit 0.5 + prefill 3 + windows 10 + insert 0.5 + retire 0.5 of 20
+    assert share.read(SERVE, registry) == pytest.approx(72.5)
 
 
 def test_counter_readers_add_up_the_replicas(registry):

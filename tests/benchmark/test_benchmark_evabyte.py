@@ -2,14 +2,11 @@
 to its source, the work functions of benchmark/work_evabyte.py on the
 issue's own arithmetic, the three readers of what the engine now counts on
 hand-built facts, and a rehearsal of the cell on the CPU from a fixture root
-of its own (``fixture_evabyte/``: the other fixture's manifest has to name
-the metrics of ``BENCHMARK.json`` and no others).
+of its own (``fixture_evabyte/``).
 
-The three readers are found by name like the rest, but ``BENCHMARK.json``
-does not list them: the fixture manifest beside it would have to list them
-too, and it is not a file a PR of this kind may change (PERF.md section 7).
-Until a ``benchmark`` PR lists them, the table below holds each to its
-future entry.  Nothing here is a device number."""
+``BENCHMARK.json`` lists the three readers since PR 39; the table below is
+the issue's, and holds the reader, the entry and the fixture's entry to one
+another.  Nothing here is a device number."""
 
 import json
 import os
@@ -98,10 +95,10 @@ def test_the_cell_is_the_issues():
     listed = {
         m["name"] for section in ("end_to_end", "per_layer")
         for m in manifest.metrics_for(SPEC, section, cell["name"])}
-    assert listed == {
+    assert listed >= {
         "serve_tokens_per_s", "setup_s", "batch_occupancy.serve",
         "decode_step_ms.serve", "device_idle_share.serve",
-        "ms_per_token_p95.offline"}
+        "ms_per_token_p95.offline"} | set(ENTRIES)
 
 
 # ------------------------------------------------------ work, from shapes
@@ -129,7 +126,7 @@ def test_work_is_the_issues_arithmetic():
 
 
 @pytest.mark.parametrize("name", sorted(ENTRIES))
-def test_reader_is_ready_for_its_entry(name):
+def test_reader_is_the_issues_and_is_listed(name):
     reader = manifest.load_layer_metric(name)
     unit, layer, source, moves, _ = ENTRIES[name]
     assert (reader.UNIT, reader.LAYER, reader.SOURCE, reader.MOVES) == (
@@ -137,7 +134,10 @@ def test_reader_is_ready_for_its_entry(name):
     assert reader.MOVES in {m["name"] for m in SPEC["end_to_end"]}
     assert reader.LAYER in {m["layer"] for m in SPEC["per_layer"]}
     assert manifest.NAME_RE.match(name) and manifest.UNIT_RE.match(unit)
-    assert name not in {m["name"] for m in SPEC["per_layer"]}
+    listed = {m["name"]: m for m in SPEC["per_layer"]}[name]
+    assert (listed["unit"], listed["layer"], listed["source"],
+            listed["moves"], listed["better"]) == ENTRIES[name]
+    assert "evabyte-6.5b.doc-decode-closed" in listed["workloads"]
     assert reader.read({}) is None
     with open(os.path.join(FIXTURE, "BENCHMARK.json")) as f:
         entry = {m["name"]: m for m in json.load(f)["per_layer"]}[name]
@@ -185,6 +185,28 @@ def test_decode_share_is_bytes_over_bandwidth_over_the_steps_time(registry):
     want = 100.0 * ((weights + 5e9) / 819e9) / 0.025
     assert reader.read(facts(MODULES), registry) == pytest.approx(want)
     assert 50.0 < want < 60.0
+
+
+@pytest.mark.parametrize("kinds", [
+    {"latent": 1e9},                        # openPangu's contract
+    {"window": 4e9, "full": 1e9},           # command-a's
+    {"window": 4e9},                        # a ring alone
+    {"window": 4e9, "chunk": 1e9, "latent": 1e9},
+])
+def test_decode_share_asks_for_its_own_kinds_of_cache(kinds):
+    """Another contract's cache bytes are not priced with EvaByte's weight
+    arithmetic: until PR 39 the reader summed every kind and returned 44.2
+    in openPangu's cell."""
+    from tpu_pipelines.observability.metrics import MetricsRegistry
+    from tpu_pipelines.serving.generative import DecodeTelemetry
+
+    reg = MetricsRegistry()
+    t = DecodeTelemetry(reg, "0")
+    for _ in range(10):
+        t.on_step(0.02, 0.02, 8, 8, 0, 8)
+        t.on_cache({"cache_bytes": kinds})
+    reader = manifest.load_layer_metric("eva_decode_hbm_share.serve")
+    assert reader.read(facts(MODULES), reg) is None
 
 
 def test_prefill_mfu_is_the_mean_windows_flops_over_its_time(registry):
@@ -244,7 +266,7 @@ def test_readers_look_for_the_programs_own_names():
 
 def rehearse(capsys, *extra, seed=2 ** 31 + 27):
     code = bench_run.main([
-        "--workload", CELL, "--seed", str(seed), "--seconds", "3",
+        "--workload", CELL, "--seed", str(seed), "--seconds", "6",
         "--manifest-root", FIXTURE, "--rehearse", *extra])
     out = capsys.readouterr().out
     return code, out
@@ -254,7 +276,9 @@ def rehearse(capsys, *extra, seed=2 ** 31 + 27):
 def test_rehearsal_of_the_cell_ends_in_the_contracts_line(capsys, trace):
     """The unchanged ``engine`` driver, the engine with the contract of
     models/evabyte.py, prompts of one to three windows prefilled a window
-    at a time, the served bytes compared with reference/evabyte.py."""
+    at a time, the served bytes compared with reference/evabyte.py.  Six
+    seconds of window: under six busy test workers three have seen no
+    request come due."""
     code, out = rehearse(capsys, "--trace", str(trace), "--control")
     assert code == 0
     result = read_result(out)

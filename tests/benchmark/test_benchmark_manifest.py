@@ -178,10 +178,58 @@ def test_paced_rate_is_a_number_in_its_traffic_file():
         assert mix[key] == closed[key]
 
 
-def test_fixture_manifest_has_the_same_metrics():
-    root = os.path.join(os.path.dirname(__file__), "fixture")
-    with open(os.path.join(root, "BENCHMARK.json")) as f:
-        fixture = json.load(f)
-    for section in ("end_to_end", "per_layer"):
-        assert [m["name"] for m in fixture[section]] == [
-            m["name"] for m in SPEC[section]]
+def _fixture_manifests():
+    """``{directory: manifest}`` of every ``tests/benchmark/fixture*/``."""
+    here = os.path.dirname(__file__)
+    found = {}
+    for name in sorted(os.listdir(here)):
+        path = os.path.join(here, name, "BENCHMARK.json")
+        if name.startswith("fixture") and os.path.exists(path):
+            with open(path) as f:
+                found[name] = json.load(f)
+    return found
+
+
+FIXTURES = _fixture_manifests()
+
+
+@pytest.mark.parametrize("directory", sorted(FIXTURES))
+def test_a_fixture_manifest_names_only_listed_metrics(directory):
+    """A later PR lists a metric by adding an entry here, a reader and a
+    fixture directory of its own: it edits no manifest that is there.  So
+    a fixture manifest need not name every metric, only listed ones, and
+    each of its entries says what the listed one says."""
+    listed = {
+        section: {m["name"]: m for m in SPEC[section]}
+        for section in ("end_to_end", "per_layer")}
+    for section, entries in listed.items():
+        for m in FIXTURES[directory][section]:
+            assert m["name"] in entries, (section, m["name"])
+            for key in ("unit", "better", "source", "layer", "moves"):
+                assert m.get(key) == entries[m["name"]].get(key), (
+                    m["name"], key)
+
+
+@pytest.mark.parametrize(
+    "metric", SPEC["per_layer"], ids=lambda m: m["name"])
+def test_a_listed_layer_metric_stands_in_a_fixture_manifest(metric):
+    """Every per-layer entry has a fixture cell in which a rehearsal can
+    drive its reader (test_layer_metric_reader_matches_its_entry loads
+    the reader itself)."""
+    assert any(
+        metric["name"] in {m["name"] for m in spec["per_layer"]}
+        for spec in FIXTURES.values())
+
+
+@pytest.mark.parametrize(
+    "entry", SPEC["configs"], ids=lambda c: c["name"])
+def test_limits_because_speaks_of_the_limits_that_are_there(entry):
+    """Where a configuration's check gives its limits' readings
+    (``limits_because``; the two oldest have theirs in PERF.md section 6),
+    each limit has its text and no text is left behind for a limit that is
+    gone.  A text may also speak of another key of the check itself."""
+    check = manifest.load_config(SPEC, entry["name"])["check"]
+    assert check["limits"]
+    if "limits_because" in check:
+        assert set(check["limits_because"]) - set(check) == set(
+            check["limits"])

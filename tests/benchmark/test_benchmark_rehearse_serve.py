@@ -76,7 +76,12 @@ def test_a_token_altered_where_it_is_produced_is_not_correct(
 
 def test_a_compile_inside_the_window_fails_the_run(capsys, monkeypatch):
     """A step program that warm-up missed compiles under traffic: the
-    engine counts it, and the run counts it as a failure."""
+    engine counts it, and the run counts it as a failure.  Six seconds of
+    window, as the routed-expert fixture has: the eight callers' first
+    requests are all due before the window opens and none returns before
+    the missed program has compiled, which under six busy test workers
+    outlasts a window of 1.5 s, so that no request came due inside it and
+    the run raised where it should have counted."""
     from tpu_pipelines.serving.generative import GenerativeEngine
 
     warm = GenerativeEngine.warm
@@ -87,7 +92,7 @@ def test_a_compile_inside_the_window_fails_the_run(capsys, monkeypatch):
 
     monkeypatch.setattr(GenerativeEngine, "warm", warm_and_forget)
     code, out = run_cell(
-        capsys, "tiny-t5.closed", "--rehearse", "--trace", "0")
+        capsys, "tiny-t5.closed", "--rehearse", "--trace", "0", seconds=6)
     assert code == 0
     result = read_result(out)
     assert result["failed"] > 0 and result["correct"] is False
