@@ -1,0 +1,295 @@
+"""The parts of the device programs (ISSUE 40): every program a cell of
+the benchmark runs names its parts with ``jax.named_scope``, in the one
+vocabulary ``observability.trace.DEVICE_PARTS``.
+
+* The scopes are metadata and nothing else: the lowered text of every
+  program at fixture size hashes to what the commit before the scopes
+  (79564dd) lowered to, the module's name aside (the train window's was
+  ``jit__lambda``).  A PR that changes a program on purpose lowers it
+  again and replaces the hash: ``python tests/test_device_parts.py``
+  prints the table.
+* Every instruction of the lowered HLO that a line of the program wrote
+  and that computes carries a word of the vocabulary in its scope path
+  or inherits one by the rule of ``benchmark/program_parts.py``; under
+  2 % stay ``unnamed``.
+* The train window's program is ``jit_train_window``.
+"""
+
+import hashlib
+import importlib
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+# sha256 of ``lowered.as_text()`` with the module's name taken out, at
+# commit 79564dd (the parent of the scopes), jax 0.9.0, 8 CPU devices.
+PARENT_SHA256 = {
+    "t5 prefill":
+        "78e3c0297e8727c951835f623307a467a28c383a2f2fb948af2a20bbad3c584c",
+    "t5 insert":
+        "0f2c8cb4807b8ba8297f117bcaf0f8dd374a467b0b38d7151a825a4023975498",
+    "t5 move":
+        "f0354c989720b1db3839c3d0d7d6e037e4b2c2bc3d0a769867c90717292791c1",
+    "t5 clear":
+        "4847510cc08740e7785e65e5815b99f7d90a8667a056c7890b10de5ddb1adc74",
+    "t5 step 2x4":
+        "964f204aba8c9969113a9e48817099ad891275aa5a72806f953b2c155b62d48f",
+    "t5 step 4x8":
+        "24119531de8bdc60a36bc497fbb47eee11850561961afe2fcaf8e4ee57d6d438",
+    "evabyte step":
+        "9cac10e1515ec1bef2ad1dfbc0ba3be91a78383abb66bb422286597e92e984fc",
+    "evabyte prefill_window":
+        "225d0ab75b35d40ac33bdab436e2a1b231c9dffb6550a51307fcd06bdc12fc3b",
+    "pangu_moe step":
+        "acfcff53f46301ab5c8da8094b9762da0213dc399d6509a6cf8cef17b15b50d3",
+    "pangu_moe prefill_window":
+        "b8521f090df4036c850fc824f22c2c5c6bb36eff295679066ab4dd2d151f9574",
+    "command_a step":
+        "4b329dc3fea080eb858b46b27c40e35addc5ec185c792198fbb2ce02a61e4a59",
+    "command_a prefill_window":
+        "7e9824a475451993b54c051b67f99ee0a9982652f5c5161283a01b21cde08ba5",
+    "bert train window":
+        "7d82ea7ece7539cce25527b6839238514229a4fca89d159572e35d93bcbf696a",
+}
+PROGRAMS = (
+    "t5 prefill", "t5 insert", "t5 move", "t5 clear", "t5 step 2x4",
+    "t5 step 4x8", "evabyte step", "evabyte prefill_window",
+    "pangu_moe step", "pangu_moe prefill_window", "command_a step",
+    "command_a prefill_window", "bert train window",
+)
+OLDER_SCOPES = {
+    "eva.attend", "eva.summarize", "mla.attend", "moe.route",
+    "moe.experts", "moe.shared",
+}
+
+
+def _t5_programs(note):
+    import jax
+    import jax.numpy as jnp
+
+    from tpu_pipelines.models.t5 import T5, make_continuous_decode_fns
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    model = T5(
+        vocab_size=48, d_model=16, n_layers=2, n_heads=2, head_dim=8,
+        d_ff=32, dropout_rate=0.0, dtype=jnp.float32)
+    batch = {
+        "inputs": np.arange(12, dtype=np.int32).reshape(2, 6) % 13 + 2,
+        "targets": np.ones((2, 5), np.int32),
+    }
+    params = model.init(jax.random.key(0), batch)["params"]
+    fns = make_continuous_decode_fns(
+        model, max_decode_len=8, eos_id=1, max_input_len=6)
+    engine = GenerativeEngine(fns, params, max_batch_size=4, page_size=2)
+    try:
+        engine._ensure_arena()
+        zin = np.zeros((1, 6), np.int32)
+        c1, e1, _ = engine._jit_prefill(engine.params, zin, zin)
+        slot, one = np.int32(0), np.int32(1)
+        a = engine._arena
+        note("t5 prefill", engine._jit_prefill.lower(engine.params, zin, zin))
+        note("t5 insert",
+             engine._jit_insert.lower(a, c1, e1, zin, one, slot))
+        note("t5 move", engine._jit_move.lower(a, slot, slot))
+        note("t5 clear", engine._jit_clear.lower(a, slot))
+        note("t5 step 2x4", engine._step_for(2, 4).lower(engine.params, a))
+        note("t5 step 4x8", engine._step_for(4, 8).lower(engine.params, a))
+    finally:
+        engine.close()
+
+
+def _decoder_programs(note):
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    for name in ("evabyte", "pangu_moe", "command_a"):
+        tiny = importlib.import_module("test_" + name)
+        model, params = tiny.build()
+        engine = GenerativeEngine(
+            tiny.decode_fns(model), params, max_batch_size=4)
+        try:
+            engine._ensure_arena()
+            tokens = np.zeros((1, engine._window_len), np.int32)
+            note(f"{name} step", engine._step_for(
+                2, engine.kv_buckets[-1]).lower(engine.params, engine._arena))
+            note(f"{name} prefill_window", engine._jit_prefill_window.lower(
+                engine.params, engine._row_cache, tokens, np.int32(1),
+                np.int32(0)))
+        finally:
+            engine.close()
+
+
+def _train_window(note, monkeypatch):
+    """The window program as ``train_loop`` itself builds it for a tiny
+    BERT with dropout: the sharded programs are caught on their way into
+    ``jax.jit`` and lowered at their first call.  -> the function's
+    name."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from tpu_pipelines.models.bert import build_bert_model
+    from tpu_pipelines.trainer import TrainLoopConfig, train_loop
+
+    real_jit = jax.jit
+    called = {}
+
+    def catching(fn, **kw):
+        jitted = real_jit(fn, **kw)
+        if "in_shardings" not in kw:
+            return jitted
+
+        def call(*args):
+            if fn.__name__ not in called:
+                called[fn.__name__] = jitted.lower(*args)
+            return jitted(*args)
+
+        return call
+
+    monkeypatch.setattr(jax, "jit", catching)
+    model = build_bert_model(dict(
+        vocab_size=64, d_model=16, n_layers=2, n_heads=2, d_ff=32,
+        max_len=8, dropout_rate=0.1, num_classes=2, attn_impl="dense"))
+    rng = np.random.default_rng(0)
+
+    def batches(n):
+        for _ in range(n):
+            yield {
+                "input_ids": rng.integers(0, 64, (8, 8)).astype(np.int32),
+                "attention_mask": np.ones((8, 8), np.int32),
+                "label": rng.integers(0, 2, (8,)).astype(np.int32),
+            }
+
+    def features(b):
+        return {k: v for k, v in b.items() if k != "label"}
+
+    def loss_fn(params, b, step_rng):
+        logits = model.apply(
+            {"params": params}, features(b), deterministic=False,
+            rngs={"dropout": step_rng})
+        labels = jnp.asarray(b["label"], jnp.int32)
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, labels).mean(), {}
+
+    train_loop(
+        loss_fn=loss_fn,
+        init_params_fn=lambda r, b: model.init(r, features(b))["params"],
+        optimizer=optax.adamw(1e-3), train_iter=batches(4),
+        config=TrainLoopConfig(
+            train_steps=4, batch_size=8, log_every=2, window_steps=2))
+    monkeypatch.setattr(jax, "jit", real_jit)
+    # every step of the run went through one sharded program: the window
+    (name, program), = called.items()
+    note("bert train window", program)
+    return name
+
+
+def lower_all(monkeypatch):
+    """``{program: (module name, sha256 of its text, HloModuleProto)}``."""
+    out = {}
+
+    def note(name, lowered):
+        text = lowered.as_text()
+        module = re.search(r"module @(\w+)", text).group(1)
+        text = re.sub(r"module @\w+", "module @_", text, count=1)
+        out[name] = (
+            module, hashlib.sha256(text.encode()).hexdigest(),
+            lowered.compiler_ir(
+                dialect="hlo").as_serialized_hlo_module_proto())
+
+    _t5_programs(note)
+    _decoder_programs(note)
+    out["window function"] = _train_window(note, monkeypatch)
+    return out
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    patch = pytest.MonkeyPatch()
+    try:
+        return lower_all(patch)
+    finally:
+        patch.undo()
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_scopes_leave_the_lowered_program_as_it_was(program, lowered):
+    assert lowered[program][1] == PARENT_SHA256[program], (
+        f"{program} lowers to another text than at 79564dd: a scope moved "
+        "an operation, or the program was changed on purpose (then print "
+        "the table anew: python tests/test_device_parts.py)")
+
+
+# What computes nothing of its own: wires, the callers of other
+# computations, and a mesh's sharding annotations (the only custom calls
+# of a CPU lowering).
+CARRIERS = {"parameter", "constant", "tuple", "get-tuple-element", "while",
+            "call", "conditional", "custom-call"}
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_every_instruction_has_a_part_or_inherits_one(program, lowered):
+    """Of the instructions that a line of the program wrote (the converter's
+    own carry no ``op_name``) and that compute, under 2 % are left without
+    a part: the scan's own slicing and counting in the train window."""
+    from benchmark import program_parts
+
+    raw = lowered[program][2]
+    parts = program_parts.module_parts(raw)
+    module = program_parts.messages()["HloModule"].FromString(raw)
+    written = [
+        ins.name for comp in module.computations
+        for ins in comp.instructions
+        if ins.metadata.op_name and ins.opcode not in CARRIERS]
+    unnamed = [n for n in written if parts[n][0] == program_parts.UNNAMED]
+    assert len(written) > 0.25 * len(parts)
+    assert sum(parts[n][1] == "own" for n in written) > 0.5 * len(written)
+    assert len(unnamed) < 0.02 * len(written), unnamed[:20]
+    assert {part for part, _ in parts.values()} - {
+        program_parts.UNNAMED} <= set(program_parts.PARTS)
+
+
+def test_the_window_program_has_its_name(lowered):
+    from tpu_pipelines.trainer import train_loop as train_loop_module
+    train_loop = sys.modules[train_loop_module.__module__]
+
+    assert lowered["bert train window"][0] == "jit_train_window"
+    assert "jit_" + lowered["window function"] \
+        == train_loop.WINDOW_PROGRAM_NAME == "jit_train_window"
+
+
+def test_one_vocabulary_in_one_place():
+    """Every scope the program opens is a word of ``DEVICE_PARTS`` or one
+    of the older, finer scopes, which stand letter for letter; the
+    benchmark's copy of the vocabulary is the program's."""
+    from benchmark import program_parts
+    from tpu_pipelines.observability import trace
+
+    assert program_parts.PARTS == trace.DEVICE_PARTS
+    assert len(set(trace.DEVICE_PARTS)) == len(trace.DEVICE_PARTS)
+    root = os.path.join(os.path.dirname(__file__), "..", "tpu_pipelines")
+    found = {}
+    for sub in ("models", "trainer", "serving"):
+        for name in sorted(os.listdir(os.path.join(root, sub))):
+            if name.endswith(".py"):
+                with open(os.path.join(root, sub, name)) as f:
+                    for scope in re.findall(
+                            r'named_scope\(\s*"([^"]+)"', f.read()):
+                        found.setdefault(scope, set()).add(name)
+    assert set(found) <= set(trace.DEVICE_PARTS) | OLDER_SCOPES, found
+    assert OLDER_SCOPES <= set(found)
+    # the part every word is opened in somewhere
+    assert set(trace.DEVICE_PARTS) <= set(found)
+    for name in ("transformer.py", "bert.py", "t5.py", "evabyte.py",
+                 "pangu_moe.py", "command_a.py", "train_loop.py",
+                 "generative.py"):
+        assert any(name in files for files in found.values()), name
+
+
+if __name__ == "__main__":
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    table = lower_all(pytest.MonkeyPatch())
+    for key in PROGRAMS:
+        print(f'    "{key}":\n        "{table[key][1]}",')

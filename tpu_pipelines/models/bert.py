@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
+import jax
 import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import Mesh, PartitionSpec as P
@@ -55,17 +56,21 @@ class BertEncoder(nn.Module):
     ):
         ids = jnp.asarray(input_ids, jnp.int32)
         b, l = ids.shape
-        x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
-                     name="embed")(ids)
-        x = x + nn.Embed(self.max_len, self.d_model, dtype=self.dtype,
-                         name="pos_embed")(jnp.arange(l)[None, :])
-        types = (jnp.zeros_like(ids) if token_type_ids is None
-                 else jnp.asarray(token_type_ids, jnp.int32))
-        x = x + nn.Embed(self.type_vocab_size, self.d_model, dtype=self.dtype,
-                         name="type_embed")(types)
-        x = nn.LayerNorm(dtype=self.dtype, name="embed_norm")(x)
+        with jax.named_scope("embed_head"):
+            x = nn.Embed(self.vocab_size, self.d_model, dtype=self.dtype,
+                         name="embed")(ids)
+            x = x + nn.Embed(self.max_len, self.d_model, dtype=self.dtype,
+                             name="pos_embed")(jnp.arange(l)[None, :])
+            types = (jnp.zeros_like(ids) if token_type_ids is None
+                     else jnp.asarray(token_type_ids, jnp.int32))
+            x = x + nn.Embed(self.type_vocab_size, self.d_model,
+                             dtype=self.dtype, name="type_embed")(types)
+        with jax.named_scope("norm"):
+            x = nn.LayerNorm(dtype=self.dtype, name="embed_norm")(x)
         if self.dropout_rate:
-            x = nn.Dropout(self.dropout_rate)(x, deterministic=deterministic)
+            with jax.named_scope("dropout"):
+                x = nn.Dropout(self.dropout_rate)(
+                    x, deterministic=deterministic)
         for i in range(self.n_layers):
             x = TransformerBlock(
                 n_heads=self.n_heads,
@@ -98,16 +103,21 @@ class BertClassifier(nn.Module):
             attention_mask=batch.get("attention_mask"),
             deterministic=deterministic,
         )
-        pooled = nn.tanh(
-            nn.Dense(x.shape[-1], dtype=jnp.float32, name="pooler")(
-                x[:, 0].astype(jnp.float32)
+        with jax.named_scope("embed_head"):
+            pooled = nn.tanh(
+                nn.Dense(x.shape[-1], dtype=jnp.float32, name="pooler")(
+                    x[:, 0].astype(jnp.float32)
+                )
             )
-        )
         if self.dropout_rate:
-            pooled = nn.Dropout(self.dropout_rate)(
-                pooled, deterministic=deterministic
-            )
-        return nn.Dense(self.num_classes, dtype=jnp.float32, name="head")(pooled)
+            with jax.named_scope("dropout"):
+                pooled = nn.Dropout(self.dropout_rate)(
+                    pooled, deterministic=deterministic
+                )
+        with jax.named_scope("embed_head"):
+            return nn.Dense(
+                self.num_classes, dtype=jnp.float32, name="head"
+            )(pooled)
 
 
 class BertMLMHead(nn.Module):
@@ -123,11 +133,15 @@ class BertMLMHead(nn.Module):
             attention_mask=batch.get("attention_mask"),
             deterministic=deterministic,
         )
-        x = nn.gelu(nn.Dense(x.shape[-1], dtype=x.dtype, name="mlm_dense")(x))
-        x = nn.LayerNorm(dtype=x.dtype, name="mlm_norm")(x)
-        return nn.Dense(
-            self.encoder.vocab_size, dtype=jnp.float32, name="mlm_head"
-        )(x)
+        with jax.named_scope("embed_head"):
+            x = nn.gelu(
+                nn.Dense(x.shape[-1], dtype=x.dtype, name="mlm_dense")(x))
+        with jax.named_scope("norm"):
+            x = nn.LayerNorm(dtype=x.dtype, name="mlm_norm")(x)
+        with jax.named_scope("embed_head"):
+            return nn.Dense(
+                self.encoder.vocab_size, dtype=jnp.float32, name="mlm_head"
+            )(x)
 
 
 DEFAULT_HPARAMS = {

@@ -135,10 +135,11 @@ class LayerNorm(nn.Module):
     def __call__(self, x):
         g = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                        self.param_dtype)
-        x = x.astype(jnp.float32)
-        x = x - jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(var + self.eps) * g.astype(jnp.float32)
+        with jax.named_scope("norm"):
+            x = x.astype(jnp.float32)
+            x = x - jnp.mean(x, axis=-1, keepdims=True)
+            var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+            return x * jax.lax.rsqrt(var + self.eps) * g.astype(jnp.float32)
 
 
 class GroupedAttention(nn.Module):
@@ -163,31 +164,33 @@ class GroupedAttention(nn.Module):
         k and v [b, kv, l, d]: query head ``i`` is ``(i // g, i % g)``;
         the rotary code is on q and k of a window layer."""
         c = self.cfg
-        x = x.astype(c.dtype)
-        b, l = x.shape[:2]
-        # The barrier keeps the products as they are written.  Without it
-        # the chip's compiler lays q, k and v out for the attention
-        # products, pushes that layout back through the projections and
-        # copies Wq, Wk and Wv into another layout at every call (1.6 ms
-        # of a 22.8 ms step, PERF.md section 6, PR 35).
-        q, k, v = jax.lax.optimization_barrier(
-            (self.q_proj(x), self.k_proj(x), self.v_proj(x)))
-        q = q.reshape(b, l, c.n_heads, c.head_dim)
-        k = k.reshape(b, l, c.n_kv_heads, c.head_dim)
-        v = v.reshape(b, l, c.n_kv_heads, c.head_dim)
-        if not self.full:
-            q = rope_interleaved(q, pos, c.rope_theta)
-            k = rope_interleaved(k, pos, c.rope_theta)
-        q = (q.astype(jnp.float32) * c.head_dim ** -0.5).astype(c.dtype)
-        q = q.reshape(b, l, c.n_kv_heads, -1, c.head_dim)
-        heads_first = lambda y: jnp.swapaxes(y.astype(c.dtype), 1, 2)
-        return (jnp.transpose(q, (0, 2, 3, 1, 4)), heads_first(k),
-                heads_first(v))
+        with jax.named_scope("attention_proj"):
+            x = x.astype(c.dtype)
+            b, l = x.shape[:2]
+            # The barrier keeps the products as they are written.  Without
+            # it the chip's compiler lays q, k and v out for the attention
+            # products, pushes that layout back through the projections
+            # and copies Wq, Wk and Wv into another layout at every call
+            # (1.6 ms of a 22.8 ms step, PERF.md section 6, PR 35).
+            q, k, v = jax.lax.optimization_barrier(
+                (self.q_proj(x), self.k_proj(x), self.v_proj(x)))
+            q = q.reshape(b, l, c.n_heads, c.head_dim)
+            k = k.reshape(b, l, c.n_kv_heads, c.head_dim)
+            v = v.reshape(b, l, c.n_kv_heads, c.head_dim)
+            if not self.full:
+                q = rope_interleaved(q, pos, c.rope_theta)
+                k = rope_interleaved(k, pos, c.rope_theta)
+            q = (q.astype(jnp.float32) * c.head_dim ** -0.5).astype(c.dtype)
+            q = q.reshape(b, l, c.n_kv_heads, -1, c.head_dim)
+            heads_first = lambda y: jnp.swapaxes(y.astype(c.dtype), 1, 2)
+            return (jnp.transpose(q, (0, 2, 3, 1, 4)), heads_first(k),
+                    heads_first(v))
 
     def merge(self, out):
         """out [kv, g, l, d] -> the layer's output [l, d_model]."""
-        out = jnp.transpose(out, (2, 0, 1, 3)).reshape(out.shape[2], -1)
-        return self.o_proj(out.astype(self.cfg.dtype))
+        with jax.named_scope("attention_proj"):
+            out = jnp.transpose(out, (2, 0, 1, 3)).reshape(out.shape[2], -1)
+            return self.o_proj(out.astype(self.cfg.dtype))
 
     def sees(self, t, u):
         """Whether the query at ``t`` sees the key at position ``u``
@@ -204,7 +207,7 @@ class GroupedAttention(nn.Module):
         [kv, g, l, d] at positions ``start + [0, l)``; k and v [kv, s, d],
         of which entry ``j`` holds position ``held[j]`` (negative: none).
         Every query sees a key.  -> [l, d_model]."""
-        with jax.named_scope(self.span):
+        with jax.named_scope("attention_core"), jax.named_scope(self.span):
             out = grouped_attention(q, k, v, held, start, self.sees)
         return self.merge(out)
 
@@ -223,36 +226,48 @@ class GroupedAttention(nn.Module):
         because a position past the end would lie over one that the
         steps still see."""
         p = x.shape[1]
-        start = index * p
-        q, k, v = self.project(x, start + jnp.arange(p)[None])
+        with jax.named_scope("attention_proj"):
+            start = index * p
+            positions = start + jnp.arange(p)[None]
+        q, k, v = self.project(x, positions)
         if self.full:
             put = lambda a, new: jax.lax.dynamic_update_slice_in_dim(
                 a, new, start, axis=2)
-            cache = {"full_k": put(cache["full_k"], k),
-                     "full_v": put(cache["full_v"], v)}
-            out = self.blocks(
-                q[0], cache["full_k"][0], cache["full_v"][0], start,
-                jnp.arange(cache["full_k"].shape[2]))
-            return out[None], cache
+            with jax.named_scope("cache_write"):
+                cache = {"full_k": put(cache["full_k"], k),
+                         "full_v": put(cache["full_v"], v)}
+            with jax.named_scope("attention_core"):
+                entries = (
+                    q[0], cache["full_k"][0], cache["full_v"][0], start,
+                    jnp.arange(cache["full_k"].shape[2]))
+            out = self.blocks(*entries)
+            with jax.named_scope("attention_proj"):
+                return out[None], cache
         w = self.cfg.window_size
         # The window's own keys, then the ring as it was: entry j holds
         # the last position before ``start`` that lies at j, a negative
         # one (no key) until the ring has wrapped that far.
-        at = jnp.arange(w)
-        held = jnp.concatenate([
-            start + jnp.arange(p), start - 1 - (start - 1 - at) % w])
-        keys = jnp.concatenate([k[0], cache["ring_k"][0]], 1)
-        values = jnp.concatenate([v[0], cache["ring_v"][0]], 1)
-        out = self.blocks(q[0], keys, values, start, held)
-        valid = (jnp.arange(p) < n_valid)[None, None, :, None]
+        with jax.named_scope("attention_core"):
+            at = jnp.arange(w)
+            held = jnp.concatenate([
+                start + jnp.arange(p), start - 1 - (start - 1 - at) % w])
+            keys = jnp.concatenate([k[0], cache["ring_k"][0]], 1)
+            values = jnp.concatenate([v[0], cache["ring_v"][0]], 1)
+            entries = q[0], keys, values, start, held
+        out = self.blocks(*entries)
 
         def put(ring, new):
             old = jax.lax.dynamic_slice_in_dim(ring, start % w, p, axis=2)
             return jax.lax.dynamic_update_slice_in_dim(
                 ring, jnp.where(valid, new, old), start % w, axis=2)
 
-        return out[None], {"ring_k": put(cache["ring_k"], k),
-                           "ring_v": put(cache["ring_v"], v)}
+        with jax.named_scope("cache_write"):
+            valid = (jnp.arange(p) < n_valid)[None, None, :, None]
+        with jax.named_scope("attention_proj"):
+            out = out[None]
+        with jax.named_scope("cache_write"):
+            return out, {"ring_k": put(cache["ring_k"], k),
+                         "ring_v": put(cache["ring_v"], v)}
 
     def step(self, x, pos, cache, klen: int):
         """One token per row.  x [b, d_model], pos [b]; cache leaves
@@ -262,29 +277,39 @@ class GroupedAttention(nn.Module):
         the ring."""
         c = self.cfg
         b = x.shape[0]
-        q, k, v = self.project(x[:, None], pos[:, None])
+        with jax.named_scope("attention_proj"):
+            one = x[:, None], pos[:, None]
+        q, k, v = self.project(*one)
         names = ("full_k", "full_v") if self.full else ("ring_k", "ring_v")
         ck, cv = cache[names[0]], cache[names[1]]
         entries = klen if self.full else c.window_size
-        at = pos if self.full else pos % c.window_size
+        with jax.named_scope("cache_write"):
+            at = pos if self.full else pos % c.window_size
         # One write per row, not a scatter over rows: for a scatter the
         # chip's compiler copies the whole array into another layout.
-        for r in range(b):
-            ck = jax.lax.dynamic_update_slice(ck, k[r][None], (r, 0, at[r], 0))
-            cv = jax.lax.dynamic_update_slice(cv, v[r][None], (r, 0, at[r], 0))
-        j = jnp.arange(entries)[None, :]
-        # the position each entry holds once this step's is written
-        u = j if self.full else pos[:, None] - (pos[:, None] - j) % entries
-        ok = self.sees(pos[:, None], u)[:, None, None, :]
-        with jax.named_scope(self.span):
-            f32 = dict(preferred_element_type=jnp.float32)
-            score = jnp.einsum(
-                "bkgd,bksd->bkgs", q[:, :, :, 0], ck[:b, :, :entries], **f32)
-            p = jax.nn.softmax(jnp.where(ok, score, NEG_INF), -1)
-            out = jnp.einsum(
-                "bkgs,bksd->bkgd", p.astype(c.dtype), cv[:b, :, :entries],
-                **f32)
-        out = self.o_proj(out.reshape(b, -1).astype(c.dtype))
+        with jax.named_scope("cache_write"):
+            for r in range(b):
+                ck = jax.lax.dynamic_update_slice(
+                    ck, k[r][None], (r, 0, at[r], 0))
+                cv = jax.lax.dynamic_update_slice(
+                    cv, v[r][None], (r, 0, at[r], 0))
+        with jax.named_scope("attention_core"):
+            j = jnp.arange(entries)[None, :]
+            # the position each entry holds once this step's is written
+            u = j if self.full else (
+                pos[:, None] - (pos[:, None] - j) % entries)
+            ok = self.sees(pos[:, None], u)[:, None, None, :]
+            with jax.named_scope(self.span):
+                f32 = dict(preferred_element_type=jnp.float32)
+                score = jnp.einsum(
+                    "bkgd,bksd->bkgs", q[:, :, :, 0], ck[:b, :, :entries],
+                    **f32)
+                p = jax.nn.softmax(jnp.where(ok, score, NEG_INF), -1)
+                out = jnp.einsum(
+                    "bkgs,bksd->bkgd", p.astype(c.dtype),
+                    cv[:b, :, :entries], **f32)
+        with jax.named_scope("attention_proj"):
+            out = self.o_proj(out.reshape(b, -1).astype(c.dtype))
         return out, {names[0]: ck, names[1]: cv}
 
 
@@ -301,9 +326,16 @@ class CommandABlock(nn.Module):
     def _both(self, h, x, a):
         """-> the stream with both branches added, and which held experts
         each token chose."""
-        m, picked = self.ffn(x.reshape(-1, x.shape[-1]))
-        h = h + a.astype(jnp.float32) + m.reshape(x.shape)
-        return h, picked.reshape(x.shape[:-1] + (-1,))
+        # The add that takes a branch into the stream is booked with the
+        # part that closes the branch.
+        with jax.named_scope("mlp"):
+            rows = x.reshape(-1, x.shape[-1])
+        m, picked = self.ffn(rows)
+        with jax.named_scope("attention_proj"):
+            h = h + a.astype(jnp.float32)
+        with jax.named_scope("mlp"):
+            h = h + m.reshape(x.shape)
+            return h, picked.reshape(x.shape[:-1] + (-1,))
 
     def whole(self, h, pos):
         x = self.norm(h)
@@ -357,35 +389,41 @@ class CommandA(nn.Module):
         product in the compute dtype, accumulated and handed out in
         float32."""
         c = self.cfg
-        return c.logit_scale * jnp.einsum(
-            "...d,vd->...v", self.final_norm(h).astype(c.dtype),
-            self.embed.embedding.astype(c.dtype),
-            preferred_element_type=jnp.float32)
+        with jax.named_scope("embed_head"):
+            return c.logit_scale * jnp.einsum(
+                "...d,vd->...v", self.final_norm(h).astype(c.dtype),
+                self.embed.embedding.astype(c.dtype),
+                preferred_element_type=jnp.float32)
 
     def prefill_window(self, tokens, n_valid, index, cache):
         """One window of a prompt: ``n_valid`` of the ``P`` tokens count.
         -> the row's cache and the logits [1, vocab] at the last valid
         position (the prompt's first new token when this is its last
         window)."""
-        h = self.embed(tokens).astype(jnp.float32)
+        with jax.named_scope("embed_head"):
+            h = self.embed(tokens).astype(jnp.float32)
         new = {}
         for i, block in enumerate(self.blocks):
             h, new[f"layer_{i}"] = block.window(
                 h, n_valid, index, cache[f"layer_{i}"])
-        last = jax.lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
-        return new, self.head_logits(last[:, 0])
+        with jax.named_scope("embed_head"):
+            last = jax.lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
+            return new, self.head_logits(last[:, 0])
 
     def decode_step(self, tok, pos, cache, klen: int):
         """tok, pos [b] -> cache, logits [b, vocab], and which held
         experts each row chose, [b, n_layers * experts_held], layer by
         layer."""
-        h = self.embed(tok).astype(jnp.float32)
+        with jax.named_scope("embed_head"):
+            h = self.embed(tok).astype(jnp.float32)
         new, picked = {}, []
         for i, block in enumerate(self.blocks):
             h, new[f"layer_{i}"], chose = block.step(
                 h, pos, cache[f"layer_{i}"], klen)
             picked.append(chose)
-        return new, self.head_logits(h), jnp.concatenate(picked, -1)
+        logits = self.head_logits(h)
+        with jax.named_scope("mlp"):
+            return new, logits, jnp.concatenate(picked, -1)
 
     def __call__(self, batch: Dict[str, Any], *, deterministic: bool = True):
         inputs = jnp.asarray(batch["inputs"], jnp.int32)
@@ -393,7 +431,8 @@ class CommandA(nn.Module):
         pos = jnp.arange(n)[None]
         rows = []
         for r in range(b):
-            h = self.embed(inputs[r:r + 1]).astype(jnp.float32)
+            with jax.named_scope("embed_head"):
+                h = self.embed(inputs[r:r + 1]).astype(jnp.float32)
             for block in self.blocks:
                 h = block.whole(h, pos)
             rows.append(h)

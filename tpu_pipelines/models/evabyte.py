@@ -64,10 +64,11 @@ class UnitOffsetRMSNorm(nn.Module):
     def __call__(self, x):
         g = self.param("scale", nn.initializers.zeros, (x.shape[-1],),
                        self.param_dtype)
-        x = x.astype(jnp.float32)
-        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(ms + self.eps) * (
-            1.0 + g.astype(jnp.float32))
+        with jax.named_scope("norm"):
+            x = x.astype(jnp.float32)
+            ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+            return x * jax.lax.rsqrt(ms + self.eps) * (
+                1.0 + g.astype(jnp.float32))
 
 
 class EvaAttention(nn.Module):
@@ -100,16 +101,18 @@ class EvaAttention(nn.Module):
         rotary code is on q and k."""
         split = lambda y: y.reshape(
             y.shape[:2] + (self.n_heads, self.head_dim))
-        x = x.astype(self.dtype)
-        q = rope(split(self.q_proj(x)), pos, self.rope_theta)
-        k = rope(split(self.k_proj(x)), pos, self.rope_theta)
-        return (q.astype(self.dtype), k.astype(self.dtype),
-                split(self.v_proj(x)))
+        with jax.named_scope("attention_proj"):
+            x = x.astype(self.dtype)
+            q = rope(split(self.q_proj(x)), pos, self.rope_theta)
+            k = rope(split(self.k_proj(x)), pos, self.rope_theta)
+            return (q.astype(self.dtype), k.astype(self.dtype),
+                    split(self.v_proj(x)))
 
     def summarize(self, k, v):
         """One pooled pair per chunk.  k, v [..., chunk, h, d] (keys as
         cached: after the rotary code) -> [..., h, d] each."""
-        with jax.named_scope("eva.summarize"):
+        with jax.named_scope("attention_core"), \
+                jax.named_scope("eva.summarize"):
             kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
             score = jnp.einsum(
                 "...chd,hd->...ch", kf, self.phi.astype(jnp.float32)
@@ -125,7 +128,8 @@ class EvaAttention(nn.Module):
         [b, lq, h, d]; k, v [b, lk, h, d] with k_ok [b, lq, lk]; ck, cv
         [b, lc, h, d] with c_ok [b, lq, lc].  Every query sees at least
         its own position among the exact pairs.  -> [b, lq, h * d]."""
-        with jax.named_scope("eva.attend"):
+        with jax.named_scope("attention_core"), \
+                jax.named_scope("eva.attend"):
             s = self.head_dim ** -0.5
             f32 = dict(preferred_element_type=jnp.float32)
             sx = jnp.einsum("bqhd,bkhd->bhqk", q, k, **f32) * s
@@ -150,33 +154,38 @@ class EvaAttention(nn.Module):
         the decode steps that follow."""
         w, c = self.window_size, self.chunk_size
         per = w // c
-        pos = index * w + jnp.arange(w)[None]
+        with jax.named_scope("attention_proj"):
+            pos = index * w + jnp.arange(w)[None]
         q, k, v = self.qkv(x, pos)
         n_chunks = cache["chunk_k"].shape[1]
-        c_ok = (jnp.arange(n_chunks) < index * per)[None, None]
         qb = next(n for n in range(min(w, 512), 0, -1) if w % n == 0)
-
-        def block(i):
-            rows = i * qb + jnp.arange(qb)
-            k_ok = (jnp.arange(w)[None, :] <= rows[:, None])[None]
-            qs = jax.lax.dynamic_slice_in_dim(q, i * qb, qb, axis=1)
-            return self.attend(
-                qs, k, v, k_ok, cache["chunk_k"], cache["chunk_v"], c_ok)[0]
-
-        out = jax.lax.map(block, jnp.arange(w // qb)).reshape(1, w, -1)
         chunks = lambda y: y.reshape((1, per, c) + y.shape[2:])
-        ks, vs = self.summarize(chunks(k), chunks(v))
+        with jax.named_scope("attention_core"):
+            c_ok = (jnp.arange(n_chunks) < index * per)[None, None]
+
+            def block(i):
+                rows = i * qb + jnp.arange(qb)
+                k_ok = (jnp.arange(w)[None, :] <= rows[:, None])[None]
+                qs = jax.lax.dynamic_slice_in_dim(q, i * qb, qb, axis=1)
+                return self.attend(
+                    qs, k, v, k_ok, cache["chunk_k"], cache["chunk_v"],
+                    c_ok)[0]
+
+            out = jax.lax.map(block, jnp.arange(w // qb)).reshape(1, w, -1)
+            ks, vs = self.summarize(chunks(k), chunks(v))
         put = lambda table, rows: jax.lax.dynamic_update_slice_in_dim(
             table, rows, index * per, axis=1)
-        cache = {
-            # ``set`` and not the bare ``k``: the ring that came in is
-            # then an operand, and its buffer is used again.
-            "window_k": cache["window_k"].at[:].set(k),
-            "window_v": cache["window_v"].at[:].set(v),
-            "chunk_k": put(cache["chunk_k"], ks),
-            "chunk_v": put(cache["chunk_v"], vs),
-        }
-        return self.o_proj(out), cache
+        with jax.named_scope("cache_write"):
+            cache = {
+                # ``set`` and not the bare ``k``: the ring that came in is
+                # then an operand, and its buffer is used again.
+                "window_k": cache["window_k"].at[:].set(k),
+                "window_v": cache["window_v"].at[:].set(v),
+                "chunk_k": put(cache["chunk_k"], ks),
+                "chunk_v": put(cache["chunk_v"], vs),
+            }
+        with jax.named_scope("attention_proj"):
+            return self.o_proj(out), cache
 
     def step(self, x, pos, cache):
         """One token per row.  x [b, d_model], pos [b] (each row's own
@@ -184,39 +193,47 @@ class EvaAttention(nn.Module):
         ``[0, b)`` are read and written where they lie."""
         w, c = self.window_size, self.chunk_size
         b = x.shape[0]
-        rows = jnp.arange(b)
-        q, k, v = self.qkv(x[:, None], pos[:, None])
-        at = pos % w
-        ring_k = cache["window_k"].at[rows, at].set(k[:, 0])
-        ring_v = cache["window_v"].at[rows, at].set(v[:, 0])
-        k_ok = (jnp.arange(w)[None, :] <= at[:, None])[:, None]
+        with jax.named_scope("cache_write"):
+            rows = jnp.arange(b)
+        with jax.named_scope("attention_proj"):
+            one = x[:, None], pos[:, None]
+        q, k, v = self.qkv(*one)
+        with jax.named_scope("cache_write"):
+            at = pos % w
+            ring_k = cache["window_k"].at[rows, at].set(k[:, 0])
+            ring_v = cache["window_v"].at[rows, at].set(v[:, 0])
         n_chunks = cache["chunk_k"].shape[1]
-        c_ok = (
-            jnp.arange(n_chunks)[None, :] < ((pos // w) * (w // c))[:, None]
-        )[:, None]
-        out = self.attend(
-            q, ring_k[:b], ring_v[:b], k_ok,
-            cache["chunk_k"][:b], cache["chunk_v"][:b], c_ok)[:, 0]
-        # The chunk this position lies in, as the ring holds it; its
-        # summary is stored only by the step that closes the chunk (an
-        # index past the table's end is dropped by the scatter).
-        start = (at // c) * c
-        # One slice per row, not a gather over rows: for a gather the
-        # chip's compiler copies the whole ring into another layout.
-        take = lambda ring: jnp.concatenate([
-            jax.lax.dynamic_slice(
-                ring, (r, start[r], 0, 0), (1, c) + ring.shape[2:])
-            for r in range(b)])
-        ks, vs = self.summarize(take(ring_k), take(ring_v))
-        entry = jnp.where((pos + 1) % c == 0, pos // c, n_chunks)
-        cache = {
-            "window_k": ring_k, "window_v": ring_v,
-            "chunk_k": cache["chunk_k"].at[rows, entry].set(
-                ks, mode="drop"),
-            "chunk_v": cache["chunk_v"].at[rows, entry].set(
-                vs, mode="drop"),
-        }
-        return self.o_proj(out), cache
+        with jax.named_scope("attention_core"):
+            k_ok = (jnp.arange(w)[None, :] <= at[:, None])[:, None]
+            c_ok = (
+                jnp.arange(n_chunks)[None, :]
+                < ((pos // w) * (w // c))[:, None]
+            )[:, None]
+            out = self.attend(
+                q, ring_k[:b], ring_v[:b], k_ok,
+                cache["chunk_k"][:b], cache["chunk_v"][:b], c_ok)[:, 0]
+            # The chunk this position lies in, as the ring holds it; its
+            # summary is stored only by the step that closes the chunk (an
+            # index past the table's end is dropped by the scatter).
+            start = (at // c) * c
+            # One slice per row, not a gather over rows: for a gather the
+            # chip's compiler copies the whole ring into another layout.
+            take = lambda ring: jnp.concatenate([
+                jax.lax.dynamic_slice(
+                    ring, (r, start[r], 0, 0), (1, c) + ring.shape[2:])
+                for r in range(b)])
+            ks, vs = self.summarize(take(ring_k), take(ring_v))
+        with jax.named_scope("cache_write"):
+            entry = jnp.where((pos + 1) % c == 0, pos // c, n_chunks)
+            cache = {
+                "window_k": ring_k, "window_v": ring_v,
+                "chunk_k": cache["chunk_k"].at[rows, entry].set(
+                    ks, mode="drop"),
+                "chunk_v": cache["chunk_v"].at[rows, entry].set(
+                    vs, mode="drop"),
+            }
+        with jax.named_scope("attention_proj"):
+            return self.o_proj(out), cache
 
 
 class GatedMlp(nn.Module):
@@ -230,11 +247,12 @@ class GatedMlp(nn.Module):
         dense = lambda n, name: nn.Dense(
             n, use_bias=False, dtype=self.dtype,
             param_dtype=self.param_dtype, name=name)
-        x = x.astype(self.dtype)
-        gate = dense(self.d_ff, "gate")(x).astype(jnp.float32)
-        up = dense(self.d_ff, "up")(x).astype(jnp.float32)
-        return dense(self.d_model, "down")(
-            (jax.nn.silu(gate) * up).astype(self.dtype))
+        with jax.named_scope("mlp"):
+            x = x.astype(self.dtype)
+            gate = dense(self.d_ff, "gate")(x).astype(jnp.float32)
+            up = dense(self.d_ff, "up")(x).astype(jnp.float32)
+            return dense(self.d_model, "down")(
+                (jax.nn.silu(gate) * up).astype(self.dtype))
 
 
 class OutputHeads(nn.Module):
@@ -249,9 +267,10 @@ class OutputHeads(nn.Module):
         kernel = self.param(
             "kernel", nn.initializers.lecun_normal(),
             (x.shape[-1], self.features), self.param_dtype)
-        return jnp.dot(
-            x, kernel[:, :columns].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST)
+        with jax.named_scope("embed_head"):
+            return jnp.dot(
+                x, kernel[:, :columns].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
 
 
 class EvaBlock(nn.Module):
@@ -281,8 +300,13 @@ class EvaBlock(nn.Module):
             name="mlp")
 
     def _rest(self, h, a):
-        h = h + a.astype(jnp.float32)
-        return h + self.mlp(self.mlp_norm(h)).astype(jnp.float32)
+        # The add that takes a sub-layer into the stream is booked with
+        # the part that closes the sub-layer.
+        with jax.named_scope("attention_proj"):
+            h = h + a.astype(jnp.float32)
+        m = self.mlp(self.mlp_norm(h))
+        with jax.named_scope("mlp"):
+            return h + m.astype(jnp.float32)
 
     def window(self, h, index, cache):
         a, cache = self.attn.window(self.attn_norm(h), index, cache)
@@ -352,7 +376,8 @@ class EvaByte(nn.Module):
     def window_hidden(self, tokens, index, cache):
         """tokens [1, W] -> residual stream [1, W, d_model] after the
         last block, and the row's cache with this window in it."""
-        h = self.embed(tokens).astype(jnp.float32)
+        with jax.named_scope("embed_head"):
+            h = self.embed(tokens).astype(jnp.float32)
         new = {}
         for i, block in enumerate(self.blocks):
             h, new[f"layer_{i}"] = block.window(
@@ -373,12 +398,14 @@ class EvaByte(nn.Module):
         valid position (the prompt's first new byte when this is its
         last window)."""
         h, cache = self.window_hidden(tokens, index, cache)
-        last = jax.lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
-        return cache, self.head_logits(last[:, 0], heads=1)
+        with jax.named_scope("embed_head"):
+            last = jax.lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
+            return cache, self.head_logits(last[:, 0], heads=1)
 
     def decode_step(self, tok, pos, cache):
         """tok, pos [b] -> cache, head 0's logits [b, vocab]."""
-        h = self.embed(tok).astype(jnp.float32)
+        with jax.named_scope("embed_head"):
+            h = self.embed(tok).astype(jnp.float32)
         new = {}
         for i, block in enumerate(self.blocks):
             h, new[f"layer_{i}"] = block.step(h, pos, cache[f"layer_{i}"])

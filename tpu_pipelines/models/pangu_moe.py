@@ -146,9 +146,10 @@ class RMSNorm(nn.Module):
     def __call__(self, x):
         g = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                        self.param_dtype)
-        x = x.astype(jnp.float32)
-        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        return x * jax.lax.rsqrt(ms + self.eps) * g.astype(jnp.float32)
+        with jax.named_scope("norm"):
+            x = x.astype(jnp.float32)
+            ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+            return x * jax.lax.rsqrt(ms + self.eps) * g.astype(jnp.float32)
 
 
 class LatentAttention(nn.Module):
@@ -184,22 +185,25 @@ class LatentAttention(nn.Module):
         and the cache rows ``[b, l, kv_lora_rank + rope]``: the normed
         latent, then the one rotary key."""
         c = self.cfg
-        x = x.astype(c.dtype)
-        c_q = self.q_norm(self.q_down(x)).astype(c.dtype)
-        q = jnp.einsum("blr,rhd->blhd", c_q, self.q_up.astype(c.dtype))
-        q_rope = rope(q[..., c.qk_nope_head_dim:], pos, c.rope_theta)
-        down = self.kv_down(x)
-        latent = self.kv_norm(down[..., :c.kv_lora_rank])
-        k_r = rope(
-            down[..., None, c.kv_lora_rank:], pos, c.rope_theta)[:, :, 0]
-        rows = jnp.concatenate([latent, k_r], -1).astype(c.dtype)
-        return q[..., :c.qk_nope_head_dim], q_rope.astype(c.dtype), rows
+        with jax.named_scope("attention_proj"):
+            x = x.astype(c.dtype)
+            c_q = self.q_norm(self.q_down(x)).astype(c.dtype)
+            q = jnp.einsum("blr,rhd->blhd", c_q, self.q_up.astype(c.dtype))
+            q_rope = rope(q[..., c.qk_nope_head_dim:], pos, c.rope_theta)
+            down = self.kv_down(x)
+            latent = self.kv_norm(down[..., :c.kv_lora_rank])
+            k_r = rope(
+                down[..., None, c.kv_lora_rank:], pos, c.rope_theta)[:, :, 0]
+            rows = jnp.concatenate([latent, k_r], -1).astype(c.dtype)
+            return (q[..., :c.qk_nope_head_dim], q_rope.astype(c.dtype),
+                    rows)
 
     def expanded(self, q_nope, q_rope, rows, ok):
         """Ordinary attention over keys and values expanded from
         ``rows [b, lk, r + rope]``; ``ok [b, lq, lk]``.
         -> [b, lq, h * v]."""
-        with jax.named_scope("mla.attend"):
+        with jax.named_scope("attention_core"), \
+                jax.named_scope("mla.attend"):
             dtype, r = self.cfg.dtype, self.cfg.kv_lora_rank
             f32 = dict(preferred_element_type=jnp.float32)
             latent, k_r = rows[..., :r], rows[..., r:]
@@ -218,7 +222,8 @@ class LatentAttention(nn.Module):
         """One query a row over the latents themselves.  q_nope
         [b, h, nope], q_rope [b, h, rope], rows [b, lk, r + rope], ok
         [b, lk] -> [b, h * v]."""
-        with jax.named_scope("mla.attend"):
+        with jax.named_scope("attention_core"), \
+                jax.named_scope("mla.attend"):
             dtype, r = self.cfg.dtype, self.cfg.kv_lora_rank
             f32 = dict(preferred_element_type=jnp.float32)
             q_lat = jnp.einsum(
@@ -236,8 +241,11 @@ class LatentAttention(nn.Module):
     def full(self, x, pos):
         """A whole sequence under a causal mask, no cache."""
         q_nope, q_rope, rows = self.project(x, pos)
-        ok = pos[:, :, None] >= pos[:, None, :]
-        return self.o_proj(self.expanded(q_nope, q_rope, rows, ok))
+        with jax.named_scope("attention_core"):
+            ok = pos[:, :, None] >= pos[:, None, :]
+            out = self.expanded(q_nope, q_rope, rows, ok)
+        with jax.named_scope("attention_proj"):
+            return self.o_proj(out)
 
     def window(self, x, index, cache, span: int):
         """One window of one row.  x [1, W, d_model]; ``cache
@@ -247,13 +255,17 @@ class LatentAttention(nn.Module):
         to each query's own.  What lies past the prompt's end is masked
         or rewritten by the decode steps that follow."""
         w = x.shape[1]
-        pos = index * w + jnp.arange(w)[None]
+        with jax.named_scope("attention_proj"):
+            pos = index * w + jnp.arange(w)[None]
         q_nope, q_rope, rows = self.project(x, pos)
-        cache = jax.lax.dynamic_update_slice_in_dim(
-            cache, rows, index * w, axis=1)
-        ok = pos[:, :, None] >= jnp.arange(span)[None, None, :]
-        out = self.expanded(q_nope, q_rope, cache[:, :span], ok)
-        return self.o_proj(out), cache
+        with jax.named_scope("cache_write"):
+            cache = jax.lax.dynamic_update_slice_in_dim(
+                cache, rows, index * w, axis=1)
+        with jax.named_scope("attention_core"):
+            ok = pos[:, :, None] >= jnp.arange(span)[None, None, :]
+            out = self.expanded(q_nope, q_rope, cache[:, :span], ok)
+        with jax.named_scope("attention_proj"):
+            return self.o_proj(out), cache
 
     def step(self, x, pos, cache, klen: int):
         """One token per row.  x [b, d_model], pos [b]; ``cache
@@ -261,14 +273,19 @@ class LatentAttention(nn.Module):
         ``[0, b)`` are written at their own positions where they lie and
         attend over their first ``klen`` positions."""
         b = x.shape[0]
-        q_nope, q_rope, rows = self.project(x[:, None], pos[:, None])
-        for r in range(b):
-            cache = jax.lax.dynamic_update_slice(
-                cache, rows[r][None], (r, pos[r], 0))
-        ok = jnp.arange(klen)[None, :] <= pos[:, None]
-        out = self.absorbed(
-            q_nope[:, 0], q_rope[:, 0], cache[:b, :klen], ok)
-        return self.o_proj(out), cache
+        with jax.named_scope("attention_proj"):
+            one = x[:, None], pos[:, None]
+        q_nope, q_rope, rows = self.project(*one)
+        with jax.named_scope("cache_write"):
+            for r in range(b):
+                cache = jax.lax.dynamic_update_slice(
+                    cache, rows[r][None], (r, pos[r], 0))
+        with jax.named_scope("attention_core"):
+            ok = jnp.arange(klen)[None, :] <= pos[:, None]
+            out = self.absorbed(
+                q_nope[:, 0], q_rope[:, 0], cache[:b, :klen], ok)
+        with jax.named_scope("attention_proj"):
+            return self.o_proj(out), cache
 
 
 class RoutedExperts(nn.Module):
@@ -305,7 +322,7 @@ class RoutedExperts(nn.Module):
 
     def route(self, x):
         """-> weights [n, k] and expert ids [n, k] over ALL experts."""
-        with jax.named_scope("moe.route"):
+        with jax.named_scope("mlp"), jax.named_scope("moe.route"):
             sigma = jax.nn.sigmoid(jnp.dot(
                 x.astype(jnp.float32), self.router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST))
@@ -318,13 +335,14 @@ class RoutedExperts(nn.Module):
         c = self.cfg
         k, e = c.experts_per_token, c.experts_held
         weights, ids = self.route(x)
-        local = ids - c.expert_offset
-        held = (local >= 0) & (local < e)
-        x = x.astype(c.dtype)
-        picked = jnp.sum(
-            local[:, :, None] == jnp.arange(e)[None, None, :], 1,
-            dtype=jnp.int32)
-        with jax.named_scope("moe.experts"):
+        with jax.named_scope("mlp"):
+            local = ids - c.expert_offset
+            held = (local >= 0) & (local < e)
+            x = x.astype(c.dtype)
+            picked = jnp.sum(
+                local[:, :, None] == jnp.arange(e)[None, None, :], 1,
+                dtype=jnp.int32)
+        with jax.named_scope("mlp"), jax.named_scope("moe.experts"):
             # Every (token, choice) pair is a row; the pairs of held
             # experts sorted by expert, the others behind them in a group
             # that no product visits.
@@ -346,11 +364,11 @@ class RoutedExperts(nn.Module):
                 x.shape[0], k, -1)
             routed = jnp.sum(
                 jnp.where(held[..., None], ys * weights[..., None], 0.0), 1)
-        with jax.named_scope("moe.shared"):
+        with jax.named_scope("mlp"), jax.named_scope("moe.shared"):
             shared = self.shared(x).astype(jnp.float32)
             if c.shared_average:
                 shared = shared / c.n_shared_experts
-        return shared + routed, picked
+            return shared + routed, picked
 
 
 class PanguBlock(nn.Module):
@@ -371,16 +389,26 @@ class PanguBlock(nn.Module):
     def _rest(self, h, a):
         """-> the stream after both sub-layers, and which held experts
         each token chose (none of them in a dense block)."""
-        h = h + self.attn_post_norm(a)
+        # The add that takes a sub-layer into the stream is booked with
+        # the part that closes the sub-layer.
+        a = self.attn_post_norm(a)
+        with jax.named_scope("attention_proj"):
+            h = h + a
         x = self.ffn_norm(h)
         if self.routed:
-            y, picked = self.ffn(x.reshape(-1, x.shape[-1]))
-            y = y.reshape(x.shape)
-            picked = picked.reshape(x.shape[:-1] + (-1,))
+            with jax.named_scope("mlp"):
+                rows = x.reshape(-1, x.shape[-1])
+            y, picked = self.ffn(rows)
+            with jax.named_scope("mlp"):
+                y = y.reshape(x.shape)
+                picked = picked.reshape(x.shape[:-1] + (-1,))
         else:
             y = self.ffn(x)
-            picked = jnp.zeros(x.shape[:-1] + (0,), jnp.int32)
-        return h + self.ffn_post_norm(y), picked
+            with jax.named_scope("mlp"):
+                picked = jnp.zeros(x.shape[:-1] + (0,), jnp.int32)
+        y = self.ffn_post_norm(y)
+        with jax.named_scope("mlp"):
+            return h + y, picked
 
     def full(self, h, pos):
         return self._rest(h, self.attn.full(self.attn_norm(h), pos))[0]
@@ -436,42 +464,49 @@ class PanguMoE(nn.Module):
     def head_logits(self, h):
         """Float32 logits: the product in the compute dtype, accumulated
         and handed out in float32."""
-        return jnp.dot(
-            self.final_norm(h).astype(self.cfg.dtype),
-            self.head.astype(self.cfg.dtype),
-            preferred_element_type=jnp.float32)
+        with jax.named_scope("embed_head"):
+            return jnp.dot(
+                self.final_norm(h).astype(self.cfg.dtype),
+                self.head.astype(self.cfg.dtype),
+                preferred_element_type=jnp.float32)
 
     def prefill_window(self, tokens, n_valid, index, cache, span: int):
         """One window of a prompt: ``n_valid`` of the ``W`` tokens count.
         -> the row's cache and the logits [1, vocab] at the last valid
         position (the prompt's first new token when this is its last
         window)."""
-        h = self.embed(tokens).astype(jnp.float32)
+        with jax.named_scope("embed_head"):
+            h = self.embed(tokens).astype(jnp.float32)
         new = {}
         for i, block in enumerate(self.blocks):
             h, rows = block.window(
                 h, index, cache[f"layer_{i}"]["latent"], span)
             new[f"layer_{i}"] = {"latent": rows}
-        last = jax.lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
-        return new, self.head_logits(last[:, 0])
+        with jax.named_scope("embed_head"):
+            last = jax.lax.dynamic_slice_in_dim(h, n_valid - 1, 1, axis=1)
+            return new, self.head_logits(last[:, 0])
 
     def decode_step(self, tok, pos, cache, klen: int):
         """tok, pos [b] -> cache, logits [b, vocab], and which held
         experts each row chose, [b, expert layers * experts_held], layer
         by layer."""
-        h = self.embed(tok).astype(jnp.float32)
+        with jax.named_scope("embed_head"):
+            h = self.embed(tok).astype(jnp.float32)
         new, picked = {}, []
         for i, block in enumerate(self.blocks):
             h, rows, chose = block.step(
                 h, pos, cache[f"layer_{i}"]["latent"], klen)
             new[f"layer_{i}"] = {"latent": rows}
             picked.append(chose)
-        return new, self.head_logits(h), jnp.concatenate(picked, -1)
+        logits = self.head_logits(h)
+        with jax.named_scope("mlp"):
+            return new, logits, jnp.concatenate(picked, -1)
 
     def __call__(self, batch: Dict[str, Any], *, deterministic: bool = True):
         inputs = jnp.asarray(batch["inputs"], jnp.int32)
         pos = jnp.broadcast_to(jnp.arange(inputs.shape[1]), inputs.shape)
-        h = self.embed(inputs).astype(jnp.float32)
+        with jax.named_scope("embed_head"):
+            h = self.embed(inputs).astype(jnp.float32)
         for block in self.blocks:
             h = block.full(h, pos)
         logits = self.head_logits(h)

@@ -116,23 +116,23 @@ class T5Stack(nn.Module):
     def __call__(self, x, *, encoded=None, kv_mask=None, enc_mask=None,
                  deterministic: bool = True, decode_pos=None,
                  max_decode_len: Optional[int] = None):
-        if decode_pos is not None:
-            # One-token decode step: bias is the single row of the full
-            # [max_decode_len, max_decode_len] relative-position matrix at
-            # this step's position; the causal structure comes from the
-            # attention cache's <=pos validity mask.  Per-row positions
-            # (a vector) come with one token per row; the attention
-            # layer refuses anything else.
-            bias = RelativePositionBias(
-                n_heads=self.n_heads, bidirectional=not self.causal,
-                name="rel_pos",
-            )(max_decode_len, max_decode_len, row=decode_pos)
-            kv_mask = None
-        else:
-            bias = RelativePositionBias(
-                n_heads=self.n_heads, bidirectional=not self.causal,
-                name="rel_pos",
-            )(x.shape[1], x.shape[1])
+        rel_pos = RelativePositionBias(
+            n_heads=self.n_heads, bidirectional=not self.causal,
+            name="rel_pos",
+        )
+        # The bias is a term of every layer's scores.
+        with jax.named_scope("attention_core"):
+            if decode_pos is not None:
+                # One-token decode step: bias is the single row of the
+                # full [max_decode_len, max_decode_len] relative-position
+                # matrix at this step's position; the causal structure
+                # comes from the attention cache's <=pos validity mask.
+                # Per-row positions (a vector) come with one token per
+                # row; the attention layer refuses anything else.
+                bias = rel_pos(max_decode_len, max_decode_len, row=decode_pos)
+                kv_mask = None
+            else:
+                bias = rel_pos(x.shape[1], x.shape[1])
         for i in range(self.n_layers):
             x = TransformerBlock(
                 n_heads=self.n_heads, head_dim=self.head_dim, d_ff=self.d_ff,
@@ -147,7 +147,8 @@ class T5Stack(nn.Module):
                 self_bias=bias, deterministic=deterministic,
                 decode_pos=decode_pos, max_decode_len=max_decode_len,
             )
-        return nn.RMSNorm(dtype=self.dtype, name="final_norm")(x)
+        with jax.named_scope("norm"):
+            return nn.RMSNorm(dtype=self.dtype, name="final_norm")(x)
 
 
 class T5(nn.Module):
@@ -183,13 +184,15 @@ class T5(nn.Module):
                                name="decoder", **common)
 
     def encode(self, inputs, input_mask=None, *, deterministic=True):
-        x = self.shared(jnp.asarray(inputs, jnp.int32))
+        with jax.named_scope("embed_head"):
+            x = self.shared(jnp.asarray(inputs, jnp.int32))
         return self.encoder(x, kv_mask=input_mask, deterministic=deterministic)
 
     def decode(self, decoder_input_ids, encoded, *, target_mask=None,
                enc_mask=None, deterministic=True, decode_pos=None,
                max_decode_len=None):
-        y = self.shared(jnp.asarray(decoder_input_ids, jnp.int32))
+        with jax.named_scope("embed_head"):
+            y = self.shared(jnp.asarray(decoder_input_ids, jnp.int32))
         y = self.decoder(
             y, encoded=encoded, kv_mask=target_mask, enc_mask=enc_mask,
             deterministic=deterministic, decode_pos=decode_pos,
@@ -197,11 +200,12 @@ class T5(nn.Module):
         )
         # tied embedding as the output projection, T5's 1/sqrt(d) scaling;
         # logits in float32 for a stable softmax loss
-        y = y * (self.d_model ** -0.5)
-        return jnp.einsum(
-            "bld,vd->blv", y.astype(jnp.float32),
-            self.shared.embedding.astype(jnp.float32),
-        )
+        with jax.named_scope("embed_head"):
+            y = y * (self.d_model ** -0.5)
+            return jnp.einsum(
+                "bld,vd->blv", y.astype(jnp.float32),
+                self.shared.embedding.astype(jnp.float32),
+            )
 
     def __call__(self, batch: Dict[str, Any], *, deterministic: bool = True):
         inputs = jnp.asarray(batch["inputs"], jnp.int32)
@@ -271,12 +275,15 @@ def _decode_one(model, params, cache, tok, encoded, enc_mask, pos,
     variables = {"params": params}
     if cache is not None:
         variables["cache"] = cache
+    with jax.named_scope("embed_head"):
+        one = tok[:, None]
     logits, mut = model.apply(
-        variables, tok[:, None], encoded, enc_mask=enc_mask,
+        variables, one, encoded, enc_mask=enc_mask,
         decode_pos=pos, max_decode_len=max_decode_len,
         method=T5.decode, mutable=["cache"],
     )
-    return mut["cache"], logits[:, 0]
+    with jax.named_scope("embed_head"):
+        return mut["cache"], logits[:, 0]
 
 
 def prefill_decode(model, params, inputs, input_mask, max_decode_len: int,
@@ -295,7 +302,8 @@ def prefill_decode(model, params, inputs, input_mask, max_decode_len: int,
     encoded = model.apply(
         {"params": params}, inputs, input_mask, method=T5.encode
     )
-    bos = jnp.full((inputs.shape[0],), pad_id, jnp.int32)
+    with jax.named_scope("embed_head"):
+        bos = jnp.full((inputs.shape[0],), pad_id, jnp.int32)
     cache, logits0 = _decode_one(
         model, params, None, bos, encoded, input_mask, 0, max_decode_len
     )
@@ -354,12 +362,15 @@ def make_continuous_decode_fns(
 
     def step(params, cache, tok, pos, encoded, enc_mask, klen: int):
         variables = {"params": params, "cache": cache}
+        with jax.named_scope("embed_head"):
+            one = tok[:, None]
         logits, mut = model.apply(
-            variables, tok[:, None], encoded, enc_mask=enc_mask,
+            variables, one, encoded, enc_mask=enc_mask,
             decode_pos=pos, max_decode_len=klen,
             method=T5.decode, mutable=["cache"],
         )
-        return mut["cache"], logits[:, 0]
+        with jax.named_scope("embed_head"):
+            return mut["cache"], logits[:, 0]
 
     return SimpleNamespace(
         prefill=prefill,

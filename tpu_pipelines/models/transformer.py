@@ -222,13 +222,19 @@ class MlpBlock(nn.Module):
     @nn.compact
     def __call__(self, x, *, deterministic: bool = True):
         d_model = x.shape[-1]
-        h = nn.Dense(self.d_ff, dtype=self.dtype, name="wi")(x)
-        h = getattr(nn, self.activation)(h)
+        with jax.named_scope("mlp"):
+            h = nn.Dense(self.d_ff, dtype=self.dtype, name="wi")(x)
+            h = getattr(nn, self.activation)(h)
         if self.dropout_rate and self.dropout_site == "hidden":
-            h = nn.Dropout(self.dropout_rate)(h, deterministic=deterministic)
-        out = nn.Dense(d_model, dtype=self.dtype, name="wo")(h)
+            with jax.named_scope("dropout"):
+                h = nn.Dropout(self.dropout_rate)(
+                    h, deterministic=deterministic)
+        with jax.named_scope("mlp"):
+            out = nn.Dense(d_model, dtype=self.dtype, name="wo")(h)
         if self.dropout_rate and self.dropout_site == "output":
-            out = nn.Dropout(self.dropout_rate)(out, deterministic=deterministic)
+            with jax.named_scope("dropout"):
+                out = nn.Dropout(self.dropout_rate)(
+                    out, deterministic=deterministic)
         return out
 
 
@@ -334,9 +340,10 @@ class MoEMlpBlock(nn.Module):
         out = out.reshape(b, l, d)
         if self.dropout_rate:
             # Same output-site dropout as the dense MlpBlock it replaces.
-            out = nn.Dropout(self.dropout_rate)(
-                out, deterministic=deterministic
-            )
+            with jax.named_scope("dropout"):
+                out = nn.Dropout(self.dropout_rate)(
+                    out, deterministic=deterministic
+                )
         return out
 
 
@@ -430,7 +437,11 @@ class MultiHeadAttention(nn.Module):
         proj = lambda name: nn.DenseGeneral(
             (self.n_heads, self.head_dim), axis=-1, dtype=self.dtype, name=name
         )
-        q = proj("query")(x_q)
+        out_proj = lambda: nn.DenseGeneral(
+            x_q.shape[-1], axis=(-2, -1), dtype=self.dtype, name="out"
+        )
+        with jax.named_scope("attention_proj"):
+            q = proj("query")(x_q)
 
         if decode_pos is not None and not is_self:
             # Cross attention during incremental decoding: the encoder output
@@ -439,22 +450,24 @@ class MultiHeadAttention(nn.Module):
             # the cache-creating apply (step 0) and later steps reuse the
             # stored arrays instead of re-projecting [b, enc_len, d_model]
             # through two matmuls per layer per token.
-            cached_ek = self.variable(
-                "cache", "cached_enc_key", lambda: proj("key")(x_kv)
-            )
-            cached_ev = self.variable(
-                "cache", "cached_enc_value", lambda: proj("value")(x_kv)
-            )
-            out = dense_attention(
-                q, cached_ek.value, cached_ev.value, causal=False,
-                kv_mask=kv_mask, bias=bias,
-            )
-            return nn.DenseGeneral(
-                x_q.shape[-1], axis=(-2, -1), dtype=self.dtype, name="out"
-            )(out)
+            with jax.named_scope("attention_proj"):
+                cached_ek = self.variable(
+                    "cache", "cached_enc_key", lambda: proj("key")(x_kv)
+                )
+                cached_ev = self.variable(
+                    "cache", "cached_enc_value", lambda: proj("value")(x_kv)
+                )
+            with jax.named_scope("attention_core"):
+                out = dense_attention(
+                    q, cached_ek.value, cached_ev.value, causal=False,
+                    kv_mask=kv_mask, bias=bias,
+                )
+            with jax.named_scope("attention_proj"):
+                return out_proj()(out)
 
-        k = proj("key")(x_kv)
-        v = proj("value")(x_kv)
+        with jax.named_scope("attention_proj"):
+            k = proj("key")(x_kv)
+            v = proj("value")(x_kv)
 
         if decode_pos is not None and is_self:
             # Incremental decoding: x_q is this step's single token
@@ -474,31 +487,40 @@ class MultiHeadAttention(nn.Module):
             if max_decode_len is None:
                 raise ValueError("decode_pos requires max_decode_len")
             b = q.shape[0]
-            cached_k = self.variable(
-                "cache", "cached_key", jnp.zeros,
-                (b, max_decode_len, self.n_heads, self.head_dim), k.dtype,
-            )
-            cached_v = self.variable(
-                "cache", "cached_value", jnp.zeros,
-                (b, max_decode_len, self.n_heads, self.head_dim), v.dtype,
-            )
+            with jax.named_scope("cache_write"):
+                cached_k = self.variable(
+                    "cache", "cached_key", jnp.zeros,
+                    (b, max_decode_len, self.n_heads, self.head_dim),
+                    k.dtype,
+                )
+                cached_v = self.variable(
+                    "cache", "cached_value", jnp.zeros,
+                    (b, max_decode_len, self.n_heads, self.head_dim),
+                    v.dtype,
+                )
             pos = jnp.asarray(decode_pos, jnp.int32)
             if pos.ndim == 0:
-                cached_k.value = jax.lax.dynamic_update_slice_in_dim(
-                    cached_k.value, k, pos, axis=1
-                )
-                cached_v.value = jax.lax.dynamic_update_slice_in_dim(
-                    cached_v.value, v, pos, axis=1
-                )
+                with jax.named_scope("cache_write"):
+                    cached_k.value = jax.lax.dynamic_update_slice_in_dim(
+                        cached_k.value, k, pos, axis=1
+                    )
+                    cached_v.value = jax.lax.dynamic_update_slice_in_dim(
+                        cached_v.value, v, pos, axis=1
+                    )
                 # Positions after ``pos`` are zeros (future steps): mask.
-                valid = jnp.broadcast_to(
-                    (jnp.arange(max_decode_len) <= pos)[None, :],
-                    (b, max_decode_len),
-                )
+                with jax.named_scope("attention_core"):
+                    valid = jnp.broadcast_to(
+                        (jnp.arange(max_decode_len) <= pos)[None, :],
+                        (b, max_decode_len),
+                    )
             elif q.shape[1] == 1:
-                cached_k.value = _write_rows_at(cached_k.value, k, pos)
-                cached_v.value = _write_rows_at(cached_v.value, v, pos)
-                valid = jnp.arange(max_decode_len)[None, :] <= pos[:, None]
+                with jax.named_scope("cache_write"):
+                    cached_k.value = _write_rows_at(cached_k.value, k, pos)
+                    cached_v.value = _write_rows_at(cached_v.value, v, pos)
+                with jax.named_scope("attention_core"):
+                    valid = (
+                        jnp.arange(max_decode_len)[None, :] <= pos[:, None]
+                    )
             else:
                 raise ValueError(
                     "per-row decode positions come with one token per "
@@ -513,23 +535,23 @@ class MultiHeadAttention(nn.Module):
                 impl = choose_decode_impl(
                     b, self.n_heads, max_decode_len, self.head_dim
                 )
-            if impl == "flash":
-                from tpu_pipelines.ops.flash_attention import (
-                    flash_decode_attention,
-                )
+            with jax.named_scope("attention_core"):
+                if impl == "flash":
+                    from tpu_pipelines.ops.flash_attention import (
+                        flash_decode_attention,
+                    )
 
-                out = flash_decode_attention(
-                    q, cached_k.value, cached_v.value,
-                    kv_mask=valid, bias=bias,
-                )
-            else:
-                out = dense_attention(
-                    q, cached_k.value, cached_v.value, causal=False,
-                    kv_mask=valid, bias=bias,
-                )
-            return nn.DenseGeneral(
-                x_q.shape[-1], axis=(-2, -1), dtype=self.dtype, name="out"
-            )(out)
+                    out = flash_decode_attention(
+                        q, cached_k.value, cached_v.value,
+                        kv_mask=valid, bias=bias,
+                    )
+                else:
+                    out = dense_attention(
+                        q, cached_k.value, cached_v.value, causal=False,
+                        kv_mask=valid, bias=bias,
+                    )
+            with jax.named_scope("attention_proj"):
+                return out_proj()(out)
 
         impl = self.attn_impl
         if impl == "auto":
@@ -552,31 +574,38 @@ class MultiHeadAttention(nn.Module):
         use_flash = (
             impl == "flash" and is_self and bias is None
         )
-        if use_ring:
-            out = ring_attention(
-                q, k, v, mesh=self.mesh, causal=self.causal, kv_mask=kv_mask
-            )
-        elif use_ulysses:
-            from tpu_pipelines.parallel.ring_attention import ulysses_attention
+        with jax.named_scope("attention_core"):
+            if use_ring:
+                out = ring_attention(
+                    q, k, v, mesh=self.mesh, causal=self.causal,
+                    kv_mask=kv_mask,
+                )
+            elif use_ulysses:
+                from tpu_pipelines.parallel.ring_attention import (
+                    ulysses_attention,
+                )
 
-            out = ulysses_attention(
-                q, k, v, mesh=self.mesh, causal=self.causal, kv_mask=kv_mask
-            )
-        elif use_flash:
-            from tpu_pipelines.ops.flash_attention import flash_attention
+                out = ulysses_attention(
+                    q, k, v, mesh=self.mesh, causal=self.causal,
+                    kv_mask=kv_mask,
+                )
+            elif use_flash:
+                from tpu_pipelines.ops.flash_attention import flash_attention
 
-            out = flash_attention(
-                q, k, v, causal=self.causal, kv_mask=kv_mask
-            )
-        else:
-            out = dense_attention(
-                q, k, v, causal=self.causal, kv_mask=kv_mask, bias=bias
-            )
-        out = nn.DenseGeneral(
-            x_q.shape[-1], axis=(-2, -1), dtype=self.dtype, name="out"
-        )(out)
+                out = flash_attention(
+                    q, k, v, causal=self.causal, kv_mask=kv_mask
+                )
+            else:
+                out = dense_attention(
+                    q, k, v, causal=self.causal, kv_mask=kv_mask, bias=bias
+                )
+        with jax.named_scope("attention_proj"):
+            out = out_proj()(out)
         if self.dropout_rate:
-            out = nn.Dropout(self.dropout_rate)(out, deterministic=deterministic)
+            with jax.named_scope("dropout"):
+                out = nn.Dropout(self.dropout_rate)(
+                    out, deterministic=deterministic
+                )
         return out
 
 
@@ -622,10 +651,18 @@ class TransformerBlock(nn.Module):
         norm_cls = nn.RMSNorm if self.norm == "rmsnorm" else nn.LayerNorm
         ln = lambda name: norm_cls(dtype=self.dtype, name=name)
 
+        def normed(name, x):
+            with jax.named_scope("norm"):
+                return ln(f"{name}_norm")(x)
+
         def sub(x, name, fn):
-            if self.prenorm:
-                return x + fn(ln(f"{name}_norm")(x))
-            return ln(f"{name}_norm")(x + fn(x))
+            # The add that takes a sub-layer into the stream is booked
+            # with the part that closes the sub-layer.
+            part = "mlp" if name == "mlp" else "attention_proj"
+            y = fn(normed(name, x) if self.prenorm else x)
+            with jax.named_scope(part):
+                x = x + y
+            return x if self.prenorm else normed(name, x)
 
         x = sub(x, "attn", lambda h: mha("attn", self.causal)(
             h, kv_mask=kv_mask, bias=self_bias, deterministic=deterministic,
@@ -637,12 +674,16 @@ class TransformerBlock(nn.Module):
                 decode_pos=decode_pos,
             ))
         if self.moe_experts > 0:
-            x = sub(x, "mlp", lambda h: MoEMlpBlock(
-                num_experts=self.moe_experts, d_ff=self.d_ff,
-                capacity_factor=self.moe_capacity_factor,
-                dropout_rate=self.dropout_rate,
-                dtype=self.dtype, name="moe",
-            )(h, deterministic=deterministic))
+            def moe(h):
+                with jax.named_scope("mlp"):
+                    return MoEMlpBlock(
+                        num_experts=self.moe_experts, d_ff=self.d_ff,
+                        capacity_factor=self.moe_capacity_factor,
+                        dropout_rate=self.dropout_rate,
+                        dtype=self.dtype, name="moe",
+                    )(h, deterministic=deterministic)
+
+            x = sub(x, "mlp", moe)
         else:
             x = sub(x, "mlp", lambda h: MlpBlock(
                 d_ff=self.d_ff, dropout_rate=self.dropout_rate,

@@ -59,6 +59,29 @@ from typing import Any, Dict, Iterator, Optional
 
 ENV_TRACE = "TPP_TRACE"
 
+# The parts of a device program, one vocabulary for every model, the
+# trainer and the engine: each is a ``jax.named_scope`` around the
+# operations it names (models/*.py, trainer/train_loop.py ``optimizer``,
+# serving/generative.py ``arena`` and ``sample``), so a profile's
+# operations carry it in their ``op_name``.  A scope costs at trace time
+# only.  Readers of device traces book an operation to the innermost of
+# these words in its path (the benchmark's ``program_parts``); the models'
+# older, finer scopes (``eva.attend``, ``mla.attend``, ``moe.experts``,
+# ``attn.full``, ...) each lie inside one of them.  docs/OBSERVABILITY.md
+# "Parts of a device program in a profile".
+DEVICE_PARTS = (
+    "attention_core",   # scores, mask, softmax, weighted sum
+    "attention_proj",   # q/k/v/o products, rotary, latent up/down
+    "mlp",              # dense MLP; route + grouped products + shared
+    "norm",
+    "embed_head",       # embedding lookup, logits, the trainer's loss
+    "dropout",          # the mask's bits and the compare
+    "optimizer",
+    "cache_write",      # a step's or a window's write into its cache
+    "arena",            # insert, move, clear, a step's bucket cut and put
+    "sample",           # argmax / token choice
+)
+
 SCHEMA_VERSION = 1
 
 

@@ -338,6 +338,14 @@ class TrainLoopConfig:
 LossFn = Callable[[Any, Dict[str, jax.Array], jax.Array], Tuple[jax.Array, Dict[str, jax.Array]]]
 
 
+# The windowed loop's device program as a profile's "XLA Modules" line
+# names it: ``jit_`` + the ``__name__`` of the function handed to
+# jax.jit.  Readers of traces find the program by this name (the
+# benchmark's ``*_share.train`` readers): renaming the inner function is
+# a change to this constant and to those readers.
+WINDOW_PROGRAM_NAME = "jit_train_window"
+
+
 def _param_sharding(mesh: Mesh, config: TrainLoopConfig, params):
     if config.param_partition is None:
         return jax.tree_util.tree_map(lambda _: replicate(mesh), params)
@@ -477,11 +485,14 @@ def _make_dp_forward_backward(
     data_axis = mesh.shape["data"]
 
     def call_loss(params, ms, mb, rng):
-        """Either loss contract -> (loss, (metrics, new_model_state))."""
-        if has_model_state:
-            return loss_fn(params, ms, mb, rng)
-        loss, metrics = loss_fn(params, mb, rng)
-        return loss, (metrics, ms)
+        """Either loss contract -> (loss, (metrics, new_model_state)).
+        Under the head's scope, as ``forward_backward`` of the implicit
+        path has it."""
+        with jax.named_scope("embed_head"):
+            if has_model_state:
+                return loss_fn(params, ms, mb, rng)
+            loss, metrics = loss_fn(params, mb, rng)
+            return loss, (metrics, ms)
 
     def plain_micro(params, ms, mb, rng):
         (loss, (metrics, new_ms)), grads = jax.value_and_grad(
@@ -886,19 +897,23 @@ def train_loop(
         )
 
     def forward_backward(params, mstate, mb, rng):
-        if has_model_state:
-            (loss, (metrics, new_mstate)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True
-            )(params, mstate, mb, rng)
-        else:
-            (loss, metrics), grads = jax.value_and_grad(loss_fn, has_aux=True)(
-                params, mb, rng
-            )
-            new_mstate = mstate
+        # What the loss function does outside its model's own scopes (the
+        # loss itself, its labels and metrics) is the head's.
+        with jax.named_scope("embed_head"):
+            if has_model_state:
+                (loss, (metrics, new_mstate)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True
+                )(params, mstate, mb, rng)
+            else:
+                (loss, metrics), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True
+                )(params, mb, rng)
+                new_mstate = mstate
         return loss, metrics, grads, new_mstate
 
     def step_fn(state: TrainState, batch) -> Tuple[TrainState, Dict[str, jax.Array]]:
-        step_rng = jax.random.fold_in(state.rng, state.step)
+        with jax.named_scope("dropout"):
+            step_rng = jax.random.fold_in(state.rng, state.step)
         if dp_fb is not None:
             # Accumulation and model_state live INSIDE the collective fb
             # (the inner scan accumulates under the same shard_map as the
@@ -962,8 +977,11 @@ def train_loop(
             grads = jax.tree_util.tree_map(lambda g: g * inv, g_sum)
             loss = l_sum * inv
             metrics = {k: v * inv for k, v in m_sum.items()}
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         metrics = {"loss": loss, **metrics}
         return (
             TrainState(
@@ -1323,8 +1341,13 @@ def train_loop(
                 k: NamedSharding(mesh, P(None, *s.spec))
                 for k, s in batch_shard.items()
             }
+
+            # WINDOW_PROGRAM_NAME is ``jit_`` + this function's name.
+            def train_window(st, bats):
+                return jax.lax.scan(step_fn, st, bats)
+
             train_window = jax.jit(
-                lambda st, bats: jax.lax.scan(step_fn, st, bats),
+                train_window,
                 in_shardings=(state_shard, win_shard),
                 out_shardings=(state_shard, None),
                 donate_argnums=(0,) if config.donate_state else (),
