@@ -7,12 +7,15 @@ vocabulary ``observability.trace.DEVICE_PARTS``.
   (79564dd) lowered to, the module's name aside (the train window's was
   ``jit__lambda``).  A PR that changes a program on purpose lowers it
   again and replaces the hash: ``python tests/test_device_parts.py``
-  prints the table.
+  prints the table.  PR 41 did so for ``pangu_moe step`` (its attention
+  became one kernel); the twelve others stand as they were.
 * Every instruction of the lowered HLO that a line of the program wrote
   and that computes carries a word of the vocabulary in its scope path
   or inherits one by the rule of ``benchmark/program_parts.py``; under
   2 % stay ``unnamed``.
 * The train window's program is ``jit_train_window``.
+* openPangu's step hands its attention kernel every layer's cache where
+  it lies, and the kernel's own operations are attention (ISSUE 41).
 """
 
 import hashlib
@@ -25,7 +28,8 @@ import numpy as np
 import pytest
 
 # sha256 of ``lowered.as_text()`` with the module's name taken out, at
-# commit 79564dd (the parent of the scopes), jax 0.9.0, 8 CPU devices.
+# commit 79564dd (the parent of the scopes), jax 0.9.0, 8 CPU devices;
+# ``pangu_moe step`` at PR 41.
 PARENT_SHA256 = {
     "t5 prefill":
         "78e3c0297e8727c951835f623307a467a28c383a2f2fb948af2a20bbad3c584c",
@@ -44,7 +48,7 @@ PARENT_SHA256 = {
     "evabyte prefill_window":
         "225d0ab75b35d40ac33bdab436e2a1b231c9dffb6550a51307fcd06bdc12fc3b",
     "pangu_moe step":
-        "acfcff53f46301ab5c8da8094b9762da0213dc399d6509a6cf8cef17b15b50d3",
+        "c161da6fa0d013c7b1894d9e0d57ec29a793993fb57fbcc5a076cb0c8a0db3cf",
     "pangu_moe prefill_window":
         "b8521f090df4036c850fc824f22c2c5c6bb36eff295679066ab4dd2d151f9574",
     "command_a step":
@@ -187,17 +191,18 @@ def _train_window(note, monkeypatch):
 
 
 def lower_all(monkeypatch):
-    """``{program: (module name, sha256 of its text, HloModuleProto)}``."""
+    """``{program: (module name, sha256 of its text, HloModuleProto, HLO
+    text)}``."""
     out = {}
 
     def note(name, lowered):
         text = lowered.as_text()
         module = re.search(r"module @(\w+)", text).group(1)
         text = re.sub(r"module @\w+", "module @_", text, count=1)
+        hlo = lowered.compiler_ir(dialect="hlo")
         out[name] = (
             module, hashlib.sha256(text.encode()).hexdigest(),
-            lowered.compiler_ir(
-                dialect="hlo").as_serialized_hlo_module_proto())
+            hlo.as_serialized_hlo_module_proto(), hlo.as_hlo_text())
 
     _t5_programs(note)
     _decoder_programs(note)
@@ -249,6 +254,51 @@ def test_every_instruction_has_a_part_or_inherits_one(program, lowered):
     assert len(unnamed) < 0.02 * len(written), unnamed[:20]
     assert {part for part, _ in parts.values()} - {
         program_parts.UNNAMED} <= set(program_parts.PARTS)
+
+
+def test_pangu_step_hands_its_kernel_the_cache_where_it_lies(lowered):
+    """The fixture's arena is 4 slots x 160 positions x 24 numbers in
+    each of 3 layers, and the step runs 2 rows.  Nothing of the arena's
+    size is copied, padded, sliced or gathered on its way to the
+    attention (the parent sliced ``cache[:2, :160]`` out): each layer's
+    array is written row by row and handed over whole, as ONE view with
+    its positions last, which is how the chip keeps such an array
+    (tests/test_tpu_compile.py holds the compiled step to "nothing
+    moved").  Every operation of the kernel, interpreted here, is booked
+    to the attention itself."""
+    from benchmark import program_parts
+
+    raw, text = lowered["pangu_moe step"][2:]
+    module = program_parts.messages()["HloModule"].FromString(raw)
+    path = {ins.name: ins.metadata.op_name
+            for comp in module.computations for ins in comp.instructions}
+    parts = program_parts.module_parts(raw)
+    kernel = [n for n, p in path.items() if "latent_decode_attention" in p]
+    assert len(kernel) > 100
+    assert {parts[n] for n in kernel} == {("attention_core", "own")}
+
+    arena, live = ["4", "160", "24"], ["2", "160", "24"]
+    made = {}         # opcode -> [(name, dims, the line)] of the arena's size
+    for line in text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(", line)
+        if m is None:
+            continue
+        name, dims, opcode = m.groups()
+        assert sorted(dims.split(",")) != sorted(live), line
+        if sorted(dims.split(",")) == sorted(arena):
+            made.setdefault(opcode, []).append((name, dims, line))
+    assert set(made) == {
+        "parameter", "dynamic-update-slice", "transpose"}, sorted(made)
+    assert len(made["transpose"]) == 3                  # one a layer
+    for name, dims, line in made["transpose"]:
+        assert dims == "4,24,160" and "dimensions={0,2,1}" in line
+        assert "mla.attend" in path[name]
+        assert parts[name] == ("attention_core", "own")
+    # the writes: 2 rows x 3 layers, each into the array as it came
+    assert len(made["dynamic-update-slice"]) == 2 * 3
+    assert {dims for _, dims, _ in made["dynamic-update-slice"]} == {
+        "4,160,24"}
 
 
 def test_the_window_program_has_its_name(lowered):

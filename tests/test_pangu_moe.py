@@ -211,7 +211,8 @@ def test_absorbed_attention_is_the_expanded_attention(f32):
     depth = jnp.asarray([39, 0, 17, 5, 30])
     ok = jnp.arange(40)[None, :] <= depth[:, None]
     take = lambda q: q[jnp.arange(5), depth]
-    one = attn.apply(p, take(q_n), take(q_r), rows, ok, method="absorbed")
+    one = attn.apply(p, take(q_n), take(q_r), rows, depth, 40,
+                     method="absorbed")
     two = attn.apply(
         p, take(q_n)[:, None], take(q_r)[:, None], rows, ok[:, None],
         method="expanded")[:, 0]
@@ -465,6 +466,23 @@ def test_engine_counts_latent_bytes_and_expert_assignments(engine_run):
     assert 0 < steps <= get("serving_decode_steps_total")
     ratio = get("serving_decode_expert_load_ratio_sum") / steps
     assert 1.0 <= ratio <= HELD
+
+
+def test_engine_counts_the_key_blocks_the_kernel_fetches(engine_run):
+    """Beside the valid latents, the span: whole key blocks up to the one
+    that holds a row's position.  160 positions are one block of the
+    kernel's (cut at the array's end), so every fed row is handed all 160
+    in each layer, whatever its depth."""
+    from tpu_pipelines.ops.flash_attention import latent_block
+
+    _, reg, prompts, budgets, _ = engine_run
+    get = lambda name: reg.get(name).labels("0", "latent").get()
+    assert latent_block(MAX_IN + MAX_OUT) == 256
+    fed = sum(m - 1 for m in budgets)
+    span = get("serving_decode_cache_span_bytes_total")
+    assert span == fed * (MAX_IN + MAX_OUT) * 3 * ROW * 4
+    valid = get("serving_decode_cache_read_bytes_total")
+    assert 0.2 * span < valid < 0.8 * span
 
 
 def test_kv_bucket_and_pages_count_the_prompt(f32):

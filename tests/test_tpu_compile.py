@@ -528,19 +528,31 @@ def test_grouped_expert_products_compile_for_v5e(one_chip, tokens):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 ** 2
 
 
-def test_latent_row_write_keeps_the_cache_where_it_lies_on_v5e(one_chip):
+def test_latent_row_write_keeps_the_cache_where_it_lies_on_v5e(
+        one_chip, monkeypatch):
     """The engine's own step program for the latent-cache contract
     (models/pangu_moe.py) at the published widths, two layers (one dense,
     one with its 16 experts), the whole 128 x 2,560 arena handed over in
     place and donated: the chip keeps ``bf16[128, 2560, 576]``
     position-minor, and the 128 row-wise ``dynamic_update_slice`` of a
     step leave it so.  ``.at[rows, pos].set`` compiled to two copies of
-    the whole array a layer (PERF.md §6, PR 31)."""
+    the whole array a layer (PERF.md §6, PR 31).
+
+    Each layer's attention over the latents is ONE Pallas kernel
+    (ops/flash_attention.py ``latent_decode_attention``) that is handed
+    the array with its positions last: where it lies, so the view is a
+    bitcast and nothing of the array's size is copied or transposed (a
+    kernel over ``[slots, positions, width]`` blocks cost a copy of the
+    whole array a layer; PERF.md §6, PR 41).  No float32 array of 128
+    heads' scores over the positions is left in the program.  The program
+    picks its kernels by the backend, which is the CPU here: the test
+    tells it the chip's."""
     from types import SimpleNamespace
 
     from tpu_pipelines.models import pangu_moe as pm
     from tpu_pipelines.serving import generative as gen
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     rows, positions = 128, 2560
     model = pm.build_pangu_moe_model(dict(
         vocab_size=19200, n_layers=2, n_dense_layers=1, experts_held=16,
@@ -573,8 +585,56 @@ def test_latent_row_write_keeps_the_cache_where_it_lies_on_v5e(one_chip):
     moved = re.findall(
         rf"= {re.escape(leaf)}\S* (?:copy|transpose|scatter)\(.*", text)
     assert not moved, (len(moved), moved[:2])
+    assert not re.findall(
+        rf"= bf16\[{rows},576,{positions}\]\S* (?:copy|transpose)\(.*", text)
+    kernels = re.findall(r"custom_call_target=\"tpu_custom_call\".*", text)
+    assert sum("latent_decode_attention" in k for k in kernels) == 2
+    assert len(kernels) == 2 + 3       # and the expert layer's three products
+    assert f"f32[{rows},128,{positions}]" not in text
     # the step's tally rides behind its tokens: one result of 128 + 16
     assert f"s32[{rows + 16}]" in text
+
+
+LATENT_CASES = [
+    # (id, rows, heads, r, rope, slots, positions, dtype): the cell's
+    # shapes; the fixtures' (one key block reaching past the array's
+    # end); positions that no block divides
+    ("cell", 128, 128, 512, 64, 128, 2560, jnp.bfloat16),
+    ("fixture", 2, 4, 16, 8, 4, 160, jnp.float32),
+    ("ragged_1000", 8, 16, 128, 64, 8, 1000, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize(
+    "rows,heads,r,rope,slots,positions,dtype",
+    [c[1:] for c in LATENT_CASES], ids=[c[0] for c in LATENT_CASES])
+def test_latent_decode_kernel_reads_the_cache_where_it_lies_on_v5e(
+        one_chip, monkeypatch, rows, heads, r, rope, slots, positions, dtype):
+    """``latent_decode_attention`` alone: the chip's compiler takes its
+    blocks (a key block is ``[r + rope, 512]`` of an array it keeps
+    position-minor), and the view it is handed, positions last, is a
+    bitcast of the array: no copy or transpose of it."""
+    import importlib
+
+    fa = importlib.import_module("tpu_pipelines.ops.flash_attention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    width = r + rope
+    compiled = jax.jit(lambda q, cache, pos: fa.latent_decode_attention(
+        q, cache, pos, positions, scale=width ** -0.5, r=r)).lower(
+            _sds((rows, heads, width), dtype, one_chip),
+            _sds((slots, positions, width), dtype, one_chip),
+            _sds((rows,), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    name = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    param_layouts, _, _ = _entry_layouts(text)
+    assert param_layouts[1].startswith(
+        f"{name}[{slots},{positions},{width}]{{1,2,0")
+    moved = re.findall(
+        rf"= {name}\[{slots},(?:{positions},{width}|{width},{positions})\]"
+        r"\S* (?:copy|transpose)\(.*", text)
+    assert not moved, moved[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 ** 2
 
 
 @pytest.mark.parametrize("tokens", [32, 512])

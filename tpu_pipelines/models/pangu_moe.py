@@ -25,6 +25,9 @@ function (tests/test_pangu_moe.py holds them equal):
     ``W_uv`` into the output, and the row attends over the latents as
     they lie in the cache; expanding them would cost ``heads * (nope + v)
     * kv_lora_rank`` multiply-adds a cached position at every step.
+    Scores, mask, softmax and weights x latents are one Pallas kernel
+    (``ops/flash_attention.py latent_decode_attention``; interpreted off
+    the chip) that reads each row's latents once, to the row's depth.
 
 The routed-expert layer is told which experts it holds
 (``experts_held`` from ``expert_offset``).  It scores all ``n_experts``
@@ -55,6 +58,8 @@ import jax.numpy as jnp
 from flax import linen as nn
 
 from tpu_pipelines.models.evabyte import NEG_INF, GatedMlp, rope
+from tpu_pipelines.ops.flash_attention import (
+    latent_block, latent_decode_attention)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -218,24 +223,23 @@ class LatentAttention(nn.Module):
             out = jnp.einsum("bhqk,bkhd->bqhd", p.astype(dtype), v)
             return out.reshape(out.shape[:2] + (-1,))
 
-    def absorbed(self, q_nope, q_rope, rows, ok):
-        """One query a row over the latents themselves.  q_nope
-        [b, h, nope], q_rope [b, h, rope], rows [b, lk, r + rope], ok
-        [b, lk] -> [b, h * v]."""
+    def absorbed(self, q_nope, q_rope, cache, pos, klen: int):
+        """One query a row over the latents themselves, as they lie:
+        q_nope [b, h, nope], q_rope [b, h, rope]; row ``i`` attends over
+        ``cache[i, :pos[i] + 1]`` of ``cache [slots, positions, r +
+        rope]``, ``pos`` [b] under ``klen``.  -> [b, h * v]."""
         with jax.named_scope("attention_core"), \
                 jax.named_scope("mla.attend"):
             dtype, r = self.cfg.dtype, self.cfg.kv_lora_rank
-            f32 = dict(preferred_element_type=jnp.float32)
             q_lat = jnp.einsum(
                 "bhd,rhd->bhr", q_nope, self.k_up.astype(dtype))
-            q = jnp.concatenate([q_lat, q_rope], -1)
-            score = jnp.einsum("bhr,bkr->bhk", q, rows, **f32) * self.scale
-            p = jax.nn.softmax(jnp.where(ok[:, None], score, NEG_INF), -1)
-            # Over the whole row, the rotary key's columns with it: a
-            # slice of the cache would be a copy of it.
-            o_lat = jnp.einsum("bhk,bkr->bhr", p.astype(dtype), rows)
-            out = jnp.einsum(
-                "bhr,rhd->bhd", o_lat[..., :r], self.v_up.astype(dtype))
+            # One kernel for scores, mask, softmax and weights x latents:
+            # a cached row is its own key (every column) and value (the
+            # first ``r``), read once and only to the row's depth.
+            o_lat = latent_decode_attention(
+                jnp.concatenate([q_lat, q_rope], -1), cache, pos, klen,
+                scale=self.scale, r=r)
+            out = jnp.einsum("bhr,rhd->bhd", o_lat, self.v_up.astype(dtype))
             return out.reshape(out.shape[0], -1)
 
     def full(self, x, pos):
@@ -271,7 +275,8 @@ class LatentAttention(nn.Module):
         """One token per row.  x [b, d_model], pos [b]; ``cache
         [slots, positions, r + rope]`` with ``slots >= b``: rows
         ``[0, b)`` are written at their own positions where they lie and
-        attend over their first ``klen`` positions."""
+        attend over their own first ``pos + 1`` positions, all of them
+        among the first ``klen``."""
         b = x.shape[0]
         with jax.named_scope("attention_proj"):
             one = x[:, None], pos[:, None]
@@ -280,10 +285,7 @@ class LatentAttention(nn.Module):
             for r in range(b):
                 cache = jax.lax.dynamic_update_slice(
                     cache, rows[r][None], (r, pos[r], 0))
-        with jax.named_scope("attention_core"):
-            ok = jnp.arange(klen)[None, :] <= pos[:, None]
-            out = self.absorbed(
-                q_nope[:, 0], q_rope[:, 0], cache[:b, :klen], ok)
+        out = self.absorbed(q_nope[:, 0], q_rope[:, 0], cache, pos, klen)
         with jax.named_scope("attention_proj"):
             return self.o_proj(out), cache
 
@@ -586,7 +588,8 @@ def make_continuous_decode_fns(
         kv_lora_rank + qk_rope_head_dim]``, indexed by position from the
         prompt's first token on, written by every step, worked on in
         place: ``step`` is handed every slot's rows, writes row ``i`` at
-        ``pos[i]`` and attends over its first ``klen`` positions;
+        ``pos[i]`` and attends over its first ``pos[i] + 1`` positions,
+        fetched by whole key blocks (``klen`` bounds their number);
       - ``cache_positions``: how many positions a row holds, prompt and
         new tokens together; the engine's kv buckets cover
         ``first_decode_pos + tokens held``;
@@ -602,6 +605,7 @@ def make_continuous_decode_fns(
     span = -(-int(max_input_len) // w) * w
     positions = max(span, int(max_input_len) + int(max_decode_len))
     row_bytes = c.n_layers * c.row_width * jnp.dtype(c.dtype).itemsize
+    block = latent_block(positions)
     expert_layers, held = c.n_layers - c.n_dense_layers, c.experts_held
 
     def prefill_window(params, cache, tokens, n_valid, index):
@@ -620,9 +624,14 @@ def make_continuous_decode_fns(
     def step_account(at, tally, bucket=None):
         """``at``: the live rows' positions; ``tally``: assignments to
         each held expert, layer by layer (``bucket``, the step's rows
-        and positions, is not read)."""
+        and positions, is not read).  The span is what the attention
+        kernel fetches for the live rows: whole key blocks up to the one
+        that holds a row's position."""
         return {
             "cache_bytes": {"latent": sum(t + 1 for t in at) * row_bytes},
+            "cache_span_bytes": {"latent": sum(
+                min((t // block + 1) * block, positions) for t in at
+            ) * row_bytes},
             **tally_account(tally, held)}
 
     return SimpleNamespace(

@@ -27,6 +27,12 @@ heads of a key/value head share each block of keys a step fetches, and the
 mask is the caller's rule over the POSITION each entry holds (a ring as it
 lies, an array by position), so a block no query sees is skipped.
 models/command_a.py's prefill window and whole-sequence pass run it.
+
+``latent_decode_attention`` (forward only, last in the file) is its
+decode-shaped sibling for a latent cache: one query a row, the row's
+heads as the rows of both products, each row's own cached rows as keys
+and (their first columns) values, read once and only to the row's depth.
+models/pangu_moe.py's decode step runs it.
 """
 
 from __future__ import annotations
@@ -747,3 +753,131 @@ def grouped_attention(q, k, v, held, start, sees):
         name="grouped_attention",
     )(start.reshape(1), fetch, q, k, v, held[None, :])
     return out[:, :, :l]
+
+
+# ------------------------------------------- a row's heads, to its depth
+
+# Cached rows that a step of ``latent_decode_attention``'s grid holds at
+# most, from a sweep on the chip at openPangu's shapes (128 rows of 128
+# heads x 576 over 2,560 positions at depths 128 to 2,560; PERF.md section
+# 6, PR 41).
+LATENT_BLOCK_K = 512
+
+
+def latent_block(positions: int) -> int:
+    """Cached rows a key block of ``latent_decode_attention`` holds over a
+    cache of ``positions`` (a block tiles by the lanes of its scores); a
+    row at position ``t`` is handed the blocks ``[0, t // block]`` and no
+    other."""
+    return min(LATENT_BLOCK_K, -(-positions // LANES) * LANES)
+
+
+def _latent_fetch(j, depth, block_k: int):
+    """The key block that step ``j`` of a row at position ``depth`` is
+    handed: its own, or the row's last live one again."""
+    return jnp.minimum(j, depth // block_k)
+
+
+def _latent_kernel(pos_ref, q_ref, c_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                   scale):
+    r, block_k = acc_ref.shape[1], c_ref.shape[2]
+    j = pl.program_id(1)
+    depth = pos_ref[pl.program_id(0)]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    # A block that starts past the row's depth was handed the last live
+    # block again (nothing is fetched) and computes nothing.
+    @pl.when(j * block_k <= depth)
+    def _step():
+        # What a block holds past the row's depth, and past the array's
+        # end, is no number that may be used, as a score or as a value.
+        # (Masking only a row's last live block saves nothing: 0.64 ms
+        # either way at openPangu's shapes; PERF.md section 6, PR 41.)
+        live = j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1) <= depth
+        keys = c_ref[0]                                  # [width, bk]
+        values = jnp.where(live, keys[:r], 0)
+        s = jnp.where(live, jnp.dot(                     # [h, bk] on MXU
+            q_ref[0], keys, preferred_element_type=jnp.float32
+        ) * scale, NEG_INF)
+        m_prev = m_ref[...]                              # [h, LANES]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _across(m_new, block_k))
+        keep = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * keep + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * _across(keep, r) + jax.lax.dot_general(
+            p.astype(values.dtype), values, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _final():
+        o_ref[0] = (acc_ref[...] / _across(l_ref[...], r)).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q, cache, pos, klen: int, *, scale: float,
+                            r: int):
+    """One query a row and head over the row's own cached rows, to the
+    row's depth (forward only): the middle of latent attention's absorbed
+    form.
+
+    ``q``: [rows, heads, width].  ``cache``: [slots, positions, width] as
+    it lies, ``slots >= rows``, ``positions >= klen``: row ``i`` attends
+    over ``cache[i, :pos[i] + 1]``, every column of a cached row as the
+    key and its first ``r`` as the value (``r`` at most 128 or a multiple
+    of it); ``pos`` [rows] int32, each under ``klen``.  What lies past a
+    row's depth is never used, whatever it holds.
+
+    Grid (row, key block), the key block the sequential axis.  A step
+    holds the row's heads as the M side of both products and ONE block of
+    the row's cached rows, fetched once.  Scores [heads, block], their
+    running maximum and sum and the accumulator are float32 and never
+    leave VMEM; the scale multiplies the float32 scores; the weights enter
+    the second product in the cache's dtype.  A key block that starts past
+    ``pos[i]`` (handed over by scalar prefetch) is neither computed nor
+    fetched: its step's index map names the row's last live block again.
+
+    The kernel is handed the cache with its positions last.  That is how
+    the chip keeps an array whose rows are no multiple of 128 numbers wide
+    and whose positions are (openPangu's 576 x 2,560: no padding that
+    way), so the view is the array where it lies and nothing is moved
+    (tests/test_tpu_compile.py holds the compiled step to that); a block
+    of keys is then the right-hand side of the first product as it comes.
+    -> [rows, heads, r] in ``q``'s dtype.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, heads, width = q.shape
+    block_k = latent_block(cache.shape[1])
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, scale=scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows, -(-klen // block_k)),
+            in_specs=[
+                pl.BlockSpec((1, heads, width), lambda i, j, pos: (i, 0, 0)),
+                pl.BlockSpec(
+                    (1, width, block_k),
+                    lambda i, j, pos: (
+                        i, 0, _latent_fetch(j, pos[i], block_k))),
+            ],
+            out_specs=pl.BlockSpec(
+                (1, heads, r), lambda i, j, pos: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((heads, r), jnp.float32),
+                pltpu.VMEM((heads, LANES), jnp.float32),
+                pltpu.VMEM((heads, LANES), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, heads, r), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_resolve_interpret(None),
+        name="latent_decode_attention",
+    )(pos.astype(jnp.int32), q, jnp.swapaxes(cache, 1, 2))

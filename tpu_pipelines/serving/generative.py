@@ -1860,7 +1860,9 @@ class DecodeTelemetry:
             "Bytes that the arrays of each kind span in the decode steps' "
             "(rows, positions) buckets, summed over the steps run: what a "
             "step that reads its arrays whole reads, beside "
-            "serving_decode_cache_read_bytes_total, what is valid.",
+            "serving_decode_cache_read_bytes_total, what is valid (for a "
+            "kind that a kernel reads to each row's depth, the key blocks "
+            "it fetches).",
             labels=kind_lab,
         )
         self._expert_assignments = registry.counter(
