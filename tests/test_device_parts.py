@@ -29,7 +29,7 @@ import pytest
 
 # sha256 of ``lowered.as_text()`` with the module's name taken out, at
 # commit 79564dd (the parent of the scopes), jax 0.9.0, 8 CPU devices;
-# ``pangu_moe step`` at PR 41.
+# ``pangu_moe step`` at PR 41, the two of ``xing`` at PR 42.
 PARENT_SHA256 = {
     "t5 prefill":
         "78e3c0297e8727c951835f623307a467a28c383a2f2fb948af2a20bbad3c584c",
@@ -57,16 +57,22 @@ PARENT_SHA256 = {
         "7e9824a475451993b54c051b67f99ee0a9982652f5c5161283a01b21cde08ba5",
     "bert train window":
         "7d82ea7ece7539cce25527b6839238514229a4fca89d159572e35d93bcbf696a",
+    # new in PR 42, as that PR lowered them: a guard from there on
+    "xing step":
+        "aad54e255503e26a996e8f1141e2b914ded8250ad5ccddd6b0890901a0b7c3a5",
+    "xing prefill_window":
+        "53b5e966d36b60dd07935419c0c58b2d7a5f61bc48499a82bfee47961e66b568",
 }
 PROGRAMS = (
     "t5 prefill", "t5 insert", "t5 move", "t5 clear", "t5 step 2x4",
     "t5 step 4x8", "evabyte step", "evabyte prefill_window",
     "pangu_moe step", "pangu_moe prefill_window", "command_a step",
     "command_a prefill_window", "bert train window",
+    "xing step", "xing prefill_window",
 )
 OLDER_SCOPES = {
     "eva.attend", "eva.summarize", "mla.attend", "moe.route",
-    "moe.experts", "moe.shared",
+    "moe.experts", "moe.shared", "mhc.mix", "mhc.apply",
 }
 
 
@@ -108,7 +114,7 @@ def _t5_programs(note):
 def _decoder_programs(note):
     from tpu_pipelines.serving.generative import GenerativeEngine
 
-    for name in ("evabyte", "pangu_moe", "command_a"):
+    for name in ("evabyte", "pangu_moe", "command_a", "xing"):
         tiny = importlib.import_module("test_" + name)
         model, params = tiny.build()
         engine = GenerativeEngine(
@@ -333,7 +339,7 @@ def test_one_vocabulary_in_one_place():
     # the part every word is opened in somewhere
     assert set(trace.DEVICE_PARTS) <= set(found)
     for name in ("transformer.py", "bert.py", "t5.py", "evabyte.py",
-                 "pangu_moe.py", "command_a.py", "train_loop.py",
+                 "pangu_moe.py", "command_a.py", "xing.py", "train_loop.py",
                  "generative.py"):
         assert any(name in files for files in found.values()), name
 

@@ -595,6 +595,72 @@ def test_latent_row_write_keeps_the_cache_where_it_lies_on_v5e(
     assert f"s32[{rows + 16}]" in text
 
 
+def test_xing_step_and_window_compile_at_the_cells_shapes_on_v5e(
+        one_chip, monkeypatch):
+    """The engine's step program for models/xing.py at the cell's shapes
+    (32 rows, 32 heads, 18,432 positions, all 64 experts held), two layers
+    (one dense, one with experts), and the prefill window of 1,024 tokens
+    over a span of 16,384.  The latent kernel of PR 41, written at 128
+    heads x 2,560 positions, is handed ``bf16[32, 18432, 576]`` where it
+    lies, one kernel a layer; the stream mixing leaves the arena alone;
+    the window attends in blocks (``LatentAttention.blocked``, one
+    ``grouped_attention`` a layer at 640 columns), so no float32 array of
+    32 heads' scores over the span is left in either program; the grouped
+    products take the tiles of ``TILES`` for 3,584 x 1,024."""
+    from types import SimpleNamespace
+
+    from tpu_pipelines.models import pangu_moe as pm, xing
+    from tpu_pipelines.serving import generative as gen
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, positions, window = 32, 18432, 1024
+    model = xing.build_xing_model(dict(
+        n_layers=2, n_dense_layers=1, n_mtp=0))
+    assert (3584, 1024) in pm.TILES and (1024, 3584) in pm.TILES
+    fns = xing.make_continuous_decode_fns(
+        model, max_decode_len=2048, eos_id=131072, max_input_len=16384,
+        prefill_window_len=window)
+    assert fns.cache_positions == positions
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), {"inputs": jnp.zeros((1, 8), jnp.int32)})["params"])
+    state = (
+        jax.eval_shape(lambda: fns.blank_cache(rows)),
+        jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
+        jnp.zeros((rows,), bool), jnp.zeros((rows, 0), jnp.float32),
+        jnp.zeros((rows, 16384), jnp.int32),
+    )
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+    program = gen.GenerativeEngine._build_step(
+        SimpleNamespace(pad_id=0), rows, positions, fns)
+    compiled = program.lower(on_chip(params), on_chip(state)).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    leaf = f"bf16[{rows},{positions},576]"
+    moved = re.findall(
+        rf"= {re.escape(leaf)}\S* (?:copy|transpose|scatter)\(.*", text)
+    assert not moved, (len(moved), moved[:2])
+    kernels = re.findall(r"custom_call_target=\"tpu_custom_call\".*", text)
+    assert sum("latent_decode_attention" in k for k in kernels) == 2
+    assert len(kernels) == 2 + 3       # and the expert layer's three products
+    assert f"f32[{rows},32,{positions}]" not in text
+    # the step's tally rides behind its tokens: one result of 32 + 64
+    assert f"s32[{rows + 64}]" in text
+
+    row = jax.eval_shape(lambda: fns.blank_cache(1))
+    i32 = _sds((), jnp.int32, one_chip)
+    compiled = jax.jit(fns.prefill_window, donate_argnums=1).lower(
+        on_chip(params), on_chip(row), _sds((1, window), jnp.int32, one_chip),
+        i32, i32).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    kernels = re.findall(r"custom_call_target=\"tpu_custom_call\".*", text)
+    assert sum("grouped_attention" in k for k in kernels) == 2
+    assert f"f32[1,32,{window},16384]" not in text
+    assert f"f32[32,{window},16384]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 ** 3
+
+
 LATENT_CASES = [
     # (id, rows, heads, r, rope, slots, positions, dtype): the cell's
     # shapes; the fixtures' (one key block reaching past the array's

@@ -15,6 +15,11 @@ Present:
     over grouped-query heads, a parallel block and sigmoid-routed experts
     beside averaged shared ones (``build_command_a_model``; served through
     serving/generative.py; rings and by-position arrays in one arena)
+  - xing: decoder-only LM whose residual path is four streams mixed by
+    Sinkhorn-normalised matrices (mHC) around pangu_moe's latent attention
+    (under YaRN) and routed experts (with a selection bias)
+    (``tpu_pipelines.models.xing.build_xing_model``; served through
+    serving/generative.py under pangu_moe's contract)
   - transformer: shared sharded blocks (TP over 'model', ring-attention SP
     over 'seq') used by bert/t5
 

@@ -31,6 +31,8 @@ window: what the tests compare with the plain reference).
 
 from __future__ import annotations
 
+import dataclasses
+import math
 from types import SimpleNamespace
 from typing import Any, Dict, Optional
 
@@ -41,17 +43,65 @@ from flax import linen as nn
 NEG_INF = -1e30
 
 
-def rope(x, pos, theta: float):
+def rope(x, pos, theta: float, inv=None):
     """Rotary position code in float32.  x [b, l, h, d], pos [b, l]
     (absolute positions).  Halves are paired: element ``i`` with
-    ``i + d/2``, the convention of the source's family."""
+    ``i + d/2``, the convention of the source's family.  ``inv`` [d/2]:
+    the inverse frequencies where they are not ``theta``'s own (scaled
+    positions: ``yarn_inv_freq``)."""
     d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if inv is None:
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = pos.astype(jnp.float32)[..., None] * inv           # [b, l, d/2]
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
     x = x.astype(jnp.float32)
     x1, x2 = x[..., : d // 2], x[..., d // 2:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+@dataclasses.dataclass(frozen=True)
+class Yarn:
+    """A source's ``rope_scaling`` group of type ``yarn``, under its own
+    keys."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    @classmethod
+    def of(cls, group):
+        """``group``: the source's dict (other keys passed over), a
+        ``Yarn``, or None."""
+        if group is None or isinstance(group, cls):
+            return group
+        return cls(**{f.name: group[f.name] for f in dataclasses.fields(cls)
+                      if f.name in group})
+
+
+def yarn_mscale(factor: float, k: float) -> float:
+    """``0.1 k ln(factor) + 1``: what a scaling by ``factor`` multiplies
+    magnitudes with."""
+    return 0.1 * k * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(d: int, theta: float, y: Yarn):
+    """YaRN's inverse frequencies [d/2], float32: pair ``i`` keeps
+    ``theta ** (-2i/d)`` below the ramp (it turns more than ``beta_fast``
+    times over the original positions), is divided by ``factor`` above it
+    (under ``beta_slow`` turns), and is blended linearly between."""
+    turn = lambda beta: d * math.log(
+        y.original_max_position_embeddings / (2 * math.pi * beta)) \
+        / (2 * math.log(theta))
+    lo = max(math.floor(turn(y.beta_fast)), 0)
+    hi = min(math.ceil(turn(y.beta_slow)), d - 1)
+    f = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ramp = jnp.clip(
+        (jnp.arange(d // 2, dtype=jnp.float32) - lo)
+        / (hi - lo if hi > lo else 0.001), 0.0, 1.0)
+    return f / y.factor * ramp + f * (1.0 - ramp)
 
 
 class UnitOffsetRMSNorm(nn.Module):

@@ -57,9 +57,10 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from tpu_pipelines.models.evabyte import NEG_INF, GatedMlp, rope
+from tpu_pipelines.models.evabyte import (
+    NEG_INF, GatedMlp, rope, yarn_inv_freq, yarn_mscale)
 from tpu_pipelines.ops.flash_attention import (
-    latent_block, latent_decode_attention)
+    LANES, grouped_attention, latent_block, latent_decode_attention)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,6 +91,12 @@ class PanguConfig:
     rope_theta: float = 25600000.0
     rms_norm_eps: float = 1e-5
     n_mtp: int = 1
+    # Scaled rotary positions (an ``evabyte.Yarn``); None: ``rope_theta``'s
+    # own frequencies and the plain softmax scale.
+    rope_scaling: Any = None
+    # The router chooses by ``sigmoid(score) + e_score_correction_bias``
+    # (a stored leaf) and weighs by the sigmoid alone.
+    selection_bias: bool = False
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
 
@@ -109,11 +116,15 @@ ROW_TILE = 32
 TILE_IN, TILE_OUT = (768, 2048), (2048, 768)
 # The tile of a product by its weights' shape ``(k, n)``, where a sweep on
 # the chip found one (openPangu's 7,680 x 2,048 experts: the two above;
-# Command A+'s 4,096 x 4,096: PERF.md section 6, PR 35); ``TILE_IN``
-# where none was swept.
+# Command A+'s 4,096 x 4,096: PERF.md section 6, PR 35; Xing4.0's 3,584 x
+# 1,024 at 2 and 4 rows an expert over 64: the whole matrix into the
+# expert width, 578 and 661 us against 598 and 699 at (512, 1024), and
+# half of it out, 578 and 667 us, where the whole read 577 us and once
+# 1,711; PERF.md section 6, PR 42); ``TILE_IN`` where none was swept.
 TILES = {
     (7680, 2048): TILE_IN, (2048, 7680): TILE_OUT,
     (4096, 4096): (4096, 512),
+    (3584, 1024): (3584, 1024), (1024, 3584): (1024, 1792),
 }
 
 
@@ -157,6 +168,28 @@ class RMSNorm(nn.Module):
             return x * jax.lax.rsqrt(ms + self.eps) * g.astype(jnp.float32)
 
 
+# The most a prefill window's float32 scores may take when they are written
+# out (heads x window x span x 4 bytes; openPangu's 128 x 256 x 1,024 are
+# 134 MB).  Over it the window attends in blocks (``LatentAttention.
+# blocked``): Xing4.0's 32 x 1,024 x 16,384 would be 2.1 GB beside 13.8 GB
+# resident.
+WINDOW_SCORE_BYTES = 256 * 2 ** 20
+# Query heads that share one fetch of a key block in ``blocked``: what the
+# kernel holds of them at 512 queries x 640 columns is 38 MB of VMEM.
+BLOCKED_HEADS = 8
+
+
+def softmax_scale(c) -> float:
+    """What attention scores are multiplied with: the key width's inverse
+    root, times YaRN's ``mscale(factor, mscale_all_dim) ** 2`` where the
+    positions are scaled."""
+    scale = (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+    y = c.rope_scaling
+    if y is None:
+        return scale
+    return scale * yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+
+
 class LatentAttention(nn.Module):
     cfg: PanguConfig
 
@@ -182,7 +215,19 @@ class LatentAttention(nn.Module):
         self.v_up = self.param(
             "v_up", init, (r, h, c.v_head_dim), c.param_dtype)
         self.o_proj = dense(c.d_model, "o_proj")
-        self.scale = (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+        self.scale = softmax_scale(c)
+
+    def rotate(self, x, pos):
+        """The rotary code of the configuration on x [b, l, h, rope]."""
+        c = self.cfg
+        y = c.rope_scaling
+        if y is None:
+            return rope(x, pos, c.rope_theta)
+        turned = rope(x, pos, c.rope_theta, yarn_inv_freq(
+            c.qk_rope_head_dim, c.rope_theta, y))
+        gain = yarn_mscale(y.factor, y.mscale) \
+            / yarn_mscale(y.factor, y.mscale_all_dim)
+        return turned if gain == 1.0 else turned * gain
 
     def project(self, x, pos):
         """x [b, l, d_model], pos [b, l] -> the queries' two parts
@@ -194,11 +239,10 @@ class LatentAttention(nn.Module):
             x = x.astype(c.dtype)
             c_q = self.q_norm(self.q_down(x)).astype(c.dtype)
             q = jnp.einsum("blr,rhd->blhd", c_q, self.q_up.astype(c.dtype))
-            q_rope = rope(q[..., c.qk_nope_head_dim:], pos, c.rope_theta)
+            q_rope = self.rotate(q[..., c.qk_nope_head_dim:], pos)
             down = self.kv_down(x)
             latent = self.kv_norm(down[..., :c.kv_lora_rank])
-            k_r = rope(
-                down[..., None, c.kv_lora_rank:], pos, c.rope_theta)[:, :, 0]
+            k_r = self.rotate(down[..., None, c.kv_lora_rank:], pos)[:, :, 0]
             rows = jnp.concatenate([latent, k_r], -1).astype(c.dtype)
             return (q[..., :c.qk_nope_head_dim], q_rope.astype(c.dtype),
                     rows)
@@ -242,6 +286,42 @@ class LatentAttention(nn.Module):
             out = jnp.einsum("bhr,rhd->bhd", o_lat, self.v_up.astype(dtype))
             return out.reshape(out.shape[0], -1)
 
+    def blocked(self, q_nope, q_rope, rows, start):
+        """One row's window over the row's own latents in the absorbed
+        form, as ONE kernel (ops/flash_attention.py ``grouped_attention``):
+        every head's key and value is the cached row itself, so the heads
+        are the grouped queries of one key/value head, a key block is
+        fetched once for ``BLOCKED_HEADS`` of them, the scores never leave
+        the chip's fast memory and a block past the window's last position
+        is neither fetched nor computed.  q_nope [1, W, h, nope], q_rope
+        [1, W, h, rope] at positions ``start + [0, W)``; ``rows [1, span,
+        r + rope]`` by position.  -> [1, W, h * v]."""
+        with jax.named_scope("attention_core"), \
+                jax.named_scope("mla.attend"):
+            c, dtype, r = self.cfg, self.cfg.dtype, self.cfg.kv_lora_rank
+            h, w = c.n_heads, q_nope.shape[1]
+            g = BLOCKED_HEADS if h % BLOCKED_HEADS == 0 else h
+            q_lat = jnp.einsum(
+                "lhd,rhd->hlr", q_nope[0], self.k_up.astype(dtype),
+                preferred_element_type=jnp.float32)
+            q = jnp.concatenate(
+                [q_lat, jnp.swapaxes(q_rope[0], 0, 1)], -1) * self.scale
+            # columns to whole lanes: zeros add nothing to a score, and
+            # the columns past ``r`` of the result are not read
+            pad = -c.row_width % LANES
+            q = jnp.pad(q.astype(dtype), ((0, 0), (0, 0), (0, pad)))
+            keys = jnp.broadcast_to(
+                jnp.pad(rows[0], ((0, 0), (0, pad)))[None],
+                (h // g, rows.shape[1], c.row_width + pad))
+            o_lat = grouped_attention(
+                q.reshape(h // g, g, w, -1), keys, keys,
+                jnp.arange(rows.shape[1]), start,
+                lambda t, u: (u <= t) & (u >= 0))
+            out = jnp.einsum(
+                "hlr,rhd->lhd", o_lat.reshape(h, w, -1)[..., :r],
+                self.v_up.astype(dtype))
+            return out.reshape(1, w, -1)
+
     def full(self, x, pos):
         """A whole sequence under a causal mask, no cache."""
         q_nope, q_rope, rows = self.project(x, pos)
@@ -265,9 +345,12 @@ class LatentAttention(nn.Module):
         with jax.named_scope("cache_write"):
             cache = jax.lax.dynamic_update_slice_in_dim(
                 cache, rows, index * w, axis=1)
-        with jax.named_scope("attention_core"):
-            ok = pos[:, :, None] >= jnp.arange(span)[None, None, :]
-            out = self.expanded(q_nope, q_rope, cache[:, :span], ok)
+        if self.cfg.n_heads * w * span * 4 > WINDOW_SCORE_BYTES:
+            out = self.blocked(q_nope, q_rope, cache[:, :span], index * w)
+        else:
+            with jax.named_scope("attention_core"):
+                ok = pos[:, :, None] >= jnp.arange(span)[None, None, :]
+                out = self.expanded(q_nope, q_rope, cache[:, :span], ok)
         with jax.named_scope("attention_proj"):
             return self.o_proj(out), cache
 
@@ -300,8 +383,9 @@ class RoutedExperts(nn.Module):
     ``experts_held`` from ``expert_offset``, ``experts_per_token``,
     ``routed_scaling_factor`` (1 where the source has none),
     ``n_shared_experts`` as ONE gated MLP of their joint width, whose
-    output is their sum, or with ``shared_average`` their mean; ``dtype``
-    and ``param_dtype``."""
+    output is their sum, or with ``shared_average`` their mean;
+    ``selection_bias`` where it has the field; ``dtype`` and
+    ``param_dtype``."""
 
     cfg: Any
 
@@ -321,14 +405,24 @@ class RoutedExperts(nn.Module):
             "experts_up", init, (e, d, f), c.param_dtype)
         self.experts_down = self.param(
             "experts_down", init, (e, f, d), c.param_dtype)
+        self.bias = self.param(
+            "e_score_correction_bias", nn.initializers.zeros,
+            (c.n_experts,), jnp.float32,
+        ) if getattr(c, "selection_bias", False) else None
 
     def route(self, x):
-        """-> weights [n, k] and expert ids [n, k] over ALL experts."""
+        """-> weights [n, k] and expert ids [n, k] over ALL experts.  A
+        selection bias chooses and does not weigh."""
         with jax.named_scope("mlp"), jax.named_scope("moe.route"):
             sigma = jax.nn.sigmoid(jnp.dot(
                 x.astype(jnp.float32), self.router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST))
-            top, ids = jax.lax.top_k(sigma, self.cfg.experts_per_token)
+            if self.bias is None:
+                top, ids = jax.lax.top_k(sigma, self.cfg.experts_per_token)
+            else:
+                _, ids = jax.lax.top_k(
+                    sigma + self.bias, self.cfg.experts_per_token)
+                top = jnp.take_along_axis(sigma, ids, -1)
             weights = self.cfg.routed_scaling_factor * top / jnp.sum(
                 top, -1, keepdims=True)
             return weights, ids
@@ -431,13 +525,17 @@ class PanguMoE(nn.Module):
     token ``t + 2`` from the stream at ``t`` and token ``t + 1``."""
 
     cfg: PanguConfig
+    # the block every layer and the prediction module are made of
+    # (models/xing.py puts its own here)
+    block_cls = PanguBlock
 
     def setup(self):
         c = self.cfg
         self.embed = nn.Embed(
             c.vocab_size, c.d_model, param_dtype=c.param_dtype, name="embed")
         self.blocks = [
-            PanguBlock(c, routed=i >= c.n_dense_layers, name=f"layer_{i}")
+            self.block_cls(
+                c, routed=i >= c.n_dense_layers, name=f"layer_{i}")
             for i in range(c.n_layers)
         ]
         norm = lambda name: RMSNorm(c.rms_norm_eps, c.param_dtype, name=name)
@@ -451,7 +549,8 @@ class PanguMoE(nn.Module):
             self.mtp_proj = nn.Dense(
                 c.d_model, use_bias=False, dtype=c.dtype,
                 param_dtype=c.param_dtype, name="mtp_proj")
-            self.mtp_block = PanguBlock(c, routed=True, name="mtp_block")
+            self.mtp_block = self.block_cls(
+                c, routed=True, name="mtp_block")
 
     def blank_cache(self, batch: int, positions: int):
         """Per layer one array of latent rows, ``[batch, positions,
@@ -611,12 +710,12 @@ def make_continuous_decode_fns(
     def prefill_window(params, cache, tokens, n_valid, index):
         return model.apply(
             {"params": params}, tokens, n_valid, index, cache, span,
-            method=PanguMoE.prefill_window)
+            method="prefill_window")
 
     def step(params, cache, tok, pos, encoded, enc_mask, klen: int):
         return model.apply(
             {"params": params}, tok, pos, cache, klen,
-            method=PanguMoE.decode_step)
+            method="decode_step")
 
     def blank_cache(batch: int):
         return model.blank_cache(batch, positions)
