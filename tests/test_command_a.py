@@ -540,11 +540,13 @@ def test_engine_counts_both_kinds_of_cache_and_the_experts(engine_run):
     assert read("window") == sum(
         min(t + 1, WINDOW) for t in fed) * 6 * ENTRY * 4
     assert read("full") == sum(t + 1 for t in fed) * 2 * ENTRY * 4
-    # the arrays span whole rings and every position, for the bucket's rows
+    # the span is what the step's kernel fetches for the live rows, whole
+    # key blocks to a row's depth: at this size one block a row, which
+    # holds the ring's 16 entries or the array's 104 positions
     span = lambda kind: get("serving_decode_cache_span_bytes_total", kind)
     steps = get("serving_decode_steps_total")
-    assert span("window") % (6 * WINDOW * ENTRY * 4) == 0
-    assert span("full") % (2 * (MAX_IN + MAX_OUT) * ENTRY * 4) == 0
+    assert span("window") == len(fed) * 6 * WINDOW * ENTRY * 4
+    assert span("full") == len(fed) * 2 * (MAX_IN + MAX_OUT) * ENTRY * 4
     assert span("window") >= steps * 6 * WINDOW * ENTRY * 4
     assert read("window") < span("window") and read("full") < span("full")
     assert get("serving_decode_window_rollovers_total") == sum(
@@ -564,7 +566,9 @@ def test_kv_buckets_cut_the_full_arrays_and_not_the_rings(f32):
     """``page_size`` 16: the step of a row that holds a prompt of 40 and
     ``held`` tokens runs in a bucket of at least ``40 + held`` positions
     of the full arrays, whatever the rings hold, and the streams are the
-    same under any bucket."""
+    same under any bucket.  The bucket bounds the kernel's grid; what a
+    row fetches is whole key blocks to its depth under either bucket, at
+    this size one block that holds all 104 positions."""
     from tpu_pipelines.observability.metrics import MetricsRegistry
     from tpu_pipelines.serving.generative import GenerativeEngine
 
@@ -590,7 +594,7 @@ def test_kv_buckets_cut_the_full_arrays_and_not_the_rings(f32):
     assert np.asarray(served).tolist() == alone.tolist()
     assert engine.compiles_after_warm == 0
     span = reg.get("serving_decode_cache_span_bytes_total")
-    assert span.labels("0", "full").get() < 29 * 2 * 104 * ENTRY * 4
+    assert span.labels("0", "full").get() == 29 * 2 * 104 * ENTRY * 4
 
 
 def test_the_contract_states_what_the_engine_may_not_guess(f32):
@@ -619,6 +623,7 @@ def test_the_contract_states_what_the_engine_may_not_guess(f32):
     account = fns.step_account([3, 40], [0] * 31 + [2], (2, 104))
     assert account["cache_entries"] == {
         "window": 6 * (4 + 16), "full": 2 * (4 + 41)}
+    # one key block a row: the ring's 16 entries, the array's 104 positions
     assert account["cache_span_bytes"] == {
         "window": 6 * 2 * 16 * ENTRY * 4, "full": 2 * 2 * 104 * ENTRY * 4}
     assert account["experts_touched"] == 1

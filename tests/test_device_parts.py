@@ -7,8 +7,9 @@ vocabulary ``observability.trace.DEVICE_PARTS``.
   (79564dd) lowered to, the module's name aside (the train window's was
   ``jit__lambda``).  A PR that changes a program on purpose lowers it
   again and replaces the hash: ``python tests/test_device_parts.py``
-  prints the table.  PR 41 did so for ``pangu_moe step`` (its attention
-  became one kernel); the twelve others stand as they were.
+  prints the table.  PR 41 did so for ``pangu_moe step`` and PR 43 for
+  ``command_a step`` (their attention became one kernel); the others
+  stand as they were.
 * Every instruction of the lowered HLO that a line of the program wrote
   and that computes carries a word of the vocabulary in its scope path
   or inherits one by the rule of ``benchmark/program_parts.py``; under
@@ -16,6 +17,8 @@ vocabulary ``observability.trace.DEVICE_PARTS``.
 * The train window's program is ``jit_train_window``.
 * openPangu's step hands its attention kernel every layer's cache where
   it lies, and the kernel's own operations are attention (ISSUE 41).
+* Command A+'s step hands its attention kernel every layer's ring or
+  array, keys and values, where they lie (ISSUE 43).
 """
 
 import hashlib
@@ -29,7 +32,8 @@ import pytest
 
 # sha256 of ``lowered.as_text()`` with the module's name taken out, at
 # commit 79564dd (the parent of the scopes), jax 0.9.0, 8 CPU devices;
-# ``pangu_moe step`` at PR 41, the two of ``xing`` at PR 42.
+# ``pangu_moe step`` at PR 41, the two of ``xing`` at PR 42, ``command_a
+# step`` at PR 43.
 PARENT_SHA256 = {
     "t5 prefill":
         "78e3c0297e8727c951835f623307a467a28c383a2f2fb948af2a20bbad3c584c",
@@ -52,7 +56,7 @@ PARENT_SHA256 = {
     "pangu_moe prefill_window":
         "b8521f090df4036c850fc824f22c2c5c6bb36eff295679066ab4dd2d151f9574",
     "command_a step":
-        "4b329dc3fea080eb858b46b27c40e35addc5ec185c792198fbb2ce02a61e4a59",
+        "146fc5412644dccbf0f3fbac36638185cb2053528f82ab9828ffe01e1f05a4cb",
     "command_a prefill_window":
         "7e9824a475451993b54c051b67f99ee0a9982652f5c5161283a01b21cde08ba5",
     "bert train window":
@@ -305,6 +309,53 @@ def test_pangu_step_hands_its_kernel_the_cache_where_it_lies(lowered):
     assert len(made["dynamic-update-slice"]) == 2 * 3
     assert {dims for _, dims, _ in made["dynamic-update-slice"]} == {
         "4,160,24"}
+
+
+def test_command_a_step_hands_its_kernel_both_caches_where_they_lie(lowered):
+    """The fixture's arena is 4 slots x 2 key/value heads x (a ring of 16
+    or 104 positions) x 16 numbers, keys and values, in each of 8 layers,
+    and the step runs 2 rows.  Nothing of an array's size is copied,
+    padded, sliced, transposed or gathered on its way to the attention
+    (the parent sliced ``ck[:2, :, :entries]`` out): each array is written
+    row by row and handed over whole and as it lies (ISSUE 43).  Every
+    operation of the kernel, interpreted here, is booked to the attention
+    itself, under the layer kind's own scope."""
+    from benchmark import program_parts
+
+    raw, text = lowered["command_a step"][2:]
+    module = program_parts.messages()["HloModule"].FromString(raw)
+    path = {ins.name: ins.metadata.op_name
+            for comp in module.computations for ins in comp.instructions}
+    parts = program_parts.module_parts(raw)
+    kernel = [n for n, p in path.items() if "grouped_decode_attention" in p]
+    assert len(kernel) > 100
+    assert {parts[n] for n in kernel} == {("attention_core", "own")}
+    by_kind = {kind: sum(kind in path[n] for n in kernel)
+               for kind in ("attn.window", "attn.full")}
+    assert by_kind["attn.window"] + by_kind["attn.full"] == len(kernel)
+    assert by_kind["attn.window"] > by_kind["attn.full"] > 0  # 6 and 2 layers
+
+    made = {}         # dims -> opcode -> count, of an array's size
+    for line in text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(", line)
+        if m is None:
+            continue
+        _, dims, opcode = m.groups()
+        # the live rows' part of an array is never cut out
+        assert dims not in ("2,2,16,16", "2,2,104,16"), line
+        if sorted(dims.split(",")) in (
+                sorted("4,2,16,16".split(",")),
+                sorted("4,2,104,16".split(","))):
+            made.setdefault(dims, {}).setdefault(opcode, 0)
+            made[dims][opcode] += 1
+    # 2 arrays a layer, each handed in (to the program, and on to the
+    # interpreted kernel's own computation) and written once a row: 6
+    # rings and 2 arrays by position
+    assert set(made) == {"4,2,16,16", "4,2,104,16"}, made
+    for dims, arrays in (("4,2,16,16", 12), ("4,2,104,16", 4)):
+        assert set(made[dims]) == {"parameter", "dynamic-update-slice"}, made
+        assert made[dims]["dynamic-update-slice"] == 2 * arrays
 
 
 def test_the_window_program_has_its_name(lowered):

@@ -703,6 +703,46 @@ def test_latent_decode_kernel_reads_the_cache_where_it_lies_on_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 ** 2
 
 
+GROUPED_DECODE_CASES = [
+    # (id, rows, kv, g, d, slots, entries, klen, dtype): the cell's ring
+    # and its array by position; the fixtures' ring (a small part of one
+    # key block) and their array under a bucket that ends inside it;
+    # entries that no block divides
+    ("cell_ring", 32, 8, 16, 128, 32, 4096, 4096, jnp.bfloat16),
+    ("cell_full", 32, 8, 16, 128, 32, 18432, 18432, jnp.bfloat16),
+    ("fixture_ring", 2, 2, 4, 16, 4, 16, 16, jnp.float32),
+    ("fixture_full", 2, 2, 4, 16, 4, 104, 64, jnp.bfloat16),
+    ("ragged_1000", 8, 8, 8, 128, 8, 1000, 1000, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize(
+    "rows,kv,g,d,slots,entries,klen,dtype",
+    [c[1:] for c in GROUPED_DECODE_CASES],
+    ids=[c[0] for c in GROUPED_DECODE_CASES])
+def test_grouped_decode_kernel_compiles_for_v5e(
+        one_chip, monkeypatch, rows, kv, g, d, slots, entries, klen, dtype):
+    """``grouped_decode_attention`` alone: the chip's compiler takes its
+    blocks (every key/value head's 512 entries of keys and of values a
+    step, or the fewest lanes that hold the array) and its share of the
+    fast memory, and at the cell's shapes the arrays, rows of 128 lying
+    row-major behind the heads, are handed over as they are."""
+    import importlib
+
+    fa = importlib.import_module("tpu_pipelines.ops.flash_attention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cache = _sds((slots, kv, entries, d), dtype, one_chip)
+    compiled = jax.jit(lambda q, k, v, depth: fa.grouped_decode_attention(
+        q, k, v, depth, klen)).lower(
+            _sds((rows, kv, g, d), dtype, one_chip), cache, cache,
+            _sds((rows,), jnp.int32, one_chip)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 ** 2
+    if d == 128:
+        assert not re.findall(r" (?:copy|transpose)\(", text)
+
+
 @pytest.mark.parametrize("tokens", [32, 512])
 def test_grouped_expert_products_at_4096_compile_for_v5e(one_chip, tokens):
     """The same kernel at Command A+'s expert shape (16 experts held,
@@ -725,7 +765,7 @@ def test_grouped_expert_products_at_4096_compile_for_v5e(one_chip, tokens):
 
 
 def test_window_and_full_step_keeps_caches_and_weights_where_they_lie_on_v5e(
-        one_chip):
+        one_chip, monkeypatch):
     """The engine's own step program for the contract of
     models/command_a.py at the published widths, one period of layers
     (three rings and a full array a slot) with its 16 experts, the whole
@@ -734,12 +774,19 @@ def test_window_and_full_step_keeps_caches_and_weights_where_they_lie_on_v5e(
     leave the key/value-heads-before-entries layout alone, and no
     projection's weights are copied into another layout (without the
     barrier behind the q, k and v products the compiler copied Wq, Wk and
-    Wv at every step: PERF.md section 6, PR 35)."""
+    Wv at every step: PERF.md section 6, PR 35).  Each layer's attention
+    is ONE Pallas kernel (ops/flash_attention.py
+    ``grouped_decode_attention``) that is handed the layer's two arrays
+    where they lie, beside the three grouped products of its expert layer,
+    and no float32 scores over a ring or over the array's positions are
+    left in the program.  The program picks its kernels by the backend,
+    which is the CPU here: the test tells it the chip's."""
     from types import SimpleNamespace
 
     from tpu_pipelines.models import command_a as ca
     from tpu_pipelines.serving import generative as gen
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     rows, positions = 32, 18432
     model = ca.build_command_a_model(dict(
         vocab_size=32768, n_layers=4, experts_held=16))
@@ -760,8 +807,12 @@ def test_window_and_full_step_keeps_caches_and_weights_where_they_lie_on_v5e(
         SimpleNamespace(pad_id=0), rows, positions, fns)
     compiled = program.lower(on_chip(params), on_chip(state)).compile()
     m = _fits(compiled)
-    assert m.temp_size_in_bytes < 1024 ** 3
+    assert m.temp_size_in_bytes < 64 * 1024 ** 2
     text = compiled.as_text()
+    kernels = re.findall(r"custom_call_target=\"tpu_custom_call\".*", text)
+    assert sum("grouped_decode_attention" in k for k in kernels) == 4
+    assert len(kernels) == 4 + 12      # and a layer's three grouped products
+    assert not re.findall(rf"f32\[{rows},8,16,(?:{positions}|4096)\]", text)
     param_layouts, result_layouts, aliases = _entry_layouts(text)
     result_of = {param: out for out, param in aliases.items()}
     for leaf, count in ((f"bf16[{rows},8,{positions},128]", 2),

@@ -58,7 +58,10 @@ fetches, a block's scores and the running softmax stay in the chip's fast
 memory, the mask is ``GroupedAttention.sees`` over the position each entry
 holds (a ring as it lies, an array whose tail is not yet written), and a
 block of entries that no query of a block sees is neither fetched nor
-computed.
+computed.  A decode step's is one more (``grouped_decode_attention``):
+each row's 16 query heads a key/value head over the row's own entries, a
+ring's or an array's as they lie, fetched once and only to the row's
+depth.
 """
 
 from __future__ import annotations
@@ -71,10 +74,10 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from tpu_pipelines.models.evabyte import NEG_INF
 from tpu_pipelines.models.pangu_moe import (
     RoutedExperts, config_from, tally_account)
-from tpu_pipelines.ops.flash_attention import grouped_attention
+from tpu_pipelines.ops.flash_attention import (
+    grouped_attention, grouped_decode_attention, grouped_decode_block)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -272,9 +275,10 @@ class GroupedAttention(nn.Module):
     def step(self, x, pos, cache, klen: int):
         """One token per row.  x [b, d_model], pos [b]; cache leaves
         [slots, kv, entries, d] with ``slots >= b``: rows ``[0, b)`` are
-        written at their own positions where they lie; a full layer
-        attends over its first ``klen`` positions, a window layer over
-        the ring."""
+        written at their own positions where they lie; then ONE kernel
+        (ops/flash_attention.py ``grouped_decode_attention``) reads each
+        row's entries to the row's depth, a full layer's within its first
+        ``klen`` positions, a window layer's within the ring."""
         c = self.cfg
         b = x.shape[0]
         with jax.named_scope("attention_proj"):
@@ -293,23 +297,16 @@ class GroupedAttention(nn.Module):
                     ck, k[r][None], (r, 0, at[r], 0))
                 cv = jax.lax.dynamic_update_slice(
                     cv, v[r][None], (r, 0, at[r], 0))
-        with jax.named_scope("attention_core"):
-            j = jnp.arange(entries)[None, :]
-            # the position each entry holds once this step's is written
-            u = j if self.full else (
-                pos[:, None] - (pos[:, None] - j) % entries)
-            ok = self.sees(pos[:, None], u)[:, None, None, :]
-            with jax.named_scope(self.span):
-                f32 = dict(preferred_element_type=jnp.float32)
-                score = jnp.einsum(
-                    "bkgd,bksd->bkgs", q[:, :, :, 0], ck[:b, :, :entries],
-                    **f32)
-                p = jax.nn.softmax(jnp.where(ok, score, NEG_INF), -1)
-                out = jnp.einsum(
-                    "bkgs,bksd->bkgd", p.astype(c.dtype),
-                    cv[:b, :, :entries], **f32)
+        # How deep a row's valid entries go once this step's is written: a
+        # ring holds ``[0, pos]`` until it has wrapped and every entry
+        # after, each inside the window by construction, so the kernel
+        # needs no mask by position.
+        with jax.named_scope("attention_core"), jax.named_scope(self.span):
+            depth = pos if self.full else jnp.minimum(pos, entries - 1)
+            out = grouped_decode_attention(
+                q[:, :, :, 0], ck, cv, depth, entries)
         with jax.named_scope("attention_proj"):
-            out = self.o_proj(out.reshape(b, -1).astype(c.dtype))
+            out = self.o_proj(out.reshape(b, -1))
         return out, {names[0]: ck, names[1]: cv}
 
 
@@ -482,7 +479,8 @@ def make_continuous_decode_fns(
         lie in the ring without a wrap inside them.
       - ``step_account(positions, tally, bucket)``: per kind the entries
         and bytes that are valid for the live rows, and the bytes that
-        the arrays of the step's ``(rows, klen)`` bucket span.
+        the step's attention kernel fetches for them: whole key blocks up
+        to the one that holds a row's depth.
     """
     from tpu_pipelines.serving.generative import CacheKind
 
@@ -497,6 +495,11 @@ def make_continuous_decode_fns(
     entry_bytes = (
         2 * c.n_kv_heads * c.head_dim * jnp.dtype(c.dtype).itemsize)
     held = c.experts_held
+    # per kind: its layers, the entries an array holds, and of them a
+    # key block of the step's kernel
+    layers = {"window": n_ring, "full": n_full}
+    size = {"window": w, "full": positions}
+    block = {k: grouped_decode_block(n) for k, n in size.items()}
 
     def prefill_window(params, cache, tokens, n_valid, index):
         return model.apply(
@@ -515,20 +518,24 @@ def make_continuous_decode_fns(
         leaf = str(getattr(path[-1], "key", path[-1]))
         return "window" if leaf.startswith("ring") else "full"
 
-    def step_account(at, tally, bucket):
+    def step_account(at, tally, bucket=None):
         """``at``: the live rows' positions; ``tally``: assignments to
-        each held expert, layer by layer; ``bucket``: the step's rows
-        and positions."""
-        rows, klen = bucket
-        entries = {
-            "window": n_ring * sum(min(t + 1, w) for t in at),
-            "full": n_full * sum(t + 1 for t in at)}
+        each held expert, layer by layer (``bucket``, the step's rows
+        and positions, is not read: the kernel stops at a row's depth,
+        not at the bucket's end).  The span is what
+        ``grouped_decode_attention`` fetches for the live rows: whole key
+        blocks up to the one that holds a row's depth, cut at the array's
+        end."""
+        depth = {"window": [min(t, w - 1) for t in at], "full": list(at)}
+        entries = {k: layers[k] * sum(t + 1 for t in depth[k]) for k in depth}
         return {
             "cache_entries": entries,
             "cache_bytes": {k: n * entry_bytes for k, n in entries.items()},
             "cache_span_bytes": {
-                "window": n_ring * rows * w * entry_bytes,
-                "full": n_full * rows * klen * entry_bytes},
+                k: layers[k] * entry_bytes * sum(
+                    min((t // block[k] + 1) * block[k], size[k])
+                    for t in depth[k])
+                for k in depth},
             "window_rollovers": sum(t % w == 0 for t in at),
             **tally_account(tally, held)}
 

@@ -881,3 +881,132 @@ def latent_decode_attention(q, cache, pos, klen: int, *, scale: float,
         interpret=_resolve_interpret(None),
         name="latent_decode_attention",
     )(pos.astype(jnp.int32), q, jnp.swapaxes(cache, 1, 2))
+
+
+# ------------------------------- a row's grouped heads, to its depth
+
+# Entries that a step of ``grouped_decode_attention``'s grid holds at
+# most, of every key/value head at once, from a sweep on the chip at
+# Command A+'s shapes (32 rows of 8 x 16 heads x 128 over rings of 4,096
+# and arrays of 18,432 entries; PERF.md section 6, PR 43).
+GROUPED_DECODE_BLOCK_K = 512
+
+
+def grouped_decode_block(entries: int) -> int:
+    """Entries a key block of ``grouped_decode_attention`` holds over an
+    array of ``entries`` (a block tiles by the lanes of its scores); a row
+    of depth ``t`` is handed the blocks ``[0, t // block]`` and no
+    other."""
+    return min(GROUPED_DECODE_BLOCK_K, -(-entries // LANES) * LANES)
+
+
+def _grouped_decode_kernel(depth_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
+                           m_ref, l_ref, *, entries):
+    kv, block_k, d = k_ref.shape[1:]
+    j = pl.program_id(1)
+    # what lies past the array's end is no more an entry than what lies
+    # past the row's depth
+    depth = jnp.minimum(depth_ref[pl.program_id(0)], entries - 1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    # A block that starts past the row's depth was handed the last live
+    # block again (nothing is fetched) and computes nothing.
+    @pl.when(j * block_k <= depth)
+    def _step():
+        # What a block holds past the depth may be a former occupant's
+        # numbers or no number at all: it is used neither as a score nor
+        # as a value (0 x NaN is NaN).
+        at = lambda shape, axis: j * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, shape, axis) <= depth
+        live_key, live_value = at((1, block_k), 1), at((block_k, 1), 0)
+
+        # The key/value heads one after another in ONE straight body, so
+        # that one head's products run beside another's softmax, as
+        # ``_grouped_kernel`` unrolls its query heads.
+        for h in range(kv):
+            values = jnp.where(live_value, v_ref[0, h], 0)
+            s = jnp.where(live_key, jax.lax.dot_general(  # [g, bk] on MXU
+                q_ref[0, h], k_ref[0, h], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ), NEG_INF)
+            m_prev = m_ref[h]                            # [g, LANES]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - _across(m_new, block_k))
+            keep = jnp.exp(m_prev - m_new)
+            l_ref[h] = l_ref[h] * keep + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * _across(keep, d) + jax.lax.dot_general(
+                p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_ref[h] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _final():
+        for h in range(kv):
+            o_ref[0, h] = (
+                acc_ref[h] / _across(l_ref[h], d)).astype(o_ref.dtype)
+
+
+def grouped_decode_attention(q, k, v, depth, klen: int):
+    """One query a row and head over the row's own entries, to the row's
+    depth, the ``g`` query heads of a key/value head sharing each entry
+    they fetch (forward only): a decode step's attention over a cache of
+    grouped heads, a ring or an array by position alike.
+
+    ``q``: [rows, kv_heads, g, head_dim], already scaled.  ``k``/``v``:
+    [slots, kv_heads, entries, head_dim] as they lie, ``slots >= rows``,
+    ``entries >= klen``: row ``i`` attends over entries ``[0, depth[i]]``
+    of slot ``i``, and ``klen`` bounds every depth (the grid covers the
+    first ``klen`` entries); ``depth`` [rows] int32.  ``head_dim`` at most
+    128 or a multiple of it.  What lies past a row's depth, in its own
+    slot or in another, is never used, whatever it holds.  The caller
+    says how deep a row's valid entries go: a position for an array
+    written by position, the last entry written for a ring that has not
+    wrapped, the whole ring for one that has.
+
+    Grid (row, key block), the key block the sequential axis.  A step
+    holds the row's queries and ONE block of entries of EVERY key/value
+    head, keys and values, fetched once; a head's [g, block] scores, their
+    running maximum and sum and the accumulator are float32 and never
+    leave VMEM, the products take their operands as they come and the
+    weights enter the second in ``v``'s dtype.  A key block that starts
+    past ``depth[i]`` (handed over by scalar prefetch) is neither computed
+    nor fetched: its step's index map names the row's last live block
+    again.  Any number of entries: a block is the fewest lanes that hold
+    them (``grouped_decode_block``), and what it holds past the array's
+    end is masked like what lies past the depth.
+    -> [rows, kv_heads, g, head_dim] in ``q``'s dtype.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, kv, g, d = q.shape
+    entries = k.shape[2]
+    block_k = grouped_decode_block(entries)
+    q_spec = pl.BlockSpec((1, kv, g, d), lambda i, j, depth: (i, 0, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (1, kv, block_k, d),
+        lambda i, j, depth: (i, 0, _latent_fetch(j, depth[i], block_k), 0))
+    return pl.pallas_call(
+        functools.partial(_grouped_decode_kernel, entries=entries),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows, -(-klen // block_k)),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((kv, g, d), jnp.float32),
+                pltpu.VMEM((kv, g, LANES), jnp.float32),
+                pltpu.VMEM((kv, g, LANES), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_resolve_interpret(None),
+        name="grouped_decode_attention",
+    )(depth.astype(jnp.int32), q, k, v)

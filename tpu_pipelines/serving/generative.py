@@ -1857,12 +1857,11 @@ class DecodeTelemetry:
         )
         self._cache_span = registry.counter(
             "serving_decode_cache_span_bytes_total",
-            "Bytes that the arrays of each kind span in the decode steps' "
-            "(rows, positions) buckets, summed over the steps run: what a "
-            "step that reads its arrays whole reads, beside "
-            "serving_decode_cache_read_bytes_total, what is valid (for a "
-            "kind that a kernel reads to each row's depth, the key blocks "
-            "it fetches).",
+            "Bytes of each kind of cache that the decode steps move for "
+            "their live rows, as the contract's step_account states them "
+            "(the whole key blocks that a kernel reading to each row's "
+            "depth fetches), summed over the steps run, beside "
+            "serving_decode_cache_read_bytes_total, what is valid.",
             labels=kind_lab,
         )
         self._expert_assignments = registry.counter(
