@@ -33,7 +33,7 @@ import pytest
 # sha256 of ``lowered.as_text()`` with the module's name taken out, at
 # commit 79564dd (the parent of the scopes), jax 0.9.0, 8 CPU devices;
 # ``pangu_moe step`` at PR 41, the two of ``xing`` at PR 42, ``command_a
-# step`` at PR 43.
+# step`` at PR 43, the two of ``keye`` at PR 44.
 PARENT_SHA256 = {
     "t5 prefill":
         "78e3c0297e8727c951835f623307a467a28c383a2f2fb948af2a20bbad3c584c",
@@ -66,6 +66,11 @@ PARENT_SHA256 = {
         "aad54e255503e26a996e8f1141e2b914ded8250ad5ccddd6b0890901a0b7c3a5",
     "xing prefill_window":
         "53b5e966d36b60dd07935419c0c58b2d7a5f61bc48499a82bfee47961e66b568",
+    # new in PR 44, as that PR lowered them
+    "keye step":
+        "551d46ca0249454b63225940e194913909e95c08316bb3edf35a92ed3cb27c53",
+    "keye prefill_window":
+        "94ef3309bec8bd61f4acce5700992154d3619a1a94fa56db59b8afd82596b425",
 }
 PROGRAMS = (
     "t5 prefill", "t5 insert", "t5 move", "t5 clear", "t5 step 2x4",
@@ -73,10 +78,12 @@ PROGRAMS = (
     "pangu_moe step", "pangu_moe prefill_window", "command_a step",
     "command_a prefill_window", "bert train window",
     "xing step", "xing prefill_window",
+    "keye step", "keye prefill_window",
 )
 OLDER_SCOPES = {
     "eva.attend", "eva.summarize", "mla.attend", "moe.route",
     "moe.experts", "moe.shared", "mhc.mix", "mhc.apply",
+    "dsa.index", "dsa.select", "dsa.gather",
 }
 
 
@@ -118,7 +125,7 @@ def _t5_programs(note):
 def _decoder_programs(note):
     from tpu_pipelines.serving.generative import GenerativeEngine
 
-    for name in ("evabyte", "pangu_moe", "command_a", "xing"):
+    for name in ("evabyte", "pangu_moe", "command_a", "xing", "keye"):
         tiny = importlib.import_module("test_" + name)
         model, params = tiny.build()
         engine = GenerativeEngine(
@@ -390,7 +397,8 @@ def test_one_vocabulary_in_one_place():
     # the part every word is opened in somewhere
     assert set(trace.DEVICE_PARTS) <= set(found)
     for name in ("transformer.py", "bert.py", "t5.py", "evabyte.py",
-                 "pangu_moe.py", "command_a.py", "xing.py", "train_loop.py",
+                 "pangu_moe.py", "command_a.py", "xing.py", "keye.py",
+                 "train_loop.py",
                  "generative.py"):
         assert any(name in files for files in found.values()), name
 

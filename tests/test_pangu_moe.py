@@ -342,6 +342,227 @@ def test_the_chips_grouped_product_is_xlas(whole_layer):
         rows, weights, sizes, pangu_moe.TILE_IN).shape == (64, 32)
 
 
+# ------------------------- the router's scoring function, no shared expert
+
+
+def keye_layer(seed=5, **over):
+    """One expert layer as models/keye.py builds it (a softmax router over
+    16 experts, 4 a token, NO shared expert), every expert held unless
+    ``over`` says otherwise, with seeded weights."""
+    import jax
+
+    from benchmark import weights
+    from tpu_pipelines.models import keye, pangu_moe
+
+    cfg = keye.build_keye_model({
+        "vocab_size": VOCAB, "d_model": 64, "n_layers": 1, "n_heads": 4,
+        "n_kv_heads": 2, "head_dim": 16, "mrope_section": [2, 3, 3],
+        "index_heads": 2, "index_dim": 8, "index_topk": 8, "d_expert": 32,
+        "n_experts": 16, "experts_held": 16, "experts_per_token": 4,
+        **over, "compute_dtype": "float32", "param_dtype": "float32"}).cfg
+    layer = pangu_moe.RoutedExperts(cfg)
+    x = np.random.default_rng(seed).normal(size=(24, 64)).astype(np.float32)
+    shapes = jax.eval_shape(
+        lambda: layer.init(jax.random.key(0), jax.numpy.asarray(x))["params"])
+    return layer, weights.make_weights(shapes, RULES, seed), x
+
+
+def test_softmax_scoring_against_a_plain_restatement():
+    """``scoring_func`` softmax: g = softmax(x Wr) over ALL 16, the 4
+    largest, weights g_e / their sum (``routed_scaling_factor`` 1), the
+    sum of the chosen experts' gated MLPs and nothing else."""
+    layer, params, x = keye_layer()
+    y, picked = layer.apply({"params": params}, x)
+    p = {k: np.asarray(v, np.float64) for k, v in params.items()}
+    logits = x.astype(np.float64) @ p["router"]
+    g = np.exp(logits - logits.max(-1, keepdims=True))
+    g /= g.sum(-1, keepdims=True)
+    want = np.zeros((24, 64))
+    for t in range(24):
+        top = np.argsort(-g[t], kind="stable")[:4]
+        assert sorted(np.flatnonzero(np.asarray(picked)[t])) == sorted(top)
+        for e in top:
+            gate = x[t] @ p["experts_gate"][e]
+            hidden = gate / (1 + np.exp(-gate)) * (x[t] @ p["experts_up"][e])
+            want[t] += g[t, e] / g[t, top].sum() * (
+                hidden @ p["experts_down"][e])
+    assert np.abs(np.asarray(y) - want).max() < F32_TOL
+    assert want.std() > 0.05
+    # the sigmoid over the same weights chooses the same experts (both are
+    # monotone in the router's product) and weighs them otherwise
+    other, same = pangu_sigmoid(layer, params, x)
+    assert np.array_equal(np.asarray(same), np.asarray(picked))
+    assert rms(np.asarray(other) - want) > 0.02 * want.std()
+
+
+def pangu_sigmoid(layer, params, x):
+    import dataclasses
+
+    from tpu_pipelines.models import pangu_moe
+
+    cfg = dataclasses.replace(layer.cfg, scoring_func="sigmoid")
+    return pangu_moe.RoutedExperts(cfg).apply({"params": params}, x)
+
+
+def test_no_shared_expert_builds_no_shared_leaf_and_adds_nothing():
+    layer, params, x = keye_layer()
+    assert set(params) == {
+        "router", "experts_gate", "experts_up", "experts_down"}
+    # with the routed part silenced the layer gives exactly nothing
+    y, _ = layer.apply(
+        {"params": {**params, "experts_down": params["experts_down"] * 0}},
+        x)
+    assert np.abs(np.asarray(y)).max() == 0
+    # openPangu's own layer keeps its shared expert
+    _, pangu = build()
+    assert "shared" in pangu["layer_1"]["ffn"]
+
+
+@pytest.mark.parametrize("scoring", ["sigmoid", "softmax"])
+def test_the_shares_add_up_without_a_shared_expert(scoring):
+    """Four chips of 4 experts each under either scoring function and no
+    shared expert: the shares' parts add up to the layer with every expert
+    held, every token's four choices are computed by somebody, and (the
+    softmax) the sum is reference/keye.py's layer."""
+    import jax
+
+    from benchmark.reference import keye as ref
+
+    layer, params, x = keye_layer(scoring_func=scoring)
+    whole, _ = layer.apply({"params": params}, x)
+    total, chosen = 0.0, 0
+    for share in range(4):
+        cut = slice(share * 4, share * 4 + 4)
+        part = {**params, **{
+            k: params[k][cut]
+            for k in ("experts_gate", "experts_up", "experts_down")}}
+        held, _, _ = keye_layer(
+            scoring_func=scoring, experts_held=4, expert_offset=share * 4)
+        y, picked = held.apply({"params": part}, x)
+        assert picked.shape == (24, 4)
+        chosen += int(np.asarray(picked).sum())
+        total = total + np.asarray(y)
+    assert np.abs(total - np.asarray(whole)).max() < F32_TOL
+    assert chosen == 24 * 4
+    assert rms(np.asarray(y) - np.asarray(whole)) > 0.1 * np.asarray(
+        whole).std()
+    if scoring == "softmax":
+        flat = {"ffn/" + k: v for k, v in params.items()}
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(ref.experts(flat, "ffn", x, "f32", 4))
+        assert np.abs(total - want).max() < F32_TOL
+
+
+@pytest.mark.parametrize("model", ["pangu_moe", "command_a", "xing"])
+def test_the_sigmoid_layer_lowers_to_what_it_did_before_the_scoring_key(
+        model):
+    """``RoutedExperts`` as each of the three routed cells builds it (their
+    tests' own small sizes) lowers to the same text as the layer did before
+    ``scoring_func`` and ``n_shared_experts`` 0 were read (PR 44): the
+    class below holds that PR's parent's three methods, letter for letter.
+    (tests/test_device_parts.py holds the cells' whole step and window
+    programs to their stored hashes besides.)"""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    from flax import linen as nn
+
+    from tpu_pipelines.models import pangu_moe
+    from tpu_pipelines.models.evabyte import GatedMlp
+    from tpu_pipelines.models.pangu_moe import (
+        ROW_TILE, TILE_IN, TILES, grouped_product)
+
+    class Before(pangu_moe.RoutedExperts):
+        def setup(self):
+            c = self.cfg
+            init = nn.initializers.variance_scaling(
+                1.0, "fan_in", "normal", in_axis=-2, out_axis=-1,
+                batch_axis=0)
+            e, d, f = c.experts_held, c.d_model, c.d_expert
+            self.router = self.param(
+                "router", nn.initializers.lecun_normal(), (d, c.n_experts),
+                c.param_dtype)
+            self.shared = GatedMlp(
+                d, c.n_shared_experts * f, c.dtype, c.param_dtype,
+                name="shared")
+            self.experts_gate = self.param(
+                "experts_gate", init, (e, d, f), c.param_dtype)
+            self.experts_up = self.param(
+                "experts_up", init, (e, d, f), c.param_dtype)
+            self.experts_down = self.param(
+                "experts_down", init, (e, f, d), c.param_dtype)
+            self.bias = self.param(
+                "e_score_correction_bias", nn.initializers.zeros,
+                (c.n_experts,), jnp.float32,
+            ) if getattr(c, "selection_bias", False) else None
+
+        def route(self, x):
+            with jax.named_scope("mlp"), jax.named_scope("moe.route"):
+                sigma = jax.nn.sigmoid(jnp.dot(
+                    x.astype(jnp.float32), self.router.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST))
+                if self.bias is None:
+                    top, ids = jax.lax.top_k(
+                        sigma, self.cfg.experts_per_token)
+                else:
+                    _, ids = jax.lax.top_k(
+                        sigma + self.bias, self.cfg.experts_per_token)
+                    top = jnp.take_along_axis(sigma, ids, -1)
+                weights = self.cfg.routed_scaling_factor * top / jnp.sum(
+                    top, -1, keepdims=True)
+                return weights, ids
+
+        def __call__(self, x):
+            c = self.cfg
+            k, e = c.experts_per_token, c.experts_held
+            weights, ids = self.route(x)
+            with jax.named_scope("mlp"):
+                local = ids - c.expert_offset
+                held = (local >= 0) & (local < e)
+                x = x.astype(c.dtype)
+                picked = jnp.sum(
+                    local[:, :, None] == jnp.arange(e)[None, None, :], 1,
+                    dtype=jnp.int32)
+            with jax.named_scope("mlp"), jax.named_scope("moe.experts"):
+                group = jnp.where(held, local, e).reshape(-1)
+                group = jnp.pad(
+                    group, (0, -group.size % ROW_TILE), constant_values=e)
+                order = jnp.argsort(group)
+                sizes = jnp.sum(picked, 0)
+                xs = x[jnp.minimum(order // k, x.shape[0] - 1)]
+                grouped = lambda rows, w: grouped_product(
+                    rows, w.astype(c.dtype), sizes,
+                    TILES.get(w.shape[1:], TILE_IN))
+                hidden = jax.nn.silu(grouped(xs, self.experts_gate)) \
+                    * grouped(xs, self.experts_up)
+                ys = grouped(hidden.astype(c.dtype), self.experts_down)
+                ys = ys[jnp.argsort(order)[:held.size]].reshape(
+                    x.shape[0], k, -1)
+                routed = jnp.sum(
+                    jnp.where(held[..., None], ys * weights[..., None], 0.0),
+                    1)
+            with jax.named_scope("mlp"), jax.named_scope("moe.shared"):
+                shared = self.shared(x).astype(jnp.float32)
+                if c.shared_average:
+                    shared = shared / c.n_shared_experts
+                return shared + routed, picked
+
+    tiny = importlib.import_module("test_" + model)
+    cfg = tiny.build()[0].cfg
+    assert getattr(cfg, "scoring_func", "sigmoid") == "sigmoid"
+    x = jnp.zeros((24, cfg.d_model), jnp.float32)
+    texts = []
+    for cls in (pangu_moe.RoutedExperts, Before):
+        layer = cls(cfg)
+        params = jax.eval_shape(
+            lambda: layer.init(jax.random.key(0), x)["params"])
+        texts.append(jax.jit(
+            lambda p, x: layer.apply({"params": p}, x)).lower(
+                params, x).as_text())
+    assert texts[0] == texts[1] and len(texts[0]) > 10000
+
+
 @pytest.mark.parametrize("left_out", ["routed", "post_norms"])
 def test_the_tolerance_would_notice_a_term_left_out(f32, left_out):
     import jax.numpy as jnp

@@ -661,6 +661,77 @@ def test_xing_step_and_window_compile_at_the_cells_shapes_on_v5e(
     assert compiled.memory_analysis().temp_size_in_bytes < 1024 ** 3
 
 
+def test_keye_step_and_window_compile_at_the_cells_shapes_on_v5e(
+        one_chip, monkeypatch):
+    """The engine's step program for models/keye.py at the cell's shapes
+    (16 rows, 22,528 positions, 32 query heads over 4 key/value heads, an
+    indexer of 16 x 64 that selects 2,048, all 128 experts held), two
+    layers, and the prefill window of 512 tokens, both inside 16 GiB.
+    The step writes its three arrays a layer row by row where they lie and
+    FETCHES the selected entries: nothing of an array's size is copied,
+    transposed or scattered (with the key/value heads before the positions
+    the compiler copied both arrays heads-innermost for every gather and
+    back), the gathered entries are ``[16, 4, 2048, 128]`` and go to ONE
+    ``grouped_decode_attention`` a layer (8 query heads a key/value head),
+    and the top-k is exact (no ``ApproxTopK``).  The window keeps no
+    float32 scores of 32 heads over the row and no index products of 16
+    heads over it: both run in blocks of keys."""
+    from types import SimpleNamespace
+
+    from tpu_pipelines.models import keye
+    from tpu_pipelines.serving import generative as gen
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, positions, window = 16, 22528, 512
+    model = keye.build_keye_model(dict(n_layers=2))
+    fns = keye.make_continuous_decode_fns(
+        model, max_decode_len=2048, eos_id=151936, max_input_len=20480,
+        prefill_window_len=window)
+    assert fns.cache_positions == positions
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), {"inputs": jnp.zeros((1, 8), jnp.int32)})["params"])
+    state = (
+        jax.eval_shape(lambda: fns.blank_cache(rows)),
+        jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
+        jnp.zeros((rows,), bool), jnp.zeros((rows, 0), jnp.float32),
+        jnp.zeros((rows, 20480), jnp.int32),
+    )
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+    program = gen.GenerativeEngine._build_step(
+        SimpleNamespace(pad_id=0), rows, positions, fns)
+    compiled = program.lower(on_chip(params), on_chip(state)).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    for leaf in (f"bf16[{rows},{positions},512]",
+                 f"bf16[{rows},{positions},64]"):
+        moved = re.findall(
+            rf"= {re.escape(leaf)}\S* (?:copy|transpose|scatter)\(.*", text)
+        assert not moved, (len(moved), moved[:2])
+    kernels = re.findall(r"custom_call_target=\"tpu_custom_call\".*", text)
+    assert sum("grouped_decode_attention" in k for k in kernels) == 2
+    assert len(kernels) == 2 + 2 * 3   # and each expert layer's three products
+    assert f"bf16[{rows},4,2048,128]" in text           # what was fetched
+    assert "ApproxTopK" not in text and "approx_top_k" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 * 2 ** 20
+    # the step's tally rides behind its tokens: one result of 16 + 2 x 128
+    assert f"s32[{rows + 256}]" in text
+
+    row = jax.eval_shape(lambda: fns.blank_cache(1))
+    i32 = _sds((), jnp.int32, one_chip)
+    compiled = jax.jit(fns.prefill_window, donate_argnums=1).lower(
+        on_chip(params), on_chip(row), _sds((1, window), jnp.int32, one_chip),
+        i32, i32).compile()
+    _fits(compiled)
+    text = compiled.as_text()
+    for whole in (f"f32[4,8,{window},{positions}]",
+                  f"f32[32,{window},{positions}]",
+                  f"f32[{window},16,{positions}]",
+                  f"f32[16,{window},{positions}]"):
+        assert whole not in text, whole
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 ** 3
+
+
 LATENT_CASES = [
     # (id, rows, heads, r, rope, slots, positions, dtype): the cell's
     # shapes; the fixtures' (one key block reaching past the array's

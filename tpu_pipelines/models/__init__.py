@@ -20,6 +20,12 @@ Present:
     (under YaRN) and routed experts (with a selection bias)
     (``tpu_pipelines.models.xing.build_xing_model``; served through
     serving/generative.py under pangu_moe's contract)
+  - keye: decoder-only LM on grouped-query attention over the positions a
+    learned indexer selects (an index cache beside the key/value cache, a
+    decode step that fetches only what was selected), a three-section
+    rotary code and softmax-routed experts without a shared one
+    (``build_keye_model``; served through serving/generative.py; two kinds
+    of cache at the same positions)
   - transformer: shared sharded blocks (TP over 'model', ring-attention SP
     over 'seq') used by bert/t5
 
@@ -36,4 +42,8 @@ def __getattr__(name):
         from tpu_pipelines.models.command_a import build_command_a_model
 
         return build_command_a_model
+    if name == "build_keye_model":
+        from tpu_pipelines.models.keye import build_keye_model
+
+        return build_keye_model
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -31,7 +31,8 @@ function (tests/test_pangu_moe.py holds them equal):
 
 The routed-expert layer is told which experts it holds
 (``experts_held`` from ``expert_offset``).  It scores all ``n_experts``
-with a sigmoid, takes the ``experts_per_token`` largest over ALL of them,
+with a sigmoid (or as the configuration's ``scoring_func`` says: Keye's
+softmax), takes the ``experts_per_token`` largest over ALL of them,
 normalises their weights to ``routed_scaling_factor``, and computes the
 shared expert plus the part of the sum that its own experts give; what
 the absent experts would add is the other chips' to compute.  No token is
@@ -373,6 +374,11 @@ class LatentAttention(nn.Module):
             return self.o_proj(out), cache
 
 
+# How a router's float32 products over ALL experts become the scores whose
+# largest are taken and weighed, by ``scoring_func``.
+SCORES = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}
+
+
 class RoutedExperts(nn.Module):
     """The shared experts and this chip's share of the routed ones.
     x [n, d_model] -> (y [n, d_model], picked [n, experts_held]): the
@@ -383,9 +389,10 @@ class RoutedExperts(nn.Module):
     ``experts_held`` from ``expert_offset``, ``experts_per_token``,
     ``routed_scaling_factor`` (1 where the source has none),
     ``n_shared_experts`` as ONE gated MLP of their joint width, whose
-    output is their sum, or with ``shared_average`` their mean;
-    ``selection_bias`` where it has the field; ``dtype`` and
-    ``param_dtype``."""
+    output is their sum, or with ``shared_average`` their mean (0: no
+    such leaf, and nothing added); ``scoring_func`` where it has the
+    field (``SCORES``: a sigmoid where it has not); ``selection_bias``
+    where it has the field; ``dtype`` and ``param_dtype``."""
 
     cfg: Any
 
@@ -398,7 +405,8 @@ class RoutedExperts(nn.Module):
             "router", nn.initializers.lecun_normal(), (d, c.n_experts),
             c.param_dtype)
         self.shared = GatedMlp(
-            d, c.n_shared_experts * f, c.dtype, c.param_dtype, name="shared")
+            d, c.n_shared_experts * f, c.dtype, c.param_dtype, name="shared"
+        ) if c.n_shared_experts else None
         self.experts_gate = self.param(
             "experts_gate", init, (e, d, f), c.param_dtype)
         self.experts_up = self.param(
@@ -413,8 +421,9 @@ class RoutedExperts(nn.Module):
     def route(self, x):
         """-> weights [n, k] and expert ids [n, k] over ALL experts.  A
         selection bias chooses and does not weigh."""
+        score = SCORES[getattr(self.cfg, "scoring_func", "sigmoid")]
         with jax.named_scope("mlp"), jax.named_scope("moe.route"):
-            sigma = jax.nn.sigmoid(jnp.dot(
+            sigma = score(jnp.dot(
                 x.astype(jnp.float32), self.router.astype(jnp.float32),
                 precision=jax.lax.Precision.HIGHEST))
             if self.bias is None:
@@ -460,6 +469,8 @@ class RoutedExperts(nn.Module):
                 x.shape[0], k, -1)
             routed = jnp.sum(
                 jnp.where(held[..., None], ys * weights[..., None], 0.0), 1)
+        if self.shared is None:
+            return routed, picked
         with jax.named_scope("mlp"), jax.named_scope("moe.shared"):
             shared = self.shared(x).astype(jnp.float32)
             if c.shared_average:
@@ -631,7 +642,7 @@ def config_from(cls, hparams: Dict):
     as its field's type, and from ``compute_dtype`` / ``param_dtype``;
     other keys are passed over."""
     hp = dict(hparams or {})
-    kinds = {"float": float, "bool": bool}
+    kinds = {"float": float, "bool": bool, "str": str}
     fields = {f.name: kinds.get(f.type, int) for f in dataclasses.fields(cls)}
     return cls(
         **{k: fields[k](v) for k, v in hp.items()
