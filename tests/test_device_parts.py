@@ -33,7 +33,8 @@ import pytest
 # sha256 of ``lowered.as_text()`` with the module's name taken out, at
 # commit 79564dd (the parent of the scopes), jax 0.9.0, 8 CPU devices;
 # ``pangu_moe step`` at PR 41, the two of ``xing`` at PR 42, ``command_a
-# step`` at PR 43, the two of ``keye`` at PR 44.
+# step`` at PR 43, the two of ``keye`` at PR 44, ``keye prefill_window``
+# again at PR 45 (its attention under the selection is one kernel).
 PARENT_SHA256 = {
     "t5 prefill":
         "78e3c0297e8727c951835f623307a467a28c383a2f2fb948af2a20bbad3c584c",
@@ -66,11 +67,11 @@ PARENT_SHA256 = {
         "aad54e255503e26a996e8f1141e2b914ded8250ad5ccddd6b0890901a0b7c3a5",
     "xing prefill_window":
         "53b5e966d36b60dd07935419c0c58b2d7a5f61bc48499a82bfee47961e66b568",
-    # new in PR 44, as that PR lowered them
+    # new in PR 44, as that PR lowered them; the window again at PR 45
     "keye step":
         "551d46ca0249454b63225940e194913909e95c08316bb3edf35a92ed3cb27c53",
     "keye prefill_window":
-        "94ef3309bec8bd61f4acce5700992154d3619a1a94fa56db59b8afd82596b425",
+        "eb5c0867bb8da9db752363a0a7465331374c4362c625e0bc10f36ecadb105b86",
 }
 PROGRAMS = (
     "t5 prefill", "t5 insert", "t5 move", "t5 clear", "t5 step 2x4",
@@ -363,6 +364,45 @@ def test_command_a_step_hands_its_kernel_both_caches_where_they_lie(lowered):
     for dims, arrays in (("4,2,16,16", 12), ("4,2,104,16", 4)):
         assert set(made[dims]) == {"parameter", "dynamic-update-slice"}, made
         assert made[dims]["dynamic-update-slice"] == 2 * arrays
+
+
+def test_keye_window_hands_its_kernel_the_row_where_it_lies(lowered):
+    """The fixture's row is 104 positions x (2 key/value heads x 16
+    numbers), keys and values, in each of 3 layers, and a window is 8
+    tokens.  Every operation of the window's kernel, interpreted here
+    (the mask and the count of equal keys among them), is booked to the
+    attention itself; the row's two arrays are written once and handed
+    over as they lie: nothing of their size is reshaped heads-apart,
+    padded, copied or transposed on the way (tests/test_tpu_compile.py
+    holds the compiled window to what it keeps of the window x the row)."""
+    from benchmark import program_parts
+
+    raw, text = lowered["keye prefill_window"][2:]
+    module = program_parts.messages()["HloModule"].FromString(raw)
+    path = {ins.name: ins.metadata.op_name
+            for comp in module.computations for ins in comp.instructions}
+    parts = program_parts.module_parts(raw)
+    kernel = [n for n, p in path.items() if "selected_attention" in p]
+    assert len(kernel) > 100
+    assert {parts[n] for n in kernel} == {("attention_core", "own")}
+    assert not any("dsa.select" in path[n] for n in kernel)
+
+    made = {}         # opcode -> count, of the row's size
+    for line in text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?(\S+) = (\w+)\[([\d,]+)\]\S* ([\w-]+)\(", line)
+        if m is None:
+            continue
+        name, _, dims, opcode = m.groups()
+        assert dims not in ("104,2,16", "1,104,2,16", "2,104,16"), line
+        if dims in ("1,104,32", "104,32") and "selected_attention" \
+                not in path.get(name.lstrip("%"), ""):
+            made[opcode] = made.get(opcode, 0) + 1
+    # 2 arrays a layer: handed in, written, the row cut out of its slot
+    assert set(made) <= {
+        "parameter", "dynamic-update-slice", "reshape", "bitcast",
+        "get-tuple-element", "tuple"}, made
+    assert made["dynamic-update-slice"] == 2 * 3
 
 
 def test_the_window_program_has_its_name(lowered):

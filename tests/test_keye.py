@@ -598,6 +598,13 @@ def test_engine_counts_both_kinds_of_cache_and_the_experts(engine_run):
     assert get("serving_decode_prefill_tokens_total") == sum(LENGTHS)
     assert get("serving_decode_engine_phase_total", "insert") == 10
     assert engine.compiles_after_warm == 0
+    # A window's kernel is handed key blocks up to the one that holds the
+    # window's last position: at this size one block of 128 holds the
+    # whole row of 104, so every window of the 3 layers visits the one
+    # block its row holds.
+    blocks = lambda state: get(
+        "serving_decode_window_key_blocks_total", state)
+    assert blocks("visited") == blocks("held") == 3 * windows
     # a step at position t has t + 1 valid entries of either kind in each
     # of the 3 layers
     fed = [
@@ -627,6 +634,25 @@ def test_engine_counts_both_kinds_of_cache_and_the_experts(engine_run):
     assert 0 < count <= steps
     assert 1.0 <= get(
         "serving_decode_expert_load_ratio_sum") / count <= EXPERTS
+
+
+def test_a_window_is_booked_the_key_blocks_up_to_its_last_position():
+    """``window_account`` at the cell's shapes (windows of 512 over 22,528
+    positions, blocks of 512 x 512): window ``m`` of a prompt visits ``m +
+    1`` of the row's 44 key blocks in each layer."""
+    from tpu_pipelines.models import keye
+
+    model = keye.build_keye_model(dict(n_layers=6))
+    fns = keye.make_continuous_decode_fns(
+        model, max_decode_len=2048, max_input_len=20480,
+        prefill_window_len=512)
+    for m in (0, 1, 12, 39, 43):
+        assert fns.window_account(m) == {
+            "key_blocks": {"visited": 6 * (m + 1), "held": 6 * 44}}
+    # a prompt of 12,288 tokens: 24 windows, 300 of 1,056 blocks a layer
+    visited = sum(
+        fns.window_account(m)["key_blocks"]["visited"] for m in range(24))
+    assert visited == 6 * 300
 
 
 def test_the_contract_states_what_the_engine_may_not_guess(f32):

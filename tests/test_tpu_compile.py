@@ -337,6 +337,20 @@ def _hlo_computations(text):
     return blocks
 
 
+def _kept(text):
+    """The result shapes of the instructions outside fused computations:
+    what the program keeps in memory between two operations (inside a
+    fusion a shape is a value on its way through the registers)."""
+    fused = set(re.findall(r"fusion\(.*calls=%([\w.\-]+)", text))
+    shapes = []
+    for header, body in _hlo_computations(text):
+        name = re.match(r"(?:ENTRY )?%?([\w.\-]+)", header).group(1)
+        if name not in fused:
+            shapes += re.findall(
+                r"^\s+(?:ROOT )?%[\w.\-]+ = (.*?) [\w\-]+\(", body, re.M)
+    return shapes
+
+
 def test_bucketed_allreduce_stays_inside_backward_on_v5e_2x2(mesh4):
     """What tests/test_multichip_window.py cannot decide on a toy (whose
     few bytes any compiler merges into one all-reduce): at BERT-base size
@@ -666,7 +680,8 @@ def test_keye_step_and_window_compile_at_the_cells_shapes_on_v5e(
     """The engine's step program for models/keye.py at the cell's shapes
     (16 rows, 22,528 positions, 32 query heads over 4 key/value heads, an
     indexer of 16 x 64 that selects 2,048, all 128 experts held), two
-    layers, and the prefill window of 512 tokens, both inside 16 GiB.
+    layers, and the prefill window of 512 tokens over a row of 22,528
+    positions, both inside 16 GiB.
     The step writes its three arrays a layer row by row where they lie and
     FETCHES the selected entries: nothing of an array's size is copied,
     transposed or scattered (with the key/value heads before the positions
@@ -675,7 +690,13 @@ def test_keye_step_and_window_compile_at_the_cells_shapes_on_v5e(
     ``grouped_decode_attention`` a layer (8 query heads a key/value head),
     and the top-k is exact (no ``ApproxTopK``).  The window keeps no
     float32 scores of 32 heads over the row and no index products of 16
-    heads over it: both run in blocks of keys."""
+    heads over it: the index products run in blocks of keys, and each
+    layer's mask, tie count and attention are ONE Pallas kernel
+    (ops/flash_attention.py ``selected_attention``) beside the three
+    grouped products of its expert layer, handed the row's keys and values
+    where they lie.  Of the row's size the program keeps the float32 index
+    scores and their ``uint32`` order keys and nothing else: no scores of
+    a block of 1,024 keys, no running count, no mask."""
     from types import SimpleNamespace
 
     from tpu_pipelines.models import keye
@@ -729,7 +750,70 @@ def test_keye_step_and_window_compile_at_the_cells_shapes_on_v5e(
                   f"f32[{window},16,{positions}]",
                   f"f32[16,{window},{positions}]"):
         assert whole not in text, whole
+    kernels = re.findall(r"custom_call_target=\"tpu_custom_call\".*", text)
+    assert sum("selected_attention" in k for k in kernels) == 2
+    assert len(kernels) == 2 + 2 * 3   # and each expert layer's three products
+    kept = "\n".join(_kept(text))
+    for gone in (f"f32[4,8,{window},1024]", f"s32[{window},{positions}]",
+                 f"s32[{window},176,128]", f"pred[{window},{positions}]",
+                 f"pred[{window},176,128]"):
+        assert gone not in kept, gone
+    assert f"u32[{window},{positions}]" in kept       # the order keys
+    # a row's keys and values: [22528, 4 x 128] as the cache holds them
+    moved = re.findall(
+        rf"= bf16\[(?:1,)?{positions},(?:512|4,128)\]\S* "
+        r"(?:copy|transpose)\(.*", text)
+    assert not moved, (len(moved), moved[:2])
+    # 62.2 MB at PR 45 (144.0 MB with the masked attention in plain XLA)
     assert compiled.memory_analysis().temp_size_in_bytes < 1024 ** 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 100 * 10 ** 6
+
+
+SELECTED_CASES = [
+    # (id, kv, g, l, n, d, start, dtype): the cell's shapes at its first
+    # window and at its last (``start`` traced, as the window program
+    # hands it, and a plain number); queries and positions that no block
+    # divides; one query head a key/value head, float32
+    ("cell_first", 4, 8, 512, 22528, 128, None, jnp.bfloat16),
+    ("cell_start_0", 4, 8, 512, 22528, 128, 0, jnp.bfloat16),
+    ("cell_start_19968", 4, 8, 512, 22528, 128, 19968, jnp.bfloat16),
+    ("ragged_300_of_1000", 4, 8, 300, 1000, 128, None, jnp.bfloat16),
+    ("g_1_float32", 2, 1, 512, 2048, 128, None, jnp.float32),
+]
+
+
+@pytest.mark.parametrize(
+    "kv,g,l,n,d,start,dtype", [c[1:] for c in SELECTED_CASES],
+    ids=[c[0] for c in SELECTED_CASES])
+def test_selected_attention_kernel_compiles_for_v5e(
+        one_chip, monkeypatch, kv, g, l, n, d, start, dtype):
+    """``selected_attention`` alone: the chip's compiler takes its blocks
+    (one head's 512 positions of the row where it lies, the queries'
+    512 x 512 order keys, the mask and the prefix count built in VMEM)
+    and its share of the fast memory; the row's keys and values, 128 lanes
+    a head side by side, are handed over as they are."""
+    import importlib
+
+    fa = importlib.import_module("tpu_pipelines.ops.flash_attention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    row = _sds((n, kv * d), dtype, one_chip)
+    args = [_sds((kv, g, l, d), dtype, one_chip), row, row,
+            _sds((l, n), jnp.uint32, one_chip),
+            _sds((l,), jnp.uint32, one_chip),
+            _sds((l,), jnp.int32, one_chip)]
+    if start is None:
+        call = fa.selected_attention
+        args.append(_sds((), jnp.int32, one_chip))
+    else:
+        call = lambda *a: fa.selected_attention(*a, start)
+    compiled = jax.jit(call).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 ** 2
+    name = {"bfloat16": "bf16", "float32": "f32"}[jnp.dtype(dtype).name]
+    if n % 512 == 0:
+        assert not re.findall(
+            rf"= {name}\[{n},{kv * d}\]\S* (?:copy|transpose)\(", text)
 
 
 LATENT_CASES = [

@@ -52,20 +52,25 @@ ONLY THOSE entries of ``kv`` (a gather by position) and attends over the
 gathered entries through ``ops/flash_attention.py
 grouped_decode_attention``, to the depth ``min(t + 1, index_topk)``: what
 it reads of ``kv`` does not grow with the row.  ``prefill_window`` and
-``__call__`` give every query its own set as a mask: the score of the
+``__call__`` give every query its own set as a threshold: the score of the
 ``min(index_topk, t + 1)``-th largest of its row is found by bisection over
-the scores' bit patterns (``kth_largest``: 32 counts, no sort), scores
-equal to it are taken in order of position while there is room (a running
-count), and attention runs in blocks of keys under that mask (plain XLA:
-its scores are heads x window x keys, so they are blocked over keys; a
-block past the window's last position is not visited).  A window's index
-scores and counts run over the whole row whatever its place in it.
+the scores' bit patterns (``kth_largest``: 32 counts, no sort), and scores
+equal to it are taken in order of position while there is room.  The mask,
+that count and the attention are ONE kernel (``ops/flash_attention.py
+selected_attention``) over the row's keys and values where they lie: it
+builds the mask of a block of 512 x 512 from the order keys and the
+threshold, carries the count of equal keys from block to block (and counts
+only where a query has more of them than room), keeps scores and
+statistics on the chip, and visits no block past the window's last
+position.  ``selected`` states the same rule over a whole row at once.  A
+window's index scores and the threshold's counts still run over the whole
+row whatever its place in it, in plain XLA.
 
 Device operations carry ``dsa.index`` (the indexer's projections inside
 ``attention_proj``, its scores inside ``attention_core``), ``dsa.select``
-(the top-k, or the threshold and the mask) and ``dsa.gather`` (the fetch by
-position), both inside ``attention_core``; the index key's write lies in
-``cache_write``.
+(the top-k, or the threshold; the window's mask is the kernel's) and
+``dsa.gather`` (the fetch by position), both inside ``attention_core``; the
+index key's write lies in ``cache_write``.
 """
 
 from __future__ import annotations
@@ -83,7 +88,8 @@ from tpu_pipelines.models.command_a import LayerNorm
 from tpu_pipelines.models.pangu_moe import (
     RMSNorm, RoutedExperts, config_from, tally_account)
 from tpu_pipelines.ops.flash_attention import (
-    NEG_INF, grouped_decode_attention, grouped_decode_block)
+    grouped_decode_attention, grouped_decode_block, selected_attention,
+    selected_block, selected_blocks, selected_last_block)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,8 +122,8 @@ class KeyeConfig:
     param_dtype: Any = jnp.bfloat16
 
 
-# Keys a block of a window's masked attention holds: its float32 scores
-# are heads x window x this (134 MB at 32 x 1,024 x 1,024).
+# Keys a block of a window's index scores holds: the heads' float32
+# products are window x heads x this (34 MB at 512 x 16 x 1,024).
 KEY_BLOCK = 1024
 
 
@@ -166,64 +172,34 @@ def kth_largest(keys, k):
         0, 32, bit, jnp.zeros(keys.shape[:1], jnp.uint32))
 
 
+def threshold(scores, t, topk: int):
+    """A window's sets as ``selected_block`` takes them.  scores [lq, n]
+    float32, entry ``[i, s]`` the index score of the key at sequence index
+    ``s``; t [lq] the queries' own indices -> the scores' order keys
+    [lq, n] uint32 (0 where ``s > t``), each query's threshold [lq], the
+    ``min(topk, t + 1)``-th largest of its keys (``kth_largest``: exact),
+    and the room [lq] int32 that the keys over it leave for those equal
+    to it."""
+    at = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :]
+    keys = jnp.where(at <= t[:, None], sortable(scores), jnp.uint32(0))
+    room = jnp.minimum(topk, t + 1).astype(jnp.int32)
+    kth = kth_largest(keys, room)
+    above = jnp.sum(keys > kth[:, None], axis=1, dtype=jnp.int32)
+    return keys, kth, room - above
+
+
 def selected(scores, t, topk: int):
     """Which keys each query attends over.  scores [lq, n] float32, entry
     ``[i, s]`` the index score of the key at sequence index ``s``; t [lq]
     the queries' own indices -> bool [lq, n]: the ``min(topk, t + 1)`` keys
     ``s <= t`` with the largest scores, equal scores to the lower index.
-    Exact: a threshold by ``kth_largest``, and of the keys equal to it as
-    many as there is room for, by a running count over the positions."""
-    n = scores.shape[1]
-    at = jnp.arange(n, dtype=jnp.int32)[None, :]
-    valid = at <= t[:, None]
-    keys = jnp.where(valid, sortable(scores), jnp.uint32(0))
-    room = jnp.minimum(topk, t + 1).astype(jnp.int32)
-    kth = kth_largest(keys, room)[:, None]
-    above, equal = keys > kth, keys == kth
-    room = room - jnp.sum(above, axis=1, dtype=jnp.int32)
-
-    # of the keys equal to the threshold, the first ``room`` by position
-    equal &= jnp.cumsum(equal, axis=1, dtype=jnp.int32) <= room[:, None]
-    return (above | equal) & valid
-
-
-def masked_attention(q, k, v, sees, n_keys):
-    """Attention under a mask a query, in blocks of keys with a running
-    softmax (plain XLA).  q [kv, g, lq, d], scaled; k, v [n, kv, d];
-    ``sees`` [lq, n] bool, every query sees a key; only the first
-    ``n_keys`` keys (traced) are visited, by whole blocks.
-    -> [kv, g, lq, d] float32."""
-    kv, g, lq, d = q.shape
-    n = k.shape[0]
-    block = min(KEY_BLOCK, n)
-    count = -(-n // block)
-    pad = count * block - n
-    k, v = (jnp.pad(a, ((0, pad), (0, 0), (0, 0))) for a in (k, v))
-    sees = jnp.pad(sees, ((0, 0), (0, pad)))
-
-    def one(j, carry):
-        m, l, acc = carry
-        cut = lambda a, axis: jax.lax.dynamic_slice_in_dim(
-            a, j * block, block, axis)
-        s = jnp.einsum("hgqd,khd->hgqk", q, cut(k, 0),
-                       preferred_element_type=jnp.float32)
-        s = jnp.where(cut(sees, 1), s, NEG_INF)
-        # A query that has seen no key yet holds exp(0) of every masked
-        # one; ``keep`` is 0 when its first key comes and wipes them.
-        m_new = jnp.maximum(m, jnp.max(s, -1))
-        p = jnp.exp(s - m_new[..., None])
-        keep = jnp.exp(m - m_new)
-        acc = acc * keep[..., None] + jnp.einsum(
-            "hgqk,khd->hgqd", p.astype(v.dtype), cut(v, 0),
-            preferred_element_type=jnp.float32)
-        return m_new, l * keep + jnp.sum(p, -1), acc
-
-    m, l, acc = jax.lax.fori_loop(
-        0, jnp.minimum(-(-n_keys // block), count), one, (
-            jnp.full((kv, g, lq), NEG_INF, jnp.float32),
-            jnp.zeros((kv, g, lq), jnp.float32),
-            jnp.zeros((kv, g, lq, d), jnp.float32)))
-    return acc / l[..., None]
+    Exact: ``threshold``, then the rule that the window's kernel applies
+    block by block (ops/flash_attention.py ``selected_block``), here over
+    the whole row as one block."""
+    keys, kth, room = threshold(scores, t, topk)
+    at = jnp.arange(scores.shape[1], dtype=jnp.int32)[None, :]
+    return selected_block(
+        keys, at, t[:, None], kth[:, None], room[:, None])[0]
 
 
 class SparseAttention(nn.Module):
@@ -300,9 +276,10 @@ class SparseAttention(nn.Module):
     def over(self, q, k, v, qi, ki, w, start):
         """One row's queries at sequence indices ``start + [0, lq)`` over
         the row's keys from index 0 on (an array by position, whose tail
-        may not be written yet): each query's own set as a mask.  q
-        [kv, g, lq, d]; k, v [n, kv, d]; qi [lq, heads, dim]; ki [n, dim];
-        w [lq, heads].  -> [lq, n_heads * d]."""
+        may not be written yet): each query over its own set, in ONE
+        kernel (``selected_attention``).  q [kv, g, lq, d]; k, v
+        [n, kv * d], a position's heads side by side; qi [lq, heads, dim];
+        ki [n, dim]; w [lq, heads].  -> [lq, n_heads * d]."""
         c = self.cfg
         lq, n = q.shape[2], k.shape[0]
         with jax.named_scope("attention_core"), jax.named_scope("dsa.index"):
@@ -317,17 +294,17 @@ class SparseAttention(nn.Module):
         with jax.named_scope("attention_core"), \
                 jax.named_scope("dsa.select"):
             t = start + jnp.arange(lq, dtype=jnp.int32)
-            sees = selected(index, t, c.index_topk)
+            keys, kth, room = threshold(index, t, c.index_topk)
         with jax.named_scope("attention_core"):
-            out = masked_attention(q, k, v, sees, start + lq)
-            return jnp.transpose(out, (2, 0, 1, 3)).reshape(lq, -1)
+            return selected_attention(q, k, v, keys, kth, room, start)
 
     def whole(self, x, pos):
         """A whole sequence of one row, no cache.  x [1, l, d_model], pos
         [3, 1, l]."""
         q, k, v = self.project(x, pos)
         qi, ki, w = self.index(x, pos)
-        out = self.over(q[0], k[0], v[0], qi[0], ki[0], w[0], 0)
+        flat = lambda a: a[0].reshape(a.shape[1], -1)
+        out = self.over(q[0], flat(k), flat(v), qi[0], ki[0], w[0], 0)
         with jax.named_scope("attention_proj"):
             return self.o_proj(out.astype(self.cfg.dtype))[None]
 
@@ -349,10 +326,9 @@ class SparseAttention(nn.Module):
                 a, new.reshape(1, p, -1), start, axis=1)
             cache = {"k": put(cache["k"], k), "v": put(cache["v"], v),
                      "index": put(cache["index"], ki)}
-        heads = lambda a: a[0].reshape(-1, *k.shape[2:])
         out = self.over(
-            q[0], heads(cache["k"]), heads(cache["v"]), qi[0],
-            cache["index"][0], w[0], start)
+            q[0], cache["k"][0], cache["v"][0], qi[0], cache["index"][0],
+            w[0], start)
         with jax.named_scope("attention_proj"):
             return self.o_proj(out.astype(self.cfg.dtype))[None], cache
 
@@ -598,7 +574,10 @@ def make_continuous_decode_fns(
         entries, as ``grouped_decode_attention`` fetches them), of
         ``index`` every position of the step's bucket, which the scores'
         product reads whatever the row's depth; and ``selected_entries``,
-        how many entries the rows' selections hold, layers together.
+        how many entries the rows' selections hold, layers together;
+      - ``window_account(index)``: the key blocks that window ``index`` of
+        a prompt visits (``selected_attention``'s, up to the window's last
+        position) and those the row holds, layers together.
     """
     from tpu_pipelines.serving.generative import CacheKind
 
@@ -648,6 +627,14 @@ def make_continuous_decode_fns(
             "selected_entries": c.n_layers * sum(chosen),
             **tally_account(tally, held)}
 
+    def window_account(index):
+        block_q, block_k = selected_blocks(p, positions)
+        each = c.n_layers * -(-p // block_q)     # layers x query blocks
+        return {"key_blocks": {
+            "visited": each * (
+                1 + selected_last_block(index * p, p, block_k)),
+            "held": each * -(-positions // block_k)}}
+
     return SimpleNamespace(
         step=step,
         step_tally_len=c.n_layers * held,
@@ -663,6 +650,7 @@ def make_continuous_decode_fns(
             jnp.asarray(input_mask, jnp.int32)),
         encoded_shape=(0,),
         step_account=step_account,
+        window_account=window_account,
         max_decode_len=int(max_decode_len),
         eos_id=int(eos_id),
         pad_id=int(pad_id),

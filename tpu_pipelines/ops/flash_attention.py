@@ -28,8 +28,15 @@ mask is the caller's rule over the POSITION each entry holds (a ring as it
 lies, an array by position), so a block no query sees is skipped.
 models/command_a.py's prefill window and whole-sequence pass run it.
 
-``latent_decode_attention`` (forward only, last in the file) is its
-decode-shaped sibling for a latent cache: one query a row, the row's
+``selected_attention`` (forward only, behind it) is its sibling for a
+window whose queries each attend over their own set of the row's
+positions, stated as a threshold over an order key a (query, position)
+pair: the mask is built in the kernel from those keys, with the count of
+keys equal to the threshold carried from block to block.
+models/keye.py's prefill window and whole-sequence pass run it.
+
+``latent_decode_attention`` (forward only, behind those) is
+``grouped_attention``'s decode-shaped sibling for a latent cache: one query a row, the row's
 heads as the rows of both products, each row's own cached rows as keys
 and (their first columns) values, read once and only to the row's depth.
 models/pangu_moe.py's decode step runs it.
@@ -614,10 +621,40 @@ def _across(x, n: int):
     return x[:, :n] if n <= LANES else jnp.tile(x, (1, n // LANES))
 
 
+def _heads_step(q_ref, k, v, masked, acc_ref, m_ref, l_ref):
+    """One block of keys against the ``g`` heads' queries.  q_ref
+    [1, g, bq, d]; k, v [bk, d]; ``masked`` [bq, bk] float32, 0 where the
+    query sees the key and NEG_INF where not; the running maximum, sum and
+    accumulator a head in the three scratch references."""
+    g, d = acc_ref.shape[0], acc_ref.shape[2]
+    block_k = k.shape[0]
+
+    # The heads one after another in ONE straight body, so that one
+    # head's products run beside another's softmax: 16 % less time than a
+    # rolled loop at 512 x 512 on the chip (PERF.md section 6, PR 37).
+    for i in range(g):
+        s = jax.lax.dot_general(                         # [bq, bk] on MXU
+            q_ref[0, i], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) + masked
+        m_prev = m_ref[i]                                # [bq, LANES]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # A masked score is exp(-1e30 - m) = 0 once a row has seen a key;
+        # before that (m still NEG_INF) what it adds is wiped by ``keep``
+        # = 0 when the first key comes.
+        p = jnp.exp(s - _across(m_new, block_k))
+        keep = jnp.exp(m_prev - m_new)
+        l_ref[i] = l_ref[i] * keep + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[i] = acc_ref[i] * _across(keep, d) + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[i] = m_new
+
+
 def _grouped_kernel(start_ref, fetch_ref, q_ref, k_ref, v_ref, held_ref,
                     o_ref, acc_ref, m_ref, l_ref, *, sees):
     g, block_q, d = q_ref.shape[1:]
-    block_k = k_ref.shape[1]
     qi = pl.program_id(1)
     kj = pl.program_id(2)
     n_kv = pl.num_programs(2)
@@ -637,31 +674,7 @@ def _grouped_kernel(start_ref, fetch_ref, q_ref, k_ref, v_ref, held_ref,
         # One mask for the g heads: a masked score is NEG_INF to the last
         # bit (a score is far below float32's step there).
         masked = jnp.where(sees(t, held_ref[...]), 0.0, NEG_INF)
-        k = k_ref[0]
-        v = v_ref[0]
-
-        # The heads one after another in ONE straight body, so that one
-        # head's products run beside another's softmax: 16 % less time
-        # than a rolled loop at 512 x 512 on the chip (PERF.md section 6,
-        # PR 37).
-        for i in range(g):
-            s = jax.lax.dot_general(                     # [bq, bk] on MXU
-                q_ref[0, i], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) + masked
-            m_prev = m_ref[i]                            # [bq, LANES]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            # A masked score is exp(-1e30 - m) = 0 once a row has seen a
-            # key; before that (m still NEG_INF) what it adds is wiped by
-            # ``keep`` = 0 when the first key comes.
-            p = jnp.exp(s - _across(m_new, block_k))
-            keep = jnp.exp(m_prev - m_new)
-            l_ref[i] = l_ref[i] * keep + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[i] = acc_ref[i] * _across(keep, d) + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_ref[i] = m_new
+        _heads_step(q_ref, k_ref[0], v_ref[0], masked, acc_ref, m_ref, l_ref)
 
     @pl.when(kj == n_kv - 1)
     def _final():
@@ -753,6 +766,200 @@ def grouped_attention(q, k, v, held, start, sees):
         name="grouped_attention",
     )(start.reshape(1), fetch, q, k, v, held[None, :])
     return out[:, :, :l]
+
+
+# ------------------------------------- grouped, under a set a query
+
+# Queries of one head that a step of ``selected_attention``'s grid holds
+# at most; the keys are ``GROUPED_BLOCK_K`` (PERF.md section 6, PR 45).
+SELECTED_BLOCK_Q = 512
+
+
+def selected_blocks(l: int, n: int):
+    """-> the queries and the keys that a step of ``selected_attention``
+    holds for ``l`` queries over ``n`` positions: the fewest blocks of at
+    most ``SELECTED_BLOCK_Q`` x ``GROUPED_BLOCK_K`` that hold them."""
+    return (_blocks_of(l, SELECTED_BLOCK_Q, 16)[0],
+            _blocks_of(n, GROUPED_BLOCK_K, LANES)[0])
+
+
+def selected_last_block(start, l: int, block_k: int):
+    """The last key block that a window at positions ``start + [0, l)`` is
+    handed: the one that holds its last query's own position (``start`` a
+    plain number or traced)."""
+    return (start + l - 1) // block_k
+
+
+def selected_block(keys, at, t, kth, room=None, seen=0.0):
+    """Which keys of one block of positions each query attends over, its
+    set stated as a threshold: the keys at positions ``s <= t`` whose
+    order key lies over the query's ``kth``, and of those equal to it the
+    first ``room`` by position.  keys [q, k], in any type whose order is
+    the scores'; at [1, k] int32, the positions the block holds; t, kth,
+    room [q, 1]; ``seen`` [q, 1] float32, how many keys equal to ``kth``
+    lie at the positions before the block.  ``room`` None says that every
+    equal key has room, and nothing is counted.
+    -> bool [q, k], and ``seen`` with this block's equal keys."""
+    valid = at <= t
+    if room is None:
+        return valid & (keys >= kth), seen
+    equal = valid & (keys == kth)
+    # The count of equal keys up to each position, on the matrix unit: a
+    # 0/1 matrix times an upper triangle of ones is exact in float32
+    # (counts stay under 2^24).
+    k = keys.shape[1]
+    upto = jax.lax.broadcasted_iota(
+        jnp.int32, (k, k), 0) <= jax.lax.broadcasted_iota(
+            jnp.int32, (k, k), 1)
+    ones = lambda x: jnp.where(x, 1.0, 0.0).astype(jnp.bfloat16)
+    count = seen + jnp.dot(
+        ones(equal), ones(upto), preferred_element_type=jnp.float32)
+    fits = count <= room.astype(jnp.float32)
+    return valid & ((keys > kth) | (equal & fits)), count[:, k - 1:]
+
+
+def _selected_kernel(start_ref, ties_ref, q_ref, k_ref, v_ref, keys_ref,
+                     kth_ref, room_ref, o_ref, acc_ref, m_ref, l_ref,
+                     seen_ref, masked_ref, *, l, n):
+    g, block_q, d = q_ref.shape[1:]
+    block_k = k_ref.shape[0]
+    qi = pl.program_id(1)
+    kj = pl.program_id(2)
+
+    @pl.when(kj == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        seen_ref[...] = jnp.zeros_like(seen_ref)
+
+    # A block past the window's last position was handed the last live
+    # block again (nothing is fetched) and computes nothing.
+    @pl.when(kj <= selected_last_block(start_ref[0], l, block_k))
+    def _step():
+        t = start_ref[0] + qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, 1), 0)
+        at = kj * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_k), 1)
+        # One mask for the g heads, as ``_grouped_kernel``'s.  Only a
+        # query block in which some query has more keys equal to its
+        # threshold than room counts them.
+        mask = lambda sees: jnp.where(sees, 0.0, NEG_INF)
+
+        @pl.when(ties_ref[qi] == 0)
+        def _all_fit():
+            masked_ref[...] = mask(selected_block(
+                keys_ref[...], at, t, kth_ref[...])[0])
+
+        @pl.when(ties_ref[qi] != 0)
+        def _counted():
+            sees, seen = selected_block(
+                keys_ref[...], at, t, kth_ref[...], room_ref[...],
+                seen_ref[:, :1])
+            masked_ref[...] = mask(sees)
+            seen_ref[...] = jnp.broadcast_to(seen, seen_ref.shape)
+
+        k, v = k_ref[...], v_ref[...]
+        if n % block_k:
+            # past the arrays' end a block holds no number at all
+            inside = kj * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_k, 1), 0) < n
+            k, v = jnp.where(inside, k, 0), jnp.where(inside, v, 0)
+        _heads_step(q_ref, k, v, masked_ref[...], acc_ref, m_ref, l_ref)
+
+    @pl.when(kj == pl.num_programs(2) - 1)
+    def _final():
+        for i in range(g):
+            o_ref[:, i * d:(i + 1) * d] = (
+                acc_ref[i] / _across(l_ref[i], d)).astype(o_ref.dtype)
+
+
+def selected_attention(q, k, v, keys, kth, room, start):
+    """Attention of grouped query heads over a row's entries by position,
+    each query over its own set of them, stated as a threshold over an
+    order key a (query, position) pair (forward only): a prefill window
+    under a learned selection.
+
+    ``q``: [kv_heads, g, l, head_dim], already scaled, at positions
+    ``start + [0, l)`` (``start`` a scalar, traced or not).  ``k``/``v``:
+    [n, kv_heads * head_dim], the row as its cache holds it, positions
+    first and a position's heads side by side, written up to ``start + l``
+    at least; what lies behind is finite and never used.  ``keys``: [l, n]
+    uint32, entry ``[i, s]`` the order key of position ``s`` for query
+    ``i``; ``kth`` [l] uint32 and ``room`` [l] int32: the query attends
+    over the positions ``s <= start + i`` whose key lies over ``kth``, and
+    of those whose key equals it over the first ``room`` by position
+    (``selected_block``, the rule, traced into the kernel a block at a
+    time).  Every query sees at least one key.
+
+    Grid (key/value head, query block, key block), the key block the
+    sequential axis.  A step holds the ``g`` heads' queries and ONE block
+    of one head's keys and values, cut from the row where it lies (no
+    copy of the row), and the queries' block of order keys; the mask is
+    built from them in VMEM, once for the ``g`` heads; each head's
+    scores, their running maximum and sum and the accumulator are float32
+    and never leave VMEM, as in ``grouped_attention``.  The count of keys
+    equal to the threshold is carried from block to block beside them,
+    and runs only in a query block where some query has more equal keys
+    than room (told by one count over the row outside the kernel).  A key
+    block past the window's last position is neither computed nor
+    fetched: its step's index map names the last live block again.  Any
+    ``l`` and ``n``: what a block holds past an array's end is masked.
+    On the chip ``head_dim`` is a multiple of 128 (a block is one head's
+    columns of the row).
+    -> [l, kv_heads * g * head_dim] in ``q``'s dtype, query head ``i`` of
+    key/value head ``h`` at columns ``(h * g + i) * head_dim``.
+    """
+    from jax.experimental.pallas import tpu as pltpu
+
+    kv, g, l, d = q.shape
+    n = k.shape[0]
+    block_q, block_k = selected_blocks(l, n)
+    n_q, n_kv = -(-l // block_q), -(-n // block_k)
+    start = jnp.asarray(start, jnp.int32)
+    t = start + jnp.arange(l, dtype=jnp.int32)
+    equal = jnp.sum(
+        (keys == kth[:, None]) & (jnp.arange(n)[None, :] <= t[:, None]),
+        axis=1, dtype=jnp.int32)
+    ties = jnp.pad(equal > room, (0, n_q * block_q - l)).reshape(
+        n_q, block_q).any(1).astype(jnp.int32)
+
+    # the key block a step is handed: its own, or the last live one again
+    at = lambda j, start_ref: jnp.minimum(
+        j, selected_last_block(start_ref[0], l, block_k))
+    q_spec = pl.BlockSpec(
+        (1, g, block_q, d), lambda h, i, j, *_: (h, 0, i, 0))
+    kv_spec = pl.BlockSpec(
+        (block_k, d), lambda h, i, j, start_ref, _: (at(j, start_ref), h))
+    keys_spec = pl.BlockSpec(
+        (block_q, block_k),
+        lambda h, i, j, start_ref, _: (i, at(j, start_ref)))
+    query_spec = pl.BlockSpec((block_q, 1), lambda h, i, j, *_: (i, 0))
+    return pl.pallas_call(
+        functools.partial(_selected_kernel, l=l, n=n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(kv, n_q, n_kv),
+            in_specs=[q_spec, kv_spec, kv_spec, keys_spec, query_spec,
+                      query_spec],
+            out_specs=pl.BlockSpec(
+                (block_q, g * d), lambda h, i, j, *_: (i, h)),
+            scratch_shapes=[
+                pltpu.VMEM((g, block_q, d), jnp.float32),
+                pltpu.VMEM((g, block_q, LANES), jnp.float32),
+                pltpu.VMEM((g, block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, LANES), jnp.float32),
+                pltpu.VMEM((block_q, block_k), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((l, kv * g * d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_GROUPED_VMEM_BYTES),
+        interpret=_resolve_interpret(None),
+        name="selected_attention",
+    )(start.reshape(1), ties, q, k, v, keys, kth[:, None],
+      room.astype(jnp.int32)[:, None])
 
 
 # ------------------------------------------- a row's heads, to its depth

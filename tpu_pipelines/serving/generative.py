@@ -660,6 +660,7 @@ class GenerativeEngine:
                 "a contract prefilled by window takes no prefix cache"
             )
         self._account = getattr(fns, "step_account", None)
+        self._window_account = getattr(fns, "window_account", None)
         # What ``insert`` is handed as a row's encoder output where the
         # contract has no whole-prompt prefill to return one.
         self._no_encoded = np.zeros(
@@ -1360,7 +1361,9 @@ class GenerativeEngine:
                     self.params, self._row_cache, tokens,
                     np.int32(count), np.int32(index),
                 )
-                self.telemetry.on_prefill_window(count)
+                self.telemetry.on_prefill_window(
+                    count, self._window_account and self._window_account(
+                        index))
                 if (index + 1) * W < n_prompt:
                     self._partial = (seq, index + 1)
                     return True
@@ -1704,6 +1707,7 @@ class DecodeTelemetry:
         self._queue_wait = self._ttft = self._first_reads = None
         self._dispatches = self._wasted = None
         self._prefill_tokens = self._prefill_windows = None
+        self._window_key_blocks = None
         self._rollovers = self._summaries = None
         self._cache_bytes = self._cache_read = None
         self._cache_entries = self._cache_span = None
@@ -1827,6 +1831,12 @@ class DecodeTelemetry:
             "Prefill-window programs run: a prompt of L tokens costs "
             "ceil(L / window).", labels=lab,
         ).labels(self.replica)
+        self._window_key_blocks = registry.counter(
+            "serving_decode_window_key_blocks_total",
+            "Key blocks that the prefill windows' attention visited and "
+            "that their rows held, layers together, as the contract's "
+            "window_account states them.", labels=("replica", "state"),
+        )
         self._rollovers = registry.counter(
             "serving_decode_window_rollovers_total",
             "Decode steps of a row that began a new attention window "
@@ -1942,10 +1952,15 @@ class DecodeTelemetry:
         if self._wasted is not None:
             self._wasted.inc(n_rows)
 
-    def on_prefill_window(self, n_tokens: int) -> None:
-        if self._prefill_windows is not None:
-            self._prefill_windows.inc()
-            self._prefill_tokens.inc(n_tokens)
+    def on_prefill_window(self, n_tokens: int, account=None) -> None:
+        """One prefill window of ``n_tokens``, and its account by the
+        contract (``window_account``) where it keeps one."""
+        if self._prefill_windows is None:
+            return
+        self._prefill_windows.inc()
+        self._prefill_tokens.inc(n_tokens)
+        for state, n in (account or {}).get("key_blocks", {}).items():
+            self._window_key_blocks.labels(self.replica, state).inc(n)
 
     def on_cache(self, account: Dict[str, Any]) -> None:
         """One decode step's account by the contract (``step_account``):
