@@ -7,9 +7,9 @@ vocabulary ``observability.trace.DEVICE_PARTS``.
   (79564dd) lowered to, the module's name aside (the train window's was
   ``jit__lambda``).  A PR that changes a program on purpose lowers it
   again and replaces the hash: ``python tests/test_device_parts.py``
-  prints the table.  PR 41 did so for ``pangu_moe step`` and PR 43 for
-  ``command_a step`` (their attention became one kernel); the others
-  stand as they were.
+  prints the table.  PR 41 did so for ``pangu_moe step``, PR 43 for
+  ``command_a step`` and PR 46 for ``evabyte step`` (their attention
+  became one kernel); the others stand as they were.
 * Every instruction of the lowered HLO that a line of the program wrote
   and that computes carries a word of the vocabulary in its scope path
   or inherits one by the rule of ``benchmark/program_parts.py``; under
@@ -19,6 +19,8 @@ vocabulary ``observability.trace.DEVICE_PARTS``.
   it lies, and the kernel's own operations are attention (ISSUE 41).
 * Command A+'s step hands its attention kernel every layer's ring or
   array, keys and values, where they lie (ISSUE 43).
+* EvaByte's step hands its attention kernel every layer's ring and chunk
+  table, keys and values, where they lie (ISSUE 46).
 """
 
 import hashlib
@@ -34,7 +36,8 @@ import pytest
 # commit 79564dd (the parent of the scopes), jax 0.9.0, 8 CPU devices;
 # ``pangu_moe step`` at PR 41, the two of ``xing`` at PR 42, ``command_a
 # step`` at PR 43, the two of ``keye`` at PR 44, ``keye prefill_window``
-# again at PR 45 (its attention under the selection is one kernel).
+# again at PR 45 (its attention under the selection is one kernel),
+# ``evabyte step`` at PR 46 (its attention over ring and table is one).
 PARENT_SHA256 = {
     "t5 prefill":
         "78e3c0297e8727c951835f623307a467a28c383a2f2fb948af2a20bbad3c584c",
@@ -49,7 +52,7 @@ PARENT_SHA256 = {
     "t5 step 4x8":
         "24119531de8bdc60a36bc497fbb47eee11850561961afe2fcaf8e4ee57d6d438",
     "evabyte step":
-        "9cac10e1515ec1bef2ad1dfbc0ba3be91a78383abb66bb422286597e92e984fc",
+        "22edc58a956dc8f7abb91f3510b699654a890c55d060c234254f649346efc0b0",
     "evabyte prefill_window":
         "225d0ab75b35d40ac33bdab436e2a1b231c9dffb6550a51307fcd06bdc12fc3b",
     "pangu_moe step":
@@ -364,6 +367,62 @@ def test_command_a_step_hands_its_kernel_both_caches_where_they_lie(lowered):
     for dims, arrays in (("4,2,16,16", 12), ("4,2,104,16", 4)):
         assert set(made[dims]) == {"parameter", "dynamic-update-slice"}, made
         assert made[dims]["dynamic-update-slice"] == 2 * arrays
+
+
+def test_evabyte_step_hands_its_kernel_ring_and_table_where_they_lie(lowered):
+    """The fixture's arena is 4 slots x (a ring of 32 entries or a table
+    of 40) x 4 heads x 16 numbers, keys and values, in each of 2 layers,
+    and the step runs 2 rows.  Nothing of an array's size is copied,
+    padded, sliced, reshaped, transposed or gathered on its way to the
+    attention (the parent sliced ``ring_k[:2]`` and ``chunk_k[:2]`` out):
+    each array is written row by row and handed over whole and as it lies
+    (ISSUE 46).  Every operation of the kernel, interpreted here, is
+    booked to the attention itself."""
+    from benchmark import program_parts
+
+    raw, text = lowered["evabyte step"][2:]
+    module = program_parts.messages()["HloModule"].FromString(raw)
+    path = {ins.name: ins.metadata.op_name
+            for comp in module.computations for ins in comp.instructions}
+    parts = program_parts.module_parts(raw)
+    kernel = [n for n, p in path.items()
+              if "ring_table_decode_attention" in p]
+    assert len(kernel) > 100
+    # The layers share ONE lowering of the kernel (a function of the
+    # module, called once a layer): its operations take their part from
+    # the call, which stands under the scope of the lines it replaced.
+    assert {parts[n] for n in kernel} == {("attention_core", "caller")}
+    calls = sorted(
+        p for p in path.values() if p.endswith("jit(_ring_table_call)"))
+    assert len(calls) == 2 and all(
+        f"layer_{i}.step/attn.step/attention_core/eva.attend/" in p
+        for i, p in enumerate(calls))
+
+    made = {}         # dims -> opcode -> count, of an array's size
+    for line in text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?(\S+) = \w+\[([\d,]+)\]\S* ([\w-]+)\(", line)
+        if m is None:
+            continue
+        _, dims, opcode = m.groups()
+        # the live rows' part of an array is never cut out
+        assert dims not in ("2,32,4,16", "2,40,4,16"), line
+        if sorted(dims.split(",")) in (
+                sorted("4,32,4,16".split(",")),
+                sorted("4,40,4,16".split(","))):
+            made.setdefault(dims, {}).setdefault(opcode, 0)
+            made[dims][opcode] += 1
+    # 2 arrays a layer of either kind, each handed in (to the program, and
+    # on through the interpreted kernel's own loop) and written once: the
+    # step's entry into the ring, the closed chunk's into the table
+    assert set(made) == {"4,32,4,16", "4,40,4,16"}, made
+    for dims in made:
+        assert set(made[dims]) == {
+            "parameter", "get-tuple-element", "dynamic-update-slice",
+            "scatter"}, made
+        assert made[dims]["scatter"] == 2 * 2
+        # the program's own and the shared kernel function's two
+        assert made[dims]["parameter"] == 2 * 2 + 2
 
 
 def test_keye_window_hands_its_kernel_the_row_where_it_lies(lowered):

@@ -898,6 +898,101 @@ def test_grouped_decode_kernel_compiles_for_v5e(
         assert not re.findall(r" (?:copy|transpose)\(", text)
 
 
+RING_TABLE_CASES = [
+    # (id, rows, heads, d, slots, ring, table, dtype): the cell's ring
+    # and table; entries that no block divides; float32; the fixtures'
+    ("cell", 8, 32, 128, 9, 2048, 896, jnp.bfloat16),
+    ("ragged", 8, 16, 128, 8, 1000, 300, jnp.bfloat16),
+    ("float32", 4, 8, 128, 4, 512, 128, jnp.float32),
+    ("fixture", 2, 4, 16, 4, 32, 40, jnp.float32),
+]
+
+
+@pytest.mark.parametrize(
+    "rows,heads,d,slots,ring,table,dtype",
+    [c[1:] for c in RING_TABLE_CASES], ids=[c[0] for c in RING_TABLE_CASES])
+def test_ring_table_decode_kernel_compiles_for_v5e(
+        one_chip, monkeypatch, rows, heads, d, slots, ring, table, dtype):
+    """``ring_table_decode_attention`` alone: the chip's compiler takes its
+    blocks (128 entries of a ring or of a table, every head of an entry
+    at once, read as the rows of one product) within the fast memory a
+    kernel has without asking, and at the cell's shapes the four arrays,
+    a position's heads side by side, are handed over as they are."""
+    import importlib
+
+    fa = importlib.import_module("tpu_pipelines.ops.flash_attention")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    a_ring = _sds((slots, ring, heads, d), dtype, one_chip)
+    a_table = _sds((slots, table, heads, d), dtype, one_chip)
+    depth = _sds((rows,), jnp.int32, one_chip)
+    compiled = jax.jit(lambda *a: fa.ring_table_decode_attention(
+        *a, scale=d ** -0.5)).lower(
+            _sds((rows, heads, d), dtype, one_chip), a_ring, a_ring,
+            a_table, a_table, depth, depth).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 ** 2
+    if d == 128:
+        assert not re.findall(r" (?:copy|transpose)\(", text)
+
+
+def test_evabyte_step_keeps_ring_and_table_where_they_lie_on_v5e(
+        one_chip, monkeypatch):
+    """The engine's own step program for the contract of
+    models/evabyte.py at the published widths, two layers, the whole
+    8-slot arena (rings of 2,048, tables of 896 entries) handed over in
+    place and donated: every cache array comes back where it lay, each
+    layer's attention is ONE Pallas kernel
+    (ops/flash_attention.py ``ring_table_decode_attention``) that is
+    handed the layer's four arrays where they lie, nothing of an array's
+    size is copied or transposed, and no float32 scores over a ring or a
+    table are left in the program (ISSUE 46).  The program picks its
+    kernels by the backend, which is the CPU here: the test tells it the
+    chip's."""
+    from types import SimpleNamespace
+
+    from tpu_pipelines.models import evabyte as ev
+    from tpu_pipelines.serving import generative as gen
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, layers = 8, 2
+    model = ev.build_evabyte_model(dict(n_layers=layers))
+    fns = ev.make_continuous_decode_fns(
+        model, max_decode_len=1024, eos_id=320, max_input_len=12288)
+    params = jax.eval_shape(lambda: model.init(
+        jax.random.key(0), {"inputs": jnp.zeros((1, 8), jnp.int32)})["params"])
+    state = (
+        jax.eval_shape(lambda: fns.blank_cache(rows)),
+        jnp.zeros((rows,), jnp.int32), jnp.zeros((rows,), jnp.int32),
+        jnp.zeros((rows,), bool), jnp.zeros((rows, 0), jnp.float32),
+        jnp.zeros((rows, 12288), jnp.int32),
+    )
+    on_chip = lambda tree: jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip), tree)
+    program = gen.GenerativeEngine._build_step(
+        SimpleNamespace(pad_id=0), rows, 13312, fns)
+    compiled = program.lower(on_chip(params), on_chip(state)).compile()
+    m = _fits(compiled)
+    # one q, k or v kernel transposed (33.5 MB: ROADMAP S9) and no ring
+    assert m.temp_size_in_bytes < 64 * 1024 ** 2
+    text = compiled.as_text()
+    kernels = re.findall(r"custom_call_target=\"tpu_custom_call\".*", text)
+    assert len(kernels) == layers
+    assert all("ring_table_decode_attention" in k for k in kernels)
+    assert not re.findall(rf"f32\[{rows},32,1,(?:2048|896)\]", text)
+    param_layouts, result_layouts, aliases = _entry_layouts(text)
+    result_of = {param: out for out, param in aliases.items()}
+    for leaf in (f"bf16[{rows},2048,32,128]", f"bf16[{rows},896,32,128]"):
+        leaves = [
+            i for i, s in enumerate(param_layouts) if s.startswith(leaf)]
+        assert len(leaves) == 2 * layers
+        for i in leaves:
+            assert result_layouts[result_of[i]] == param_layouts[i]
+        moved = re.findall(
+            rf"= {re.escape(leaf)}\S* (?:copy|transpose)\(.*", text)
+        assert not moved, (len(moved), moved[:2])
+
+
 @pytest.mark.parametrize("tokens", [32, 512])
 def test_grouped_expert_products_at_4096_compile_for_v5e(one_chip, tokens):
     """The same kernel at Command A+'s expert shape (16 experts held,

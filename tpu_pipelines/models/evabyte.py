@@ -40,6 +40,9 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from tpu_pipelines.ops.flash_attention import (
+    ring_table_blocks, ring_table_decode_attention)
+
 NEG_INF = -1e30
 
 
@@ -254,14 +257,15 @@ class EvaAttention(nn.Module):
             ring_v = cache["window_v"].at[rows, at].set(v[:, 0])
         n_chunks = cache["chunk_k"].shape[1]
         with jax.named_scope("attention_core"):
-            k_ok = (jnp.arange(w)[None, :] <= at[:, None])[:, None]
-            c_ok = (
-                jnp.arange(n_chunks)[None, :]
-                < ((pos // w) * (w // c))[:, None]
-            )[:, None]
-            out = self.attend(
-                q, ring_k[:b], ring_v[:b], k_ok,
-                cache["chunk_k"][:b], cache["chunk_v"][:b], c_ok)[:, 0]
+            # One kernel over the row's ring to ``at`` and its table to
+            # the last completed window, each array where it lies; what
+            # lies past either depth is neither fetched nor used.
+            with jax.named_scope("eva.attend"):
+                out = ring_table_decode_attention(
+                    q[:, 0], ring_k, ring_v, cache["chunk_k"],
+                    cache["chunk_v"], at + 1,
+                    jnp.minimum((pos // w) * (w // c), n_chunks),
+                    scale=self.head_dim ** -0.5).reshape(b, -1)
             # The chunk this position lies in, as the ring holds it; its
             # summary is stored only by the step that closes the chunk (an
             # index past the table's end is dropped by the scatter).
@@ -541,9 +545,15 @@ def make_continuous_decode_fns(
       - ``first_decode_pos(input_mask)``: a sequence's first decode
         position is its prompt's length; ``encoded`` has no rows;
       - ``step_account(positions, tally, bucket)``: what one step over
-        rows at these positions reads of each kind and which events it
-        holds, for the telemetry (``tally`` is empty: the step hands none
-        back; ``bucket``, the step's rows and positions, is not read).
+        rows at these positions must read of each kind (``cache_bytes``:
+        the valid entries), what its attention kernel fetches for them
+        (``cache_span_bytes``: whole blocks of a ring up to the one that
+        holds ``t % W``, whole blocks of a table up to the last completed
+        window's entries, none of an empty table; what lies behind is
+        never read) and which events it holds, for the telemetry
+        (``tally`` is empty: the step hands none back; ``bucket``, the
+        step's rows and positions, is not read: the kernel stops at a
+        row's depths whatever the bucket).
     """
     from tpu_pipelines.serving.generative import CacheKind
 
@@ -552,6 +562,8 @@ def make_continuous_decode_fns(
     entry_bytes = (
         2 * model.n_layers * model.n_heads * model.head_dim
         * jnp.dtype(model.dtype).itemsize)
+    entries = {"window": w, "chunk": -(-context // w) * w // c}
+    block = dict(zip(entries, ring_table_blocks(*entries.values())))
 
     def prefill_window(params, cache, tokens, n_valid, index):
         return model.apply(
@@ -570,11 +582,24 @@ def make_continuous_decode_fns(
         return "window" if leaf.startswith("window") else "chunk"
 
     def step_account(positions, tally=(), bucket=None):
-        ring = sum(t % w + 1 for t in positions)
-        table = sum((t // w) * (w // c) for t in positions)
+        """``cache_bytes``: the valid entries, a ring to ``t % w`` and a
+        table to the last completed window.  ``cache_span_bytes``: what
+        ``ring_table_decode_attention`` fetches for the live rows, whole
+        blocks up to the one that holds a row's last valid entry, cut at
+        the array's end; of an empty table nothing."""
+        ring = [t % w + 1 for t in positions]
+        table = [min((t // w) * (w // c), entries["chunk"])
+                 for t in positions]
+        span = lambda kind, depths: sum(
+            min(-(-n // block[kind]) * block[kind], entries[kind])
+            for n in depths)
         return {
             "cache_bytes": {
-                "window": ring * entry_bytes, "chunk": table * entry_bytes},
+                "window": sum(ring) * entry_bytes,
+                "chunk": sum(table) * entry_bytes},
+            "cache_span_bytes": {
+                "window": span("window", ring) * entry_bytes,
+                "chunk": span("chunk", table) * entry_bytes},
             "window_rollovers": sum(t % w == 0 for t in positions),
             "chunk_summaries": sum((t + 1) % c == 0 for t in positions),
         }

@@ -40,6 +40,12 @@ models/keye.py's prefill window and whole-sequence pass run it.
 heads as the rows of both products, each row's own cached rows as keys
 and (their first columns) values, read once and only to the row's depth.
 models/pangu_moe.py's decode step runs it.
+
+``grouped_decode_attention`` (behind it) is the same step over a cache of
+grouped heads, and ``ring_table_decode_attention`` (the last) over a ring
+and a table of entries whose heads lie side by side, every head with keys
+of its own, under one softmax.  models/command_a.py's and
+models/evabyte.py's decode steps run them.
 """
 
 from __future__ import annotations
@@ -1217,3 +1223,181 @@ def grouped_decode_attention(q, k, v, depth, klen: int):
         interpret=_resolve_interpret(None),
         name="grouped_decode_attention",
     )(depth.astype(jnp.int32), q, k, v)
+
+
+# ------------------- a row's heads over its ring and its table, to depth
+
+# Entries that a step of ``ring_table_decode_attention``'s grid holds at
+# most, of a ring or of a table, every head of an entry at once, from two
+# sweeps on the chip at EvaByte's shapes (8 rows of 32 heads x 128 over
+# rings of 2,048 and tables of 896 entries): the kernel takes as long an
+# entry whatever the block, so the fewest entries past a row's depth win
+# (PERF.md section 6, PR 46).
+RING_TABLE_BLOCK_K = 128
+
+
+def ring_table_blocks(ring: int, table: int):
+    """Entries a block of ``ring_table_decode_attention`` holds over a
+    ring of ``ring`` entries and over a table of ``table``; a row that
+    attends over the first ``n`` entries of either is handed its blocks
+    ``[0, (n - 1) // block]`` and no other, and none where ``n`` is 0."""
+    return tuple(
+        min(RING_TABLE_BLOCK_K, -(-n // 8) * 8) for n in (ring, table))
+
+
+def _ring_table_fetch(i, j, ring_n, table_n, came_from, blocks,
+                      ring_steps: int):
+    """-> ((slot, block) of the ring, (slot, block) of the table) that step
+    ``j`` of row ``i`` is handed: its own block, else the last one
+    handed before it again, which is then not fetched.  For the table that
+    is the row's first block while the row's ring goes by, and for a row
+    whose table is empty whatever the row before it left
+    (``came_from``: the last row up to this one that has a table, row 0
+    where none has)."""
+    ring = jnp.minimum(j, (ring_n[i] - 1) // blocks[0])
+    src = came_from[i]
+    last = jnp.maximum(table_n[src] - 1, 0) // blocks[1]
+    table = jnp.where(
+        table_n[i] > 0, jnp.clip(j - ring_steps, 0, last), last)
+    return (i, ring), (src, table)
+
+
+def _ring_table_kernel(ring_n_ref, table_n_ref, from_ref, q_ref, rk_ref,
+                       rv_ref, tk_ref, tv_ref, o_ref, acc_ref, m_ref, l_ref,
+                       *, scale, ring_steps):
+    heads, d = acc_ref.shape
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def block(k_ref, v_ref, first, n):
+        """The entries ``[first, first + block)`` of which those under
+        ``n`` count.  An entry's heads lie side by side, so the block's
+        (entry, head) pairs are the columns of ONE product against the
+        row's heads, of which a head keeps its own entries' and no other
+        head's: the cache is read where it lies, and the products run on
+        the MXU as in the siblings."""
+        width = k_ref.shape[1] * heads
+        # ``_across`` for any width (a fixture's is no multiple of LANES)
+        across = lambda x, n: jnp.broadcast_to(x[:, :1], (heads, n))
+        # What a block holds past the row's depth (the window before's
+        # entries, a former occupant's numbers, no number at all) is used
+        # neither as a score nor as a value (0 x NaN is NaN).
+        live = (n - first) * heads
+        at = lambda shape, axis: jax.lax.broadcasted_iota(
+            jnp.int32, shape, axis)
+        column = at((heads, width), 1)
+        own = (jax.lax.rem(column, heads) == at((heads, width), 0)) & (
+            column < live)
+        values = jnp.where(
+            at((width, 1), 0) < live, v_ref[0].reshape(width, d), 0)
+        s = jnp.where(own, jax.lax.dot_general(       # [h, bk * h] on MXU
+            q_ref[0], k_ref[0].reshape(width, d), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale, NEG_INF)
+        m_prev = m_ref[...]                              # [h, LANES]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - across(m_new, width))
+        keep = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * keep + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * across(keep, d) + jax.lax.dot_general(
+            p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = m_new
+
+    # A block that starts past the row's depth was handed the last live
+    # block again (nothing is fetched) and computes nothing.
+    ring_n, table_n = ring_n_ref[i], table_n_ref[i]
+    ring_first = j * rk_ref.shape[1]
+    table_first = (j - ring_steps) * tk_ref.shape[1]
+    pl.when((j < ring_steps) & (ring_first < ring_n))(
+        lambda: block(rk_ref, rv_ref, ring_first, ring_n))
+    pl.when((j >= ring_steps) & (table_first < table_n))(
+        lambda: block(tk_ref, tv_ref, table_first, table_n))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _final():
+        o_ref[0] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+def ring_table_decode_attention(q, ring_k, ring_v, table_k, table_v, ring_n,
+                                table_n, *, scale: float):
+    """One query a row and head over the row's own entries of a ring AND
+    of a table, each to the row's depth, under ONE softmax, every head
+    with keys and values of its own (forward only): a decode step of
+    chunked linearised attention (models/evabyte.py).
+
+    ``q``: [rows, heads, head_dim].  ``ring_k``/``ring_v``: [slots, w,
+    heads, head_dim] and ``table_k``/``table_v``: [slots, t, heads,
+    head_dim] as they lie, an entry's heads side by side, ``slots >=
+    rows``: row ``i`` attends over the ring's entries ``[0, ring_n[i])`` and
+    the table's ``[0, table_n[i])`` of slot ``i``; ``ring_n`` [rows] int32
+    in ``[1, w]``, ``table_n`` [rows] int32 in ``[0, t]``.  What lies past
+    a row's depth, in its own slot or in another, is never used, whatever
+    it holds.
+
+    Grid (row, block), the block the sequential axis: the ring's blocks,
+    then the table's, of 128 entries each.  A step holds the row's queries
+    and ONE block of entries, keys and values, every head of an entry at
+    once as it lies (no copy, no transposition); the scores [heads, block
+    x heads], their running maximum and sum and the accumulator are
+    float32 and never leave VMEM; the scale multiplies the float32 scores;
+    the weights enter the second product in the cache's dtype.  A block
+    that starts past a row's depth (handed over by scalar prefetch) is
+    neither computed nor fetched: its step's index map names the last
+    block handed before it again, so a row whose table is empty reads no
+    block of it (a call's very first step is handed a block of each array
+    whatever it names: the table's first, of slot 0, where row 0 has
+    none).
+    -> [rows, heads, head_dim] in ``q``'s dtype.
+    """
+    return _ring_table_call(
+        q, ring_k, ring_v, table_k, table_v, ring_n, table_n, scale=scale,
+        blocks=ring_table_blocks(ring_k.shape[1], table_k.shape[1]),
+        interpret=_resolve_interpret(None))
+
+
+# A program's layers call the kernel on arrays of one shape: under ``jit``
+# they share ONE lowering of it (a step of 16 layers lowered 16 kernel
+# bodies, a second a program, before; PERF.md section 6, PR 46).
+@functools.partial(jax.jit, static_argnames=("scale", "blocks", "interpret"))
+def _ring_table_call(q, ring_k, ring_v, table_k, table_v, ring_n, table_n, *,
+                     scale: float, blocks, interpret: bool):
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, heads, d = q.shape
+    steps = [-(-a.shape[1] // n) for a, n in zip((ring_k, table_k), blocks)]
+    ring_n, table_n = ring_n.astype(jnp.int32), table_n.astype(jnp.int32)
+    came_from = jax.lax.cummax(
+        jnp.where(table_n > 0, jnp.arange(rows, dtype=jnp.int32), 0))
+
+    fetch = lambda which: lambda i, j, *depths: _ring_table_fetch(
+        i, j, *depths, blocks, steps[0])[which] + (0, 0)
+    q_spec = pl.BlockSpec((1, heads, d), lambda i, j, *_: (i, 0, 0))
+    ring_spec = pl.BlockSpec((1, blocks[0], heads, d), fetch(0))
+    table_spec = pl.BlockSpec((1, blocks[1], heads, d), fetch(1))
+    return pl.pallas_call(
+        functools.partial(
+            _ring_table_kernel, scale=scale, ring_steps=steps[0]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(rows, sum(steps)),
+            in_specs=[q_spec, ring_spec, ring_spec, table_spec, table_spec],
+            out_specs=q_spec,
+            scratch_shapes=[
+                pltpu.VMEM((heads, d), jnp.float32),
+                pltpu.VMEM((heads, LANES), jnp.float32),
+                pltpu.VMEM((heads, LANES), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="ring_table_decode_attention",
+    )(ring_n, table_n, came_from, q, ring_k, ring_v, table_k, table_v)
