@@ -43,7 +43,7 @@ def create_pipeline(base_dir: str = ""):
     data = int(os.environ.get("STAGED_DATA", "2"))
     pipe = int(os.environ.get("STAGED_PIPE", "4"))
     if jax.device_count() < data * pipe:
-        # Single-chip fallback (e.g. the real-TPU bench host): plain DP,
+        # Single-chip fallback (e.g. a one-chip TPU host): plain DP,
         # sequential stages — same network, no pipeline schedule.
         data, pipe = -1, 1
 

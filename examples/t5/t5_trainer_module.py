@@ -56,8 +56,8 @@ def make_generate_step(model, hyperparameters):
 
 
 def make_decode_fns(model, hyperparameters):
-    """Export hook (trainer/export.py): the continuous-batching decode
-    contract — prefill/step + geometry — that opts this payload into the
+    """Export hook (trainer/export.py): the ``DecodeContract``
+    (models/decode_contract.py) that opts this payload into the
     generative fleet model type (serving/generative.py).  Same eos/pad
     conventions as make_generate_step above."""
     from tpu_pipelines.models.t5 import make_continuous_decode_fns

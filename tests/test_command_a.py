@@ -619,7 +619,7 @@ def test_the_contract_states_what_the_engine_may_not_guess(f32):
     assert fns.cache_positions == MAX_IN + MAX_OUT
     assert fns.step_tally_len == 8 * HELD
     assert int(fns.first_decode_pos(np.array([[1, 1, 1, 0, 0]]))) == 3
-    assert not hasattr(fns, "prefill")
+    assert fns.prefill is None
     account = fns.step_account([3, 40], [0] * 31 + [2], (2, 104))
     assert account["cache_entries"] == {
         "window": 6 * (4 + 16), "full": 2 * (4 + 41)}

@@ -142,20 +142,27 @@ def test_tpu_resource_class_serialized_host_overlaps(tmp_path):
 # ---------------------------------------------- determinism + lineage
 
 
-def _node_executions(metadata_path):
+def _node_executions(metadata_path, root=None):
+    """node id -> [(execution id, state, input events, output events)];
+    with ``root``, URIs relative to it, so that two homes compare."""
     from tpu_pipelines.metadata import MetadataStore
     from tpu_pipelines.metadata.types import EventType
 
     store = MetadataStore(metadata_path)
     out = {}
+
+    def uri(ev):
+        u = store.get_artifact(ev.artifact_id).uri
+        return os.path.relpath(u, root) if root else u
+
     for ex in store.get_executions():
         events = store.get_events_by_execution(ex.id)
         ins = sorted(
-            (ev.path, ev.index, store.get_artifact(ev.artifact_id).uri)
+            (ev.path, ev.index, uri(ev))
             for ev in events if ev.type == EventType.INPUT
         )
         outs = sorted(
-            (ev.path, ev.index, store.get_artifact(ev.artifact_id).uri)
+            (ev.path, ev.index, uri(ev))
             for ev in events if ev.type == EventType.OUTPUT
         )
         out.setdefault(ex.node_id, []).append(
@@ -166,29 +173,19 @@ def _node_executions(metadata_path):
 
 
 def test_execution_ids_deterministic_and_lineage_complete(tmp_path):
-    """Two concurrent runs of the same DAG register the same execution ids
-    (and so the same output URIs), and every COMPLETE execution carries its
-    full input/output event lineage."""
+    """Two concurrent runs of the same DAG, and a sequential one, register
+    the same execution ids (and so the same output URIs), and every
+    COMPLETE execution carries its full input/output event lineage."""
     recs = []
-    for sub in ("a", "b"):
+    for sub, workers in (("a", 3), ("b", 3), ("seq", 1)):
         p = _diamond(tmp_path, sleep_s=0.15, subdir=sub)
-        LocalDagRunner(max_parallel_nodes=3).run(p, run_id="fixed")
-        recs.append((_node_executions(p.metadata_path), p.pipeline_root))
+        result = LocalDagRunner(max_parallel_nodes=workers).run(
+            p, run_id="fixed")
+        assert result.max_parallel_nodes == workers
+        recs.append(_node_executions(p.metadata_path, p.pipeline_root))
 
-    def normalize(node_execs, root):
-        return {
-            node: [
-                (ex_id, state,
-                 [(pa, i, os.path.relpath(u, root)) for pa, i, u in ins],
-                 [(pa, i, os.path.relpath(u, root)) for pa, i, u in outs])
-                for ex_id, state, ins, outs in entries
-            ]
-            for node, entries in node_execs.items()
-        }
-
-    a = normalize(*recs[0])
-    b = normalize(*recs[1])
-    assert a == b
+    a = recs[0]
+    assert a == recs[1] == recs[2]
     for node in ("Gen", "Left", "Right", "Join"):
         (ex_id, state, ins, outs), = a[node]
         assert state == "COMPLETE"

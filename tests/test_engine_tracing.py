@@ -65,17 +65,33 @@ def phase_series(reg, family):
     return {p: series.get(("0", p), 0.0) for p in ENGINE_PHASES}
 
 
-def test_phase_seconds_add_up_to_the_workers_lifetime():
+def test_phase_seconds_add_up_to_the_workers_lifetime(monkeypatch):
     """Self seconds: a nested phase's time is taken out of its parent's,
     so the seven sums are the worker thread's life, warm-up (idle) and
-    all.  The thread starts in the constructor and is joined by close."""
-    engine, reg, handles, lifetime_s = run_traffic(pause_s=0.05)
+    all.  That life is timed where it is lived, inside the thread and on
+    the clock ``_phase`` reads: the thread's start in the constructor and
+    its join in ``close`` belong to the caller, not to a phase."""
+    from tpu_pipelines.serving.generative import GenerativeEngine
+
+    run, stamps = GenerativeEngine._run, []
+
+    def stamped_run(self):
+        stamps.append(time.perf_counter())
+        try:
+            run(self)
+        finally:
+            stamps.append(time.perf_counter())
+
+    monkeypatch.setattr(GenerativeEngine, "_run", stamped_run)
+    engine, reg, handles, outside_s = run_traffic(pause_s=0.05)
+    assert not engine._worker.is_alive() and len(stamps) == 2
+    lifetime_s = stamps[1] - stamps[0]
     seconds = phase_series(reg, "serving_decode_engine_seconds_total")
     assert all(s >= 0.0 for s in seconds.values())
     assert seconds["idle"] > 0.0 and seconds["step"] > 0.0
     assert sum(seconds.values()) == pytest.approx(lifetime_s, rel=0.02)
     # the step's sum is what the EWMA gauge could never give
-    assert seconds["step"] <= lifetime_s
+    assert seconds["step"] <= lifetime_s <= outside_s
 
 
 @pytest.mark.parametrize("engine_kw", [

@@ -298,7 +298,7 @@ def test_the_contract_states_what_the_engine_may_not_guess(f32):
     # whole windows over 96 + 64 positions: 5, so 40 chunks
     assert shapes == {(3, WINDOW, 4, 16), (3, 160 // CHUNK, 4, 16)}
     assert int(fns.first_decode_pos(np.array([[1, 1, 1, 0, 0]]))) == 3
-    assert not hasattr(fns, "prefill")
+    assert fns.prefill is None
     with pytest.raises(ValueError, match="prefilled by window"):
         GenerativeEngine(fns, params, prefix_cache_entries=2)
 

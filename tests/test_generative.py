@@ -39,12 +39,15 @@ EOS = 4  # with the chain below: ~half the seeds terminate, half run full
 
 
 def make_stub_fns(max_decode_len=12, eos_id=EOS, pad_id=0, max_input_len=6):
-    """A deterministic autoregressive chain with the engine's duck-typed
-    contract: the next token depends on the input seed, every token the
-    cache has accumulated, and the decode position — so any arena slot
-    mix-up, stale cache row, or wrong position corrupts the stream."""
+    """A deterministic autoregressive chain as a ``DecodeContract`` that
+    states nothing but its programs and geometry: the next token depends
+    on the input seed, every token the cache has accumulated, and the
+    decode position — so any arena slot mix-up, stale cache row, or wrong
+    position corrupts the stream."""
     import jax
     import jax.numpy as jnp
+
+    from tpu_pipelines.models.decode_contract import DecodeContract
 
     def prefill(params, inputs, input_mask=None):
         if input_mask is None:
@@ -69,10 +72,10 @@ def make_stub_fns(max_decode_len=12, eos_id=EOS, pad_id=0, max_input_len=6):
         ) % VOCAB
         return {"toks": toks}, jax.nn.one_hot(nxt, VOCAB)
 
-    return SimpleNamespace(
+    return DecodeContract(
         prefill=prefill, step=step,
-        max_decode_len=int(max_decode_len), eos_id=int(eos_id),
-        pad_id=int(pad_id), max_input_len=int(max_input_len),
+        max_decode_len=max_decode_len, eos_id=eos_id,
+        pad_id=pad_id, max_input_len=max_input_len,
     )
 
 

@@ -677,7 +677,7 @@ def test_the_contract_states_what_the_engine_may_not_guess(f32):
     assert fns.cache_positions == POSITIONS
     assert fns.step_tally_len == 3 * EXPERTS
     assert int(fns.first_decode_pos(np.array([[1, 1, 1, 0, 0]]))) == 3
-    assert not hasattr(fns, "prefill")
+    assert fns.prefill is None
     account = fns.step_account([3, 40], [0] * 23 + [2], (2, POSITIONS))
     assert account["cache_entries"] == {
         "kv": 3 * (4 + 41), "index": 3 * (4 + 41)}

@@ -759,7 +759,7 @@ def test_the_contract_states_what_the_engine_may_not_guess(f32):
     assert fns.cache_positions == MAX_IN + MAX_OUT
     assert fns.step_tally_len == 2 * HELD
     assert int(fns.first_decode_pos(np.array([[1, 1, 1, 0, 0]]))) == 3
-    assert not hasattr(fns, "prefill")
+    assert fns.prefill is None
     # whole windows over the prompt lie inside the row
     assert decode_fns(model, max_input_len=90, max_decode_len=2
                       ).cache_positions == 96

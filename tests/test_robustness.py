@@ -340,6 +340,50 @@ def test_transient_fault_kind_with_retry_policy(tmp_path):
     assert _counter_total("retry_attempts_total", "node:Gen") - before == 2
 
 
+def test_run_under_a_fault_schedule_publishes_a_fault_free_lineage(tmp_path):
+    """The whole schedule at once (transient executor errors at one node,
+    store contention on its publish): the run completes on the fleet's
+    retry rung, and what it published is what a fault-free run does."""
+    from test_concurrent_runner import _node_executions
+
+    def two_nodes(home):
+        @component(outputs={"examples": "Examples"}, name="Gen")
+        def Gen(ctx):
+            with open(os.path.join(ctx.output("examples").uri, "d"), "w") as f:
+                f.write("rows")
+
+        @component(inputs={"examples": "Examples"},
+                   outputs={"model": "Model"}, name="Fit")
+        def Fit(ctx):
+            assert os.path.exists(os.path.join(ctx.input("examples").uri, "d"))
+            with open(os.path.join(ctx.output("model").uri, "m"), "w") as f:
+                f.write("fit")
+
+        gen = Gen()
+        return Pipeline(
+            "chaos", [gen, Fit(examples=gen.outputs["examples"])],
+            pipeline_root=str(home / "root"),
+            metadata_path=str(home / "md.sqlite"),
+            retry_policy={"max_attempts": 3, "base_delay_s": 0.001},
+        )
+
+    clean = two_nodes(tmp_path / "clean")
+    assert LocalDagRunner().run(clean).succeeded
+    chaos = two_nodes(tmp_path / "chaos")
+    plan = FaultPlan({
+        "Fit": NodeFault(TRANSIENT_EXECUTOR_ERROR, times=2),
+        STORE_KEY: NodeFault(STORE_CONTENTION, times=2),
+    })
+    with plan.activate():
+        result = LocalDagRunner().run(chaos)
+    assert result.succeeded and result.nodes["Fit"].retries == 2
+    assert {e for _, e in plan.log} == {
+        "transient_executor_error", "store_contention:publish_execution"}
+    assert _node_executions(
+        chaos.metadata_path, chaos.pipeline_root
+    ) == _node_executions(clean.metadata_path, clean.pipeline_root)
+
+
 def test_spmd_sync_refuses_retry_policies(tmp_path):
     node = _flaky_component()().with_retry_policy(max_attempts=3)
     with pytest.raises(ValueError, match="spmd_sync is incompatible"):
@@ -358,8 +402,7 @@ def test_retry_without_any_policy_unchanged(tmp_path):
 
 # ------------------------------------------------------ shard resilience
 # (The fork-pool kill/replacement paths are covered by the
-# sanity-by-construction tests below; the taxi-scale run lives in the
-# robustness.taxi_chaos bench leg.)
+# sanity-by-construction tests below.)
 
 
 _POISON_STRIKES = {"n": 0}

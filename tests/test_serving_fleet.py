@@ -5,7 +5,7 @@ manager's ``loader`` seam / a monkeypatched default loader), so the suite
 exercises the real fleet machinery — version leases, canary gate, router,
 per-replica batchers, the full REST surface — without exporting or
 jit-compiling a model.  The heavyweight exported-payload paths stay in
-tests/test_serving.py (slow) and the ``serving_fleet`` bench leg.
+tests/test_serving.py (slow).
 """
 
 import json

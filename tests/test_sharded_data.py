@@ -315,6 +315,33 @@ def test_statistics_gen_sharded_equals_single(tmp_path, monkeypatch):
                 assert fa.string.top_values == fb.string.top_values
 
 
+def test_transform_sharded_rows_identical_and_layout_mirrored(tmp_path):
+    """Transform over sharded Examples: shard i in is shard i out, and the
+    transformed rows are those of the single-file run."""
+    from tpu_pipelines.components import SchemaGen, Transform
+
+    module = os.path.join(
+        os.path.dirname(os.path.dirname(__file__)),
+        "examples", "taxi", "taxi_preprocessing.py")
+    out = {}
+    for tag, shards in (("single", 1), ("sharded", 3)):
+        gen = CsvExampleGen(input_path=TAXI_CSV, num_shards=shards)
+        stats = StatisticsGen(examples=gen.outputs["examples"])
+        schema = SchemaGen(statistics=stats.outputs["statistics"])
+        transform = Transform(
+            examples=gen.outputs["examples"],
+            schema=schema.outputs["schema"], module_file=module)
+        result = LocalDagRunner().run(Pipeline(
+            "tf", [transform], pipeline_root=str(tmp_path / tag / "root"),
+            metadata_path=str(tmp_path / tag / "md.sqlite")))
+        out[tag] = result.outputs_of(
+            "Transform", "transformed_examples")[0].uri
+    for split in ("train", "eval"):
+        assert examples_io.num_split_shards(out["sharded"], split) == 3
+        assert _row_multiset(out["single"], split) == _row_multiset(
+            out["sharded"], split)
+
+
 def test_cache_hit_across_shard_count_env(tmp_path, monkeypatch):
     """Shard count is a performance knob, not a semantic input: a re-run
     with a different TPP_DATA_SHARDS env must still hit the execution cache

@@ -95,7 +95,7 @@ def test_window_defaults_to_log_every_and_env_overrides(monkeypatch):
     # Explicit config wins over the env.
     _, r_explicit, _ = _run(3, steps=12, log_every=4)
     assert r_explicit.window_steps == 3
-    # log_every=0 (bench legs) stays per-step unless asked otherwise.
+    # log_every=0 stays per-step unless asked otherwise.
     monkeypatch.delenv("TPP_WINDOW_STEPS")
     _, r_bench, _ = _run(None, steps=6, log_every=0)
     assert r_bench.window_steps == 1

@@ -45,7 +45,7 @@ the python or the native C++ backend).
 Prints the measured run profile (per-node durations, critical path,
 queue/gate waits, cache-hit ratio); ``--perfetto`` writes a Chrome/
 Perfetto-loadable timeline, ``--metrics`` the machine-readable summary
-``bench.py`` and the cluster runner consume.  ``trace diff`` compares
+the cluster runner consumes.  ``trace diff`` compares
 two runs node by node (baseline first) and exits 3 when any node or the
 critical path regressed past the threshold — the CI tripwire.
 

@@ -109,8 +109,7 @@ def WindowStatisticsMerger(ctx):
     touching the data: fold each split's pre-merge shard accumulators in
     global (span, shard) order, finalize once, save.  Bit-identical to a
     cold StatisticsGen over the SpanWindow artifact while shards fit
-    their reservoirs — asserted by the ``continuous.taxi_spans`` bench
-    leg's lineage-identity check."""
+    their reservoirs (tests/test_continuous.py)."""
     from tpu_pipelines.data.statistics import (
         load_split_accumulators,
         merge_accumulators,

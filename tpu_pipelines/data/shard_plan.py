@@ -50,7 +50,6 @@ from tpu_pipelines.robustness import (
     classify_error,
     record_retry,
 )
-from tpu_pipelines.testing import faults as _faults
 from tpu_pipelines.utils.chip import held_accelerator
 
 log = logging.getLogger("tpu_pipelines.data.shard_plan")
@@ -73,7 +72,7 @@ class ShardPlan:
 
     ``source`` records which rung of the precedence ladder decided
     (``param`` > ``env`` > ``host_cpus``) — it lands in execution summaries
-    so BENCH/debug output says *why* an artifact has N shards.
+    so debug output says *why* an artifact has N shards.
     """
 
     num_shards: int
@@ -175,9 +174,6 @@ class _TracedShardFn:
 
     def __call__(self, indexed):
         i, task = indexed
-        # Fault hook (testing/faults.py KILL_SHARD_WORKER): one module-
-        # global read when no plan is active.
-        _faults.in_shard(i)
         in_child = os.getpid() != self.parent_pid
         if in_child:
             _fed.note_fork_baseline()

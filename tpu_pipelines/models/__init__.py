@@ -26,6 +26,8 @@ Present:
     rotary code and softmax-routed experts without a shared one
     (``build_keye_model``; served through serving/generative.py; two kinds
     of cache at the same positions)
+  - decode_contract: ``DecodeContract`` and ``CacheKind``, what each of
+    the served models above hands serving/generative.py
   - transformer: shared sharded blocks (TP over 'model', ring-attention SP
     over 'seq') used by bert/t5
 

@@ -67,13 +67,14 @@ depth.
 from __future__ import annotations
 
 import dataclasses
-from types import SimpleNamespace
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from tpu_pipelines.models.decode_contract import (
+    CacheKind, DecodeContract, window_positions)
 from tpu_pipelines.models.pangu_moe import (
     RoutedExperts, config_from, tally_account)
 from tpu_pipelines.ops.flash_attention import (
@@ -458,38 +459,34 @@ def make_continuous_decode_fns(
     max_input_len: int = 64,
     prefill_window_len: int = 512,
 ):
-    """The decode contract of serving/generative.py for a decoder-only
-    model whose layers keep caches of TWO kinds in one arena.
-
-    As the contract of models/pangu_moe.py (``prefill_window``,
-    ``blank_cache``, ``cache_positions``, ``first_decode_pos``, no
-    encoder rows, ``step_tally_len``), and:
+    """Command A's ``DecodeContract`` (models/decode_contract.py), of the
+    decoder-only family (``DecodeContract.decoder_only``): layers that
+    keep caches of TWO kinds in one arena.  Its own:
 
       - ``window``, ``CacheKind(by_position=False)``: a window layer's
         ring of ``window_size`` entries, written at ``pos %
         window_size``, valid by mask.  It wraps while a prompt longer
         than the window is prefilled and again while decoding.
       - ``full``, ``CacheKind(by_position=True)``: a full layer's keys
-        and values at every position from the prompt's first token on;
-        ``step`` attends over the first ``klen`` of them.
+        and values at every position from the prompt's first token on
+        (``cache_positions``); ``step`` attends over the first ``klen``
+        of them.
       - both worked on in place, both ``[slots, n_kv_heads, entries,
         head_dim]``: the engine only ever indexes the slot axis of an
         array it hands over whole.
       - ``prefill_window_len`` divides ``window_size``: a window's keys
         lie in the ring without a wrap inside them.
-      - ``step_account(positions, tally, bucket)``: per kind the entries
-        and bytes that are valid for the live rows, and the bytes that
-        the step's attention kernel fetches for them: whole key blocks up
-        to the one that holds a row's depth.
+      - ``step_tally_len``: the held experts of every layer.
+      - ``step_account``: per kind the entries and bytes that are valid
+        for the live rows, and the bytes that the step's attention
+        kernel fetches for them: whole key blocks up to the one that
+        holds a row's depth.
     """
-    from tpu_pipelines.serving.generative import CacheKind
-
     c = model.cfg
     p, w = int(prefill_window_len), c.window_size
     if w % p:
         raise ValueError("prefill_window_len must divide window_size")
-    span = -(-int(max_input_len) // p) * p
-    positions = max(span, int(max_input_len) + int(max_decode_len))
+    _, positions = window_positions(max_input_len, max_decode_len, p)
     n_full = sum(c.is_full(i) for i in range(c.n_layers))
     n_ring = c.n_layers - n_full
     entry_bytes = (
@@ -539,7 +536,7 @@ def make_continuous_decode_fns(
             "window_rollovers": sum(t % w == 0 for t in at),
             **tally_account(tally, held)}
 
-    return SimpleNamespace(
+    return DecodeContract.decoder_only(
         step=step,
         step_tally_len=c.n_layers * held,
         prefill_window=prefill_window,
@@ -550,12 +547,9 @@ def make_continuous_decode_fns(
             "window": CacheKind(False, written=True, in_place=True),
             "full": CacheKind(True, written=True, in_place=True)},
         cache_kind_of=cache_kind_of,
-        first_decode_pos=lambda input_mask: jnp.sum(
-            jnp.asarray(input_mask, jnp.int32)),
-        encoded_shape=(0,),
         step_account=step_account,
-        max_decode_len=int(max_decode_len),
-        eos_id=int(eos_id),
-        pad_id=int(pad_id),
-        max_input_len=int(max_input_len),
+        max_decode_len=max_decode_len,
+        eos_id=eos_id,
+        pad_id=pad_id,
+        max_input_len=max_input_len,
     )

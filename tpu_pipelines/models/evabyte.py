@@ -33,13 +33,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from types import SimpleNamespace
 from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from tpu_pipelines.models.decode_contract import CacheKind, DecodeContract
 from tpu_pipelines.ops.flash_attention import (
     ring_table_blocks, ring_table_decode_attention)
 
@@ -524,39 +524,24 @@ def make_continuous_decode_fns(
     pad_id: int = 0,
     max_input_len: int = 64,
 ):
-    """The decode contract of serving/generative.py for a decoder-only
-    model whose prefill runs a window at a time.
+    """EvaByte's ``DecodeContract`` (models/decode_contract.py), of the
+    decoder-only family (``DecodeContract.decoder_only``).  Its own:
 
-    Beside the geometry every contract gives, and ``step``:
-
-      - ``prefill_window(params, cache, tokens [1, W], n_valid, index)``
-        -> ``(cache, logits [1, V])``: one window of one prompt against
-        that row's cache; ``prefill_window_len`` is ``W``.  There is no
-        whole-prompt ``prefill``: a prompt of ``L`` bytes costs
-        ``ceil(L / W)`` calls, and its last call's logits give its first
-        new byte;
-      - ``blank_cache(batch)``: the cache's arrays for ``batch`` rows;
-      - ``cache_kinds`` / ``cache_kind_of(path)``: the window ring and
-        the chunk table, neither indexed by decode position, both
-        worked on in place: ``step`` is handed every slot's rows, reads
-        and writes the first ``len(tok)`` of them and returns the arrays
-        (a bucket of either, copied out and set back at every step,
-        would cost more than the step);
-      - ``first_decode_pos(input_mask)``: a sequence's first decode
-        position is its prompt's length; ``encoded`` has no rows;
-      - ``step_account(positions, tally, bucket)``: what one step over
-        rows at these positions must read of each kind (``cache_bytes``:
-        the valid entries), what its attention kernel fetches for them
-        (``cache_span_bytes``: whole blocks of a ring up to the one that
-        holds ``t % W``, whole blocks of a table up to the last completed
-        window's entries, none of an empty table; what lies behind is
-        never read) and which events it holds, for the telemetry
-        (``tally`` is empty: the step hands none back; ``bucket``, the
-        step's rows and positions, is not read: the kernel stops at a
-        row's depths whatever the bucket).
+      - ``prefill_window_len`` is the model's attention window;
+      - two kinds of cache, the window ring and the chunk table, neither
+        indexed by decode position (no ``cache_positions``), both worked
+        on in place (a bucket of either, copied out and set back at
+        every step, would cost more than the step);
+      - ``step_account``: what one step over rows at these positions
+        must read of each kind (``cache_bytes``: the valid entries),
+        what its attention kernel fetches for them (``cache_span_bytes``:
+        whole blocks of a ring up to the one that holds ``t % W``, whole
+        blocks of a table up to the last completed window's entries,
+        none of an empty table; what lies behind is never read) and
+        which events it holds (``tally`` is empty: the step hands none
+        back; ``bucket`` is not read: the kernel stops at a row's depths
+        whatever the bucket).
     """
-    from tpu_pipelines.serving.generative import CacheKind
-
     w, c = model.window_size, model.chunk_size
     context = int(max_input_len) + int(max_decode_len)
     entry_bytes = (
@@ -604,22 +589,19 @@ def make_continuous_decode_fns(
             "chunk_summaries": sum((t + 1) % c == 0 for t in positions),
         }
 
-    return SimpleNamespace(
+    return DecodeContract.decoder_only(
         step=step,
         prefill_window=prefill_window,
-        prefill_window_len=int(w),
+        prefill_window_len=w,
         blank_cache=blank_cache,
         cache_kinds={
             "window": CacheKind(False, written=True, in_place=True),
             "chunk": CacheKind(False, written=True, in_place=True),
         },
         cache_kind_of=cache_kind_of,
-        first_decode_pos=lambda input_mask: jnp.sum(
-            jnp.asarray(input_mask, jnp.int32)),
-        encoded_shape=(0,),
         step_account=step_account,
-        max_decode_len=int(max_decode_len),
-        eos_id=int(eos_id),
-        pad_id=int(pad_id),
-        max_input_len=int(max_input_len),
+        max_decode_len=max_decode_len,
+        eos_id=eos_id,
+        pad_id=pad_id,
+        max_input_len=max_input_len,
     )

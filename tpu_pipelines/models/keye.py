@@ -76,7 +76,6 @@ index key's write lies in ``cache_write``.
 from __future__ import annotations
 
 import dataclasses
-from types import SimpleNamespace
 from typing import Any, Dict, Tuple
 
 import jax
@@ -85,6 +84,7 @@ import numpy as np
 from flax import linen as nn
 
 from tpu_pipelines.models.command_a import LayerNorm
+from tpu_pipelines.models.decode_contract import CacheKind, DecodeContract
 from tpu_pipelines.models.pangu_moe import (
     RMSNorm, RoutedExperts, config_from, tally_account)
 from tpu_pipelines.ops.flash_attention import (
@@ -548,13 +548,10 @@ def make_continuous_decode_fns(
     max_input_len: int = 64,
     prefill_window_len: int = 512,
 ):
-    """The decode contract of serving/generative.py for a decoder-only
-    model that keeps TWO kinds of cache at the same positions and reads
-    one of them only where the other says.
-
-    As the contract of models/command_a.py (``prefill_window``,
-    ``blank_cache``, ``cache_positions``, ``first_decode_pos``, no encoder
-    rows, ``step_tally_len``), and:
+    """Keye's ``DecodeContract`` (models/decode_contract.py), of the
+    decoder-only family (``DecodeContract.decoder_only``): TWO kinds of
+    cache at the same positions, one read only where the other says.
+    Its own:
 
       - ``kv``, ``CacheKind(by_position=True)``: a layer's keys and values
         at every position from the prompt's first token on, ``[slots,
@@ -567,20 +564,19 @@ def make_continuous_decode_fns(
       - the engine's traffic is text: a step's ``pos`` and a window's
         positions are one number a token, and stand for all three
         components of the rotary code;
-      - ``step_account(positions, tally, bucket)``: per kind the entries
-        and bytes that are valid for the live rows and the bytes that the
-        step fetches for them: of ``kv`` the ``min(t + 1, index_topk)``
-        entries a row that were selected (whole blocks of the gathered
-        entries, as ``grouped_decode_attention`` fetches them), of
-        ``index`` every position of the step's bucket, which the scores'
-        product reads whatever the row's depth; and ``selected_entries``,
-        how many entries the rows' selections hold, layers together;
-      - ``window_account(index)``: the key blocks that window ``index`` of
-        a prompt visits (``selected_attention``'s, up to the window's last
+      - ``step_tally_len``: the held experts of every layer;
+      - ``step_account``: per kind the entries and bytes that are valid
+        for the live rows and the bytes that the step fetches for them:
+        of ``kv`` the ``min(t + 1, index_topk)`` entries a row that were
+        selected (whole blocks of the gathered entries, as
+        ``grouped_decode_attention`` fetches them), of ``index`` every
+        position of the step's bucket, which the scores' product reads
+        whatever the row's depth; and ``selected_entries``, how many
+        entries the rows' selections hold, layers together;
+      - ``window_account``: the key blocks that window ``index`` of a
+        prompt visits (``selected_attention``'s, up to the window's last
         position) and those the row holds, layers together.
     """
-    from tpu_pipelines.serving.generative import CacheKind
-
     c = model.cfg
     p = int(prefill_window_len)
     positions = -(-(int(max_input_len) + int(max_decode_len)) // p) * p
@@ -635,7 +631,7 @@ def make_continuous_decode_fns(
                 1 + selected_last_block(index * p, p, block_k)),
             "held": each * -(-positions // block_k)}}
 
-    return SimpleNamespace(
+    return DecodeContract.decoder_only(
         step=step,
         step_tally_len=c.n_layers * held,
         prefill_window=prefill_window,
@@ -646,13 +642,10 @@ def make_continuous_decode_fns(
             "kv": CacheKind(True, written=True, in_place=True),
             "index": CacheKind(True, written=True, in_place=True)},
         cache_kind_of=cache_kind_of,
-        first_decode_pos=lambda input_mask: jnp.sum(
-            jnp.asarray(input_mask, jnp.int32)),
-        encoded_shape=(0,),
         step_account=step_account,
         window_account=window_account,
-        max_decode_len=int(max_decode_len),
-        eos_id=int(eos_id),
-        pad_id=int(pad_id),
-        max_input_len=int(max_input_len),
+        max_decode_len=max_decode_len,
+        eos_id=eos_id,
+        pad_id=pad_id,
+        max_input_len=max_input_len,
     )

@@ -51,13 +51,14 @@ prediction module on top of it.
 from __future__ import annotations
 
 import dataclasses
-from types import SimpleNamespace
 from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from tpu_pipelines.models.decode_contract import (
+    CacheKind, DecodeContract, window_positions)
 from tpu_pipelines.models.evabyte import (
     NEG_INF, GatedMlp, rope, yarn_inv_freq, yarn_mscale)
 from tpu_pipelines.ops.flash_attention import (
@@ -688,32 +689,22 @@ def make_continuous_decode_fns(
     max_input_len: int = 64,
     prefill_window_len: int = 256,
 ):
-    """The decode contract of serving/generative.py for a decoder-only
-    model whose cache is indexed by position and holds the prompt.
-
-    As the contract of models/evabyte.py (``prefill_window``,
-    ``blank_cache``, ``first_decode_pos``, no encoder rows), and:
+    """openPangu's ``DecodeContract`` (models/decode_contract.py), of the
+    decoder-only family (``DecodeContract.decoder_only``).  Its own:
 
       - one kind of cache, ``latent``: per layer ``[slots, positions,
         kv_lora_rank + qk_rope_head_dim]``, indexed by position from the
-        prompt's first token on, written by every step, worked on in
-        place: ``step`` is handed every slot's rows, writes row ``i`` at
-        ``pos[i]`` and attends over its first ``pos[i] + 1`` positions,
-        fetched by whole key blocks (``klen`` bounds their number);
-      - ``cache_positions``: how many positions a row holds, prompt and
-        new tokens together; the engine's kv buckets cover
-        ``first_decode_pos + tokens held``;
-      - ``step`` returns a third value, ``[b, step_tally_len]`` int32:
-        per row, which held experts it chose in each expert layer.  The
-        engine sums it over the live rows and hands the sums to
-        ``step_account(positions, tally, bucket)``.
+        prompt's first token on (``cache_positions``), written by every
+        step, worked on in place: ``step`` writes row ``i`` at ``pos[i]``
+        and attends over its first ``pos[i] + 1`` positions, fetched by
+        whole key blocks (``klen`` bounds their number);
+      - ``step_tally_len``: the held experts of every expert layer;
+      - ``step_account``: the valid bytes, the key blocks fetched and
+        the tally of the experts held.
     """
-    from tpu_pipelines.serving.generative import CacheKind
-
     c = model.cfg
     w = int(prefill_window_len)
-    span = -(-int(max_input_len) // w) * w
-    positions = max(span, int(max_input_len) + int(max_decode_len))
+    span, positions = window_positions(max_input_len, max_decode_len, w)
     row_bytes = c.n_layers * c.row_width * jnp.dtype(c.dtype).itemsize
     block = latent_block(positions)
     expert_layers, held = c.n_layers - c.n_dense_layers, c.experts_held
@@ -744,7 +735,7 @@ def make_continuous_decode_fns(
             ) * row_bytes},
             **tally_account(tally, held)}
 
-    return SimpleNamespace(
+    return DecodeContract.decoder_only(
         step=step,
         step_tally_len=expert_layers * held,
         prefill_window=prefill_window,
@@ -754,12 +745,9 @@ def make_continuous_decode_fns(
         cache_kinds={
             "latent": CacheKind(True, written=True, in_place=True)},
         cache_kind_of=lambda path: "latent",
-        first_decode_pos=lambda input_mask: jnp.sum(
-            jnp.asarray(input_mask, jnp.int32)),
-        encoded_shape=(0,),
         step_account=step_account,
-        max_decode_len=int(max_decode_len),
-        eos_id=int(eos_id),
-        pad_id=int(pad_id),
-        max_input_len=int(max_input_len),
+        max_decode_len=max_decode_len,
+        eos_id=eos_id,
+        pad_id=pad_id,
+        max_input_len=max_input_len,
     )

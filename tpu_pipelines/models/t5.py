@@ -21,6 +21,7 @@ import numpy as np
 from flax import linen as nn
 from jax.sharding import Mesh, PartitionSpec as P
 
+from tpu_pipelines.models.decode_contract import CacheKind, DecodeContract
 from tpu_pipelines.models.transformer import (
     TRANSFORMER_PARTITION_RULES,
     TransformerBlock,
@@ -318,37 +319,24 @@ def make_continuous_decode_fns(
     pad_id: int = 0,
     max_input_len: int = 64,
 ):
-    """Decode fns for the continuous-batching engine (serving/generative.py).
+    """T5's ``DecodeContract`` (models/decode_contract.py) for the
+    continuous-batching engine: an encoder-decoder with a whole-prompt
+    ``prefill``.
 
-    Returns a namespace with the engine's duck-typed contract:
-
-      - ``prefill(params, inputs [1, enc_len], input_mask)`` ->
-        ``(cache, encoded, logits0)`` — one request's encoder pass + the
-        cache-creating step-0 decoder pass (``prefill_decode``, the same
-        math greedy/beam step 0 runs);
-      - ``step(params, cache, tok [b], pos [b], encoded, enc_mask, klen)``
-        -> ``(cache, logits [b, V])`` — ONE decode step for a batch whose
-        rows sit at per-row positions ``pos``, over a cache sliced to the
-        static KV bucket ``klen`` (the engine's paged-arena slice; the
-        per-row masking makes the result independent of ``klen`` as long
-        as every live position fits);
-      - geometry/vocabulary constants (``max_decode_len``, ``eos_id``,
-        ``pad_id``, ``max_input_len``) the engine sizes its arena from;
-      - what the engine may not guess: the two kinds of cache array
-        (``cache_kinds``, ``cache_kind_of(path)``: self-attention K/V by
-        decode position, which a step writes, beside the cross-attention
-        K/V at the encoder length, which it only reads), and a
-        sequence's first decode position (``first_decode_pos``: 1, behind
-        the BOS that ``prefill`` consumed).
+      - ``prefill`` is ``prefill_decode``: one request's encoder pass and
+        the cache-creating step-0 decoder pass, the same math greedy and
+        beam step 0 run;
+      - two kinds of cache array: self-attention K/V by decode position,
+        which a step writes and the engine cuts to the kv bucket, beside
+        the cross-attention K/V at the encoder length, which a step only
+        reads;
+      - a sequence's first decode position is 1, behind the BOS that
+        ``prefill`` consumed (the contract's default).
 
     Exported modules opt their payloads into generative serving by
     defining ``make_decode_fns(model, hyperparameters)`` returning this
     (trainer/export.py wires it onto ``LoadedModel.decode_fns``).
     """
-    from types import SimpleNamespace
-
-    from tpu_pipelines.serving.generative import CacheKind
-
     def cache_kind_of(path) -> str:
         # models/transformer.py names the cross-attention K/V
         # ``cached_enc_key`` / ``cached_enc_value``.
@@ -372,7 +360,7 @@ def make_continuous_decode_fns(
         with jax.named_scope("embed_head"):
             return mut["cache"], logits[:, 0]
 
-    return SimpleNamespace(
+    return DecodeContract(
         prefill=prefill,
         step=step,
         cache_kinds={
@@ -380,11 +368,10 @@ def make_continuous_decode_fns(
             "cross_kv": CacheKind(by_position=False, written=False),
         },
         cache_kind_of=cache_kind_of,
-        first_decode_pos=lambda input_mask: 1,
-        max_decode_len=int(max_decode_len),
-        eos_id=int(eos_id),
-        pad_id=int(pad_id),
-        max_input_len=int(max_input_len),
+        max_decode_len=max_decode_len,
+        eos_id=eos_id,
+        pad_id=pad_id,
+        max_input_len=max_input_len,
     )
 
 

@@ -32,7 +32,7 @@ Dtype = Any
 # "auto" attn_impl switchover is MEASURED where a measurement exists and
 # memory-feasibility-bounded always (choose_attn_impl): the autotune table
 # (ops/autotune.py) stores a per-device flash-vs-dense crossover sequence
-# length recorded by the bench flash_probe sweep — dense below it, flash
+# length (``autotune.record_crossover``) — dense below it, flash
 # at/above it.  With no recorded crossover the rule degrades to the
 # feasibility estimate alone: dense wherever its O(L^2) score temporaries
 # fit in HBM (XLA fuses the fwd score/softmax chain well; which of the two
@@ -91,7 +91,7 @@ def dense_attn_expected_temp_bytes(
     """Calibrated estimate of dense attention's O(L^2) XLA temporaries
     (per shard when a mesh divides batch over ``data`` / heads over
     ``model``).  Exposed so callers that must *skip* a dense compile
-    cleanly (the bench OOM precheck) can record the number they acted on
+    cleanly can record the number they acted on
     instead of depending on a backend error string."""
     if mesh is not None:
         shape = dict(mesh.shape)
@@ -152,8 +152,8 @@ def choose_attn_impl(
          ring's per-hop latency only pays for itself once L is large;
       1. dense's O(L^2) temporaries don't fit => "flash" (the guard —
          feasibility, exactly what ``dense_attn_fits`` was built for);
-      2. a measured crossover exists for this device_kind (recorded by
-         the bench flash_probe sweep via ``autotune.record_crossover``)
+      2. a measured crossover exists for this device_kind
+         (``autotune.record_crossover``)
          => "flash" at/above it, "dense" below it;
       3. no measurement => "dense" (every probe so far measured dense
          faster wherever it fits; flash must EARN the hot path).
@@ -191,8 +191,7 @@ def choose_decode_impl(
     is no OOM guard here; the only question is measured speed.  The
     decode step streams the whole KV cache per token, a bandwidth-bound
     profile unlike the training shapes, so it gets its OWN crossover
-    (``autotune.lookup_decode_crossover``, recorded by the bench
-    ``t5_decode`` leg): flash-decode at/above the measured cache length,
+    (``autotune.lookup_decode_crossover``): flash-decode at/above the measured cache length,
     dense below it, and dense whenever no measurement exists — the
     kernel must earn the hot path, same as training flash (PR 9).
     """
@@ -402,7 +401,7 @@ class MultiHeadAttention(nn.Module):
         O(L²) score tensor in HBM, fwd and bwd.
       - "auto":  measured flash-vs-dense choice (choose_attn_impl): dense
         below the device's recorded crossover sequence length (autotune
-        table, written by the bench flash_probe sweep), flash at/above
+        table), flash at/above
         it, and always flash when dense's O(L²) score temporaries cannot
         fit (dense_attn_fits stays as the OOM guard).  With no recorded
         crossover: dense wherever it fits (flash's unconditional win is

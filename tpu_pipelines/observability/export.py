@@ -12,8 +12,8 @@ Two consumers of ``events.jsonl`` (see trace.py for the event schema):
     critical path (longest upstream chain by scheduler-span durations),
     queue/tpu-gate wait totals, cache-hit ratio, executor/publish phase
     totals, metadata-op latencies, per-pool shard skew, and the bridged
-    goodput summary.  ``bench.py`` reports these instead of wall-clock
-    guesses; the cluster runner attaches them as template annotations.
+    goodput summary.  The cluster runner attaches them as template
+    annotations.
 
 Both readers are truncation-tolerant: a crashed run's final line may be
 half-written, and :func:`read_events` silently skips anything that does
@@ -472,8 +472,8 @@ def diff_metrics(
     than ``threshold`` slower AND the absolute growth exceeds
     ``min_abs_s`` (relative thresholds alone flag microsecond noise on
     tiny nodes).  Inputs are duck-typed: any dict carrying ``per_node``
-    and the headline keys works, so bench summaries diff as well as full
-    metrics.json payloads.
+    and the headline keys works, so a metrics-history headline diffs as
+    well as a full metrics.json payload.
     """
     nodes_a = run_a.get("per_node") or {}
     nodes_b = run_b.get("per_node") or {}

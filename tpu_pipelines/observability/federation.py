@@ -338,7 +338,7 @@ def _extend_labels(
 class FederatedRegistry:
     """Scrape-time aggregator over the local registry + the spool.
 
-    Duck-types the surface ``MetricsServer`` and bench scrape helpers
+    Duck-types the surface ``MetricsServer`` and scrape helpers
     use (``to_prometheus()``/``snapshot()``), so
     ``start_http_server(registry=FederatedRegistry(...))`` turns the
     existing opt-in metrics port into the fleet-wide endpoint.  Sources

@@ -546,7 +546,7 @@ def histogram_quantile(
     """Estimate quantile ``q`` from one histogram series snapshot
     (``{"buckets": [...], "sum": s, "count": n}``) by linear
     interpolation within the landing bucket — the PromQL
-    ``histogram_quantile`` estimator, usable offline by bench.py."""
+    ``histogram_quantile`` estimator, usable offline on a scrape."""
     count = hist_series.get("count", 0)
     if not count:
         return None

@@ -106,7 +106,7 @@ def _hist_totals(
 class SLOMonitor:
     """Multi-window burn rates over a :class:`MetricsRegistry`.
 
-    ``evaluate()`` is the whole engine (tests and the bench drill call
+    ``evaluate()`` is the whole engine (tests call
     it directly with a controlled clock); ``start(interval_s)`` runs it
     on a daemon thread.  ``on_breach(info)`` fires edge-triggered per
     SLO: once on the rising edge, re-armed when every window of that SLO
@@ -201,7 +201,7 @@ class SLOMonitor:
         )
         # Decode-speed lever counters (informational, not burn inputs):
         # windowed deltas let an operator read the prefix-hit rate off the
-        # same evaluate() table the bench drill records as evidence.
+        # same evaluate() table.
         prefix_hits = sum(
             int(v) for v in series("serving_decode_prefix_hit_total").values()
         )
@@ -270,7 +270,7 @@ class SLOMonitor:
     def evaluate(self, now: Optional[float] = None) -> Dict[str, Any]:
         """One evaluation pass: collect, compute every (window, slo)
         burn rate, publish gauges, fire edge-triggered breaches.
-        Returns the full result table (the bench drill's evidence)."""
+        Returns the full result table."""
         now = time.monotonic() if now is None else float(now)
         cur = self._collect()
         with self._lock:

@@ -303,8 +303,8 @@ def record_decode_crossover(
     The decode regime (single-query attention against the KV cache during
     autoregressive generation) is bandwidth-bound on streaming the cache,
     a different balance from the training shapes — so it carries its own
-    crossover, recorded by the bench ``t5_decode`` leg and consulted by
-    ``models/transformer.py choose_decode_impl``.  ``None`` means "dense
+    crossover, consulted by ``models/transformer.py choose_decode_impl``
+    (nothing records one since the leg that swept it went: ROADMAP D6).  ``None`` means "dense
     won at every measured cache length" (measured-no-crossover, distinct
     from never-measured)."""
 

@@ -70,7 +70,7 @@ def survivor_configs(
     coordinator_address: str = "",
 ) -> list:
     """Re-form the process topology after losing hosts: the elastic-resume
-    bootstrap (PERFORMANCE.md "Multi-chip window").
+    bootstrap (docs/TUTORIAL.md §7).
 
     jax's coordination service cannot shrink in place — the driver
     restarts the job on the survivors with a re-derived topology.  This is

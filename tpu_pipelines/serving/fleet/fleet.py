@@ -4,7 +4,7 @@ One fleet = one model name served by ``replicas`` workers (each its own
 micro-batcher, optionally its own device) over a shared
 :class:`ModelVersionManager`.  The REST/gRPC surfaces stay on
 ``ModelServer``; in fleet mode its ``predict_batch``/``reload`` simply
-delegate here, so canaries, tests, and the bench hammer exercise the
+delegate here, so canaries and tests exercise the
 identical request path single-server deployments use.
 
 Canary gating: the fleet remembers the first feature batch it serves and

@@ -15,14 +15,7 @@ static-shape substrate):
   * **Arena.**  One device-resident state pool sized ``max_batch_size``:
     the decode cache, per-slot last token, position, live flag, encoder
     output and mask.  What the cache's arrays are is the contract's to
-    say (``CacheKind``): an encoder-decoder's self-attention K/V at
-    ``max_decode_len`` beside its cross-attention K/V at the encoder
-    length; a windowed decoder's ring of exact positions beside its
-    table of chunk summaries, neither indexed by decode position; a
-    latent-attention decoder's one row of latents a position, indexed
-    by position from the prompt's first token on (``cache_positions``);
-    a decoder whose layers differ in kind, rings of a window's keys and
-    values in three layers of four and every position's in the fourth.
+    say (``DecodeContract.cache_kinds``, models/decode_contract.py).
     Live sequences occupy the compacted prefix ``[0, n_live)``; a
     departure moves the last live
     row into the hole (one scatter), an arrival lands at ``n_live`` (one
@@ -92,7 +85,7 @@ static-shape substrate):
     slot for work that can still meet SLO.
 
 Decode optimisations (ISSUE 16) — two composable levers behind the
-same ``make_decode_fns`` contract, each off by default.  (A third,
+same ``DecodeContract``, each off by default.  (A third,
 speculative decoding on a second, mirrored arena, only ever drafted with
 the target itself and was removed; a real draft comes back as proposals
 from the served model's own extra heads on the one arena, ROADMAP R7.)
@@ -159,6 +152,7 @@ from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
+from tpu_pipelines.models.decode_contract import CacheKind, DecodeContract
 from tpu_pipelines.serving.batching import (
     bucket_sizes,
     token_deadline_s,
@@ -204,38 +198,13 @@ PROGRAM_NAMES = (
 WINDOW_PROGRAM_NAME = "jit_prefill_window"
 
 
-class CacheKind(NamedTuple):
-    """What a decode contract states about one kind of cache array
-    (``fns.cache_kinds[fns.cache_kind_of(path)]``), every array being
-    ``[slots, entries, ...]``.
-
-    ``by_position``: axis 1 is the decode position.  A step's
-    ``(b, kv)`` bucket is then the first ``kv`` entries of the first
-    ``b`` rows, and entries at or past a row's position hold nothing
-    (nothing wrote them).  (An ``in_place`` array is never cut: the
-    engine indexes its slot axis alone, ``kv`` reaches the step as a
-    number, and the axes behind the slot may lie as the step reads
-    them.)  Otherwise what is valid in a row is the
-    contract's own business, and a step is handed its ``b`` rows whole.
-    ``written``: a step returns the array changed, and the engine sets it
-    back into the arena; otherwise a step only reads it.
-    ``in_place``: a step is handed the array of EVERY slot, reads and
-    writes the first ``b`` rows where they lie and returns the array: no
-    bucket is cut out and none set back.  For arrays too large to copy
-    a bucket of at every step."""
-
-    by_position: bool
-    written: bool
-    in_place: bool = False
-
-
 # A contract that states nothing: every array is K/V by decode position.
 _POSITION_KV = CacheKind(by_position=True, written=True)
 
 
-def _kind_reader(fns):
+def _kind_reader(fns: DecodeContract):
     """``path -> CacheKind`` for one contract's cache leaves."""
-    kinds = getattr(fns, "cache_kinds", None)
+    kinds = fns.cache_kinds
     if kinds is None:
         return lambda path: _POSITION_KV
     return lambda path: kinds[fns.cache_kind_of(path)]
@@ -584,9 +553,9 @@ class PrefixCache:
 class GenerativeEngine:
     """One continuous-batching decode engine over one (model, params).
 
-    ``fns`` is the duck-typed decode contract (see
-    ``models/t5.py make_continuous_decode_fns``): ``prefill``/``step``
-    plus geometry constants.  The engine owns a single worker thread; all
+    ``fns`` is the model's ``DecodeContract``
+    (models/decode_contract.py): its programs, what its cache's arrays
+    are, its accounts and its geometry.  The engine owns a single worker thread; all
     device work — prefill, bucketed steps, arena scatters — happens
     there, so the jit-compiled programs never race.  ``submit`` blocks
     like ``RequestBatcher.submit``; ``submit_nowait`` returns a handle
@@ -599,7 +568,7 @@ class GenerativeEngine:
 
     def __init__(
         self,
-        fns,
+        fns: DecodeContract,
         params,
         *,
         max_batch_size: int = 8,
@@ -621,10 +590,10 @@ class GenerativeEngine:
         self._fault_hook = fault_hook
         self.fns = fns
         self.params = params
-        self.max_decode_len = int(fns.max_decode_len)
-        self.eos_id = int(fns.eos_id)
-        self.pad_id = int(fns.pad_id)
-        self.max_input_len = int(getattr(fns, "max_input_len", 64))
+        self.max_decode_len = fns.max_decode_len
+        self.eos_id = fns.eos_id
+        self.pad_id = fns.pad_id
+        self.max_input_len = fns.max_input_len
         self.max_batch_size = max(1, int(max_batch_size))
         self.page_size = int(page_size)
         self.max_queue_tokens = max(0, int(max_queue_tokens))
@@ -637,7 +606,7 @@ class GenerativeEngine:
         # (``cache_positions``), and a row's depth then counts from the
         # prompt's first token (``_depth``); otherwise the cache begins
         # behind a BOS and holds the emitted tokens alone.
-        positions = getattr(fns, "cache_positions", None)
+        positions = fns.cache_positions
         self._prompt_cached = positions is not None
         positions = int(positions or self.max_decode_len)
         self.kv_buckets = kv_bucket_sizes(positions, self.page_size)
@@ -646,7 +615,7 @@ class GenerativeEngine:
         )
         # A contract prefilled by window (``prefill_window``) has no
         # whole-prompt prefill program; 0 = a whole-prompt ``prefill``.
-        self._window_len = int(getattr(fns, "prefill_window_len", 0))
+        self._window_len = fns.prefill_window_len
         # Prompt-side page unit (prefix hashing + admission credits): one
         # prefill window, else the configured page size, or the whole
         # prompt when unpaged.
@@ -659,12 +628,12 @@ class GenerativeEngine:
             raise ValueError(
                 "a contract prefilled by window takes no prefix cache"
             )
-        self._account = getattr(fns, "step_account", None)
-        self._window_account = getattr(fns, "window_account", None)
+        self._account = fns.step_account
+        self._window_account = fns.window_account
         # What ``insert`` is handed as a row's encoder output where the
         # contract has no whole-prompt prefill to return one.
         self._no_encoded = np.zeros(
-            (1,) + tuple(getattr(fns, "encoded_shape", (0,))), np.float32
+            (1,) + tuple(fns.encoded_shape), np.float32
         )
         self.prefix_cache_entries = max(0, int(prefix_cache_entries))
         self._prefix = (
@@ -743,10 +712,6 @@ class GenerativeEngine:
         import jax.numpy as jnp
 
         fns = self.fns
-        # A sequence's first decode position, from its prompt's mask
-        # [1, max_input_len]: 1 (behind a BOS at 0) unless the contract
-        # states otherwise.
-        first_pos = getattr(fns, "first_decode_pos", lambda input_mask: 1)
 
         def first_token(logits):
             with jax.named_scope("sample"):
@@ -767,7 +732,7 @@ class GenerativeEngine:
                 return (
                     cache,
                     tok.at[slot].set(tok0),
-                    pos.at[slot].set(first_pos(enc_mask)),
+                    pos.at[slot].set(fns.first_decode_pos(enc_mask)),
                     live.at[slot].set(True),
                     enc.at[slot].set(encoded[0].astype(enc.dtype)),
                     mask.at[slot].set(jnp.asarray(enc_mask[0], mask.dtype)),
@@ -793,7 +758,7 @@ class GenerativeEngine:
                     mask,
                 )
 
-        if hasattr(fns, "prefill"):
+        if fns.prefill is not None:
             self._jit_prefill = _jit_program(prefill)
         self._jit_insert = _jit_program(insert)
         self._jit_move = _jit_program(move)
@@ -822,7 +787,7 @@ class GenerativeEngine:
         # experts the row chose; ``step_tally_len`` of them): summed over
         # the live rows here, read with the tokens, handed to
         # ``step_account``.
-        tallied = bool(getattr(fns, "step_tally_len", 0))
+        tallied = bool(fns.step_tally_len)
 
         def run(params, state):
             cache, tok, pos, live, encoded, enc_mask = state

@@ -48,7 +48,6 @@ from tpu_pipelines.observability.metrics import (
     MetricsRegistry,
 )
 from tpu_pipelines.observability.request_trace import RequestTracer
-from tpu_pipelines.testing import faults as _faults
 from tpu_pipelines.trainer.export import LoadedModel, load_exported_model
 
 log = logging.getLogger("tpu_pipelines.serving")
@@ -782,9 +781,6 @@ class ModelServer:
                         self._trace_code = 0
                         trace_token = request_trace.push(ctx)
                 try:
-                    # Fault hook (RELOAD_DURING_HAMMER): a no-op global
-                    # read unless a test plan is active.
-                    _faults.serving_request(server, endpoint)
                     if endpoint != "reload":
                         server._admit(endpoint)
                         admitted = True

@@ -23,15 +23,9 @@ fleet produces, at the exact runner phase where they occur:
   TRANSIENT_EXECUTOR_  inside the executor attempt: an explicitly-
   ERROR                classified TransientError, ``times`` times, then
                        clean — the classified-retry-with-backoff bait
-  KILL_SHARD_WORKER    inside a ShardPlan fork child (key ``SHARD_KEY``):
-                       os._exit, the preempted-worker shape the pool's
-                       replacement-worker path must absorb
   STORE_CONTENTION     inside a store write transaction (key
                        ``STORE_KEY``): transient StoreUnavailableError,
                        ``times`` times — multi-writer SQLITE_BUSY shape
-  RELOAD_DURING_HAMMER per serving request (key ``SERVING_KEY``): after
-                       the ``after``-th request, hot-reload the model in
-                       a background thread mid-storm
   ==================== =====================================================
 
 The crash kinds raise :class:`SimulatedCrash` — a ``BaseException`` so no
@@ -67,9 +61,7 @@ KILL_ORCHESTRATOR = "kill_orchestrator"
 # Robustness-layer kinds (ISSUE 7): the failure modes the unified
 # fault-tolerance layer must absorb rather than surface.
 TRANSIENT_EXECUTOR_ERROR = "transient_executor_error"  # classified-retry bait
-KILL_SHARD_WORKER = "kill_shard_worker"    # SIGKILL-equivalent in a fork child
 STORE_CONTENTION = "store_contention"      # transient StoreUnavailableError
-RELOAD_DURING_HAMMER = "reload_during_hammer"  # hot-swap mid-request-storm
 # Fleet-supervision kinds (ISSUE 17): the per-replica failure modes the
 # ReplicaSupervisor/failover layer must absorb (plan key ``REPLICA_KEY``).
 KILL_REPLICA = "kill_replica"      # latched death until rebuild (generation)
@@ -78,8 +70,6 @@ DEVICE_ERROR = "device_error"      # transient device fault, `times` times
 
 # Sentinel plan keys for faults that are not tied to a pipeline node.
 STORE_KEY = "__store__"
-SHARD_KEY = "__shards__"
-SERVING_KEY = "__serving__"
 REPLICA_KEY = "__replica__"
 
 # kind -> the runner phase whose hook triggers it.
@@ -90,9 +80,7 @@ _KIND_TO_POINT = {
     CRASH_BEFORE_PUBLISH: "before_publish",
     CRASH_AFTER_PUBLISH: "after_publish",
     KILL_ORCHESTRATOR: "at_dispatch",
-    KILL_SHARD_WORKER: "in_shard",
     STORE_CONTENTION: "store_op",
-    RELOAD_DURING_HAMMER: "serving_request",
     KILL_REPLICA: "replica_predict",
     WEDGE_PREDICT: "replica_predict",
     DEVICE_ERROR: "replica_predict",
@@ -129,11 +117,8 @@ class NodeFault:
     # TRANSIENT_EXECUTOR_ERROR / STORE_CONTENTION: fail N attempts, then
     # succeed — the shape a classified retry policy must absorb).
     times: int = 1
-    # KILL_SHARD_WORKER: which shard index of the fanned-out pool dies.
-    shard: int = 0
-    # RELOAD_DURING_HAMMER / KILL_REPLICA: fire once the Nth request has
-    # arrived (so the hammer is demonstrably in flight when the swap or
-    # kill happens).
+    # KILL_REPLICA: fire once the Nth request has arrived (so the hammer
+    # is demonstrably in flight when the kill happens).
     after: int = 1
     # Replica-fault targeting (KILL_REPLICA / WEDGE_PREDICT /
     # DEVICE_ERROR): which replica name the fault applies to; "" = the
@@ -144,11 +129,6 @@ class NodeFault:
     release: threading.Event = dataclasses.field(
         default_factory=threading.Event, compare=False
     )
-    # KILL_SHARD_WORKER cross-process once-token: fork children inherit a
-    # COPY of the plan's fired-set, so in-memory once-semantics cannot
-    # span the pool — the first child to atomically create this file is
-    # the one that dies.  Auto-assigned at activate() when left empty.
-    once_file: str = ""
 
     def __post_init__(self):
         if self.kind not in _KIND_TO_POINT:
@@ -171,13 +151,11 @@ class FaultPlan:
     def __init__(self, faults: Dict[str, NodeFault]):
         self.faults = dict(faults)
         self._fired: Dict[str, int] = {}
-        self._requests = 0  # serving_request arrivals (RELOAD_DURING_HAMMER)
         self._replica_calls = 0   # replica_predict arrivals (KILL_REPLICA)
         # KILL_REPLICA latch: replica name -> the generation that died.
         # Every call from that (replica, generation) fails; the rebuild
         # bumps the generation, so the rebuilt incarnation runs clean.
         self._killed: Dict[str, int] = {}
-        self._pid = None    # set at activate(): detects fork children
         self._lock = threading.Lock()
         self.log: List[Tuple[str, str]] = []
 
@@ -201,32 +179,13 @@ class FaultPlan:
     @contextmanager
     def activate(self):
         """Install this plan for the duration of the block (test-only)."""
-        import os
-        import tempfile
-
         global _ACTIVE
         prev = _ACTIVE
-        self._pid = os.getpid()
-        tokens: List[str] = []
-        for fault in self.faults.values():
-            if fault.kind == KILL_SHARD_WORKER and not fault.once_file:
-                # Reserve a name only — the first shard child to O_EXCL-
-                # create it wins the kill; parent cleans up afterwards.
-                fault.once_file = os.path.join(
-                    tempfile.gettempdir(),
-                    f"tpp-fault-{os.getpid()}-{id(fault)}.token",
-                )
-                tokens.append(fault.once_file)
         _ACTIVE = self
         try:
             yield self
         finally:
             _ACTIVE = prev
-            for token in tokens:
-                try:
-                    os.unlink(token)
-                except OSError:
-                    pass
 
 
 _ACTIVE: Optional[FaultPlan] = None
@@ -298,44 +257,6 @@ def after_publish(node_id: str) -> None:
         raise SimulatedCrash(node_id, "after_publish")
 
 
-def in_shard(shard_index: int) -> None:
-    """Inside a ShardPlan pool worker, before the real per-shard fn.
-
-    KILL_SHARD_WORKER (plan key ``SHARD_KEY``): the matching shard's
-    worker dies with ``os._exit`` — a SIGKILL-equivalent the pool
-    observes as BrokenProcessPool, forcing the replacement-worker path.
-    Cross-process once-semantics ride the fault's ``once_file`` token
-    (fork children inherit plan COPIES, so in-memory state cannot span
-    the pool).  In a same-process fallback pool (threads/sequential) the
-    fault degrades to a TransientError raise: killing the interpreter
-    would take the whole run (and the test) with it.
-    """
-    import os
-
-    plan = _ACTIVE
-    if plan is None:
-        return
-    fault = plan.faults.get(SHARD_KEY)
-    if fault is None or fault.kind != KILL_SHARD_WORKER:
-        return
-    if shard_index != fault.shard or not fault.once_file:
-        return
-    try:
-        fd = os.open(fault.once_file, os.O_CREAT | os.O_EXCL | os.O_WRONLY)
-        os.close(fd)
-    except OSError:
-        return  # another worker (or a prior attempt) already fired
-    if plan._pid is not None and os.getpid() != plan._pid:
-        # Fork child: die the way a preempted/OOM-killed worker does.
-        os._exit(3)
-    from tpu_pipelines.robustness.errors import TransientError
-
-    plan.record(SHARD_KEY, "kill_shard_worker_inline")
-    raise TransientError(
-        f"{fault.message} (same-process pool: raised instead of killed)"
-    )
-
-
 def store_op(op: str) -> None:
     """Inside a MetadataStore write transaction, before the commit.
 
@@ -354,34 +275,6 @@ def store_op(op: str) -> None:
     from tpu_pipelines.metadata.store import StoreUnavailableError
 
     raise StoreUnavailableError(fault.message)
-
-
-def serving_request(server, endpoint: str) -> None:
-    """Per request on the ModelServer's hot endpoints.
-
-    RELOAD_DURING_HAMMER (plan key ``SERVING_KEY``): once the ``after``-th
-    request has arrived — i.e. the hammer is demonstrably in flight — a
-    background thread calls ``server.reload()``, so the zero-5xx
-    reload-under-load guarantee is exercised mid-storm rather than
-    between requests.
-    """
-    plan = _ACTIVE
-    if plan is None:
-        return
-    fault = plan.faults.get(SERVING_KEY)
-    if fault is None or fault.kind != RELOAD_DURING_HAMMER:
-        return
-    with plan._lock:
-        plan._requests += 1
-        n = plan._requests
-    if n < max(1, fault.after):
-        return
-    if plan._take(SERVING_KEY, "serving_request") is None:
-        return
-    plan.record(SERVING_KEY, f"reload_during_hammer:{endpoint}")
-    threading.Thread(
-        target=server.reload, name="tpp-fault-reload", daemon=True
-    ).start()
 
 
 def replica_predict(replica_name: str, generation: int = 0) -> None:

@@ -28,6 +28,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
+from tpu_pipelines.models.decode_contract import DecodeContract
 from tpu_pipelines.trainer import quantize as qz
 from tpu_pipelines.transform.graph import TransformGraph
 from tpu_pipelines.utils.module_loader import load_fn, load_module
@@ -189,12 +190,12 @@ class LoadedModel:
     # ``make_generate_fn(model, params, hyperparameters)``.  ``generate``
     # takes raw batches (host transform applied first); None otherwise.
     generate: Optional[Callable[[Dict[str, np.ndarray]], Any]] = None
-    # Continuous-batching decode contract (serving/generative.py): present
+    # The model's ``DecodeContract`` (models/decode_contract.py): present
     # when the exported module defines ``make_decode_fns(model,
-    # hyperparameters)`` (e.g. ``models/t5.py make_continuous_decode_fns``)
-    # — prefill/step + geometry the generative fleet model type builds its
-    # per-replica engines from.  None = whole-request generate only.
-    decode_fns: Any = None
+    # hyperparameters)`` (e.g. ``models/t5.py make_continuous_decode_fns``);
+    # what the generative fleet model type builds its per-replica engines
+    # from.  None = whole-request generate only.
+    decode_fns: Optional[DecodeContract] = None
     # The two halves of `predict`, exposed for exporters (serving/
     # saved_model.py): host string stage (numpy, identity when no transform)
     # and the device computation (numeric transform fused with the forward

@@ -127,7 +127,7 @@ class TrainResult:
     goodput: float = 0.0
     goodput_source: str = "host_input_wait_proxy"
     # Goodput over the post-compile window only (1 - input-wait/elapsed,
-    # both measured after step 1 retires).  At bench scale the strict
+    # both measured after step 1 retires).  On a short run the strict
     # figure above is dominated by one-time compile; this one is the
     # steady-state number a long run would converge to.
     goodput_post_compile: float = 0.0

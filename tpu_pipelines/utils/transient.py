@@ -3,9 +3,9 @@
 One list, one predicate: a run can flake with network-shaped failures
 (a reset connection, a deadline, an unavailable service); retrying those
 is worth chip time, retrying deterministic failures (ImportError, shape
-errors, OOM, XLA compile bugs) is not.  bench.py and the Evaluator's batch
-loop both classify with THIS helper so a newly observed flake signature
-added here changes both at once.
+errors, OOM, XLA compile bugs) is not.  The Evaluator's batch loop
+classifies with this helper, so a newly observed flake signature is added
+here.
 
 Classification is two-tier (bare substrings like ``internal`` also match
 deterministic ``INTERNAL: ...`` XLA compile bugs, so the Evaluator's retry
